@@ -14,10 +14,13 @@ single-chip measurement):
 - ``sharded_reduce`` — Reduce_Mesh (keyed per-batch reduce): shuffle +
   segmented combine + per-slot harvest.
 
-On a CPU backend it forces the virtual 8-device mesh the test suite
-uses (``windflow_tpu.mesh.ensure_virtual_devices`` — no hand-rolled
-XLA_FLAGS); on a real TPU it uses however many chips exist. Prints ONE
-JSON line: tuples/s, windows/s, shuffle bytes/s, mesh shape, platform.
+Its own command: one process owning all chips of the host (bench.py
+starts no child). It uses however many chips ``jax.devices()`` gives and
+exits non-zero when they are not TPUs. The exception is a caller that
+set ``JAX_PLATFORMS=cpu``: the run then forces the virtual 8-device CPU
+mesh the test suite uses (``windflow_tpu.mesh.ensure_virtual_devices``)
+and every metric name ends in `` (cpu)``. Prints ONE JSON line:
+tuples/s, windows/s, shuffle bytes/s, mesh shape, platform, device_kind.
 """
 
 import json
@@ -29,8 +32,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from windflow_tpu.mesh import ensure_virtual_devices  # noqa: E402
 
-if os.environ.get("JAX_PLATFORMS", "") == "cpu" \
-        or os.environ.get("WF_MESH_BENCH_CPU") == "1":
+if os.environ.get("JAX_PLATFORMS", "") == "cpu":
     ensure_virtual_devices()
 
 N_KEYS = 64
@@ -93,7 +95,6 @@ def _drive(rep, batches, state_leaf):
 
 
 def main() -> None:
-    import jax
     import numpy as np
 
     import bench
@@ -102,8 +103,9 @@ def main() -> None:
     from windflow_tpu.mesh.ops_mesh import Map_Mesh, Reduce_Mesh
     from windflow_tpu.tpu.schema import TupleSchema
 
-    platform = jax.devices()[0].platform
-    n_dev = len(jax.devices())
+    devices = bench.chip_devices("bench_mesh")
+    platform = devices[0].platform
+    n_dev = len(devices)
     schema = TupleSchema({"key": np.int32, "value": np.float32})
     n_total = REPEATS * N_BATCHES + WARMUP
 
@@ -132,6 +134,7 @@ def main() -> None:
         "device_programs": rep.stats.device_programs_run,
         "shuffle_bytes_total": rep.stats.mesh_shuffle_bytes,
         "platform": platform,
+        "device_kind": devices[0].device_kind,
         "n_devices": n_dev,
         "throughput_aggregation": f"mean-of-{REPEATS}-chunks",
     }

@@ -1,5 +1,5 @@
 """Pallas forest-rebuild kernel, validated with the interpreter on CPU
-(the same kernel compiles for real TPUs under WF_PALLAS=1)."""
+(chip_smoke.py stage C compiles the same kernel with Mosaic on a TPU)."""
 
 import numpy as np
 import pytest
@@ -33,7 +33,10 @@ def _numpy_rebuild(vals, valid, combine):
     return out, ov
 
 
-@pytest.mark.parametrize("F,K", [(8, 8), (32, 16), (64, 8)])
+# (8, 4): fewer keys than one 128-lane row packs; (128, 8): a tree wider
+# than one row
+@pytest.mark.parametrize("F,K", [(8, 8), (32, 16), (64, 8), (8, 4),
+                                 (128, 8)])
 def test_forest_rebuild_matches_oracle(F, K):
     combine = lambda a, b: {"v": a["v"] + b["v"]}
     rng = np.random.default_rng(F * K)
@@ -50,6 +53,23 @@ def test_forest_rebuild_matches_oracle(F, K):
     assert (got_valid[:, 1:] == expv[:, 1:]).all()
     live = expv[:, 1:]
     assert (got_v[:, 1:][live] == exp["v"][:, 1:][live]).all()
+
+
+@pytest.mark.parametrize("F,K", [(8, 64), (32, 256), (128, 16)])
+def test_forest_rebuild_bit_equal_to_xla(F, K):
+    """Every lane — node 0, stale internal nodes, invalid nodes' values,
+    leaves — equals the XLA rebuild the operator otherwise runs."""
+    from windflow_tpu.tpu.ffat_tpu import xla_rebuild_levels
+    combine = lambda a, b: {"v": a["v"] * 3 - b["v"]}  # order-sensitive
+    rng = np.random.default_rng(F + K)
+    trees = {"v": jnp.asarray(
+        rng.integers(-50, 50, (K, 2 * F)).astype(np.int32))}
+    valid = rng.random((K, 2 * F)) < 0.5  # stale internal flags too
+    got_t, got_v = make_forest_rebuild(combine, ["v"], F, interpret=True)(
+        trees, jnp.asarray(valid))
+    want_t, want_v = xla_rebuild_levels(combine, F)(trees, jnp.asarray(valid))
+    assert (np.asarray(got_v) == np.asarray(want_v)).all()
+    assert (np.asarray(got_t["v"]) == np.asarray(want_t["v"])).all()
 
 
 def test_forest_rebuild_multifield_noncommutative():

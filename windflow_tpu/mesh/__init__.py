@@ -6,7 +6,7 @@ What lives here (absorbing the old ``parallel/mesh.py`` bolt-on):
 - ``core``: the collective primitives — ``('key','data')`` mesh
   construction, the in-program bucket-by-owner + ``lax.all_to_all``
   KEYBY shuffle, the sharded FlatFAT forest, the flat-owner grid-scan
-  and keyed-reduce step builders, and the jax compat seam
+  and keyed-reduce step builders, and the ``shard_map`` seam
   (``wf_shard_map``/``pvary_fn``);
 - ``ffat_mesh``: ``Ffat_Windows_Mesh`` — keyed sliding windows sharded
   over the mesh, with sharded snapshot/restore;

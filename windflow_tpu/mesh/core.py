@@ -73,37 +73,19 @@ def healthy_devices():
 
 
 def wf_shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``shard_map`` across the jax generations this repo runs on: the
-    stable ``jax.shard_map`` (``check_vma``) when it exists, else the
-    ``jax.experimental.shard_map`` of the 0.4.x line (``check_rep`` —
-    the same switch under its pre-rename name). One definition so every
-    mesh program builds through the same compat seam."""
+    """``jax.shard_map`` with this repo's argument order — the one seam
+    every mesh program builds through."""
     import jax
 
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        try:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=check_vma)
-        except TypeError:  # stable API before the check_rep rename
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
-    from jax.experimental.shard_map import shard_map as sm_exp
-    return sm_exp(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_vma)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def pvary_fn(axes):
-    """``lax.pcast(..., to="varying")`` when the running jax has the
-    varying-axis type system; identity on older jax (whose shard_map
-    rep-checking predates pcast — the call sites there run with
-    ``check_vma=False``, where the cast is a no-op anyway)."""
+    """Cast to varying over ``axes`` (shard_map's varying-axis types)."""
     from jax import lax
 
-    pc = getattr(lax, "pcast", None)
-    if pc is not None:
-        return lambda a: pc(a, axes, to="varying")
-    return lambda a: a
+    return lambda a: lax.pcast(a, axes, to="varying")
 
 
 def default_ring_panes(win_panes: int, slide_panes: int,
@@ -121,7 +103,9 @@ def make_key_mesh(n_devices: int, shape=None):
     """Largest 2D ('key', 'data') mesh for n devices (data axis >= 1).
     ``shape=(ka, da)`` forces an explicit factorization (result invariance
     under mesh reshape is a correctness property — tests exercise 8x1 /
-    4x2 / 2x4 over the same stream)."""
+    4x2 / 2x4 over the same stream). Asking for more devices than exist
+    raises; only health exclusions (degraded recovery) shrink a mesh
+    below what was asked."""
     import jax
     from jax.sharding import Mesh
 
@@ -138,7 +122,13 @@ def make_key_mesh(n_devices: int, shape=None):
             return make_key_mesh(len(alive))
         arr = np.array(alive[:ka * da]).reshape(ka, da)
         return Mesh(arr, ("key", "data"))
-    n_devices = max(1, min(int(n_devices), len(alive)))
+    n_devices = max(1, int(n_devices))
+    if n_devices > len(alive):
+        if not _EXCLUDED_DEVICE_IDS:
+            raise ValueError(f"mesh needs {n_devices} devices, "
+                             f"have {len(alive)}")
+        # degraded recovery: rebuild over the survivors
+        n_devices = len(alive)
     devs = alive[:n_devices]
     ka = n_devices
     da = 1

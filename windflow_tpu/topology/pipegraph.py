@@ -97,11 +97,10 @@ class PipeGraph:
         # dead-letter queue (windflow_tpu.supervision.errors): created
         # lazily when any operator carries a quarantining error policy
         self._dlq = None
-        # JAX persistent compilation cache (WF_COMPILE_CACHE_DIR /
-        # with_compile_cache): supervised restarts and rescales re-use
-        # compiled chain programs instead of re-tracing from scratch
-        self._compile_cache_dir: Optional[str] = \
-            os.environ.get("WF_COMPILE_CACHE_DIR") or None
+        # JAX persistent compilation cache (with_compile_cache; placement
+        # rule in runtime/compile_cache.py): supervised restarts and
+        # rescales re-use compiled chain programs instead of recompiling
+        self._compile_cache_dir: Optional[str] = None
         # overload protection (windflow_tpu.overload): with_slo(p99_ms)
         # or WF_SLO_P99_MS attach an OverloadGovernor control loop at
         # start() — SLO-breach escalation (tune -> scale -> shed) with
@@ -341,34 +340,14 @@ class PipeGraph:
         return failure_domain_map(self)
 
     def with_compile_cache(self, cache_dir: str) -> "PipeGraph":
-        """Point JAX's persistent compilation cache at ``cache_dir`` so
-        supervised restarts and rescales re-use compiled device programs
-        (every chain signature otherwise re-traces+recompiles on each
-        rebuild). Env twin: ``WF_COMPILE_CACHE_DIR``."""
+        """Point JAX's persistent compilation cache at ``cache_dir``
+        instead of the default ``<checkout>/.jax_cache``. Ignored (with
+        one log line) when ``JAX_COMPILATION_CACHE_DIR`` is set: the
+        environment places the cache then."""
         if self._started:
             raise WindFlowError("with_compile_cache after start()")
         self._compile_cache_dir = cache_dir
         return self
-
-    def _setup_compile_cache(self) -> None:
-        """Wire the persistent compilation cache before the first device
-        program is traced (called from ``start``; the first rung of the
-        ROADMAP compile-stability item). Thresholds drop to zero so even
-        small chain programs persist — a streaming graph re-runs the
-        SAME signatures forever, which is the cache's best case."""
-        if not self._compile_cache_dir:
-            return
-        import jax
-        os.makedirs(self._compile_cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir",
-                          self._compile_cache_dir)
-        try:
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                              0)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              0.0)
-        except (AttributeError, ValueError):
-            pass  # older jax: directory alone still enables the cache
 
     def _capture_initial_positions(self) -> None:
         """Supervision prerequisite (before the first tuple ships): each
@@ -1154,9 +1133,10 @@ class PipeGraph:
             if self._device_probe is None:
                 from ..supervision.health import probe_from_env
                 self._device_probe = probe_from_env()
-        # persistent compilation cache BEFORE any device program traces
-        self._setup_compile_cache()
         if any(getattr(op, "is_tpu", False) for op in self._ops):
+            # persistent compilation cache BEFORE any device program traces
+            from ..runtime.compile_cache import setup_compile_cache
+            setup_compile_cache(self._compile_cache_dir)
             # initialize the JAX backend on the MAIN thread: lazy first-touch
             # inside a worker thread can deadlock the PJRT client handshake
             import jax
