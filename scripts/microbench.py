@@ -33,41 +33,6 @@ class _NullPort:
         pass
 
 
-def bench_staging() -> None:
-    from windflow_tpu.tpu.emitters_tpu import TPUStageEmitter
-    from windflow_tpu.tpu.schema import TupleSchema
-
-    N, B = 500_000, 16384
-    schema = TupleSchema({"key": np.int32, "value": np.int32})
-    em = TPUStageEmitter(1, B, schema, None, "forward")
-    em.set_ports([_NullPort()])
-    row = {"key": 3, "value": 7}
-    t0 = time.perf_counter()
-    for i in range(N):
-        em.emit(row, i, 0)
-    em.flush()
-    report("staging_per_row", N / (time.perf_counter() - t0))
-
-    em2 = TPUStageEmitter(1, B, schema, None, "forward")
-    em2.set_ports([_NullPort()])
-    keys = np.zeros(B, np.int32)
-    vals = np.zeros(B, np.int32)
-    ts = np.arange(B, dtype=np.int64)
-    t0 = time.perf_counter()
-    for _ in range(N // B):
-        em2.emit_columns({"key": keys, "value": vals}, ts, 0)
-    report("staging_push_columns", (N // B) * B / (time.perf_counter() - t0))
-
-    em3 = TPUStageEmitter(4, B, schema, None, "keyby", key_field="key")
-    em3.set_ports([_NullPort()] * 4)
-    rkeys = np.random.default_rng(0).integers(0, 64, B).astype(np.int32)
-    t0 = time.perf_counter()
-    for _ in range(N // B):
-        em3.emit_columns({"key": rkeys, "value": vals}, ts, 0)
-    report("staging_push_columns_keyby4",
-           (N // B) * B / (time.perf_counter() - t0))
-
-
 def bench_reshard() -> None:
     import jax
 
@@ -96,46 +61,6 @@ def bench_reshard() -> None:
     report("tpu_keyed_reshard_4dests", 20 * B / (time.perf_counter() - t0))
 
 
-def bench_channels() -> None:
-    import threading
-
-    from windflow_tpu.runtime.channel import Channel
-
-    N = 200_000
-    ch = Channel(2048)
-    ch.register_input()
-
-    def consumer():
-        for _ in range(N):
-            ch.get()
-
-    t = threading.Thread(target=consumer)
-    t.start()
-    msg = ("x", 1)
-    t0 = time.perf_counter()
-    for _ in range(N):
-        ch.put(0, msg)
-    t.join()
-    report("python_channel", N / (time.perf_counter() - t0), "msg/sec")
-
-    from windflow_tpu.native import NativeChannel, native_available
-    if native_available():
-        nch = NativeChannel(2048)
-        nch.register_input()
-
-        def nconsumer():
-            for _ in range(N):
-                nch.get()
-
-        t = threading.Thread(target=nconsumer)
-        t.start()
-        t0 = time.perf_counter()
-        for _ in range(N):
-            nch.put(0, msg)
-        t.join()
-        report("native_channel", N / (time.perf_counter() - t0), "msg/sec")
-
-
 def bench_exit_decode() -> None:
     from windflow_tpu.tpu.schema import TupleSchema
 
@@ -148,214 +73,6 @@ def bench_exit_decode() -> None:
     rows = schema.from_columns(cols, ts, n)
     assert len(rows) == n
     report("exit_from_columns", n / (time.perf_counter() - t0), "rows/sec")
-
-
-def bench_exit_pipeline() -> None:
-    """TPU->CPU exit: full device batches -> rows through TPUExitEmitter,
-    pipelined (depth 4, default) vs synchronous (depth 0). On the CPU
-    backend the two depths should be close; the difference on a chip is
-    not measured."""
-    import jax
-
-    from windflow_tpu.basic import ExecutionMode
-    from windflow_tpu.runtime.emitters import ForwardEmitter
-    from windflow_tpu.tpu.batch import BatchTPU
-    from windflow_tpu.tpu.emitters_tpu import TPUExitEmitter
-    from windflow_tpu.tpu.schema import TupleSchema
-
-    n, batches = 16384, 12
-    schema = TupleSchema({"a": np.int32, "b": np.float32})
-
-    @jax.jit
-    def bump(a, b):  # fresh device buffers per batch (no host cache)
-        return a + 1, b * 2
-
-    for depth in (4, 0):
-        inner = ForwardEmitter(1, 256, ExecutionMode.DEFAULT)
-        em = TPUExitEmitter(inner, depth=depth)
-        em.set_ports([_NullPort()])
-        staged = []
-        for i in range(batches):
-            a, b = bump(jax.device_put(np.arange(n, dtype=np.int32) + i),
-                        jax.device_put(np.arange(n, dtype=np.float32)))
-            staged.append(BatchTPU({"a": a, "b": b},
-                                   np.arange(n, dtype=np.int64), n, schema))
-        jax.block_until_ready([bt.fields["a"] for bt in staged])
-        t0 = time.perf_counter()
-        for bt in staged:
-            em.emit_device_batch(bt)
-        em.flush()
-        report(f"exit_pipeline_depth{depth}",
-               batches * n / (time.perf_counter() - t0), "rows/sec")
-
-
-def bench_dispatch() -> None:
-    """--dispatch: the device-ahead dispatch pipeline (WF_DISPATCH_DEPTH,
-    runtime/dispatch.py) on the FFAT per-batch path. Reports throughput
-    at depth 0 (synchronous prep+commit) vs the default depth 2, the
-    per-stage split from the stats counters (host-prep µs vs
-    device-commit µs per batch), and the overlap efficiency — the
-    fraction of the smaller stage's total time hidden under the larger
-    one, ((prep + commit) - wall) / min(prep, commit), 0 when the stages
-    fully serialize and 1 when one is completely hidden."""
-    import jax
-
-    from windflow_tpu.basic import WinType
-    from windflow_tpu.tpu.batch import BatchTPU
-    from windflow_tpu.tpu.ffat_tpu import Ffat_Windows_TPU
-    from windflow_tpu.tpu.schema import TupleSchema
-
-    N_KEYS, B, NB, WARMUP = 64, 16384, 24, 4
-    WIN_US, SLIDE_US, TS_STEP = 100_000, 25_000, 50
-    schema = TupleSchema({"key": np.int32, "value": np.int32})
-    rng = np.random.default_rng(0)
-    batches = []
-    ts0 = 0
-    for _ in range(NB + WARMUP):
-        keys = rng.integers(0, N_KEYS, B).astype(np.int64)
-        cols = {"key": jax.device_put(keys.astype(np.int32)),
-                "value": jax.device_put(
-                    rng.integers(0, 100, B).astype(np.int32))}
-        ts = ts0 + np.arange(B, dtype=np.int64) * TS_STEP // N_KEYS
-        ts0 = int(ts[-1]) + TS_STEP
-        bt = BatchTPU(cols, ts, B, schema, wm=int(ts[-1]), host_keys=keys)
-        batches.append(bt)
-
-    class _Sink:
-        windows = 0
-
-        def emit_device_batch(self, b):
-            self.windows += b.size
-
-        def set_stats(self, s):
-            pass
-
-    results = {}
-    prev = os.environ.get("WF_DISPATCH_DEPTH")
-    try:
-        for depth in (0, 2):
-            os.environ["WF_DISPATCH_DEPTH"] = str(depth)
-            op = Ffat_Windows_TPU(
-                lift=lambda f: {"value": f["value"]},
-                combine=lambda a, b: {"value": a["value"] + b["value"]},
-                key_extractor="key", win_len=WIN_US, slide_len=SLIDE_US,
-                win_type=WinType.TB, num_win_per_batch=128,
-                key_capacity=N_KEYS, name=f"mb_dispatch_d{depth}")
-            op.build_replicas()
-            rep = op.replicas[0]
-            rep.emitter = _Sink()
-            for bt in batches[:WARMUP]:
-                rep.handle_msg(0, bt)
-            rep.dispatch.drain()
-            jax.block_until_ready(rep.trees)
-            st = rep.stats
-            prep0, commit0 = (st.dispatch_host_prep_total_us,
-                              st.dispatch_commit_total_us)
-            t0 = time.perf_counter()
-            for bt in batches[WARMUP:]:
-                rep.handle_msg(0, bt)
-            rep.dispatch.drain()
-            jax.block_until_ready(rep.trees)
-            wall_us = (time.perf_counter() - t0) * 1e6
-            results[depth] = (NB * B / (wall_us / 1e6), wall_us,
-                              st.dispatch_host_prep_total_us - prep0,
-                              st.dispatch_commit_total_us - commit0,
-                              st.dispatch_stalls, st.dispatch_depth_max)
-    finally:
-        if prev is None:
-            os.environ.pop("WF_DISPATCH_DEPTH", None)
-        else:
-            os.environ["WF_DISPATCH_DEPTH"] = prev
-
-    for depth, (tps, _w, _p, _c, _s, _d) in results.items():
-        report(f"dispatch_ffat_depth{depth}", tps)
-    tps0, wall, prep_us, commit_us, stalls, dmax = results[2]
-    report("dispatch_host_prep_us_per_batch", prep_us / NB, "usec")
-    report("dispatch_commit_us_per_batch", commit_us / NB, "usec")
-    denom = min(prep_us, commit_us)
-    overlap = (max(0.0, min(1.0, (prep_us + commit_us - wall) / denom))
-               if denom > 0 else 0.0)
-    # ratios need 3 decimals (report() rounds to 1 for throughputs)
-    print(json.dumps({"bench": "dispatch_overlap_efficiency",
-                      "value": round(overlap, 3), "unit": "ratio"}))
-    print(json.dumps({"bench": "dispatch_depth2_vs_depth0",
-                      "value": (round(results[2][0] / results[0][0], 3)
-                                if results[0][0] else 0.0),
-                      "unit": "speedup"}))
-    print(json.dumps({"bench": "dispatch_pipeline_detail",
-                      "readback_stalls": stalls,
-                      "queue_depth_max": dmax,
-                      "wall_us": round(wall, 1),
-                      "host_prep_total_us": round(prep_us, 1),
-                      "device_commit_total_us": round(commit_us, 1)}))
-
-
-def bench_latency() -> None:
-    """--latency: latency-tracing overhead on the per-tuple CPU plane
-    (source -> map -> sink chain) at sample rates {0, 1/64, 1}, plus the
-    sampled end-to-end percentiles at rate 1. The overhead lines are the
-    acceptance gate for the tracing plane: <= 2% throughput cost at
-    1/64 (rate 0 is the no-per-tuple-work baseline — sampling off means
-    no clock reads and no histogram records on the hot path)."""
-    from windflow_tpu import (ExecutionMode, Map_Builder, PipeGraph,
-                              Sink_Builder, Source_Builder, TimePolicy)
-
-    # best-of-6 per rate: run-to-run spread on a small shared host is a
-    # few percent — larger than the 1/64 overhead being measured — and
-    # the minimum is the stable estimator of the true per-tuple cost
-    N, REPS = 300_000, 6
-
-    def one_pass(rate):
-        def src(shipper):
-            for v in range(N):
-                shipper.push({"v": v})
-
-        seen = [0]
-        builders = (Source_Builder(src),
-                    Map_Builder(lambda t: {"v": t["v"] + 1}),
-                    Sink_Builder(lambda t: seen.__setitem__(0, seen[0] + 1)
-                                 if t else None))
-        for b in builders:
-            b.with_latency_tracing(rate)
-        g = PipeGraph("mb_latency", ExecutionMode.DEFAULT,
-                      TimePolicy.INGRESS_TIME)
-        # CHAINED stages: one worker thread end-to-end, so the delta
-        # between sample rates measures per-tuple tracing work, not
-        # scheduler noise from 3 threads sharing a small host
-        g.add_source(builders[0].build()) \
-         .chain(builders[1].build()) \
-         .chain_sink(builders[2].build())
-        t0 = time.perf_counter()
-        g.run()
-        tps = N / (time.perf_counter() - t0)
-        sink = g.get_stats()["Operators"][-1]["replicas"][0]
-        return tps, sink
-
-    # INTERLEAVED passes (0, 1/64, 1, 0, 1/64, 1, ...), best-of-N per
-    # rate: back-to-back same-rate passes would fold host drift into the
-    # overhead delta on a shared 1-core box (the bench.py A/B lesson)
-    rates = (("0", 0), ("1_64", "1/64"), ("1", 1))
-    results = {label: (0.0, None) for label, _ in rates}
-    for _ in range(REPS):
-        for label, rate in rates:
-            tps, s = one_pass(rate)
-            if tps > results[label][0]:
-                results[label] = (tps, s)
-    for label, _ in rates:
-        report(f"latency_plane_sample{label}", results[label][0])
-    base = results["0"][0]
-    for label in ("1_64", "1"):
-        pct = 100.0 * (1.0 - results[label][0] / base) if base else 0.0
-        print(json.dumps({"bench": f"latency_overhead_pct_sample{label}",
-                          "value": round(pct, 2), "unit": "pct",
-                          "acceptance": "<=2% at 1/64"
-                          if label == "1_64" else None}))
-    full = results["1"][1]
-    print(json.dumps({"bench": "latency_e2e_at_sample1",
-                      "p50_us": full["Latency_e2e_p50_usec"],
-                      "p99_us": full["Latency_e2e_p99_usec"],
-                      "max_us": full["Latency_e2e_max_usec"],
-                      "samples": full["Latency_e2e_samples"]}))
 
 
 def bench_checkpoint() -> None:
@@ -879,79 +596,6 @@ def bench_megabatch() -> None:
                       "unit": "speedup"}))
 
 
-def bench_flightrec() -> None:
-    """--flightrec: flight-recorder overhead (monitoring/flightrec.py)
-    on the per-tuple CPU plane at {off, on (4096-event ring), on with a
-    1-event ring}. The 1-event leg makes EVERY event a wraparound (the
-    ring's worst case — same stores, maximum index churn), bounding the
-    cost above. Acceptance gate: <= 2% throughput with the recorder on.
-
-    CPU-plane svc spans ride the traced-cohort mask gate of the latency
-    plane (stats.end_svc): the recorder adds ring stores only for
-    SAMPLED tuples, so the gate legs run at the latency plane's own
-    gated configuration (1/64 — the PR 2 acceptance point) and the
-    off-vs-on delta isolates the recorder's marginal cost there. Two
-    extra informational legs run at sample rate 1 (every tuple a traced
-    cohort — the recorder's per-tuple worst case, several times rarer
-    than any real configuration; device-plane spans are per BATCH and
-    cheaper still)."""
-    from windflow_tpu import (ExecutionMode, Map_Builder, PipeGraph,
-                              Sink_Builder, Source_Builder, TimePolicy)
-
-    N, REPS = 300_000, 6
-
-    def one_pass(events, rate):
-        def src(shipper):
-            for v in range(N):
-                shipper.push({"v": v})
-
-        seen = [0]
-        g = PipeGraph("mb_flightrec", ExecutionMode.DEFAULT,
-                      TimePolicy.INGRESS_TIME)
-        if events:
-            g.with_flight_recorder(events=events)
-        builders = (Source_Builder(src),
-                    Map_Builder(lambda t: {"v": t["v"] + 1}),
-                    Sink_Builder(lambda t: seen.__setitem__(0, seen[0] + 1)
-                                 if t else None))
-        for b in builders:
-            b.with_latency_tracing(rate)
-        # CHAINED stages: one worker thread end-to-end (same shape as
-        # --latency, so the two gates measure the same hot path)
-        g.add_source(builders[0].build()) \
-         .chain(builders[1].build()) \
-         .chain_sink(builders[2].build())
-        t0 = time.perf_counter()
-        g.run()
-        tps = N / (time.perf_counter() - t0)
-        n_events = sum(len(r) + r.dropped for r in g._recorders)
-        return tps, n_events
-
-    # interleaved passes, best-of-N per config (the bench.py A/B lesson:
-    # back-to-back same-config passes fold host drift into the delta)
-    configs = (("off", 0, "1/64"), ("on", 4096, "1/64"),
-               ("on_1evt", 1, "1/64"),
-               ("off_rate1", 0, 1), ("on_rate1", 4096, 1))
-    best = {label: (0.0, 0) for label, _, _ in configs}
-    for _ in range(REPS):
-        for label, events, rate in configs:
-            tps, n_events = one_pass(events, rate)
-            if tps > best[label][0]:
-                best[label] = (tps, n_events)
-    for label, _, _ in configs:
-        report(f"flightrec_{label}", best[label][0])
-    for on_label, base_label, gate in (("on", "off", "<=2% on at 1/64"),
-                                       ("on_1evt", "off", None),
-                                       ("on_rate1", "off_rate1", None)):
-        base = best[base_label][0]
-        pct = 100.0 * (1.0 - best[on_label][0] / base) if base else 0.0
-        print(json.dumps({"bench": f"flightrec_overhead_pct_{on_label}",
-                          "value": round(pct, 2), "unit": "pct",
-                          "acceptance": gate}))
-    print(json.dumps({"bench": "flightrec_events_recorded",
-                      "value": best["on"][1], "unit": "events"}))
-
-
 def bench_supervise() -> None:
     """--supervise: off-path cost of the self-healing plane
     (windflow_tpu.supervision) on the per-tuple CPU chain. Three
@@ -1148,99 +792,6 @@ def bench_overload() -> None:
                       "admitted_tps": ov["Overload_admitted_tps"],
                       "note": "informational: shedding trades throughput "
                               "for bounded latency by design"}))
-
-
-def bench_ingest() -> None:
-    """--ingest: the columnar ingest plane (Columnar_Source +
-    TPUStageEmitter.append_columns) vs the per-tuple row path on the
-    ingest-bound config — source -> stateless device map -> sink at
-    output batch 4096, where host batch construction dominates.
-    Interleaved best-of-6 (the bench.py A/B lesson: back-to-back
-    same-config passes fold host drift into the delta): one row leg,
-    three block legs (block sizes 1024/4096/16384). Reports tuples/s
-    per leg, the block-vs-row speedup (acceptance gate: >= 3x at block
-    4096), the flight-recorder ``host_prep`` share of wall time per leg
-    (batch construction: rows->columns encode+pad+device_put on the row
-    path, key-concat+device_put on the block path), and the source's
-    own Ingest_* counters from the block legs."""
-    from windflow_tpu import (ArrayBlockSource, Columnar_Source_Builder,
-                              ExecutionMode, PipeGraph, Sink_Builder,
-                              Source_Builder, TimePolicy)
-    from windflow_tpu.tpu import Map_TPU_Builder
-
-    N, B, REPS = 400_000, 4096, 6
-    BLOCK_SIZES = (1024, 4096, 16384)
-    vals = np.arange(N, dtype=np.int64)
-    keys = (vals * 2654435761 % 97).astype(np.int64)
-
-    def one_pass(block_size):
-        if block_size:
-            blocks = ArrayBlockSource({"k": keys, "v": vals},
-                                      block_size=block_size)
-            sb = Columnar_Source_Builder(blocks)
-        else:
-            def src(shipper):
-                for i in range(N):
-                    shipper.push({"k": int(keys[i]), "v": int(vals[i])})
-            sb = Source_Builder(src)
-        seen = [0]
-        g = PipeGraph("mb_ingest", ExecutionMode.DEFAULT,
-                      TimePolicy.INGRESS_TIME)
-        g.with_flight_recorder(events=65536)
-        # columnar sink: the exit side must not re-introduce per-tuple
-        # Python, or the measurement caps at the decode rate and the
-        # config stops being ingest-bound
-        g.add_source(sb.with_name("src").with_output_batch_size(B)
-                     .build()) \
-         .add(Map_TPU_Builder(lambda f: {"k": f["k"],
-                                         "v": f["v"] * 2 + 1})
-              .with_name("map").build()) \
-         .add_sink(Sink_Builder(
-             lambda cols, ts: seen.__setitem__(0, seen[0] + len(ts))
-             if ts is not None else None)
-             .with_columns().with_name("snk").build())
-        t0 = time.perf_counter()
-        g.run()
-        wall_us = (time.perf_counter() - t0) * 1e6
-        assert seen[0] == N, f"sink saw {seen[0]} of {N}"
-        preps = [e[2] for rec in g._recorders
-                 for e in rec.snapshot() if e[1] == "host_prep"]
-        prep_us = sum(preps)
-        src_rep = [o for o in g.get_stats()["Operators"]
-                   if o["name"] == "src"][0]["replicas"][0]
-        return (N / (wall_us / 1e6), prep_us / wall_us,
-                prep_us / max(1, len(preps)), src_rep)
-
-    legs = [("row", 0)] + [(f"block{bs}", bs) for bs in BLOCK_SIZES]
-    best = {label: (0.0, 0.0, 0.0, None) for label, _ in legs}
-    for _ in range(REPS):
-        for label, bs in legs:
-            tps, prep_share, prep_per_batch, src_rep = one_pass(bs)
-            if tps > best[label][0]:
-                best[label] = (tps, prep_share, prep_per_batch, src_rep)
-
-    for label, _ in legs:
-        report(f"ingest_{label}_tuples_per_sec", best[label][0])
-    for label, _ in legs:
-        # per-batch cost is the directional number (the share of wall
-        # RISES on the block legs because the wall collapses around it)
-        print(json.dumps({"bench": f"ingest_host_prep_{label}",
-                          "us_per_batch": round(best[label][2], 1),
-                          "share_of_wall": round(best[label][1], 4)}))
-    base = best["row"][0]
-    for bs in BLOCK_SIZES:
-        ratio = best[f"block{bs}"][0] / base if base else 0.0
-        print(json.dumps({"bench": f"ingest_block{bs}_vs_row",
-                          "value": round(ratio, 3), "unit": "speedup",
-                          "acceptance": ">=3x at block 4096"
-                          if bs == 4096 else None}))
-    r = best["block4096"][3]
-    print(json.dumps({"bench": "ingest_source_counters_block4096",
-                      "Ingest_blocks": r["Ingest_blocks"],
-                      "Ingest_rows_per_block_avg":
-                          r["Ingest_rows_per_block_avg"],
-                      "Ingest_block_ns_per_row":
-                          r["Ingest_block_ns_per_row"]}))
 
 
 def bench_ckpt_delta() -> None:
@@ -1666,12 +1217,6 @@ def main() -> None:
     if "--rescale" in sys.argv[1:]:
         bench_rescale()
         return
-    if "--dispatch" in sys.argv[1:]:
-        bench_dispatch()
-        return
-    if "--latency" in sys.argv[1:]:
-        bench_latency()
-        return
     if "--checkpoint" in sys.argv[1:]:
         bench_checkpoint()
         return
@@ -1687,14 +1232,8 @@ def main() -> None:
     if "--megabatch" in sys.argv[1:]:
         bench_megabatch()
         return
-    if "--flightrec" in sys.argv[1:]:
-        bench_flightrec()
-        return
     if "--overload" in sys.argv[1:]:
         bench_overload()
-        return
-    if "--ingest" in sys.argv[1:]:
-        bench_ingest()
         return
     if "--tiering" in sys.argv[1:]:
         bench_tiering()
@@ -1702,17 +1241,11 @@ def main() -> None:
     if "--ckpt-delta" in sys.argv[1:]:
         bench_ckpt_delta()
         return
-    bench_staging()
     bench_reshard()
-    bench_channels()
     bench_exit_decode()
-    bench_exit_pipeline()
-    bench_dispatch()
     bench_fusion()
     bench_megabatch()
     bench_cpu_plane()
-    bench_latency()
-    bench_flightrec()
     bench_checkpoint()
     bench_txn()
     bench_supervise()

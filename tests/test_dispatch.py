@@ -84,10 +84,13 @@ def test_queue_stall_and_stage_counters():
 
     st = StatsRecord("op", 0)
     q = DeviceDispatchQueue(stats=st, depth=2)
-    q.submit(lambda: None, prep_us=100.0)
-    q.submit(lambda: None, prep_us=300.0)
+    for b in (1, 2):
+        with q.prep(b):  # the wf:prep stage counts the batch
+            pass
+        q.submit(lambda: None, b)
     assert st.dispatch_batches == 2
-    assert st.dispatch_host_prep_total_us == pytest.approx(400.0)
+    assert st.dispatch_host_prep_total_us > 0.0
+    assert st.dispatch_host_prep_us > 0.0  # the stage feeds the EWMA
     assert st.dispatch_depth_max == 2
     assert st.dispatch_stalls == 0
     q.drain(forced=True)  # ordering-point drain with entries = a stall
@@ -98,8 +101,13 @@ def test_queue_stall_and_stage_counters():
     d = st.to_dict()
     for field in ("Dispatch_host_prep_usec", "Dispatch_commit_usec",
                   "Dispatch_readback_stalls", "Dispatch_queue_depth_max",
-                  "Dispatch_batches"):
+                  "Dispatch_batches", "Dispatch_host_prep_total_usec",
+                  "Dispatch_commit_total_usec",
+                  "Dispatch_queue_wait_total_usec"):
         assert field in d
+    assert d["Dispatch_batches"] == 2
+    # both batches sat in the queue from submit until the drain
+    assert d["Dispatch_queue_wait_total_usec"] > 0.0
 
 
 # ---------------------------------------------------------------------------

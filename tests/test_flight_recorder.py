@@ -87,7 +87,7 @@ def _span_events(doc):
 # the thread was executing: with the dispatch pipeline ahead by design,
 # batch B enqueues before batch A's commit runs, so their wait spans
 # overlap legitimately
-_RESIDENCY_SPANS = {"dispatch_wait"}
+_RESIDENCY_SPANS = ("wait:queue:", "wait:fifo:")
 
 
 def _assert_same_name_spans_disjoint(doc):
@@ -97,7 +97,7 @@ def _assert_same_name_spans_disjoint(doc):
     rounding)."""
     by_key = {}
     for e in _span_events(doc):
-        if e["name"] in _RESIDENCY_SPANS:
+        if e["name"].startswith(_RESIDENCY_SPANS):
             continue
         by_key.setdefault((e["pid"], e["tid"], e["name"]), []).append(
             (e["ts"], e["ts"] + e["dur"]))
@@ -161,10 +161,19 @@ def test_device_pipeline_trace_spans(tmp_path):
     assert not validate_chrome_trace(doc), validate_chrome_trace(doc)
     names = {e["name"] for e in _span_events(doc)}
     # the dispatch pipeline's stages + the compaction readback + the jit
-    # compiles all leave spans
-    assert names >= {"host_prep", "commit", "emit", "readback", "compile",
-                     "dispatch_submit"}, names
+    # compiles all leave spans, under the stage table's names
+    # (monitoring/tracing.py STAGES: <prefix>:<stage>:<op>)
+    assert names >= {"wf:h2d:source", "wf:prep:map_tpu",
+                     "wait:queue:map_tpu", "wf:commit:map_tpu",
+                     "wf:launch:map_tpu", "wf:emit:map_tpu",
+                     "wf:prep:filter_tpu", "wf:commit:filter_tpu",
+                     "wf:readback:filter_tpu", "wf:emit:filter_tpu",
+                     "wf:exit:filter_tpu", "compile"}, names
     _assert_same_name_spans_disjoint(doc)
+    # every stage event carries its batch's id
+    staged = [e for e in _span_events(doc)
+              if e["name"].startswith("wf:") and ":launch:" not in e["name"]]
+    assert staged and all(e["args"]["b"] > 0 for e in staged)
     # compile spans carry the triggering abstract signature
     comp = [e for e in _span_events(doc) if e["name"] == "compile"]
     assert all("signature" in e["args"] for e in comp)

@@ -1,0 +1,398 @@
+"""The host timeline of the served path (``monitoring/tracing.py``): one
+stage helper writes a profiler span, a cumulative ``get_stats()`` counter
+and a flight-recorder event at once, on every thread of the device plane.
+
+One small graph of the served shape (columnar source -> ``Filter_TPU``
+chained with ``Map_TPU`` -> ``Ffat_Windows_TPU`` -> columnar sink) runs
+once with the profiler's annotation class replaced by a recording fake and
+the flight recorder on; the cases read that one run. CPU backend; no case
+asserts a duration beyond "positive" or an ordering of two of its own
+counters."""
+
+import json
+import os
+import re
+import threading
+
+import numpy as np
+import pytest
+
+from windflow_tpu import (Columnar_Source_Builder, ExecutionMode,
+                          Map_Builder, PipeGraph, Sink_Builder,
+                          Source_Builder, TimePolicy)
+from windflow_tpu.monitoring import tracing
+from windflow_tpu.tpu import (Ffat_Windows_TPU_Builder, Filter_TPU_Builder,
+                              Map_TPU_Builder)
+
+K, ROWS, BLOCKS, PANE_US = 8, 64, 12, 1000
+CHAIN, DEVICE = "views∘join", ("views∘join", "win")
+
+
+class SpanLog:
+    """What the recording fake saw: one entry per closed annotation, with
+    the annotation that enclosed it on its thread."""
+
+    def __init__(self):
+        self.spans = []
+        self._tls = threading.local()
+        self._lock = threading.Lock()
+        log = self
+
+        class Annotation:
+            def __init__(self, name, **kw):
+                self.name, self.kw = name, kw
+
+            def __enter__(self):
+                stack = log._stack()
+                self.parent = stack[-1].name if stack else None
+                stack.append(self)
+                return self
+
+            def __exit__(self, *exc):
+                assert log._stack().pop() is self
+                with log._lock:
+                    log.spans.append({
+                        "name": self.name, "b": self.kw.get("b", 0),
+                        "cause": self.kw.get("cause", 0),
+                        "parent": self.parent,
+                        "thread": threading.current_thread().name})
+
+        self.annotation = Annotation
+
+    def _stack(self):
+        if not hasattr(self._tls, "stack"):
+            self._tls.stack = []
+        return self._tls.stack
+
+    def named(self, name, **match):
+        return [s for s in self.spans if s["name"] == name
+                and all(s[k] == v for k, v in match.items())]
+
+
+class Columns:
+    def __init__(self):
+        self.calls = []
+
+    def sink(self, cols, ts):
+        if cols is not None:
+            self.calls.append({k: np.array(v) for k, v in cols.items()})
+
+
+def blocks():
+    """BLOCKS blocks of ROWS rows, one pane of event time each; the
+    watermark rides one block behind, so a block's own commit fires the
+    windows its watermark closes."""
+    rng = np.random.default_rng(7)
+    for p in range(BLOCKS):
+        cols = {"k": rng.integers(0, K, ROWS).astype(np.int32),
+                "v": rng.integers(0, 100, ROWS).astype(np.int32)}
+        ts = np.full(ROWS, p * PANE_US + 5, dtype=np.int64)
+        yield cols, ts, p * PANE_US
+
+
+def device_chain():
+    views = (Filter_TPU_Builder(lambda f: f["v"] % 2 == 0)
+             .with_name("views").build())
+    join = (Map_TPU_Builder(lambda f: {"k": f["k"], "one": f["v"] * 0 + 1})
+            .with_name("join").build())
+    return views, join
+
+
+def served_graph(sink, window=True):
+    views, join = device_chain()
+    g = PipeGraph("stages", ExecutionMode.DEFAULT, TimePolicy.EVENT_TIME)
+    g.with_flight_recorder()
+    pipe = g.add_source(Columnar_Source_Builder(blocks).with_name("src")
+                        .with_output_batch_size(ROWS).build()) \
+        .add(views).chain(join)
+    if window:
+        pipe = pipe.add(
+            Ffat_Windows_TPU_Builder(
+                lambda f: {"count": f["one"]},
+                lambda a, b: {"count": a["count"] + b["count"]})
+            .with_key_by("k").with_tb_windows(4 * PANE_US, 4 * PANE_US)
+            .with_key_capacity(K).with_name("win").build())
+    pipe.add_sink(Sink_Builder(sink).with_name("snk").with_columns().build())
+    return g
+
+
+def run_recorded(graph):
+    """Run ``graph`` with the profiler's annotation class replaced by a
+    recording fake; the log, the stats by operator, the ring's events."""
+    log = SpanLog()
+    before = tracing._ANNOTATION
+    tracing._ANNOTATION = log.annotation
+    try:
+        graph.run()
+    finally:
+        tracing._ANNOTATION = before
+    stats = {o["name"]: o["replicas"][0]
+             for o in graph.get_stats()["Operators"]}
+    ring = [e for e in graph.trace_document()["traceEvents"]
+            if e.get("ph") == "X"]
+    return log, stats, ring
+
+
+@pytest.fixture(scope="module")
+def served():
+    out = Columns()
+    g = served_graph(out.sink)
+    log, stats, ring = run_recorded(g)
+    assert out.calls, "the windows never reached the sink"
+    return {"graph": g, "log": log, "stats": stats, "ring": ring}
+
+
+# ---------------------------------------------------------------------------
+# (a) the counters, where the table says the work happens
+# ---------------------------------------------------------------------------
+# stage -> operators of the served graph whose thread runs it
+RUNS_ON = {
+    "ingest": {"src"}, "stage": {"src"}, "h2d": {"src"},
+    "prep": set(DEVICE), "queue": set(DEVICE), "commit": set(DEVICE),
+    "launch": set(DEVICE), "emit": set(DEVICE),
+    # the window operator's commit emits its fired batch without a
+    # blocking read: its readback counter reads 0
+    "readback": {CHAIN},
+    # the chain's keyed re-shard to ONE destination passes batches on
+    # without its FIFO; the window operator's columnar exit queues them
+    "fifo": {"win"}, "d2h": {"snk"}, "sink": {"snk"},
+}
+FIELDS = sorted(
+    (field, stage) for stage, sdef in tracing.STAGES.items()
+    if stage in RUNS_ON for field in (sdef.total, sdef.count) if field)
+
+
+@pytest.mark.parametrize("field,stage", FIELDS)
+def test_counter_positive_where_the_stage_runs_and_zero_elsewhere(
+        served, field, stage):
+    for op, rep in served["stats"].items():
+        if op in RUNS_ON[stage]:
+            assert rep[field] > 0, (op, field, rep[field])
+        else:
+            assert rep[field] == 0, (op, field, rep[field])
+
+
+def test_wait_stages_belong_to_the_consumer(served):
+    # a source has no input channel: nothing waits on its behalf
+    src = served["stats"]["src"]
+    assert src["Queue_blocked_put_usec"] == 0 == src["Queue_blocked_get_usec"]
+    assert served["stats"]["win"]["Exit_fifo_depth_sum"] >= \
+        served["stats"]["win"]["Exit_fifo_batches"] > 0
+
+
+@pytest.mark.parametrize("op", DEVICE)
+def test_commit_holds_its_children(served, op):
+    rep = served["stats"][op]
+    assert (rep["Dispatch_readback_wait_total_usec"]
+            + rep["Dispatch_emit_total_usec"]
+            <= rep["Dispatch_commit_total_usec"])
+    assert rep["Dispatch_batches"] == rep["Device_batches_in"] == BLOCKS
+
+
+def test_unknown_stage_raises_where_it_is_bound():
+    with pytest.raises(ValueError, match="unknown stage 'reedback'"):
+        tracing.StageCounters("op").stage("reedback")
+
+
+# ---------------------------------------------------------------------------
+# (b) the spans of one block, under the table's names, nested as it says
+# ---------------------------------------------------------------------------
+def test_one_block_from_stage_to_window_commit(served):
+    log = served["log"]
+    # a block past the first: a program's first call is a ``compile``
+    b = log.named("wf:stage:src")[BLOCKS // 2]["b"]
+    assert b > 0
+    for name in ("wf:stage:src", "wf:h2d:src", f"wf:prep:{CHAIN}",
+                 f"wf:commit:{CHAIN}", f"wf:launch:{CHAIN}",
+                 f"wf:readback:{CHAIN}", f"wf:emit:{CHAIN}",
+                 "wf:prep:win", "wf:commit:win"):
+        assert len(log.named(name, b=b)) == 1, (name, b)
+    # the source's work sits in the block's envelope, never in a wf: span
+    for name in ("wf:stage:src", "wf:h2d:src"):
+        assert log.named(name, b=b)[0]["parent"] == "blk:ingest:src"
+    for child in ("launch", "readback", "emit"):
+        assert log.named(f"wf:{child}:{CHAIN}", b=b)[0]["parent"] == \
+            f"wf:commit:{CHAIN}"
+    # a thread that only waits is never named wf:, and never encloses work
+    assert not any(s["parent"] and s["parent"].startswith("wait:")
+                   for s in log.spans)
+    assert {s["name"].split(":")[0] for s in log.spans} == \
+        {"wf", "wait", "blk"}
+
+
+def test_fired_batch_names_its_cause(served):
+    log = served["log"]
+    fired = [s for s in log.named("wf:emit:win") if s["cause"]]
+    assert fired, log.named("wf:emit:win")
+    for s in fired:
+        assert s["parent"] == "wf:commit:win"
+        # the input batch whose commit fired it, and the new batch's own
+        # id from the window operator's exit to the sink's functor
+        assert len(log.named("wf:commit:win", b=s["cause"])) == 1
+        assert s["b"] not in {c["b"] for c in log.named("wf:commit:win")}
+        for name in ("wf:exit:win", "wf:d2h:snk", "wf:sink:snk"):
+            assert log.named(name, b=s["b"], cause=s["cause"]), (name, s)
+
+
+def test_one_id_from_stage_to_sink_without_a_window():
+    out = Columns()
+    log, stats, _ = run_recorded(served_graph(out.sink, window=False))
+    kept = sum(len(c["k"]) for c in out.calls)
+    assert kept == stats["snk"]["Inputs_received"] > 0
+    ids = {s["b"] for s in log.named("wf:sink:snk")}
+    assert ids and 0 not in ids
+    for b in ids:
+        for name in ("wf:stage:src", "wf:h2d:src", f"wf:commit:{CHAIN}",
+                     f"wf:exit:{CHAIN}", "wf:d2h:snk"):
+            assert log.named(name, b=b), (name, b)
+
+
+# ---------------------------------------------------------------------------
+# (c) the ring holds the same names and ids
+# ---------------------------------------------------------------------------
+def test_ring_holds_the_same_spans(served):
+    def key(name, b, cause):
+        return (name, b, cause)
+
+    seen = sorted(key(s["name"], s["b"], s["cause"])
+                  for s in served["log"].spans
+                  if not s["name"].startswith("wait:"))
+    ring = sorted(key(e["name"], e["args"]["b"], e["args"].get("cause", 0))
+                  for e in served["ring"]
+                  if e["name"].startswith(("wf:", "blk:")))
+    assert ring == seen
+    # residency spans exist in the ring alone (a profiler span cannot be
+    # opened in the past), with the waiting batch's id
+    for name in (f"wait:queue:{CHAIN}", "wait:queue:win", "wait:fifo:win"):
+        waits = [e for e in served["ring"] if e["name"] == name]
+        assert waits and all(e["args"]["b"] > 0 for e in waits), name
+
+
+# ---------------------------------------------------------------------------
+# (d) CPU time per worker thread
+# ---------------------------------------------------------------------------
+def test_thread_cpu_while_running_and_frozen_after_the_end():
+    gate, seen = threading.Event(), threading.Event()
+
+    def gated():
+        for i, blk in enumerate(blocks()):
+            if i == BLOCKS - 2:
+                assert gate.wait(60)
+            yield blk
+
+    def sink(cols, ts):
+        if cols is not None:
+            seen.set()
+
+    views, join = device_chain()
+    g = PipeGraph("clocks", ExecutionMode.DEFAULT, TimePolicy.EVENT_TIME)
+    g.add_source(Columnar_Source_Builder(gated).with_name("src")
+                 .with_output_batch_size(ROWS).build()) \
+        .add(views).chain(join) \
+        .add_sink(Sink_Builder(sink).with_name("snk").with_columns().build())
+    g.start()
+    try:
+        assert seen.wait(60)
+        live = {o["name"]: o["replicas"][0]
+                for o in g.get_stats()["Operators"]}
+        for op, rep in live.items():
+            assert rep["Thread_cpu_usec"] > 0, op
+            assert rep["Thread_wall_usec"] >= rep["Thread_cpu_usec"] * 0.5
+    finally:
+        gate.set()
+        g.wait_end()
+    first = {o["name"]: o["replicas"][0] for o in g.get_stats()["Operators"]}
+    again = {o["name"]: o["replicas"][0] for o in g.get_stats()["Operators"]}
+    for op in first:
+        assert first[op]["Thread_cpu_usec"] == again[op]["Thread_cpu_usec"]
+        assert first[op]["Thread_wall_usec"] == again[op]["Thread_wall_usec"]
+        assert first[op]["Thread_cpu_usec"] >= live[op]["Thread_cpu_usec"]
+
+
+def test_thread_cpu_counts_each_worker_once():
+    """Three chained CPU operators share one worker: one of their records
+    reports the thread, the others 0, so a sum counts a thread once."""
+    got = []
+
+    def src(shipper):
+        for i in range(200):
+            shipper.push({"v": i})
+
+    g = PipeGraph("one_thread", ExecutionMode.DEFAULT,
+                  TimePolicy.INGRESS_TIME)
+    g.add_source(Source_Builder(src).with_name("s").build()) \
+        .chain(Map_Builder(lambda t: t).with_name("m").build()) \
+        .chain_sink(Sink_Builder(lambda t: got.append(t))
+                    .with_name("k").build())
+    g.run()
+    reps = [r for o in g.get_stats()["Operators"] for r in o["replicas"]]
+    assert len(g._workers) == 1 and len(reps) == 3
+    assert sum(1 for r in reps if r["Thread_cpu_usec"] > 0) == 1
+    assert sum(1 for r in reps if r["Thread_wall_usec"] > 0) == 1
+
+
+# ---------------------------------------------------------------------------
+# (e) stable program names
+# ---------------------------------------------------------------------------
+def _program_names(cache):
+    return {p._wrapped_jit.__name__ for p in cache.values()
+            if hasattr(p, "_wrapped_jit")}
+
+
+def test_window_programs_keep_the_names_the_roofline_metric_reads(served):
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "metrics",
+                           "window_step_roofline.sat.json")) as f:
+        pattern = re.compile(json.load(f)["params"]["modules"])
+    replica = _replica(served["graph"], "win")
+    names = _program_names(replica._prog_cache)
+    assert names == {"step", "fire", "rebuild"}
+    # XLA names a module jit_<function name>
+    assert all(pattern.search("jit_" + n) for n in names)
+
+
+def test_fused_chain_program_carries_its_operators_names(served):
+    replica = _replica(served["graph"], "views")
+    assert _program_names(replica._prog_cache) == {"chain_views_join"}
+    assert tracing.program_name("chain", "views", "jo∘in") == \
+        "chain_views_jo_in"
+
+
+def _replica(graph, op_name):
+    for w in graph._workers:
+        for node in w.chain:
+            ops = getattr(node, "ops", None) or [getattr(node, "op", None)]
+            if any(getattr(o, "name", None) == op_name for o in ops):
+                return node
+    raise KeyError(op_name)
+
+
+# ---------------------------------------------------------------------------
+# (f) the CPU plane's per-tuple path has no stage
+# ---------------------------------------------------------------------------
+def test_cpu_plane_times_nothing_per_tuple(monkeypatch):
+    n, used = 3000, []
+    real = tracing.Stage.__call__
+
+    def counting(self, b=0, cause=0):
+        used.append(self.name)
+        return real(self, b, cause)
+
+    monkeypatch.setattr(tracing.Stage, "__call__", counting)
+    got = []
+
+    def src(shipper):
+        for i in range(n):
+            shipper.push({"v": i})
+
+    g = PipeGraph("cpu_plane", ExecutionMode.DEFAULT,
+                  TimePolicy.INGRESS_TIME)
+    g.add_source(Source_Builder(src).build()) \
+        .add(Map_Builder(lambda t: {"v": t["v"] + 1}).build()) \
+        .add_sink(Sink_Builder(
+            lambda t: got.append(t) if t is not None else None).build())
+    g.run()
+    assert len(got) == n
+    # only a channel's blocked branches may open a span there: a thread
+    # that found its queue full or empty, never a tuple's processing
+    assert set(used) <= {"put", "get"}, set(used)

@@ -757,6 +757,7 @@ class Kafka_Source(BasicOperator):
 class KafkaSourceReplica(BasicReplica):
     def __init__(self, op, idx):
         super().__init__(op, idx)
+        self._st_ingest = self.stats.stage("ingest")  # one column block
         # aligned checkpointing (windflow_tpu.checkpoint): barriers inject
         # BETWEEN Kafka messages (never between the pushes of one deser
         # call) so the snapshot offsets cover exactly the shipped prefix
@@ -987,38 +988,39 @@ class KafkaSourceReplica(BasicReplica):
         here): same gate / watermark / trace contract as
         ``SourceReplica.ship_columns``, minus barrier injection — in the
         Kafka loop barriers land between polls, never inside a block."""
-        t0_ns = time.perf_counter_ns()
-        gate = self._gate
-        if gate is not None:
-            if gate.pending:
-                # row-path records accepted into the gate's buffer
-                # precede this block: emit them first (accept-time
-                # watermarks) or the stream reorders
-                for p, t, w in gate.drain_pending():
-                    self._advance_wm(w)
-                    self._emit_admitted(p, t)
-            if gate.released:
-                self._gate = None
-            else:
-                cols, ts_arr, n = gate.offer_columns(cols, ts_arr)
-                if n == 0:
-                    return
-        if wm > self.cur_wm:
-            self.cur_wm = wm
-        st = self.stats
-        n = len(ts_arr)
-        base = st.inputs_received
-        st.inputs_received = base + n
-        trace_rows = None
-        se = st.sample_every
-        if se:
-            # vectorized mask gate — the cohort the row path would stamp
-            first = (-(base + 1)) % se
-            if first < n:
-                trace_rows = np.arange(first, n, se)
-                self.emitter.trace_ts = current_time_usecs()
-        self.emitter.emit_columns(cols, ts_arr, self.cur_wm, trace_rows)
-        st.note_ingest_block(n, time.perf_counter_ns() - t0_ns)
+        # one pushed block, gate to emit, waits included (blk:ingest)
+        with self._st_ingest():
+            gate = self._gate
+            if gate is not None:
+                if gate.pending:
+                    # row-path records accepted into the gate's buffer
+                    # precede this block: emit them first (accept-time
+                    # watermarks) or the stream reorders
+                    for p, t, w in gate.drain_pending():
+                        self._advance_wm(w)
+                        self._emit_admitted(p, t)
+                if gate.released:
+                    self._gate = None
+                else:
+                    cols, ts_arr, n = gate.offer_columns(cols, ts_arr)
+                    if n == 0:
+                        return
+            if wm > self.cur_wm:
+                self.cur_wm = wm
+            st = self.stats
+            n = len(ts_arr)
+            base = st.inputs_received
+            st.inputs_received = base + n
+            trace_rows = None
+            se = st.sample_every
+            if se:
+                # vectorized mask gate — the cohort the row path would stamp
+                first = (-(base + 1)) % se
+                if first < n:
+                    trace_rows = np.arange(first, n, se)
+                    self.emitter.trace_ts = current_time_usecs()
+            self.emitter.emit_columns(cols, ts_arr, self.cur_wm, trace_rows)
+            st.ingest_rows += n
 
 
 

@@ -34,6 +34,9 @@ import numpy as np
 
 from ..tpu.schema import broadcast_scalar_fields
 
+# named scope of the in-program KEYBY shuffle in a device profile
+SCOPE_ALL_TO_ALL = "keyby_all_to_all"
+
 # -- device-health exclusion registry ---------------------------------------
 # Device ids the supervision plane has marked lost (health probe,
 # supervision/health.py). Every mesh built through make_key_mesh avoids
@@ -190,9 +193,11 @@ def _route_to_owners(ka: int, k_local: int, C: int, keys, panes, vals):
 
     # the ICI shuffle: block i of every chip goes to key-shard i
     a2a = lambda b: lax.all_to_all(b, "key", 0, 0, tiled=True).reshape(-1)
-    rk = a2a(bucketize(ksort, -1))
-    rp = a2a(bucketize(psort, 0))
-    rv = tmap(lambda a: a2a(bucketize(a, np.zeros((), a.dtype)[()])), vsort)
+    with jax.named_scope(SCOPE_ALL_TO_ALL):
+        rk = a2a(bucketize(ksort, -1))
+        rp = a2a(bucketize(psort, 0))
+        rv = tmap(lambda a: a2a(bucketize(a, np.zeros((), a.dtype)[()])),
+                  vsort)
     valid = rk >= 0
     shard = lax.axis_index("key")
     local_key = jnp.where(valid, rk - shard * k_local, 0).astype(jnp.int32)
@@ -703,9 +708,11 @@ def _route_flat(ns: int, k_local: int, C: int, slots, aux, vals):
             jnp.where(ok, col, fill), mode="drop").reshape(ns, C)
 
     a2a = lambda b: lax.all_to_all(b, MESH_AXES, 0, 0, tiled=True).reshape(-1)
-    rs = a2a(bucketize(ssort, jnp.asarray(-1, ssort.dtype)))
-    ra = a2a(bucketize(asort, jnp.zeros((), asort.dtype)))
-    rv = tmap(lambda a: a2a(bucketize(a, jnp.zeros((), a.dtype))), vsort)
+    with jax.named_scope(SCOPE_ALL_TO_ALL):
+        rs = a2a(bucketize(ssort, jnp.asarray(-1, ssort.dtype)))
+        ra = a2a(bucketize(asort, jnp.zeros((), asort.dtype)))
+        rv = tmap(lambda a: a2a(bucketize(a, jnp.zeros((), a.dtype))),
+                  vsort)
     valid = rs >= 0
     shard = lax.axis_index(MESH_AXES)
     local_key = jnp.where(valid, rs - shard * k_local, 0).astype(jnp.int32)
@@ -717,11 +724,13 @@ def _route_back(ns: int, C: int, routed, order, flat, ok, fill=0):
     the recv layout ``j*C + c``) return to their source shard — tiled
     all_to_all with equal split/concat axes is an involution — and
     un-permute to the original arrival positions."""
+    import jax
     import jax.numpy as jnp
     from jax import lax
 
-    ret = lax.all_to_all(routed.reshape(ns, C), MESH_AXES, 0, 0,
-                         tiled=True).reshape(-1)
+    with jax.named_scope(SCOPE_ALL_TO_ALL):
+        ret = lax.all_to_all(routed.reshape(ns, C), MESH_AXES, 0, 0,
+                             tiled=True).reshape(-1)
     picked = ret[flat]
     out = jnp.full((order.shape[0],), fill, dtype=routed.dtype)
     return out.at[order].set(

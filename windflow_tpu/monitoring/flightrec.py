@@ -5,13 +5,13 @@ The stats plane (PR 2) answers "how fast is each operator on average";
 this module answers "where did THIS slow batch spend its time" and "why
 did throughput just collapse". Each worker thread owns one
 ``FlightRecorder`` — a fixed-size, single-writer ring of structured
-events recorded at the points where the dispatch pipeline and the
-latency-tracing plane already take timestamps (host prep, deferred
-device commit, channel blocked put/get, barrier alignment, checkpoint
-snapshots, jit compiles), so the steady-state cost of an enabled
-recorder is one clock read plus a couple of array stores per batch
-(``scripts/microbench.py --flightrec`` gates it at <= 2%). The rings
-export as Chrome trace-event JSON (loadable in Perfetto /
+events. The per-batch stages of the device plane reach it through the
+one stage helper (``monitoring/tracing.py``: the same span, name and
+batch id the profiler and the ``get_stats()`` counters get); barrier
+alignment, checkpoint snapshots, jit compiles and the sampled ``svc:``
+spans write it where they already take timestamps. The steady-state cost
+of an enabled recorder is one clock read plus a couple of array stores
+per event. The rings export as Chrome trace-event JSON (loadable in Perfetto /
 ``chrome://tracing``): ``tid`` = worker, ``pid`` = stage/operator,
 ``args`` carry batch sizes, checkpoint ids and compile signatures.
 
@@ -45,6 +45,8 @@ import time
 import weakref
 from typing import Any, Dict, List, Optional
 
+from .tracing import StageCounters, set_thread_recorder, thread_recorder
+
 __all__ = ["FlightRecorder", "set_thread_recorder", "thread_recorder",
            "env_flightrec_events", "env_stall_sec", "instrumented_jit",
            "to_chrome_trace", "thread_stacks", "register_graph",
@@ -55,17 +57,9 @@ DEFAULT_EVENTS = 4096
 # threads record into their own ring only (single-writer contract);
 # call sites that run on a foreign thread (a producer blocking on a
 # consumer's channel, a shared compiled program) resolve the CURRENT
-# thread's ring through this TLS slot instead of reaching for an
+# thread's ring through ``thread_recorder`` (the slot lives in
+# monitoring/tracing.py, re-exported here) instead of reaching for an
 # owner's ring across threads
-_tls = threading.local()
-
-
-def set_thread_recorder(rec: Optional["FlightRecorder"]) -> None:
-    _tls.rec = rec
-
-
-def thread_recorder() -> Optional["FlightRecorder"]:
-    return getattr(_tls, "rec", None)
 
 
 def env_flightrec_events() -> int:
@@ -255,8 +249,14 @@ def _abstract_signature(args) -> tuple:
     return tuple(parts)
 
 
-def instrumented_jit(fn, stats=None, label: str = "", **jit_kwargs):
-    """``jax.jit`` with compile-vs-cache-hit attribution. The wrapped
+def instrumented_jit(fn, stats=None, label: str = "",
+                     program: Optional[str] = None, **jit_kwargs):
+    """``jax.jit`` with compile-vs-cache-hit attribution. ``program``
+    names the XLA module (``jit_<program>`` in a device profile; default:
+    the function's own name), ``label`` the operator in the compile
+    signature and in the ``wf:launch:<label>`` stage that times the call
+    of the jitted program on the cache-hit path (the Python-side program
+    call; ``Device_launch_total_usec``). The wrapped
     callable tracks the abstract signatures it has served: an unseen
     signature means jit will trace+compile synchronously inside this
     call, so the call's elapsed time is recorded as the compile cost
@@ -272,15 +272,20 @@ def instrumented_jit(fn, stats=None, label: str = "", **jit_kwargs):
     sibling replicas hit the shared cache."""
     import jax
 
+    if program:
+        fn.__name__ = fn.__qualname__ = program
     jitted = jax.jit(fn, **jit_kwargs)
     seen = set()
+    launch = (stats if stats is not None else StageCounters()).stage(
+        "launch", label or getattr(fn, "__name__", "prog"))
 
     def wrapper(*args):
         key = _abstract_signature(args)
         if key in seen:
             if stats is not None:
                 stats.compile_cache_hits += 1
-            return jitted(*args)
+            with launch():
+                return jitted(*args)
         t0 = time.perf_counter()
         out = jitted(*args)
         dt_us = (time.perf_counter() - t0) * 1e6

@@ -20,6 +20,8 @@ import os
 import time
 from typing import Any, Dict, Optional
 
+from .tracing import StageCounters
+
 _EWMA_ALPHA = 0.1
 
 
@@ -34,9 +36,11 @@ def _wm_stall_sec() -> float:
         return 5.0
 
 
-class StatsRecord:
+class StatsRecord(StageCounters):
+    # ``op_name``, ``recorder`` and the per-stage cumulative times and
+    # counts (``stage_ns`` / ``stage_n``) are StageCounters' slots
     __slots__ = (
-        "op_name", "replica_idx", "start_time",
+        "replica_idx", "start_time",
         "inputs_received", "bytes_received", "outputs_sent", "bytes_sent",
         "inputs_ignored", "punct_received", "punct_sent",
         "service_time_us", "eff_service_time_us",
@@ -44,19 +48,18 @@ class StatsRecord:
         "device_bytes_h2d", "device_bytes_d2h", "device_programs_run",
         "staging_pool_hits", "staging_pool_misses",
         "dispatch_host_prep_us", "dispatch_commit_us",
-        "dispatch_host_prep_total_us", "dispatch_commit_total_us",
-        "dispatch_batches", "dispatch_stalls", "dispatch_depth_max",
+        "dispatch_stalls", "dispatch_depth_max",
         # megabatch scan loop (runtime/dispatch.py + tpu/fused_ops.py):
         # grouped dispatches (loops), batches committed through them,
         # and the widest group observed — Programs_per_batch in to_dict
         # derives the amortization from device_programs_run
         "megabatch_loops", "megabatch_batches", "megabatch_max",
-        # columnar ingest plane (SourceReplica.ship_columns): blocks
-        # shipped, rows they carried, and host nanoseconds spent shipping
-        # them — Ingest_block_ns_per_row in to_dict is the per-row host
-        # cost of the block path (the row path has no analog: its cost
-        # IS the per-tuple Python this plane removes)
-        "ingest_blocks", "ingest_rows", "ingest_ns_total",
+        # columnar ingest plane (SourceReplica.ship_columns): rows the
+        # shipped blocks carried; blocks and host nanoseconds are the
+        # ``ingest`` stage's — Ingest_block_ns_per_row in to_dict is the
+        # per-row host cost of the block path (the row path has no
+        # analog: its cost IS the per-tuple Python this plane removes)
+        "ingest_rows",
         # aligned-barrier checkpointing (windflow_tpu.checkpoint):
         # per-replica snapshot count/duration/size + barrier-alignment
         # stall time (multi-input workers buffering behind the barrier)
@@ -133,6 +136,10 @@ class StatsRecord:
         "hist_service", "hist_prep", "hist_commit", "hist_e2e",
         # queue / backpressure plane
         "input_channel", "pipe_depth_max", "worker_idle_ticks",
+        # exit FIFO depth summed at each add (mean depth over a window is
+        # a delta ratio against Exit_fifo_batches) and the Worker whose
+        # thread clocks this record reports (first record of its chain)
+        "exit_fifo_depth_sum", "worker",
         # device-chain fusion (tpu/fused_ops.py): number of sub-operators
         # fused into this replica's single per-batch program (0 = not a
         # fused replica)
@@ -145,15 +152,11 @@ class StatsRecord:
         # worker crash visibility: a replica chain that died records the
         # exception here instead of only dying as a silent daemon thread
         "worker_crashes", "worker_last_error",
-        # flight recorder (monitoring/flightrec.py): the owning worker's
-        # event ring, or None — every note_* hook below appends a span
-        # when present
-        "recorder",
     )
 
     def __init__(self, op_name: str = "", replica_idx: int = 0,
                  sample_every: int = 0) -> None:
-        self.op_name = op_name
+        super().__init__(op_name)
         self.replica_idx = replica_idx
         self.start_time = time.monotonic()
         self.inputs_received = 0
@@ -177,17 +180,12 @@ class StatsRecord:
         # (prep) vs program dispatch + emit readbacks (commit)
         self.dispatch_host_prep_us = 0.0  # EWMA
         self.dispatch_commit_us = 0.0  # EWMA
-        self.dispatch_host_prep_total_us = 0.0
-        self.dispatch_commit_total_us = 0.0
-        self.dispatch_batches = 0
         self.dispatch_stalls = 0  # forced ordering-point drains
         self.dispatch_depth_max = 0
         self.megabatch_loops = 0
         self.megabatch_batches = 0
         self.megabatch_max = 0
-        self.ingest_blocks = 0
         self.ingest_rows = 0
-        self.ingest_ns_total = 0
         self.checkpoints_taken = 0
         self.checkpoint_snapshot_total_us = 0.0
         self.checkpoint_last_snapshot_us = 0.0
@@ -263,6 +261,8 @@ class StatsRecord:
         self.input_channel = None  # wired by PipeGraph._make_workers
         self.pipe_depth_max = 0  # emitter-side FIFO high-water mark
         self.worker_idle_ticks = 0
+        self.exit_fifo_depth_sum = 0
+        self.worker = None  # wired by the Worker that reports here
         self.fused_ops = 0  # sub-ops fused into this replica's program
         # -- compile attribution / crash visibility / flight recorder -------
         self.compile_count = 0
@@ -272,7 +272,19 @@ class StatsRecord:
         self.compile_cache_hits = 0
         self.worker_crashes = 0
         self.worker_last_error = ""
-        self.recorder = None  # FlightRecorder, wired by the Worker
+
+    # -- stage totals older call sites and tests read by these names ---------
+    @property
+    def dispatch_batches(self) -> int:
+        return self.stage_count("prep")
+
+    @property
+    def dispatch_host_prep_total_us(self) -> float:
+        return self.stage_usec("prep")
+
+    @property
+    def dispatch_commit_total_us(self) -> float:
+        return self.stage_usec("commit")
 
     # -- service-time recording (wf/basic_operator.hpp:134-158) -------------
     def start_svc(self) -> None:
@@ -300,10 +312,11 @@ class StatsRecord:
             if self.recorder is not None:
                 self.recorder.event("svc:" + self.op_name, dt_us, n_tuples)
 
-    # -- dispatch-pipeline stages (runtime/dispatch.py) ----------------------
+    # -- dispatch-pipeline latencies: the ``prep`` and ``commit`` stages feed
+    # each duration here (tracing.STAGES ``note``) for the dashboard's EWMAs
+    # and the Latency_prep/commit histograms; totals, counts and ring
+    # events are the stage helper's ------------------------------------------
     def note_host_prep(self, us: float) -> None:
-        self.dispatch_batches += 1
-        self.dispatch_host_prep_total_us += us
         if not self._prep_seeded:
             self._prep_seeded = True
             self.dispatch_host_prep_us = us
@@ -312,11 +325,8 @@ class StatsRecord:
                 us - self.dispatch_host_prep_us)
         if self.hist_prep is not None:
             self.hist_prep.record(us)
-        if self.recorder is not None:
-            self.recorder.event("host_prep", us)
 
     def note_dispatch_commit(self, us: float) -> None:
-        self.dispatch_commit_total_us += us
         if not self._commit_seeded:
             self._commit_seeded = True
             self.dispatch_commit_us = us
@@ -325,8 +335,6 @@ class StatsRecord:
                 us - self.dispatch_commit_us)
         if self.hist_commit is not None:
             self.hist_commit.record(us)
-        if self.recorder is not None:
-            self.recorder.event("commit", us)
 
     def note_megabatch(self, k: int, us: float) -> None:
         """One megabatch scan loop: K same-signature batches committed
@@ -337,16 +345,6 @@ class StatsRecord:
             self.megabatch_max = k
         if self.recorder is not None:
             self.recorder.event("megabatch:scan", us, k)
-
-    def note_ingest_block(self, n_rows: int, ns: int) -> None:
-        """One column block through ``ship_columns``: ``n_rows`` admitted
-        rows shipped in ``ns`` host nanoseconds (gate + routing + staging
-        copy; the async H2D itself is excluded by dispatch)."""
-        self.ingest_blocks += 1
-        self.ingest_rows += n_rows
-        self.ingest_ns_total += ns
-        if self.recorder is not None:
-            self.recorder.event("ingest:block", ns / 1e3, n_rows)
 
     def note_dispatch_depth(self, depth: int) -> None:
         if depth > self.dispatch_depth_max:
@@ -488,12 +486,17 @@ class StatsRecord:
             self.hist_e2e.record(us)
 
     def note_pipe_depth(self, depth: int) -> None:
-        """Emitter-side FIFO occupancy high-water mark (_D2HPipeline)."""
+        """Emitter-side FIFO occupancy at one add (_D2HPipeline): the
+        high-water mark, and the running sum a reader turns into a mean
+        depth over its window."""
+        self.exit_fifo_depth_sum += depth
         if depth > self.pipe_depth_max:
             self.pipe_depth_max = depth
 
     def to_dict(self) -> Dict[str, Any]:
         elapsed = max(time.monotonic() - self.start_time, 1e-9)
+        ingest_blocks = self.stage_count("ingest")
+        dispatch_batches = self.stage_count("prep")
         d = {
             "Operator_name": self.op_name,
             "Replica_id": self.replica_idx,
@@ -517,11 +520,6 @@ class StatsRecord:
             "Staging_pool_misses": self.staging_pool_misses,
             "Dispatch_host_prep_usec": round(self.dispatch_host_prep_us, 3),
             "Dispatch_commit_usec": round(self.dispatch_commit_us, 3),
-            "Dispatch_host_prep_total_usec": round(
-                self.dispatch_host_prep_total_us, 1),
-            "Dispatch_commit_total_usec": round(
-                self.dispatch_commit_total_us, 1),
-            "Dispatch_batches": self.dispatch_batches,
             "Dispatch_readback_stalls": self.dispatch_stalls,
             "Dispatch_queue_depth_max": self.dispatch_depth_max,
             # megabatch scan loop (0s with WF_MEGABATCH off or on
@@ -534,16 +532,15 @@ class StatsRecord:
                 if self.megabatch_loops else 0.0,
             "Megabatch_max": self.megabatch_max,
             # columnar ingest plane (0s on row-path-only sources)
-            "Ingest_blocks": self.ingest_blocks,
             "Ingest_rows_per_block_avg": round(
-                self.ingest_rows / self.ingest_blocks, 2)
-                if self.ingest_blocks else 0.0,
+                self.ingest_rows / ingest_blocks, 2)
+                if ingest_blocks else 0.0,
             "Ingest_block_ns_per_row": round(
-                self.ingest_ns_total / self.ingest_rows, 1)
+                self.stage_usec("ingest") * 1e3 / self.ingest_rows, 1)
                 if self.ingest_rows else 0.0,
             "Programs_per_batch": round(
-                self.device_programs_run / self.dispatch_batches, 3)
-                if self.dispatch_batches else 0.0,
+                self.device_programs_run / dispatch_batches, 3)
+                if dispatch_batches else 0.0,
             "Checkpoint_snapshots": self.checkpoints_taken,
             "Checkpoint_snapshot_usec_total": round(
                 self.checkpoint_snapshot_total_us, 1),
@@ -619,6 +616,12 @@ class StatsRecord:
             d["Tier_miss_rate"] = round(
                 self.tier_misses / self.tier_lookups, 4) \
                 if self.tier_lookups else 0.0
+        # -- the stage table's cumulative times and counts (tracing.STAGES:
+        # Stage_*, Dispatch_*_total_usec, Dispatch_batches, Device_launch_*,
+        # Exit_fifo_*, Sink_*, Ingest_blocks, and the Queue_blocked_* /
+        # Queue_puts_blocked waits of this replica's input channel) ---------
+        d.update(self.stage_fields())
+        d["Exit_fifo_depth_sum"] = self.exit_fifo_depth_sum
         # -- queue / backpressure plane (0s for sources and fused chains) ---
         ch = self.input_channel
         d["Queue_len"] = len(ch) if ch is not None else 0
@@ -626,16 +629,14 @@ class StatsRecord:
             else 0
         d["Queue_depth_max"] = getattr(ch, "depth_max", 0) if ch is not None \
             else 0
-        d["Queue_blocked_put_usec"] = round(
-            getattr(ch, "blocked_put_ns", 0) / 1e3, 1) if ch is not None \
-            else 0.0
-        d["Queue_blocked_get_usec"] = round(
-            getattr(ch, "blocked_get_ns", 0) / 1e3, 1) if ch is not None \
-            else 0.0
-        d["Queue_puts_blocked"] = getattr(ch, "puts_blocked", 0) \
-            if ch is not None else 0
         d["Queue_emit_fifo_depth_max"] = self.pipe_depth_max
         d["Worker_idle_ticks"] = self.worker_idle_ticks
+        # CPU and wall time of the worker thread that reports here (0 on
+        # the other records of its chain, so a sum counts a thread once)
+        w = self.worker
+        cpu_ns, wall_ns = w.thread_clocks() if w is not None else (0, 0)
+        d["Thread_cpu_usec"] = round(cpu_ns / 1e3, 1)
+        d["Thread_wall_usec"] = round(wall_ns / 1e3, 1)
         # -- latency-tracing plane ------------------------------------------
         d["Latency_sample_every"] = self.sample_every
         for label, h in (("service", self.hist_service),

@@ -1,37 +1,63 @@
-"""Sampled per-tuple latency tracing — configuration and span helpers.
+"""The host timeline of the device plane, and the sampling knobs of the
+per-tuple latency plane.
 
-The tracing plane has three parts (none of which replaces the EWMAs —
-those stay for dashboard parity):
+**Stages.** Every per-batch site of the served path is timed by ONE
+helper, taken from the owning replica's ``StatsRecord`` when the replica
+or emitter is built (``self._st_readback = stats.stage("readback")``) and
+used as ``with self._st_readback(batch.bid):``. On entry and exit it
+reads ``perf_counter_ns`` once each and writes three things at once:
 
-- SOURCES stamp a sampled subset of tuples with a wall-clock origin
-  (``current_time_usecs``, monotonic and process-wide comparable). The
-  stamp rides ``Single.trace_ts``; CPU batches carry ``trace_min`` /
-  ``trace_max`` over their traced constituents and the TPU staging path
-  propagates the same pair through ``BatchTPU`` — device batches never
-  materialize per-tuple stamps.
-- SINKS record end-to-end latency (now - origin) into their replica's
-  ``LatencyHistogram``; every replica additionally records sampled
-  service time and (device plane) dispatch prep/commit latency.
-- Device-plane stages are wrapped in ``jax.profiler.TraceAnnotation``
-  spans (``wf:prep:<op>`` / ``wf:commit:<op>``) so a device trace
-  captured with ``jax.profiler.trace`` lines up with these host stats.
+- a ``jax.profiler.TraceAnnotation`` named ``<prefix>:<stage>:<op>`` with
+  ``b=<batch id>`` (and ``cause=<id>`` where a batch was made by another
+  batch's commit), so a device profile shows the host's work on the
+  profiler's clock (inert when no profile runs);
+- the stage's cumulative nanoseconds and count on the ``StatsRecord``
+  (``get_stats()`` exports them under the names ``STAGES`` lists; a
+  reader takes deltas over its window);
+- the same span, under the same name and ids, in the thread's flight
+  recorder ring when it has one (``monitoring/flightrec.py``).
 
-Sampling knob: ``WF_LATENCY_SAMPLE`` globally, or per operator via the
-builders' ``with_latency_tracing(rate)``. A rate is ``1`` (every
-tuple), a fraction ``"1/64"``, a float ``0.01``, or ``0`` (off — the
-default: no clock reads, no histogram work on the hot path). Internally
-a rate becomes a sampling INTERVAL (record every Nth), so sampling is
-deterministic and divides exactly under test.
+``STAGES`` is the one name table: a stage that is not in it raises where
+the replica or emitter is built, never per batch. The prefix says what
+the thread does inside the span: ``wf`` is host work (the benchmark's
+trace reduction attributes device idle gaps to ``wf:`` spans only),
+``wait`` is a thread blocked on a channel or a batch waiting in a queue
+(``Stage.since``: a residency span from an enqueue stamp to now, ring
+and counters only), ``blk`` is the envelope of one source block, work
+and waits together. Per batch, never per tuple: the CPU plane's
+per-tuple path has no stage.
+
+**Batch ids.** ``next_batch_id()`` numbers batches process-wide at the
+staging edge; ``BatchTPU.bid`` travels with every batch derived from it
+and ``BatchTPU.cause`` names the input batch whose commit made a new one
+(a window fire, a re-shard split). ``id`` stays the per-channel sequence
+number the ordering collectors read.
+
+**Latency sampling.** Sources stamp a sampled subset of tuples with a
+wall-clock origin (``Single.trace_ts``; batches carry ``trace_min`` /
+``trace_max``), sinks record end-to-end latency into their replica's
+``LatencyHistogram``, and every replica records sampled service time and
+(device plane) prep/commit latency. ``WF_LATENCY_SAMPLE`` globally, or
+per operator ``with_latency_tracing(rate)``: ``1``, a fraction
+``"1/64"``, a float ``0.01``, or ``0`` (off, the default: no clock
+reads, no histogram work on the hot path). A rate becomes a sampling
+INTERVAL (record every Nth), so sampling is deterministic and divides
+exactly under test.
 """
 
 from __future__ import annotations
 
+import itertools
 import os
-from contextlib import nullcontext
-from typing import Optional
+import re
+import sys
+import threading
+import time
+from typing import Any, Dict, List, NamedTuple, Optional
 
 __all__ = ["parse_sample_rate", "env_sample_every", "resolve_sample_every",
-           "device_span"]
+           "STAGES", "StageDef", "StageCounters", "Stage", "next_batch_id",
+           "stamp_ns", "program_name"]
 
 
 def parse_sample_rate(value) -> int:
@@ -43,7 +69,7 @@ def parse_sample_rate(value) -> int:
     power of two: the source's per-tuple sampling gate is then a single
     integer AND against ``interval - 1`` — the same cost whether
     sampling is on or off, so enabling 1/64 tracing costs only the
-    sampled work itself (microbench --latency measures this)."""
+    sampled work itself."""
     if value is None:
         return 0
     if isinstance(value, str):
@@ -91,22 +117,242 @@ def resolve_sample_every(op) -> int:
     return s
 
 
-_TRACE_ANNOTATION = None  # resolved lazily; nullcontext when jax absent
+# ---------------------------------------------------------------------------
+# stages: one helper writes profiler span, cumulative counter and ring event
+# ---------------------------------------------------------------------------
+class StageDef(NamedTuple):
+    prefix: str           # wf (host work) | wait (blocked / queued) | blk
+    layer: str            # PERF.md section 3 / BENCHMARK.json layer name
+    total: Optional[str]  # get_stats() field of the cumulative usec
+    count: Optional[str]  # get_stats() field of the span count
+    note: Optional[str] = None  # StatsRecord method fed each duration (us)
+    foreign: bool = False  # may run off the owner's worker thread: the
+    # ring is then the CALLING thread's (single-writer rings), and a span
+    # given no batch id takes the one of the enclosing ``scope`` stage
+    scope: bool = False  # its batch id is the thread's while it is open
 
 
-def device_span(name: str):
-    """A ``jax.profiler.TraceAnnotation`` context manager (host TraceMe
-    span visible in device profiles), or a no-op when jax is absent —
-    the CPU plane must not pay a jax import for observability."""
-    global _TRACE_ANNOTATION
-    if _TRACE_ANNOTATION is None:
-        try:
-            from jax.profiler import TraceAnnotation
-            _TRACE_ANNOTATION = TraceAnnotation
-        except Exception:  # pragma: no cover - no jax in the venv
-            _TRACE_ANNOTATION = _null_span
-    return _TRACE_ANNOTATION(name)
+_DISPATCH, _STAGING, _EXIT = "dispatch", "staging, H2D", "exit, D2H"
+_CHANNELS = "channels, workers"
+
+STAGES: Dict[str, StageDef] = {
+    # source thread
+    "ingest": StageDef("blk", _STAGING, None, "Ingest_blocks"),
+    "stage": StageDef("wf", _STAGING, "Stage_copy_total_usec", None),
+    "h2d": StageDef("wf", _STAGING, "Stage_h2d_put_total_usec",
+                    "Stage_batches"),
+    # any producer / the consuming worker, blocked branches only
+    "put": StageDef("wait", _CHANNELS, "Queue_blocked_put_usec",
+                    "Queue_puts_blocked", foreign=True),
+    "get": StageDef("wait", _CHANNELS, "Queue_blocked_get_usec", None),
+    # device operator's worker
+    "prep": StageDef("wf", _DISPATCH, "Dispatch_host_prep_total_usec",
+                     "Dispatch_batches", note="note_host_prep", scope=True),
+    "queue": StageDef("wait", _DISPATCH, "Dispatch_queue_wait_total_usec",
+                      None),
+    "commit": StageDef("wf", _DISPATCH, "Dispatch_commit_total_usec", None,
+                       note="note_dispatch_commit", scope=True),
+    "launch": StageDef("wf", _DISPATCH, "Device_launch_total_usec", None,
+                       foreign=True),
+    "readback": StageDef("wf", _DISPATCH,
+                         "Dispatch_readback_wait_total_usec", None),
+    "emit": StageDef("wf", _DISPATCH, "Dispatch_emit_total_usec", None),
+    # exit edge and sink
+    "fifo": StageDef("wait", _EXIT, "Exit_fifo_wait_total_usec",
+                     "Exit_fifo_batches"),
+    "exit": StageDef("wf", _EXIT, None, None),
+    "d2h": StageDef("wf", _EXIT, "Sink_d2h_wait_total_usec", None),
+    "sink": StageDef("wf", _EXIT, "Sink_functor_total_usec", None),
+}
+_INDEX = {name: i for i, name in enumerate(STAGES)}
+
+# enqueue stamps handed to ``Stage.since`` come from the helper's clock
+stamp_ns = _now_ns = time.perf_counter_ns
+
+_batch_ids = itertools.count(1)
 
 
-def _null_span(name: str):  # pragma: no cover - no jax in the venv
-    return nullcontext()
+def next_batch_id() -> int:
+    """A process-wide batch identifier (``BatchTPU.bid``); 0 means none."""
+    return next(_batch_ids)
+
+
+_NON_WORD = re.compile(r"\W", re.ASCII)
+
+
+def program_name(kind: str, *ops: str) -> str:
+    """XLA module name of a device program, ``jit_<this>`` in a profile:
+    ``chain_views_join``, ``map_enrich``. Non-word characters of an
+    operator's name become ``_`` (module names are ASCII identifiers)."""
+    return "_".join([kind] + [_NON_WORD.sub("_", o) for o in ops])
+
+
+# -- per thread: its flight recorder ring (monitoring/flightrec.py owns the
+# ring itself; the slot lives here so this module imports nothing of it)
+# and the batch id of the open prep/commit stage ---------------------------
+_tls = threading.local()
+
+
+def set_thread_recorder(rec) -> None:
+    _tls.rec = rec
+
+
+def thread_recorder():
+    return getattr(_tls, "rec", None)
+
+
+_ANNOTATION: Any = None  # jax.profiler.TraceAnnotation once jax is loaded
+
+
+def _annotation_class():
+    """``TraceAnnotation`` if this process has imported jax (only then can
+    a profile run); the CPU plane never pays a jax import for a span."""
+    global _ANNOTATION
+    if "jax" not in sys.modules:
+        return None
+    from jax.profiler import TraceAnnotation
+    _ANNOTATION = TraceAnnotation
+    return TraceAnnotation
+
+
+class _Span:
+    """One timed pass through a stage (``with stage(b):``). Setting
+    ``silent`` inside the block keeps the span out of the ring (a timed
+    ``Channel.get`` that ends in an idle tick would flood it)."""
+
+    __slots__ = ("_stage", "_b", "_cause", "_ann", "_t0", "_outer_b",
+                 "silent")
+
+    def __init__(self, stage: "Stage", b: int, cause: int) -> None:
+        self._stage = stage
+        self._b = b
+        self._cause = cause
+        self._ann = None
+        self.silent = False
+
+    def __enter__(self) -> "_Span":
+        stage = self._stage
+        if stage._scope:
+            self._outer_b = getattr(_tls, "b", 0)
+            _tls.b = self._b
+        elif stage._foreign and not self._b:
+            self._b = getattr(_tls, "b", 0)
+        cls = _ANNOTATION or _annotation_class()
+        if cls is not None:
+            if self._cause:
+                ann = cls(stage.label(), b=self._b, cause=self._cause)
+            else:
+                ann = cls(stage.label(), b=self._b)
+            ann.__enter__()
+            self._ann = ann
+        self._t0 = _now_ns()
+        return self
+
+    def __exit__(self, et, ev, tb) -> bool:
+        dt = _now_ns() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(et, ev, tb)
+        stage = self._stage
+        if stage._scope:
+            _tls.b = self._outer_b
+        stage._done(dt, self._b, self._cause, self.silent)
+        return False
+
+
+class Stage:
+    """A stage of ``STAGES`` bound to the counters of one operator; made
+    once where a replica or emitter is built, called once per batch."""
+
+    __slots__ = ("owner", "name", "_idx", "_prefix", "_fixed_op", "_op",
+                 "_label", "_note", "_foreign", "_scope")
+
+    def __init__(self, owner: "StageCounters", name: str,
+                 op: Optional[str] = None) -> None:
+        sdef = STAGES.get(name)
+        if sdef is None:
+            raise ValueError(
+                f"unknown stage {name!r}: monitoring/tracing.py STAGES "
+                f"lists {', '.join(STAGES)}")
+        self.owner = owner
+        self.name = name
+        self._idx = _INDEX[name]
+        self._prefix = sdef.prefix
+        self._fixed_op = op  # a program's label; else the owner's name
+        self._op: Any = self  # sentinel: no label built yet
+        self._label = ""
+        self._note = getattr(owner, sdef.note, None) if sdef.note else None
+        self._foreign = sdef.foreign
+        self._scope = sdef.scope
+
+    def label(self) -> str:
+        """``<prefix>:<stage>:<op>``, rebuilt when the owner is renamed
+        (a fused replica takes its chain's name after it is built)."""
+        op = self._fixed_op or self.owner.op_name
+        if op is not self._op:
+            self._op = op
+            self._label = f"{self._prefix}:{self.name}:{op or '?'}"
+        return self._label
+
+    @property
+    def total_ns(self) -> int:
+        return self.owner.stage_ns[self._idx]
+
+    @property
+    def count(self) -> int:
+        return self.owner.stage_n[self._idx]
+
+    def __call__(self, b: int = 0, cause: int = 0) -> _Span:
+        return _Span(self, b, cause)
+
+    def since(self, t0_ns: int, b: int = 0, cause: int = 0) -> None:
+        """A residency span: from the ``stamp_ns()`` taken when the batch
+        was queued until now. Counters and ring only — a profiler span
+        cannot be opened in the past."""
+        self._done(_now_ns() - t0_ns, b, cause, False)
+
+    def _done(self, dt_ns: int, b: int, cause: int, silent: bool) -> None:
+        owner = self.owner
+        i = self._idx
+        owner.stage_ns[i] += dt_ns
+        owner.stage_n[i] += 1
+        if self._note is not None:
+            self._note(dt_ns / 1e3)
+        if silent:
+            return
+        rec = thread_recorder() if self._foreign else owner.recorder
+        if rec is not None:
+            rec.event(self.label(), dt_ns / 1e3,
+                      {"b": b, "cause": cause} if cause else {"b": b})
+
+
+class StageCounters:
+    """Cumulative nanoseconds and count per stage for one operator: the
+    base of ``StatsRecord``, and alone the private owner of an emitter or
+    channel nobody wired to a replica."""
+
+    __slots__ = ("op_name", "recorder", "stage_ns", "stage_n")
+
+    def __init__(self, op_name: str = "") -> None:
+        self.op_name = op_name
+        self.recorder = None  # the owning worker's FlightRecorder
+        self.stage_ns: List[int] = [0] * len(STAGES)
+        self.stage_n: List[int] = [0] * len(STAGES)
+
+    def stage(self, name: str, op: Optional[str] = None) -> Stage:
+        return Stage(self, name, op)
+
+    def stage_usec(self, name: str) -> float:
+        return self.stage_ns[_INDEX[name]] / 1e3
+
+    def stage_count(self, name: str) -> int:
+        return self.stage_n[_INDEX[name]]
+
+    def stage_fields(self) -> Dict[str, Any]:
+        """The ``get_stats()`` fields ``STAGES`` names."""
+        d: Dict[str, Any] = {}
+        for i, sdef in enumerate(STAGES.values()):
+            if sdef.total is not None:
+                d[sdef.total] = round(self.stage_ns[i] / 1e3, 1)
+            if sdef.count is not None:
+                d[sdef.count] = self.stage_n[i]
+        return d

@@ -242,6 +242,8 @@ class ColumnarSinkReplica(BasicReplica):
     def __init__(self, op, idx):
         super().__init__(op, idx)
         self._e2e = self.stats.hist_e2e
+        self._st_d2h = self.stats.stage("d2h")
+        self._st_sink = self.stats.stage("sink")
 
     def handle_msg(self, ch: int, msg: Any) -> None:
         self.stats.start_svc()
@@ -270,11 +272,15 @@ class ColumnarSinkReplica(BasicReplica):
                 self._e2e.record(now - msg.trace_max)
                 if msg.trace_max != msg.trace_min:
                     self._e2e.record(now - msg.trace_min)
-            cols = {name: np.asarray(col)[:n]
-                    for name, col in msg.fields.items()}
+            # the host read of each column (waits for its D2H), then the
+            # user's functor
+            with self._st_d2h(msg.bid, msg.cause):
+                cols = {name: np.asarray(col)[:n]
+                        for name, col in msg.fields.items()}
             ts = msg.ts_host[:n]
             self.context._set_meta(int(ts[-1]) if n else 0, self.cur_wm)
-            self._consume(cols, ts)
+            with self._st_sink(msg.bid, msg.cause):
+                self._consume(cols, ts)
         self.stats.end_svc(n)
 
     def _consume(self, cols, ts) -> None:

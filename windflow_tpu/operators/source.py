@@ -10,7 +10,6 @@ equals the tuple timestamp (monotone because "now" is monotone).
 from __future__ import annotations
 
 import os
-import time
 from typing import Any, Callable, Dict, Optional
 
 import numpy as np
@@ -132,6 +131,7 @@ class SourceReplica(BasicReplica):
         # ``inputs_received & mask`` zero, so the hot path costs the
         # same with tracing off or sampling 1/64
         self._trace_mask = self.stats.sample_every - 1
+        self._st_ingest = self.stats.stage("ingest")  # one column block
         # aligned checkpointing (windflow_tpu.checkpoint): the coordinator
         # bumps an epoch; we notice at the next tuple boundary, snapshot
         # our replay position and inject the barrier downstream
@@ -304,48 +304,49 @@ class SourceReplica(BasicReplica):
         self.emitter.emit(payload, ts, self.cur_wm)
 
     def ship_columns(self, cols, ts_arr, wm: int) -> None:
-        t0_ns = time.perf_counter_ns()
-        if self._coord is not None and not self._inject_suppressed \
-                and self._coord.requested_id != self._last_ckpt:
-            self._maybe_inject()  # before the push, like ship()
-        gate = self._gate
-        if gate is not None:
-            if gate.pending:
-                # row-path records accepted into the buffer precede
-                # this batch: emit them (with their accept-time
-                # watermarks) first — discarding them here would lose
-                # accepted records, emitting them later would reorder
-                for p, t, w in gate.drain_pending():
-                    self._advance_wm(w)
-                    self._emit_admitted(p, t)
-            if gate.released:
-                self._gate = None  # recovery: back to the ungated path
-            else:
-                cols, ts_arr, n = gate.offer_columns(cols, ts_arr)
-                if n == 0:
-                    return
-        if wm > self.cur_wm:
-            self.cur_wm = wm
-            self.stats.wm_current = wm
-            self.stats.wm_advances += 1
-        st = self.stats
-        n = len(ts_arr)
-        base = st.inputs_received
-        st.inputs_received = base + n
-        trace_rows = None
-        se = st.sample_every
-        if se:
-            # vectorized mask gate: the traced cohort is exactly the rows
-            # the row path would stamp — global positions base+1+i that
-            # are multiples of sample_every — computed as one arange, all
-            # sharing one wall-clock stamp (per-row clock reads would
-            # defeat the no-Python fast path)
-            first = (-(base + 1)) % se
-            if first < n:
-                trace_rows = np.arange(first, n, se)
-                self.emitter.trace_ts = current_time_usecs()
-        self.emitter.emit_columns(cols, ts_arr, self.cur_wm, trace_rows)
-        st.note_ingest_block(n, time.perf_counter_ns() - t0_ns)
+        # one pushed block, gate to emit, waits included (blk:ingest)
+        with self._st_ingest():
+            if self._coord is not None and not self._inject_suppressed \
+                    and self._coord.requested_id != self._last_ckpt:
+                self._maybe_inject()  # before the push, like ship()
+            gate = self._gate
+            if gate is not None:
+                if gate.pending:
+                    # row-path records accepted into the buffer precede
+                    # this batch: emit them (with their accept-time
+                    # watermarks) first — discarding them here would lose
+                    # accepted records, emitting them later would reorder
+                    for p, t, w in gate.drain_pending():
+                        self._advance_wm(w)
+                        self._emit_admitted(p, t)
+                if gate.released:
+                    self._gate = None  # recovery: back to the ungated path
+                else:
+                    cols, ts_arr, n = gate.offer_columns(cols, ts_arr)
+                    if n == 0:
+                        return
+            if wm > self.cur_wm:
+                self.cur_wm = wm
+                self.stats.wm_current = wm
+                self.stats.wm_advances += 1
+            st = self.stats
+            n = len(ts_arr)
+            base = st.inputs_received
+            st.inputs_received = base + n
+            trace_rows = None
+            se = st.sample_every
+            if se:
+                # vectorized mask gate: the traced cohort is exactly the rows
+                # the row path would stamp — global positions base+1+i that
+                # are multiples of sample_every — computed as one arange, all
+                # sharing one wall-clock stamp (per-row clock reads would
+                # defeat the no-Python fast path)
+                first = (-(base + 1)) % se
+                if first < n:
+                    trace_rows = np.arange(first, n, se)
+                    self.emitter.trace_ts = current_time_usecs()
+            self.emitter.emit_columns(cols, ts_arr, self.cur_wm, trace_rows)
+            st.ingest_rows += n
 
 
 class Columnar_Source(Source):

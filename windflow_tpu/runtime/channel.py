@@ -22,16 +22,12 @@ from typing import Any, List, Optional, Tuple
 
 from ..basic import DEFAULT_BUFFER_CAPACITY, SupervisorTeardown
 from ..message import EOS_SENTINEL
+from ..monitoring.tracing import StageCounters
 
 
 def _teardown() -> SupervisorTeardown:
     return SupervisorTeardown(
         "channel closed: the supervisor is rebuilding the runtime plane")
-# flight-recorder spans for blocked puts/gets: recorded into the CALLING
-# thread's own ring (a producer blocks on the consumer's channel, so the
-# channel itself cannot own a single-writer ring); only the already-slow
-# blocked paths ever touch this
-from ..monitoring.flightrec import thread_recorder
 
 
 class Channel:
@@ -41,8 +37,8 @@ class Channel:
     """
 
     __slots__ = ("_q", "_lock", "_not_empty", "_not_full", "capacity",
-                 "n_inputs", "depth_max", "puts_blocked", "blocked_put_ns",
-                 "blocked_get_ns", "closed")
+                 "n_inputs", "depth_max", "_st_put", "_st_get",
+                 "closed")
 
     def __init__(self, capacity: int = DEFAULT_BUFFER_CAPACITY) -> None:
         self._q: deque = deque()
@@ -58,13 +54,32 @@ class Channel:
         self.closed = False
         # backpressure / occupancy instrumentation (monitoring plane):
         # producers blocked on a full queue (this stage IS the bottleneck)
-        # vs the consumer blocked on an empty one (it is starved). Clocks
-        # are read only on the blocked paths — the uncontended hot path
-        # pays one compare for the high-water mark.
+        # vs the consumer blocked on an empty one (it is starved): the
+        # ``wait:put`` / ``wait:get`` stages, entered only on the blocked
+        # paths — the uncontended hot path pays one compare for the
+        # high-water mark. Counted on the consumer's StatsRecord once the
+        # graph wires it (``bind_stats``), privately until then.
         self.depth_max = 0
-        self.puts_blocked = 0
-        self.blocked_put_ns = 0
-        self.blocked_get_ns = 0
+        self.bind_stats(StageCounters())
+
+    def bind_stats(self, stats: StageCounters) -> None:
+        """Count this channel's waits on ``stats`` (the consuming
+        replica's record: ``Queue_blocked_put/get_usec``) and name their
+        spans after it."""
+        self._st_put = stats.stage("put")
+        self._st_get = stats.stage("get")
+
+    @property
+    def puts_blocked(self) -> int:
+        return self._st_put.count
+
+    @property
+    def blocked_put_ns(self) -> int:
+        return self._st_put.total_ns
+
+    @property
+    def blocked_get_ns(self) -> int:
+        return self._st_get.total_ns
 
     def register_input(self) -> int:
         """Returns the channel index assigned to a new producer edge."""
@@ -77,17 +92,11 @@ class Channel:
             if self.closed:
                 raise _teardown()
             if len(self._q) >= self.capacity:
-                self.puts_blocked += 1
-                t0 = time.monotonic_ns()
-                while len(self._q) >= self.capacity:
-                    self._not_full.wait()
-                    if self.closed:
-                        raise _teardown()
-                dt = time.monotonic_ns() - t0
-                self.blocked_put_ns += dt
-                rec = thread_recorder()
-                if rec is not None:
-                    rec.event("ch_put_blocked", dt / 1e3)
+                with self._st_put():
+                    while len(self._q) >= self.capacity:
+                        self._not_full.wait()
+                        if self.closed:
+                            raise _teardown()
             self._q.append((ch_idx, msg))
             if len(self._q) > self.depth_max:
                 self.depth_max = len(self._q)
@@ -103,16 +112,11 @@ class Channel:
                 if not self._q:
                     if self.closed:
                         raise _teardown()
-                    t0 = time.monotonic_ns()
-                    while not self._q:
-                        self._not_empty.wait()
-                        if self.closed and not self._q:
-                            raise _teardown()
-                    dt = time.monotonic_ns() - t0
-                    self.blocked_get_ns += dt
-                    rec = thread_recorder()
-                    if rec is not None:
-                        rec.event("ch_get_blocked", dt / 1e3)
+                    with self._st_get():
+                        while not self._q:
+                            self._not_empty.wait()
+                            if self.closed and not self._q:
+                                raise _teardown()
                 item = self._q.popleft()
                 self._not_full.notify()
                 return item
@@ -121,23 +125,17 @@ class Channel:
             if not self._q:
                 if self.closed:
                     raise _teardown()
-                t0 = time.monotonic_ns()
-                while not self._q:
-                    if self.closed:
-                        raise _teardown()
-                    remaining = deadline - time.monotonic()
-                    if remaining <= 0:
-                        self.blocked_get_ns += time.monotonic_ns() - t0
-                        return None
-                    self._not_empty.wait(remaining)
-                dt = time.monotonic_ns() - t0
-                self.blocked_get_ns += dt
-                # data arrived after a real wait: span (timeouts return
-                # None above without an event — idle waits would flood
-                # the ring on a quiet stream)
-                rec = thread_recorder()
-                if rec is not None:
-                    rec.event("ch_get_blocked", dt / 1e3)
+                with self._st_get() as span:
+                    while not self._q:
+                        if self.closed:
+                            raise _teardown()
+                        remaining = deadline - time.monotonic()
+                        if remaining <= 0:
+                            # counted, but kept out of the ring: idle
+                            # ticks would flood it on a quiet stream
+                            span.silent = True
+                            return None
+                        self._not_empty.wait(remaining)
             item = self._q.popleft()
             self._not_full.notify()
             return item

@@ -51,20 +51,21 @@ body IS the chain program, threading the same carried state
 batch-to-batch).
 
 Per-stage instrumentation lands in the replica's ``StatsRecord``
-(``Dispatch_host_prep_usec`` / ``Dispatch_commit_usec`` EWMAs + totals,
-forced-drain stall count, max queue depth) so the host-prep/device split
-is measured, not asserted — ``scripts/microbench.py --dispatch`` reports
-the split and the overlap efficiency it buys.
+through the stage helper (``monitoring/tracing.py``): ``wf:prep`` around
+the host prep (``prep()``), ``wf:commit`` around each commit, and the
+time a prepared batch sat in the queue as the ``wait:queue`` residency
+(``Dispatch_host_prep/commit_total_usec``, ``Dispatch_queue_wait_total_usec``
+and their EWMAs, forced-drain stall count, max queue depth), so the
+host-prep/device split is measured, not asserted.
 """
 
 from __future__ import annotations
 
 import os
-import time
 from collections import deque
 from typing import Callable, Optional
 
-from ..monitoring.tracing import device_span
+from ..monitoring.tracing import StageCounters, stamp_ns
 
 _DEFAULT_DEPTH = 2
 
@@ -104,40 +105,39 @@ class DeviceDispatchQueue:
         if self.depth > 0 and self.megabatch > 1:
             self.depth = max(self.depth, self.megabatch)
         self.stats = stats
-        # jax.profiler span label so captured device traces line up with
-        # the Dispatch_commit stats (prep span lives in the replica)
-        self._span_commit = "wf:commit:" + (
-            stats.op_name if stats is not None and stats.op_name else "?")
-        # entries are (commit, enqueue_perf_counter): the enqueue stamp
-        # feeds the flight recorder's dispatch_wait span (how long the
-        # prepared batch sat in the queue before its commit ran)
+        owner = stats if stats is not None else StageCounters()
+        self._st_prep = owner.stage("prep")
+        self._st_queue = owner.stage("queue")
+        self._st_commit = owner.stage("commit")
+        # entries are (commit, batch id, enqueue stamp): the stamp feeds
+        # the wait:queue residency (how long the prepared batch sat in
+        # the queue before its commit ran)
         self._q: "deque" = deque()
 
     def __len__(self) -> int:
         return len(self._q)
 
     # ------------------------------------------------------------------
-    def submit(self, commit: Callable[[], None],
-               prep_us: float = 0.0) -> None:
-        """Record the host-prep time and queue (or, at depth 0, run) one
-        batch's device-commit stage. Overflowing ``depth`` commits the
-        oldest entry — the blocking pop that gives the pipeline its
-        bounded lag."""
-        if self.stats is not None:
-            self.stats.note_host_prep(prep_us)
+    def prep(self, b: int = 0):
+        """The ``wf:prep`` stage of batch ``b``: the replica wraps its
+        host-prep in it (``with dispatch.prep(batch.bid):``), which also
+        counts the batch (``Dispatch_batches``)."""
+        return self._st_prep(b)
+
+    def submit(self, commit: Callable[[], None], b: int = 0) -> None:
+        """Queue (or, at depth 0, run) the device-commit stage of batch
+        ``b``. Overflowing ``depth`` commits the oldest entry — the
+        blocking pop that gives the pipeline its bounded lag."""
         if self.depth == 0:
-            self._run(commit, None)
+            self._run(commit, b)
             return
-        self._q.append((commit, time.perf_counter()))
+        self._q.append((commit, b, stamp_ns()))
         # record the PEAK occupancy (post-append, pre-pop): a pipeline
         # running steady-state at full depth overflows on every submit,
         # and recording only the post-pop length would under-report
         # Dispatch_queue_depth_max as never-saturated
         if self.stats is not None:
             self.stats.note_dispatch_depth(len(self._q))
-            rec = self.stats.recorder
-            if rec is not None:
-                rec.event("dispatch_submit", 0.0, len(self._q))
         while len(self._q) > self.depth:
             self._pop_run()
 
@@ -191,38 +191,23 @@ class DeviceDispatchQueue:
         (``FusedTPUReplica._run_megabatch``): one program, one dispatch,
         len(entries) batches. Error unwind matches ``_run`` — a failed
         group aborts the remaining pipeline entries."""
-        t0 = time.perf_counter()
-        if self.stats is not None:
-            rec = self.stats.recorder
-            if rec is not None:
-                for _commit, enq_t in entries:
-                    rec.event("dispatch_wait", (t0 - enq_t) * 1e6)
-        commits = [commit for commit, _t in entries]
+        for _commit, b, enq_ns in entries:
+            self._st_queue.since(enq_ns, b)
+        commits = [commit for commit, _b, _t in entries]
         try:
-            with device_span(self._span_commit):
+            with self._st_commit(entries[0][1]):  # named for its first batch
                 commits[0].scan_runner(commits)
         except BaseException:
             self.abort()
             raise
-        finally:
-            if self.stats is not None:
-                self.stats.note_dispatch_commit(
-                    (time.perf_counter() - t0) * 1e6)
 
-    def _run(self, commit: Callable[[], None],
-             enq_t: Optional[float] = None) -> None:
-        t0 = time.perf_counter()
-        if enq_t is not None and self.stats is not None:
-            rec = self.stats.recorder
-            if rec is not None:
-                rec.event("dispatch_wait", (t0 - enq_t) * 1e6)
+    def _run(self, commit: Callable[[], None], b: int = 0,
+             enq_ns: Optional[int] = None) -> None:
+        if enq_ns is not None:
+            self._st_queue.since(enq_ns, b)
         try:
-            with device_span(self._span_commit):
+            with self._st_commit(b):
                 commit()
         except BaseException:
             self.abort()
             raise
-        finally:
-            if self.stats is not None:
-                self.stats.note_dispatch_commit(
-                    (time.perf_counter() - t0) * 1e6)

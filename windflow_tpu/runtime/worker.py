@@ -27,12 +27,16 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Any, List, Optional
+from typing import Any, List, Optional, Tuple
 
 from ..basic import RescaleTeardown
 from ..message import EOS, Barrier
 from .channel import Channel
 from .collectors import BarrierAligner
+
+# Linux and most Unixes; where it is missing Thread_cpu_usec reads 0 until
+# the thread has ended
+_THREAD_CPU_CLOCK = getattr(time, "pthread_getcpuclockid", None)
 
 
 class Worker(threading.Thread):
@@ -83,6 +87,18 @@ class Worker(threading.Thread):
                 st = getattr(n, "stats", None)
                 if st is not None:
                     st.recorder = flightrec
+        # this thread's CPU and wall clocks, reported by the first chain
+        # node that owns a StatsRecord (Thread_cpu_usec / Thread_wall_usec;
+        # where Worker_idle_ticks goes). Nothing on the hot path: another
+        # thread reads the clocks at poll time, under a lock that keeps
+        # this thread alive for the read, and run() leaves the final
+        # values behind when it ends
+        self._clock_lock = threading.Lock()
+        self._t0_ns: Optional[int] = None
+        self._end_clocks: Optional[Tuple[int, int]] = None
+        st = self._stats()
+        if st is not None:
+            st.worker = self
         self._aligner: Optional[BarrierAligner] = None
         if coordinator is not None and channel is None and chain:
             # source chain: the source replica injects barriers at tuple
@@ -100,6 +116,29 @@ class Worker(threading.Thread):
                     bind(coordinator)
 
     def run(self) -> None:
+        self._t0_ns = time.perf_counter_ns()
+        try:
+            self._run()
+        finally:
+            with self._clock_lock:
+                self._end_clocks = (
+                    time.thread_time_ns(),
+                    time.perf_counter_ns() - self._t0_ns)
+
+    def thread_clocks(self) -> Tuple[int, int]:
+        """``(cpu_ns, wall_ns)`` of this worker's thread: CPU time it has
+        used and time since it started, frozen once it has ended. Called
+        from other threads (``StatsRecord.to_dict``)."""
+        with self._clock_lock:
+            if self._end_clocks is not None:
+                return self._end_clocks
+            if self._t0_ns is None:
+                return 0, 0  # not started
+            cpu_ns = (time.clock_gettime_ns(_THREAD_CPU_CLOCK(self.ident))
+                      if _THREAD_CPU_CLOCK is not None else 0)
+            return cpu_ns, time.perf_counter_ns() - self._t0_ns
+
+    def _run(self) -> None:
         if self.flightrec is not None:
             # blocked channel puts/gets and shared-program compiles find
             # this thread's ring through the TLS slot

@@ -1082,7 +1082,12 @@ class PipeGraph:
                 channel = stage.channels[i]
                 # queue-occupancy/backpressure gauges: the consumer's
                 # stats record reads its input channel live (Queue_*)
-                stage.first_op.replicas[i].stats.input_channel = channel
+                stats = stage.first_op.replicas[i].stats
+                stats.input_channel = channel
+                if hasattr(channel, "bind_stats"):
+                    # wait:put / wait:get count there (the native ring
+                    # waits in C++ and times nothing)
+                    channel.bind_stats(stats)
                 coll = self._make_collector(stage, i)
                 if coll is not None:
                     chain.append(coll)

@@ -30,6 +30,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ..message import StreamMsg
+from ..monitoring.tracing import next_batch_id
 from .schema import TupleSchema
 
 
@@ -57,7 +58,7 @@ def bucket_capacity(n: int, minimum: int = 8) -> int:
 class BatchTPU(StreamMsg):
     __slots__ = ("fields", "ts_host", "size", "capacity", "wm", "is_punct",
                  "stream_tag", "id", "schema", "host_keys", "key_slots",
-                 "slot_of_key", "trace_min", "trace_max")
+                 "slot_of_key", "trace_min", "trace_max", "bid", "cause")
 
     def __init__(self, fields: Dict[str, Any], ts_host: np.ndarray, size: int,
                  schema: TupleSchema, wm: int = 0,
@@ -81,6 +82,14 @@ class BatchTPU(StreamMsg):
         # (0 = none traced; monitoring/tracing.py)
         self.trace_min = 0
         self.trace_max = 0
+        # host-timeline identity (monitoring/tracing.py): ``bid`` is given
+        # once at the staging edge and travels with every batch derived
+        # from this one, so its spans line up from wf:stage to wf:sink;
+        # a batch another batch's commit MADE (a window fire, a re-shard
+        # split) gets its own ``bid`` and names that batch as ``cause``.
+        # ``id`` above stays the per-channel sequence number
+        self.bid = 0
+        self.cause = 0
 
     # -- protocol ----------------------------------------------------------
     def min_watermark(self) -> int:
@@ -192,10 +201,21 @@ class BatchTPU(StreamMsg):
         return self.schema.from_columns(host_cols, self.ts_host, self.size)
 
     def copy_trace_from(self, src: "BatchTPU") -> "BatchTPU":
-        """Propagate origin stamps from the batch this one derives from
-        (operator outputs, gathers, compactions)."""
+        """Propagate origin stamps and timeline identity from the batch
+        this one derives from (operator outputs, compactions, copies)."""
         self.trace_min = src.trace_min
         self.trace_max = src.trace_max
+        self.bid = src.bid
+        self.cause = src.cause
+        return self
+
+    def caused_by(self, src: "BatchTPU") -> "BatchTPU":
+        """A NEW batch made while committing ``src`` (one of several
+        gathered from it): origin stamps travel, the identity is fresh
+        and names ``src`` as its cause."""
+        self.copy_trace_from(src)
+        self.bid = next_batch_id()
+        self.cause = src.bid
         return self
 
     def with_fields(self, new_fields: Dict[str, Any]) -> "BatchTPU":

@@ -31,6 +31,7 @@ import numpy as np
 
 from ..basic import ExecutionMode, WindFlowError
 from ..message import Batch
+from ..monitoring.tracing import StageCounters, next_batch_id, stamp_ns
 from ..runtime.emitters import BasicEmitter
 from .batch import BatchTPU, bucket_capacity
 from .schema import TupleSchema
@@ -73,6 +74,11 @@ class TPUStageEmitter(BasicEmitter):
         # per-buffer min/max origin stamps of traced rows (latency tracing)
         self._trace_lo: List[int] = [0] * n_bufs
         self._trace_hi: List[int] = [0] * n_bufs
+        # timeline identity of the batch each buffer is filling (given
+        # when the buffer opens, so wf:stage and wf:h2d carry the id the
+        # shipped batch keeps; monitoring/tracing.py)
+        self._bids: List[int] = [0] * n_bufs
+        self._bind_stages(StageCounters())
         self._rr = 0
         # time-bounded staging (reference: the GPU keyby emitter flushes
         # partial batches rather than parking them, keyby_emitter_gpu.hpp:
@@ -98,6 +104,14 @@ class TPUStageEmitter(BasicEmitter):
         from ..recycling import ArrayPool, InFlightRecycler
         self.recycler = InFlightRecycler(ArrayPool())
         self._pool_seen = (0, 0)  # (hits, misses) already added to stats
+
+    def _bind_stages(self, owner: StageCounters) -> None:
+        self._st_stage = owner.stage("stage")
+        self._st_h2d = owner.stage("h2d")
+
+    def set_stats(self, stats) -> None:
+        super().set_stats(stats)
+        self._bind_stages(stats)
 
     def _update_pool_stats(self) -> None:
         """Accumulate pool counter DELTAS: several emitters may share one
@@ -199,21 +213,20 @@ class TPUStageEmitter(BasicEmitter):
         rows = self._rows[buf]
         if not rows:
             return
-        rec = self.stats.recorder if self.stats is not None else None
-        t0 = time.perf_counter_ns() if rec is not None else 0
         keys = self._keys[buf] if self.key_extractor is not None else None
-        batch = BatchTPU.stage(rows, self.schema, self._wms[buf], keys,
-                               bucket_capacity(self.output_batch_size
-                                               if len(rows) <= self.output_batch_size
-                                               else len(rows)),
-                               recycler=self.recycler)
+        bid = self._bids[buf] = next_batch_id()
+        # row-staged batches are built whole here: the rows -> columns
+        # encode + pad + device_put are all this stage
+        with self._st_h2d(bid):
+            batch = BatchTPU.stage(
+                rows, self.schema, self._wms[buf], keys,
+                bucket_capacity(self.output_batch_size
+                                if len(rows) <= self.output_batch_size
+                                else len(rows)),
+                recycler=self.recycler)
         n = len(rows)
         self._rows[buf] = []
         self._keys[buf] = []
-        if rec is not None:
-            # host batch construction IS this plane's host_prep: the
-            # rows -> columns encode + pad + device_put
-            rec.event("host_prep", (time.perf_counter_ns() - t0) / 1e3, n)
         self._dispatch_batch(buf, batch, n)
 
     def _ship_cbuf(self, buf: int) -> None:
@@ -224,19 +237,18 @@ class TPUStageEmitter(BasicEmitter):
         n = self._ccount[buf]
         if not n:
             return
-        rec = self.stats.recorder if self.stats is not None else None
-        t0 = time.perf_counter_ns() if rec is not None else 0
-        kparts = self._ckparts[buf]
-        keys = None
-        if kparts:
-            keys = kparts[0] if len(kparts) == 1 else np.concatenate(kparts)
-        batch = BatchTPU.stage_prefilled(
-            self._cbuf[buf], self._cts[buf], n, self.schema,
-            self._wms[buf], keys, self.recycler)
-        if rec is not None:
-            # block-staged host_prep: buffers already filled in place, so
-            # this is the key concat + device_put only
-            rec.event("host_prep", (time.perf_counter_ns() - t0) / 1e3, n)
+        # buffers already filled in place (wf:stage), so this stage is
+        # the key concat + the device_put calls (issue time: the copy
+        # itself is asynchronous)
+        with self._st_h2d(self._bids[buf]):
+            kparts = self._ckparts[buf]
+            keys = None
+            if kparts:
+                keys = (kparts[0] if len(kparts) == 1
+                        else np.concatenate(kparts))
+            batch = BatchTPU.stage_prefilled(
+                self._cbuf[buf], self._cts[buf], n, self.schema,
+                self._wms[buf], keys, self.recycler)
         # ownership of the staging buffers moved to the batch/recycler:
         # a fresh set is allocated at the next append (device_put may
         # alias the host buffer on the CPU backend)
@@ -254,6 +266,7 @@ class TPUStageEmitter(BasicEmitter):
         batch.trace_min = self._trace_lo[buf]
         batch.trace_max = self._trace_hi[buf]
         self._trace_lo[buf] = self._trace_hi[buf] = 0
+        batch.bid = self._bids[buf]
         self._first_append[buf] = None
         if self.routing == "keyby":
             batch.id = self._next_ids[buf]
@@ -315,32 +328,12 @@ class TPUStageEmitter(BasicEmitter):
             tmask = np.zeros(n, dtype=bool)
             tmask[trace_rows] = True
         if self.routing == "keyby":
-            kcol, dests = self._block_dests(cols, n)
-            if self.num_dests == 1:
-                self._append_part(0, {k: np.asarray(v) for k, v in
-                                      cols.items()},
-                                  ts_arr, np.array(kcol), wm, t_trace,
-                                  tmask)
-            else:
-                # ONE stable sort + one gather per column routes the
-                # whole block; per-destination slices are then contiguous
-                # views (zero further copies before the staging write)
-                order = np.argsort(dests, kind="stable")
-                counts = np.bincount(dests, minlength=self.num_dests)
-                scols = {k: np.asarray(v)[order] for k, v in cols.items()}
-                sts = ts_arr[order]
-                skeys = kcol[order]
-                stm = tmask[order] if tmask is not None else None
-                off = 0
-                for d in range(self.num_dests):
-                    c = int(counts[d])
-                    if c:
-                        sl = slice(off, off + c)
-                        self._append_part(
-                            d, {k: v[sl] for k, v in scols.items()},
-                            sts[sl], skeys[sl], wm, t_trace,
-                            stm[sl] if stm is not None else None)
-                    off += c
+            # routing the block is staging work with no batch yet (b=0);
+            # the appends below ship, so they stay outside this span
+            with self._st_stage():
+                parts = self._route_block(cols, ts_arr, n, tmask)
+            for d, pcols, pts, pkeys, ptm in parts:
+                self._append_part(d, pcols, pts, pkeys, wm, t_trace, ptm)
         else:
             keys = None
             if self.key_field is not None:
@@ -354,6 +347,34 @@ class TPUStageEmitter(BasicEmitter):
         # not per columnar push
         self._emit_count += max(0, n - 1)
         self._maybe_generate_punctuation(wm)
+
+    def _route_block(self, cols, ts_arr, n: int, tmask) -> list:
+        """A KEYBY block as per-destination ``(dest, cols, ts, keys,
+        tmask)`` slices."""
+        kcol, dests = self._block_dests(cols, n)
+        if self.num_dests == 1:
+            return [(0, {k: np.asarray(v) for k, v in cols.items()},
+                     ts_arr, np.array(kcol), tmask)]
+        # ONE stable sort + one gather per column routes the whole
+        # block; per-destination slices are then contiguous views (zero
+        # further copies before the staging write)
+        order = np.argsort(dests, kind="stable")
+        counts = np.bincount(dests, minlength=self.num_dests)
+        scols = {k: np.asarray(v)[order] for k, v in cols.items()}
+        sts = ts_arr[order]
+        skeys = kcol[order]
+        stm = tmask[order] if tmask is not None else None
+        parts = []
+        off = 0
+        for d in range(self.num_dests):
+            c = int(counts[d])
+            if c:
+                sl = slice(off, off + c)
+                parts.append((d, {k: v[sl] for k, v in scols.items()},
+                              sts[sl], skeys[sl],
+                              stm[sl] if stm is not None else None))
+            off += c
+        return parts
 
     def _block_dests(self, cols, n: int):
         """(key column, destination vector) for a KEYBY block — hashed
@@ -398,8 +419,10 @@ class TPUStageEmitter(BasicEmitter):
             # unbatched edge: the block ships as-is (no re-batching);
             # _dispatch_batch transfers the trace stamps, so fold them
             # into the buffer slots it reads
-            batch = BatchTPU.stage_columns(pcols, pts, self.schema, wm,
-                                           pkeys, self.recycler)
+            bid = self._bids[buf] = next_batch_id()
+            with self._st_h2d(bid):
+                batch = BatchTPU.stage_columns(pcols, pts, self.schema, wm,
+                                               pkeys, self.recycler)
             if t_trace and (tmask is None or tmask.any()):
                 self._trace_lo[buf] = self._trace_hi[buf] = t_trace
             self._wms[buf] = wm
@@ -408,23 +431,27 @@ class TPUStageEmitter(BasicEmitter):
         names = list(self.schema.fields)
         off = 0
         while off < n:
-            cb = self._cbuf[buf]
-            if cb is None:
-                cb = self._cbuf_alloc(buf)
             cnt = self._ccount[buf]
             if cnt == 0:
                 self._wms[buf] = wm
+                self._bids[buf] = next_batch_id()
                 if self._stage_age_s is not None:
                     self._first_append[buf] = time.monotonic()
             elif wm < self._wms[buf]:
                 self._wms[buf] = wm
             take = min(n - off, obs - cnt)
             end = off + take
-            for name in names:
-                cb[name][cnt:cnt + take] = pcols[name][off:end]
-            self._cts[buf][cnt:cnt + take] = pts[off:end]
-            if pkeys is not None:
-                self._ckparts[buf].append(pkeys[off:end])
+            # the copy alone: shipping (wf:h2d, then a channel put that
+            # may block) happens after the span has closed
+            with self._st_stage(self._bids[buf]):
+                cb = self._cbuf[buf]
+                if cb is None:
+                    cb = self._cbuf_alloc(buf)
+                for name in names:
+                    cb[name][cnt:cnt + take] = pcols[name][off:end]
+                self._cts[buf][cnt:cnt + take] = pts[off:end]
+                if pkeys is not None:
+                    self._ckparts[buf].append(pkeys[off:end])
             if t_trace and (tmask is None or tmask[off:end].any()):
                 if self._trace_lo[buf] == 0 or t_trace < self._trace_lo[buf]:
                     self._trace_lo[buf] = t_trace
@@ -530,27 +557,49 @@ class _D2HPipeline:
         # > age/depth (25 ms at the defaults), where the async D2H of an
         # entry that old has normally completed — eviction then is a
         # cheap consume, not a sync-fetch stall
-        self._max_age_s = age_ms / 1e3 if age_ms > 0 else None
-        self._pending: "deque[Tuple[float, BatchTPU]]" = deque()
+        self._max_age_ns = int(age_ms * 1e6) if age_ms > 0 else None
+        # (enqueue stamp_ns, batch): the stamp bounds the entry's age and
+        # feeds the wait:fifo residency when the batch leaves
+        self._pending: "deque[Tuple[int, BatchTPU]]" = deque()
+        self._pipe_bind(StageCounters())
+
+    def _pipe_bind(self, owner: StageCounters) -> None:
+        """Count this FIFO's stages on ``owner`` (every ``set_stats`` of a
+        class that mixes this in calls it with the replica's record)."""
+        self._st_fifo = owner.stage("fifo")
+        self._st_exit = owner.stage("exit")
 
     def _pipe_process(self, batch: BatchTPU) -> None:
         raise NotImplementedError
 
+    def _pipe_run(self, batch: BatchTPU,
+                  queued_ns: Optional[int] = None) -> None:
+        """One batch through ``_pipe_process`` as the ``wf:exit`` stage;
+        ``queued_ns`` is its ``_pipe_add`` stamp (None: it never waited)."""
+        if queued_ns is not None:
+            self._st_fifo.since(queued_ns, batch.bid, batch.cause)
+        with self._st_exit(batch.bid, batch.cause):
+            self._pipe_process(batch)
+
     def _pipe_add(self, batch: BatchTPU) -> None:
-        self._pending.append((time.monotonic(), batch))
+        self._pending.append((stamp_ns(), batch))
         stats = getattr(self, "stats", None)
         if stats is not None:
             stats.note_pipe_depth(len(self._pending))
         while len(self._pending) > self.depth:
-            self._pipe_process(self._pending.popleft()[1])
-        if self._max_age_s is not None:
-            horizon = time.monotonic() - self._max_age_s
+            self._pipe_pop()
+        if self._max_age_ns is not None:
+            horizon = stamp_ns() - self._max_age_ns
             while self._pending and self._pending[0][0] < horizon:
-                self._pipe_process(self._pending.popleft()[1])
+                self._pipe_pop()
+
+    def _pipe_pop(self) -> None:
+        queued_ns, batch = self._pending.popleft()
+        self._pipe_run(batch, queued_ns)
 
     def _drain(self) -> None:
         while self._pending:
-            self._pipe_process(self._pending.popleft()[1])
+            self._pipe_pop()
 
     def on_idle(self) -> bool:
         """Worker idle tick: deliver queued batches — an idle stream must
@@ -864,7 +913,7 @@ def gather_sub_batch(batch: BatchTPU, idx: np.ndarray,
     keys2 = host_keys
     sub = BatchTPU(sub_fields, ts2, idx.size, batch.schema, batch.wm, keys2)
     sub.stream_tag = batch.stream_tag
-    return sub.copy_trace_from(batch)
+    return sub.caused_by(batch)  # one of several made from ``batch``
 
 
 class TPUKeyByEmitter(BasicEmitter, _D2HPipeline):
@@ -916,7 +965,11 @@ class TPUKeyByEmitter(BasicEmitter, _D2HPipeline):
             self._pipe_add(batch)
             return
         self._drain()  # keep stream order ahead of an immediate route
-        self._pipe_process(batch)
+        self._pipe_run(batch)
+
+    def set_stats(self, stats) -> None:
+        super().set_stats(stats)
+        self._pipe_bind(stats)
 
     def flush(self) -> None:
         # BasicEmitter's propagate_punctuation/send_eos_all call flush()
@@ -984,6 +1037,7 @@ class TPUSplittingEmitter(BasicEmitter, _D2HPipeline):
 
     def set_stats(self, stats) -> None:
         self.stats = stats
+        self._pipe_bind(stats)
         for e in self.inner:
             e.set_stats(stats)
 
@@ -1095,6 +1149,10 @@ class TPUColumnarExitEmitter(BasicEmitter, _D2HPipeline):
         self._pipe_init("WF_EXIT_PIPELINE_DEPTH", 4, depth)
         self._rr = 0
 
+    def set_stats(self, stats) -> None:
+        super().set_stats(stats)
+        self._pipe_bind(stats)
+
     def emit_device_batch(self, batch: BatchTPU) -> None:
         batch.prefetch_host()
         self._pipe_add(batch)
@@ -1141,6 +1199,7 @@ class TPUExitEmitter(BasicEmitter, _D2HPipeline):
     def set_stats(self, stats) -> None:
         self.stats = stats
         self.inner.stats = stats
+        self._pipe_bind(stats)
 
     def _pipe_process(self, batch: BatchTPU) -> None:
         if self.stats is not None:

@@ -51,9 +51,21 @@ from typing import Any, Callable, Dict, List, Optional
 import numpy as np
 
 from ..basic import OpType, RoutingMode, WinType, WindFlowError
+from ..monitoring.tracing import next_batch_id
 from .batch import BatchTPU, bucket_capacity
 from .ops_tpu import TPUOperatorBase, TPUReplicaBase
 from .schema import TupleSchema, broadcast_scalar_fields
+
+
+# XLA module names of the window operator's programs (``jit_step``,
+# ``jit_fire``, ``jit_rebuild`` in a device profile: the benchmark's
+# window_step_roofline metric finds them by ``^jit_(step|fire|rebuild)``,
+# and tests/test_stage_spans.py pins them; the ingest-only variant is a
+# ``step`` too) and the named scopes of the step's phases
+PROG_STEP, PROG_FIRE, PROG_REBUILD = "step", "fire", "rebuild"
+SCOPE_SORT, SCOPE_SCAN, SCOPE_SCATTER = ("ingest_sort", "segmented_scan",
+                                         "leaf_scatter")
+SCOPE_REBUILD, SCOPE_FIRE, SCOPE_EVICT = "level_rebuild", "fire", "evict"
 
 
 def xla_rebuild_levels(combine: Callable, F: int):
@@ -445,16 +457,18 @@ class FfatTPUReplica(TPUReplicaBase):
                 is_end = h_end
                 flat_idx = h_flat
             else:
-                big = jnp.int32(K_cap * F)  # sentinel: late + padding
-                order = jnp.argsort(comp, stable=True)
-                sc = comp[order].astype(jnp.int32)
-                same_prev = jnp.concatenate(
-                    [jnp.zeros((1,), bool), sc[1:] == sc[:-1]])
-                is_end = jnp.concatenate(
-                    [sc[1:] != sc[:-1], jnp.ones((1,), bool)]) & (sc < big)
-                # decode slot/leaf from the sorted composite (F is a power
-                # of two, so these lower to shift/mask)
-                flat_idx = (sc // F) * NNODES + (F + sc % F)
+                with jax.named_scope(SCOPE_SORT):
+                    big = jnp.int32(K_cap * F)  # sentinel: late + padding
+                    order = jnp.argsort(comp, stable=True)
+                    sc = comp[order].astype(jnp.int32)
+                    same_prev = jnp.concatenate(
+                        [jnp.zeros((1,), bool), sc[1:] == sc[:-1]])
+                    is_end = jnp.concatenate(
+                        [sc[1:] != sc[:-1],
+                         jnp.ones((1,), bool)]) & (sc < big)
+                    # decode slot/leaf from the sorted composite (F is a
+                    # power of two, so these lower to shift/mask)
+                    flat_idx = (sc // F) * NNODES + (F + sc % F)
             svals = tmap(lambda a: a[order], vals)
 
             def seg_op(a, b):
@@ -464,22 +478,27 @@ class FfatTPUReplica(TPUReplicaBase):
                 out = tmap(lambda m, y: jnp.where(same_b, m, y), merged, fb)
                 return out, sa & same_b
 
-            scanned, _ = jax.lax.associative_scan(seg_op, (svals, same_prev))
+            with jax.named_scope(SCOPE_SCAN):
+                scanned, _ = jax.lax.associative_scan(
+                    seg_op, (svals, same_prev))
 
             # 2. scatter-combine segment tails into forest leaves
-            safe_idx = jnp.where(is_end, flat_idx, OOB)
-            gather_idx = jnp.where(is_end, flat_idx, 0)
-            leaf_valid = tvalid.reshape(-1)[gather_idx] & is_end
-            cur_leaves = tmap(lambda t: t.reshape(-1)[gather_idx], trees)
-            merged_all = combine(cur_leaves, scanned)
-            new_leaves = tmap(lambda m, sv: jnp.where(leaf_valid, m, sv),
-                              merged_all, scanned)
-            trees = tmap(
-                lambda t, nl: t.reshape(-1).at[safe_idx].set(
-                    nl, mode="drop").reshape(t.shape),
-                trees, new_leaves)
-            tvalid = tvalid.reshape(-1).at[safe_idx].set(
-                True, mode="drop").reshape(tvalid.shape)
+            with jax.named_scope(SCOPE_SCATTER):
+                safe_idx = jnp.where(is_end, flat_idx, OOB)
+                gather_idx = jnp.where(is_end, flat_idx, 0)
+                leaf_valid = tvalid.reshape(-1)[gather_idx] & is_end
+                cur_leaves = tmap(lambda t: t.reshape(-1)[gather_idx],
+                                  trees)
+                merged_all = combine(cur_leaves, scanned)
+                new_leaves = tmap(
+                    lambda m, sv: jnp.where(leaf_valid, m, sv),
+                    merged_all, scanned)
+                trees = tmap(
+                    lambda t, nl: t.reshape(-1).at[safe_idx].set(
+                        nl, mode="drop").reshape(t.shape),
+                    trees, new_leaves)
+                tvalid = tvalid.reshape(-1).at[safe_idx].set(
+                    True, mode="drop").reshape(tvalid.shape)
 
             if ingest_only:
                 # deferred rebuild: leaves are current, internal nodes
@@ -492,20 +511,24 @@ class FfatTPUReplica(TPUReplicaBase):
                         jnp.zeros((1,), jnp.int32))
 
             # 3. rebuild internal levels across the whole forest
-            trees, tvalid = rebuild_levels(trees, tvalid)
+            with jax.named_scope(SCOPE_REBUILD):
+                trees, tvalid = rebuild_levels(trees, tvalid)
 
             # 4. fired-window queries (vmapped over W_cap)
-            ftrees = tmap(lambda t: t[fire_slots], trees)
-            fvalid = tvalid[fire_slots]
-            qv, qr = jax.vmap(window_query)(ftrees, fvalid, fire_starts,
-                                            fire_lens)
-            qv = qv & fire_mask
+            with jax.named_scope(SCOPE_FIRE):
+                ftrees = tmap(lambda t: t[fire_slots], trees)
+                fvalid = tvalid[fire_slots]
+                qv, qr = jax.vmap(window_query)(ftrees, fvalid,
+                                                fire_starts, fire_lens)
+                qv = qv & fire_mask
 
             # 5. evict leaves consumed by the fired windows
-            eflat = jnp.where(evict_mask,
-                              evict_slots * NNODES + (F + evict_leaves), OOB)
-            tvalid = tvalid.reshape(-1).at[eflat].set(
-                False, mode="drop").reshape(tvalid.shape)
+            with jax.named_scope(SCOPE_EVICT):
+                eflat = jnp.where(
+                    evict_mask,
+                    evict_slots * NNODES + (F + evict_leaves), OOB)
+                tvalid = tvalid.reshape(-1).at[eflat].set(
+                    False, mode="drop").reshape(tvalid.shape)
 
             # 6. output wid/key columns built ON DEVICE: they ride the
             # program's batched argument transfer instead of costing one
@@ -531,7 +554,7 @@ class FfatTPUReplica(TPUReplicaBase):
         from ..monitoring.flightrec import instrumented_jit
         return instrumented_jit(
             step, self.stats, label=f"{self.stats.op_name}:step",
-            donate_argnums=(6, 7) if donate else ())
+            program=PROG_STEP, donate_argnums=(6, 7) if donate else ())
 
     def _make_fire_step(self):
         """Fire-only program: vmapped window queries + leaf eviction, no
@@ -563,15 +586,18 @@ class FfatTPUReplica(TPUReplicaBase):
             fire_mask = fire_mask_i != 0
             evict_slots, evict_leaves, evict_mask_i = evict_pack
             evict_mask = evict_mask_i != 0
-            ftrees = tmap(lambda t: t[fire_slots], trees)
-            fvalid = tvalid[fire_slots]
-            qv, qr = jax.vmap(window_query)(ftrees, fvalid, fire_starts,
-                                            fire_lens)
-            qv = qv & fire_mask
-            eflat = jnp.where(evict_mask,
-                              evict_slots * NNODES + (F + evict_leaves), OOB)
-            tvalid = tvalid.reshape(-1).at[eflat].set(
-                False, mode="drop").reshape(tvalid.shape)
+            with jax.named_scope(SCOPE_FIRE):
+                ftrees = tmap(lambda t: t[fire_slots], trees)
+                fvalid = tvalid[fire_slots]
+                qv, qr = jax.vmap(window_query)(ftrees, fvalid,
+                                                fire_starts, fire_lens)
+                qv = qv & fire_mask
+            with jax.named_scope(SCOPE_EVICT):
+                eflat = jnp.where(
+                    evict_mask,
+                    evict_slots * NNODES + (F + evict_leaves), OOB)
+                tvalid = tvalid.reshape(-1).at[eflat].set(
+                    False, mode="drop").reshape(tvalid.shape)
             wid_out = jnp.asarray(fire_wids)
             if use_ktable:
                 key_out = jnp.where(fire_mask, ktable[fire_slots],
@@ -584,7 +610,7 @@ class FfatTPUReplica(TPUReplicaBase):
         from ..monitoring.flightrec import instrumented_jit
         return instrumented_jit(fire, self.stats,
                                 label=f"{self.stats.op_name}:fire",
-                                donate_argnums=(1,))
+                                program=PROG_FIRE, donate_argnums=(1,))
 
     def _make_rebuild_step(self):
         """Standalone full-forest level rebuild: settles deferred
@@ -595,6 +621,7 @@ class FfatTPUReplica(TPUReplicaBase):
         from ..monitoring.flightrec import instrumented_jit
         return instrumented_jit(self._rebuild_fn(), self.stats,
                                 label=f"{self.stats.op_name}:rebuild",
+                                program=PROG_REBUILD,
                                 donate_argnums=(0, 1))
 
     def _ensure_rebuilt(self) -> None:
@@ -907,7 +934,8 @@ class FfatTPUReplica(TPUReplicaBase):
         frontier = (max(0, batch.wm - op.lateness) // op.pane_len
                     if op.win_type is WinType.TB else None)
         return self._prep_step(batch.fields, batch.wm, cap, comp_p,
-                               order_p, same_p, end_p, flat_p, frontier)
+                               order_p, same_p, end_p, flat_p, frontier,
+                               batch.bid)
 
     # ------------------------------------------------------------------
     def _fireable(self, frontier, partial: bool, budget: int):
@@ -1181,7 +1209,7 @@ class FfatTPUReplica(TPUReplicaBase):
         return ckey, ikey
 
     def _prep_step(self, fields, wm, cap, comp_p,
-                   order_p, same_p, end_p, flat_p, frontier):
+                   order_p, same_p, end_p, flat_p, frontier, bid: int = 0):
         """Host half of the per-batch step: program warm-up, the ENTIRE
         fire plan — every drain iteration's chunk arrays and packed
         fire/evict args, computed up front because ``_fireable`` reads
@@ -1237,10 +1265,10 @@ class FfatTPUReplica(TPUReplicaBase):
             self._fire_ewma += 0.25 * (total_fired - self._fire_ewma)
         seg = (comp_p, order_p, same_p, end_p, flat_p)
         return lambda: self._commit_step(fields, wm, seg, ktable,
-                                         ckey, ikey, plan)
+                                         ckey, ikey, plan, bid)
 
     def _commit_step(self, fields, wm, seg, ktable, ckey, ikey,
-                     plan) -> None:
+                     plan, bid: int) -> None:
         """Device half: runs the planned program sequence in order and
         emits each iteration's windows. Reads ``self.trees``/
         ``self.tvalid`` at COMMIT time — earlier queued commits reassign
@@ -1282,10 +1310,12 @@ class FfatTPUReplica(TPUReplicaBase):
                     f_pack, ktable, e_pack)
             self.stats.device_programs_run += 1
             self._emit_windows(wm, chunks, n_out, qr, qv,
-                               wid_dev, key_dev, budget)
+                               wid_dev, key_dev, budget, bid)
 
     def _emit_windows(self, wm, chunks, n_out, qr, qv,
-                      wid_dev, key_dev, W: int) -> None:
+                      wid_dev, key_dev, W: int, cause: int = 0) -> None:
+        """``cause``: the id of the input batch whose commit fired these
+        windows (0 for a dataless fire: a punctuation or EOS made them)."""
         import jax
 
         op = self.op
@@ -1316,6 +1346,8 @@ class FfatTPUReplica(TPUReplicaBase):
             {name: np.dtype(v.dtype) for name, v in fields.items()})
         ts = np.full(W, wm, dtype=np.int64)
         out = BatchTPU(fields, ts, n_out, out_schema, wm, out_keys)
+        out.bid = next_batch_id()  # a new batch: the fire made it
+        out.cause = cause
         self._emit_batch(out)
 
     # ------------------------------------------------------------------

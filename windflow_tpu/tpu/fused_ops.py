@@ -66,13 +66,19 @@ import numpy as np
 
 from ..basic import WindFlowError
 from ..monitoring.flightrec import instrumented_jit
-from ..runtime.dispatch import DeviceDispatchQueue, megabatch_k
+from ..monitoring.tracing import program_name
+from ..runtime.dispatch import megabatch_k
 from .batch import BatchTPU
 from .ffat_tpu import Ffat_Windows_TPU, FfatTPUReplica
 from .ops_tpu import (Filter_TPU, Map_TPU, Reduce_TPU, TPUReplicaBase,
                       _compact_order, _grid_scan_core, _KeyedStateScan,
                       cached_compile, masked_tree_reduce,
                       prewarm_zero_fields, reduce_order_and_slots)
+
+
+# XLA module names: a fused chain's program is ``chain_<ops>``, the
+# prefix-mask program of a window-terminated chain ``mask_<prefix ops>``
+_PROG_CHAIN, _PROG_MASK = "chain", "mask"
 
 
 class _SubSpec:
@@ -140,11 +146,9 @@ class FusedTPUReplica(TPUReplicaBase):
         # stats/trace attribution: the fused stage is ONE observable
         # operator named map∘filter∘map; prep/commit spans + histograms
         # land on this record
+        # (the stage labels follow the record's name: wf:prep:map∘filter∘map)
         self.stats.op_name = self.fused_name
         self.stats.fused_ops = len(ops)
-        self._span_prep = f"wf:prep:{self.fused_name}"
-        # rebuilt so the commit span label carries the fused name
-        self.dispatch = DeviceDispatchQueue(stats=self.stats)
         self.specs = _build_specs(self, ops)
         self._engines = [s.engine for s in self.specs
                          if s.engine is not None]
@@ -173,6 +177,11 @@ class FusedTPUReplica(TPUReplicaBase):
     def fused_signature(self) -> List[str]:
         return [op.name for op in self.ops]
 
+    def _program_name(self) -> str:
+        """XLA module name of the chain's program: ``chain_views_join``
+        (``jit_chain_views_join`` in a device profile)."""
+        return program_name(_PROG_CHAIN, *self.fused_signature)
+
     # -- fused program -----------------------------------------------------
     def _chain_body(self, statics) -> Callable:
         """The UN-jitted chain body ``run(fields, size, hargs, tables)``
@@ -196,8 +205,11 @@ class FusedTPUReplica(TPUReplicaBase):
             new_tables = []
             ti = 0
             for i, spec in enumerate(specs):
+                # each sub-operator's body under its own name in a profile
                 if spec.kind in ("map", "filter"):
-                    fields, valid, _ = spec.kernel(fields, valid, None)
+                    with jax.named_scope(program_name(spec.kind,
+                                                      spec.op.name)):
+                        fields, valid, _ = spec.kernel(fields, valid, None)
                     if not isinstance(fields, dict):
                         raise WindFlowError(
                             f"{fused_name}: Map_TPU function must return "
@@ -208,8 +220,10 @@ class FusedTPUReplica(TPUReplicaBase):
                                            spec.kind == "sfilter", M, KB)
                     grid_idx, touched, tmask = hargs[i]
                     tbl, dirty = tables[ti]
-                    out, t2, d2 = core(fields, valid, grid_idx, touched,
-                                       tmask, tbl, dirty)
+                    with jax.named_scope(program_name(spec.kind,
+                                                      spec.op.name)):
+                        out, t2, d2 = core(fields, valid, grid_idx,
+                                           touched, tmask, tbl, dirty)
                     new_tables.append((t2, d2))
                     ti += 1
                     if spec.kind == "sfilter":
@@ -218,7 +232,9 @@ class FusedTPUReplica(TPUReplicaBase):
                         fields = out
                 # reduce/kreduce handled at the exit below (always last)
             if reduce_combine is not None:
-                red = masked_tree_reduce(reduce_combine, fields, valid)
+                with jax.named_scope(program_name("reduce",
+                                                  specs[-1].op.name)):
+                    red = masked_tree_reduce(reduce_combine, fields, valid)
                 return (red, _compact_order(valid), jnp.sum(valid),
                         tuple(new_tables))
             if kreduce_combine is not None:
@@ -275,6 +291,7 @@ class FusedTPUReplica(TPUReplicaBase):
         # batch shapes churn shows up as a retrace storm in the trace
         return instrumented_jit(self._chain_body(statics), self.stats,
                                 label=self.fused_name,
+                                program=self._program_name(),
                                 donate_argnums=(3,))
 
     def _make_scan(self, statics, k: int) -> Callable:
@@ -309,6 +326,7 @@ class FusedTPUReplica(TPUReplicaBase):
 
         return instrumented_jit(scan_run, self.stats,
                                 label=f"{self.fused_name}:scan{k}",
+                                program=f"{self._program_name()}_scan{k}",
                                 donate_argnums=(3,))
 
     # -- compile-stability pre-warm ----------------------------------------
@@ -457,14 +475,17 @@ class FusedTPUReplica(TPUReplicaBase):
         loop (their emitted batches must be byte-identical)."""
         if self._kreduce_combine is not None:
             tails, tslots, tcount, rorder, rcount = parts
-            m = int(tcount)  # surviving key count (chain-exit readback)
-            rn = int(rcount)
+            with self._st_readback(batch.bid):
+                m = int(tcount)  # surviving key count (chain-exit readback)
+                rn = int(rcount)
+                if m:
+                    ro = np.asarray(rorder)[:rn]
+                    out_slots = np.asarray(tslots)[:m]
             self.stats.inputs_ignored += batch.size - rn
             if m == 0:
                 return
-            ro = np.asarray(rorder)[:rn]
             batch_ts = int(batch.ts_host[ro].max())
-            out_keys = [kextra[s] for s in np.asarray(tslots)[:m]]
+            out_keys = [kextra[s] for s in out_slots]
             ts2 = np.full(batch.capacity, batch_ts, dtype=np.int64)
             nb = BatchTPU(tails, ts2, m, batch.schema, batch.wm, out_keys)
             nb.stream_tag = batch.stream_tag
@@ -472,11 +493,13 @@ class FusedTPUReplica(TPUReplicaBase):
             self._emit_batch(nb)
         elif self._reduce_combine is not None:
             out, order, count = parts
-            n_out = int(count)  # the chain's single exit readback
+            with self._st_readback(batch.bid):
+                n_out = int(count)  # the chain's single exit readback
+                if n_out:
+                    order_np = np.asarray(order)
             self.stats.inputs_ignored += batch.size - n_out
             if n_out == 0:
                 return
-            order_np = np.asarray(order)
             ts = np.array([int(batch.ts_host[order_np[:n_out]].max())],
                           dtype=np.int64)
             nb = BatchTPU(out, ts, 1, batch.schema, batch.wm)
@@ -564,9 +587,6 @@ class FusedFfatReplica(FfatTPUReplica):
         self.fused_name = "∘".join(o.name for o in ops)
         self.stats.op_name = self.fused_name
         self.stats.fused_ops = len(ops)
-        self._span_prep = f"wf:prep:{self.fused_name}"
-        # rebuilt so the commit span label carries the fused name
-        self.dispatch = DeviceDispatchQueue(stats=self.stats)
         prefix = ops[:-1]
         for o in prefix:
             if not isinstance(o, (Map_TPU, Filter_TPU)) \
@@ -577,6 +597,9 @@ class FusedFfatReplica(FfatTPUReplica):
                     f"({o.name} — fusion legality should have refused "
                     "this chain)")
         self._prefix_kernels = [o.device_kernel() for o in prefix]
+        self._prefix_scopes = [
+            program_name("filter" if isinstance(o, Filter_TPU) else "map",
+                         o.name) for o in prefix]
         self._prefix_filters = any(isinstance(o, Filter_TPU)
                                    for o in prefix)
         self._tag = tuple(o.name for o in prefix)
@@ -593,7 +616,10 @@ class FusedFfatReplica(FfatTPUReplica):
     def _lift_fn(self) -> Callable:
         import jax.numpy as jnp
 
+        import jax
+
         kernels = self._prefix_kernels
+        scopes = self._prefix_scopes
         lift = self.op.lift
         if not kernels:
             return lift
@@ -601,8 +627,9 @@ class FusedFfatReplica(FfatTPUReplica):
         def lifted(fields):
             n = next(iter(fields.values())).shape[0]
             valid = jnp.ones((n,), bool)
-            for kern in kernels:
-                fields, valid, _ = kern(fields, valid, None)
+            for kern, scope in zip(kernels, scopes):
+                with jax.named_scope(scope):
+                    fields, valid, _ = kern(fields, valid, None)
             # rows the prefix filtered compute garbage through the lift;
             # their segment lanes carry the sentinel (prep scattered the
             # packed composite over surviving rows only), so the scan
@@ -637,7 +664,8 @@ class FusedFfatReplica(FfatTPUReplica):
             return valid
 
         return instrumented_jit(mask, self.stats,
-                                label=f"{self.fused_name}:mask")
+                                label=f"{self.fused_name}:mask",
+                                program=program_name(_PROG_MASK, *self._tag))
 
     # -- prewarm -----------------------------------------------------------
     def _prewarm_schema(self):

@@ -35,11 +35,19 @@ import numpy as np
 
 from ..basic import ExecutionMode, OpType, RoutingMode, WindFlowError
 from ..monitoring.flightrec import instrumented_jit
-from ..monitoring.tracing import device_span
+from ..monitoring.tracing import program_name
 from ..operators.base import BasicOperator, BasicReplica
 from ..runtime.dispatch import DeviceDispatchQueue
 from .batch import BatchTPU, key_column_np, key_column_to_list
 from .schema import TupleSchema
+
+
+# XLA module names of the standalone operators' programs
+# (``jit_<kind>_<op>`` in a device profile) and the named scope of the
+# keyed grid scan inside any program that runs it
+_PROG_MAP, _PROG_FILTER, _PROG_REDUCE = "map", "filter", "reduce"
+_PROG_SMAP, _PROG_SFILTER = "smap", "sfilter"
+SCOPE_GRID_SCAN = "grid_scan"
 
 
 def prewarm_zero_fields(schema: "TupleSchema", cap: int):
@@ -233,6 +241,7 @@ def _grid_scan_core(func, filter_mode: bool, M: int, KB: int):
         shaped = ok.reshape(ok.shape + (1,) * (new.ndim - ok.ndim))
         return jnp.where(shaped, new, old).astype(old.dtype)
 
+    @jax.named_scope(SCOPE_GRID_SCAN)
     def core(fields, valid, grid_idx, touched, touched_mask, table, dirty):
         T_cap = next(iter(jax.tree_util.tree_leaves(table))).shape[0]
         tsafe = jnp.where(touched_mask, touched, 0)
@@ -330,11 +339,11 @@ class TPUReplicaBase(BasicReplica):
 
     def __init__(self, op: BasicOperator, idx: int) -> None:
         super().__init__(op, idx)
+        # wf:prep / wait:queue / wf:commit live in the dispatch queue; the
+        # commit's children are this replica's (monitoring/tracing.py)
         self.dispatch = DeviceDispatchQueue(stats=self.stats)
-        # jax.profiler span label for the host-prep stage, so captured
-        # device traces line up with the Dispatch_* stats (the commit
-        # span lives in the dispatch queue)
-        self._span_prep = f"wf:prep:{op.name}"
+        self._st_readback = self.stats.stage("readback")
+        self._st_emit = self.stats.stage("emit")
         # per-record error policy (windflow_tpu.supervision.errors): a
         # whole batch shares one XLA program, so a failing batch is
         # BISECTED until the poison record is isolated at size 1 and the
@@ -369,14 +378,10 @@ class TPUReplicaBase(BasicReplica):
             self._process_batch_guarded(msg)
             self.stats.end_svc(msg.size)
             return
-        t0 = time.perf_counter()
-        with device_span(self._span_prep):
+        with self.dispatch.prep(msg.bid):
             commit = self.prep_device_batch(msg)
-        prep_us = (time.perf_counter() - t0) * 1e6
         if commit is not None:
-            self.dispatch.submit(commit, prep_us)
-        else:
-            self.stats.note_host_prep(prep_us)  # batch needed no commit
+            self.dispatch.submit(commit, msg.bid)
         self.stats.end_svc(msg.size)
 
     def _process_batch_guarded(self, msg: BatchTPU) -> None:
@@ -387,15 +392,11 @@ class TPUReplicaBase(BasicReplica):
         state applied keeps that prefix (document-level caveat — the
         FAIL policy is the strict choice for stateful device chains)."""
         try:
-            t0 = time.perf_counter()
-            with device_span(self._span_prep):
+            with self.dispatch.prep(msg.bid):
                 commit = self.prep_device_batch(msg)
-            prep_us = (time.perf_counter() - t0) * 1e6
             if commit is not None:
-                self.dispatch.submit(commit, prep_us)
+                self.dispatch.submit(commit, msg.bid)
                 self.dispatch.drain(forced=True)
-            else:
-                self.stats.note_host_prep(prep_us)
         except Exception as exc:  # noqa: BLE001 — the policy boundary
             from ..supervision.errors import (apply_record_policy,
                                               batch_row_payload,
@@ -442,24 +443,18 @@ class TPUReplicaBase(BasicReplica):
 
     def _emit_batch(self, batch: BatchTPU) -> None:
         self.stats.device_batches_out += 1
-        rec = self.stats.recorder
-        if rec is not None:  # per device batch, not per tuple
-            rec.event("emit", 0.0, batch.size)
-        self.emitter.emit_device_batch(batch)
+        with self._st_emit(batch.bid, batch.cause):
+            self.emitter.emit_device_batch(batch)
 
     def emit_compacted(self, batch: BatchTPU, out_fields, order, count
                        ) -> None:
         """Emit a compaction result: device columns reordered keep-first,
         host ts/keys reordered to match (shared by the filter paths)."""
-        rec = self.stats.recorder
-        t0 = time.perf_counter() if rec is not None else 0.0
         # the compaction readbacks: int(count) + the order materialization
         # block on the program result (this is why commits are deferred)
-        new_size = int(count)
-        order_np = np.asarray(order)
-        if rec is not None:
-            rec.event("readback", (time.perf_counter() - t0) * 1e6,
-                      {"kept": new_size, "of": batch.size})
+        with self._st_readback(batch.bid):
+            new_size = int(count)
+            order_np = np.asarray(order)
         self.stats.inputs_ignored += batch.size - new_size
         ts2 = batch.ts_host[order_np]
         keys2 = None
@@ -595,7 +590,9 @@ class MapTPUReplica(TPUReplicaBase):
             out, _, _ = kernel(fields, None, None)
             return out
 
-        self._jitted = instrumented_jit(run, self.stats, label=op.name)
+        self._jitted = instrumented_jit(
+            run, self.stats, label=op.name,
+            program=program_name(_PROG_MAP, op.name))
 
     def process_device_batch(self, batch: BatchTPU) -> None:
         out = self._jitted(batch.fields)
@@ -706,8 +703,11 @@ class _KeyedStateScan:
         # as the FFAT forest — every call site reassigns self.table /
         # self.dirty from the program output, so the consumed buffers are
         # never reused)
-        return instrumented_jit(run, self.replica.stats,
-                                label=self.op.name, donate_argnums=(5, 6))
+        return instrumented_jit(
+            run, self.replica.stats, label=self.op.name,
+            program=program_name(
+                _PROG_SFILTER if filter_mode else _PROG_SMAP, self.op.name),
+            donate_argnums=(5, 6))
 
     # -- host side ---------------------------------------------------------
     def _ensure_table(self, n_keys_needed: int) -> None:
@@ -853,7 +853,7 @@ class _KeyedStateScan:
                 tier.note_promote(len(plan.promote_keys),
                                   (time.perf_counter() - t0) * 1e6)
 
-        self.replica.dispatch.submit(tier_commit, 0.0)
+        self.replica.dispatch.submit(tier_commit)
 
     # -- checkpointing -----------------------------------------------------
     # The whole scan state is (key -> slot dict, capacity, one device
@@ -1127,7 +1127,9 @@ class FilterTPUReplica(TPUReplicaBase):
             out = {k: v[order] for k, v in fields2.items()}
             return out, order, jnp.sum(keep)
 
-        self._jitted = instrumented_jit(run, self.stats, label=op.name)
+        self._jitted = instrumented_jit(
+            run, self.stats, label=op.name,
+            program=program_name(_PROG_FILTER, op.name))
 
     def process_device_batch(self, batch: BatchTPU) -> None:
         out, order, count = self._jitted(batch.fields, batch.size)
@@ -1201,7 +1203,9 @@ class GlobalReduceTPUReplica(TPUReplicaBase):
             n = next(iter(fields.values())).shape[0]
             return masked_tree_reduce(combine, fields, jnp.arange(n) < size)
 
-        self._jitted = instrumented_jit(run, self.stats, label=op.name)
+        self._jitted = instrumented_jit(
+            run, self.stats, label=op.name,
+            program=program_name(_PROG_REDUCE, op.name))
 
     def prewarm(self, caps) -> Optional[int]:
         """See ``MapTPUReplica.prewarm``."""
@@ -1258,7 +1262,9 @@ class ReduceTPUReplica(TPUReplicaBase):
             idx = jnp.nonzero(is_last, size=n, fill_value=n - 1)[0]
             return {k: v[idx] for k, v in scanned.items()}
 
-        self._jitted = instrumented_jit(run, self.stats, label=op.name)
+        self._jitted = instrumented_jit(
+            run, self.stats, label=op.name,
+            program=program_name(_PROG_REDUCE, op.name))
 
     def prewarm(self, caps) -> Optional[int]:
         """See ``MapTPUReplica.prewarm`` — the keyed reduce's program
