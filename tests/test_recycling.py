@@ -1,6 +1,5 @@
-"""Pool substrate tests (reference wf/recycling.hpp capability; see
-windflow_tpu/recycling.py for why the device staging path does not use the
-pools yet)."""
+"""Pool substrate tests (reference wf/recycling.hpp capability; the
+staging path's use of the pools is in test_packed_staging.py)."""
 
 import threading
 
@@ -10,15 +9,35 @@ from windflow_tpu.recycling import ArrayPool, ObjectPool
 
 
 def test_array_pool_reuse_and_zeroing():
+    """A buffer comes back as it was released: the pool does NOT fill it
+    (the stager writes its rows and zeroes the pad rows at ship time, so
+    each byte is written once); keyed by dtype and full shape."""
+    from windflow_tpu.tpu.batch import StagingBuffers
+    from windflow_tpu.tpu.schema import TupleSchema
+
     pool = ArrayPool(max_per_bucket=4)
     a = pool.acquire(np.int32, 64)
     a[:] = 7
     pool.release(a)
     b = pool.acquire(np.int32, 64)
     assert b is a  # reused
-    assert (b == 0).all()  # zeroed on reacquire
+    assert (b == 7).all()  # not zeroed on reacquire
     c = pool.acquire(np.float32, 64)
     assert c is not a and c.dtype == np.float32
+    d = pool.acquire(np.int32, (2, 32))  # same bytes, another shape
+    assert d is not a and d.shape == (2, 32)
+    pool.release(b)
+
+    # the stager owns the zeroing: a dirty pooled buffer ships with its
+    # pad rows zero, column by column
+    from windflow_tpu.recycling import InFlightRecycler
+    st = StagingBuffers(TupleSchema({"x": np.int32, "y": np.int32}), 32,
+                        InFlightRecycler(pool, force=True))
+    assert st.groups[0] is a and (a == 7).all()
+    st.fill({"x": np.arange(5), "y": np.arange(5) + 10}, 5)
+    f = st.put(5)
+    assert np.asarray(f["x"]).tolist() == list(range(5)) + [0] * 27
+    assert np.asarray(f["y"]).tolist() == list(range(10, 15)) + [0] * 27
 
 
 def test_array_pool_bucket_cap():
@@ -26,7 +45,7 @@ def test_array_pool_bucket_cap():
     arrs = [pool.acquire(np.int64, 8) for _ in range(5)]
     for a in arrs:
         pool.release(a)
-    assert len(pool._free[(str(np.dtype(np.int64)), 8)]) == 2
+    assert len(pool._free[(str(np.dtype(np.int64)), (8,))]) == 2
 
 
 def test_object_pool_threaded():
@@ -66,7 +85,7 @@ def test_in_flight_recycler_fifo_mechanics():
         dev = jax.device_put(np.asarray(host))  # copy: content irrelevant
         rec.track([dev], [host])
     assert len(rec._q) == 2  # 4 released via the blocking pop
-    key = (str(np.dtype(np.int32)), 32)
+    key = (str(np.dtype(np.int32)), (32,))
     # released buffers were immediately re-acquired each iteration: only
     # the latest release is still free, and 3 acquires were pool hits
     assert len(pool._free[key]) == 1

@@ -66,7 +66,7 @@ from typing import Any, Callable, Dict, List, Optional
 import numpy as np
 
 from ..basic import OpType, RoutingMode, WinType, WindFlowError
-from ..tpu.batch import BatchTPU
+from ..tpu.batch import BatchTPU, host_columns
 from ..tpu.ops_tpu import TPUOperatorBase, TPUReplicaBase
 from ..tpu.schema import TupleSchema
 
@@ -499,8 +499,8 @@ class FfatMeshReplica(TPUReplicaBase):
                     "overflows the device's int32 pane domain; use a "
                     "larger pane (win/slide gcd)")
             self._max_pane_seen = max(self._max_pane_seen, int(panes.max()))
-        vals = {f: np.asarray(batch.fields[f])[:n][live]
-                for f in self._val_fields}
+        vals = {f: c[:n][live] for f, c in host_columns(
+            batch.fields, self._val_fields).items()}
         self._run_steps(keys.astype(np.int32), panes.astype(np.int32), vals)
 
     def on_punctuation(self, wm: int) -> None:

@@ -43,7 +43,8 @@ from typing import Any, Callable, Dict, List, Optional
 import numpy as np
 
 from ..basic import KeyCapacityError, OpType, RoutingMode, WindFlowError
-from ..tpu.batch import BatchTPU, bucket_capacity
+from ..tpu.batch import (BatchTPU, bucket_capacity, gather_columns,
+                         host_columns)
 from ..tpu.ops_tpu import TPUOperatorBase, TPUReplicaBase, cached_compile
 from ..tpu.schema import TupleSchema
 
@@ -472,8 +473,8 @@ class _MeshScanReplicaBase(_MeshReplicaBase):
         if env_ckpt_delta():
             # every slot row this batch scans through is dirty vs base
             self._ckpt_dirty.update(np.unique(slots).tolist())
-        cols = {f: np.asarray(batch.fields[f])[:n]
-                for f in self._val_fields}
+        cols = {f: c[:n] for f, c in host_columns(
+            batch.fields, self._val_fields).items()}
         ts = np.asarray(batch.ts_host[:n])
         GB = self._GB
         for lo in range(0, n, GB):
@@ -733,7 +734,7 @@ class FilterMeshReplica(_MeshScanReplicaBase):
         sel = np.zeros(cap, np.int32)
         sel[:len(kept)] = lo + kept  # rows of the ORIGINAL device batch
         sel_dev = jax.device_put(sel)
-        out_fields = {f: batch.fields[f][sel_dev] for f in batch.fields}
+        out_fields = gather_columns(batch.fields, sel_dev)
         ts2 = np.zeros(cap, np.int64)
         ts2[:len(kept)] = ts[lo:hi][kept]
         nb = BatchTPU(out_fields, ts2, len(kept), batch.schema, batch.wm,
@@ -778,8 +779,8 @@ class ReduceMeshReplica(_MeshReplicaBase):
         import jax  # noqa: F401  (device plane active past this point)
 
         slots, keys_raw = self._batch_slots(batch)
-        cols = {f: np.asarray(batch.fields[f])[:n]
-                for f in self._val_fields}
+        cols = {f: c[:n] for f, c in host_columns(
+            batch.fields, self._val_fields).items()}
         acc: Dict[int, dict] = {}
         GB = self._GB
         for lo in range(0, n, GB):

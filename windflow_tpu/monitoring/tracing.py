@@ -330,13 +330,22 @@ class StageCounters:
     base of ``StatsRecord``, and alone the private owner of an emitter or
     channel nobody wired to a replica."""
 
-    __slots__ = ("op_name", "recorder", "stage_ns", "stage_n")
+    __slots__ = ("op_name", "recorder", "stage_ns", "stage_n",
+                 "h2d_puts", "unpacked_columns")
 
     def __init__(self, op_name: str = "") -> None:
         self.op_name = op_name
         self.recorder = None  # the owning worker's FlightRecorder
         self.stage_ns: List[int] = [0] * len(STAGES)
         self.stage_n: List[int] = [0] * len(STAGES)
+        # the staging edge's transfers (tpu/batch.py): ``device_put``
+        # calls issued inside ``wf:h2d`` (one per dtype group of a batch:
+        # over Stage_batches, the groups of the schema), and columns of
+        # staged batches that a reader OUTSIDE a program sliced out of
+        # their packed buffer (the slow path; 0 where every consumer is a
+        # program)
+        self.h2d_puts = 0
+        self.unpacked_columns = 0
 
     def stage(self, name: str, op: Optional[str] = None) -> Stage:
         return Stage(self, name, op)
@@ -355,4 +364,6 @@ class StageCounters:
                 d[sdef.total] = round(self.stage_ns[i] / 1e3, 1)
             if sdef.count is not None:
                 d[sdef.count] = self.stage_n[i]
+        d["Stage_h2d_puts"] = self.h2d_puts
+        d["Stage_unpacked_columns"] = self.unpacked_columns
         return d

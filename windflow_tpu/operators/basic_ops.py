@@ -253,7 +253,7 @@ class ColumnarSinkReplica(BasicReplica):
             self._advance_wm(msg.wm)
             self.on_punctuation(msg.wm)
         else:
-            from ..tpu.batch import BatchTPU
+            from ..tpu.batch import BatchTPU, host_columns
             if not isinstance(msg, BatchTPU):
                 raise WindFlowError(
                     f"{self.op.name}: with_columns sink received a row "
@@ -275,8 +275,8 @@ class ColumnarSinkReplica(BasicReplica):
             # the host read of each column (waits for its D2H), then the
             # user's functor
             with self._st_d2h(msg.bid, msg.cause):
-                cols = {name: np.asarray(col)[:n]
-                        for name, col in msg.fields.items()}
+                cols = {name: col[:n]
+                        for name, col in host_columns(msg.fields).items()}
             ts = msg.ts_host[:n]
             self.context._set_meta(int(ts[-1]) if n else 0, self.cur_wm)
             with self._st_sink(msg.bid, msg.cause):

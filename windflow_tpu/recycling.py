@@ -5,10 +5,11 @@ emitter owns an MPMC pool; consumers return messages to the producer's pool
 instead of freeing, avoiding allocator pressure on the hot path.
 
 In the Python plane, message lifetime is garbage-collected and the hot
-allocations that matter are the COLUMNAR STAGING BUFFERS of the device
-boundary (one numpy array per field per staged batch). ``ArrayPool`` keeps
-free lists keyed by (dtype, capacity); the staging path acquires buffers
-from it and ``InFlightRecycler`` returns them once the device transfer is
+allocations that matter are the STAGING BUFFERS of the device boundary
+(one numpy array per dtype group per staged batch, the schema's columns of
+that dtype end to end: ``tpu/batch.py`` ``StagingBuffers``). ``ArrayPool``
+keeps free lists keyed by (dtype, shape); the staging path acquires
+buffers from it and ``InFlightRecycler`` returns them once the device transfer is
 COMMITTED (``device_put``'s host read can complete asynchronously when
 dispatch queues deepen — premature reuse corrupts in-flight batches).
 Set WF_NO_RECYCLING=1 to disable, mirroring the reference's macro."""
@@ -26,32 +27,37 @@ RECYCLING_ENABLED = os.environ.get("WF_NO_RECYCLING", "0") != "1"
 
 
 class ArrayPool:
-    """Thread-safe free lists of numpy buffers keyed by (dtype, capacity)."""
+    """Thread-safe free lists of numpy buffers keyed by (dtype, shape).
+    A buffer comes back as it was released, NOT zeroed: the stager writes
+    the rows it has and zeroes the rest at ship time, so each byte is
+    written once (``StagingBuffers.put``)."""
 
     def __init__(self, max_per_bucket: int = 32) -> None:
-        self._free: Dict[Tuple[str, int], List[np.ndarray]] = defaultdict(list)
+        self._free: Dict[Tuple[str, Tuple[int, ...]],
+                         List[np.ndarray]] = defaultdict(list)
         self._lock = threading.Lock()
         self.max_per_bucket = max_per_bucket
         self.hits = 0
         self.misses = 0
 
-    def acquire(self, dtype, capacity: int) -> np.ndarray:
-        key = (str(np.dtype(dtype)), capacity)
+    def acquire(self, dtype, shape) -> np.ndarray:
+        """``shape``: an int (1-D) or a tuple."""
+        if isinstance(shape, int):
+            shape = (shape,)
+        key = (str(np.dtype(dtype)), tuple(shape))
         if RECYCLING_ENABLED:
             with self._lock:
                 bucket = self._free.get(key)
                 if bucket:
                     self.hits += 1
-                    arr = bucket.pop()
-                    arr.fill(0)
-                    return arr
+                    return bucket.pop()
         self.misses += 1
-        return np.zeros(capacity, dtype=dtype)
+        return np.empty(shape, dtype=dtype)
 
     def release(self, arr: np.ndarray) -> None:
         if not RECYCLING_ENABLED:
             return
-        key = (str(arr.dtype), arr.shape[0])
+        key = (str(arr.dtype), arr.shape)
         with self._lock:
             bucket = self._free[key]
             if len(bucket) < self.max_per_bucket:

@@ -945,6 +945,8 @@ class PipeGraph:
                 f"operator {producer.last_op.name!r} feeds TPU operator "
                 f"{first.name!r} but declares no output batch size; call "
                 "with_output_batch_size(n) on the producer")
+        if c_tpu and not p_tpu:
+            first.staged_input = True  # its batches arrive packed
         one_to_one = (routing is RoutingMode.FORWARD
                       and branch is None
                       and not (c_tpu and not p_tpu)

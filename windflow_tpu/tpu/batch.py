@@ -8,6 +8,13 @@ punctuation flag, stream tag) as the CPU batches.
 Differences by design (TPU/XLA instead of CUDA):
 - storage is columnar (struct-of-arrays) because XLA programs want vector
   lanes, not arrays of structs;
+- a batch STAGED from the host crosses in one transfer per device dtype,
+  not one per column (a ``device_put`` costs a quarter of a millisecond a
+  call on a v5e, whatever it carries): ``StagingBuffers`` lays the
+  schema's columns of one dtype end to end in one host buffer,
+  ``PackedFields`` is the ``fields`` mapping over the device copies, and
+  a column is a static slice taken inside the program that reads it.
+  Batches a device program made keep a plain dict of columns;
 - capacity is a power-of-two bucket with an explicit host-side ``size``
   (pad+mask replaces the reference's variable-size batches — fixed shapes
   avoid re-compiles, SURVEY.md §7 step 3b);
@@ -25,12 +32,14 @@ code needing event time rebases per batch (see ffat_tpu).
 
 from __future__ import annotations
 
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+import functools
+from collections.abc import Mapping
+from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from ..message import StreamMsg
-from ..monitoring.tracing import next_batch_id
+from ..monitoring.tracing import StageCounters, next_batch_id
 from .schema import TupleSchema
 
 
@@ -55,6 +64,222 @@ def bucket_capacity(n: int, minimum: int = 8) -> int:
     return c
 
 
+class PackedLayout:
+    """Where each column of a schema lies in the packed form: columns are
+    grouped by the dtype they have ON THE DEVICE (``device_put``
+    canonicalises: without x64 an int64 column is int32 there), in schema
+    order, and column ``row`` of group ``g`` is elements ``[row * cap,
+    (row + 1) * cap)`` of that group's flat ``(rows * cap,)`` buffer — the
+    layout a column of its own had, so its slice stays aligned (``cap``
+    is a power of two). Hashable and capacity-free: it is the pytree aux
+    data of ``PackedFields``, one treedef per schema."""
+
+    __slots__ = ("key", "index", "dtypes", "rows", "_hash")
+
+    def __init__(self, schema: TupleSchema) -> None:
+        from jax.dtypes import canonicalize_dtype
+
+        groups: Dict[np.dtype, int] = {}  # device dtype -> columns so far
+        self.index = {}  # name -> (group, row), in schema order
+        for name, dt in schema.fields.items():
+            dt = np.dtype(canonicalize_dtype(dt))
+            row = groups.get(dt, 0)
+            groups[dt] = row + 1
+            self.index[name] = (list(groups).index(dt), row)
+        self.dtypes = tuple(groups)
+        self.rows = tuple(groups.values())
+        self.key = tuple((name, str(self.dtypes[g]), g, row)
+                         for name, (g, row) in self.index.items())
+        self._hash = hash(self.key)
+
+    @staticmethod
+    def of(schema: TupleSchema) -> "PackedLayout":
+        lay = getattr(schema, "_packed_layout", None)
+        if lay is None:
+            lay = schema._packed_layout = PackedLayout(schema)
+        return lay
+
+    def __eq__(self, other) -> bool:
+        return self is other or (type(other) is PackedLayout
+                                 and self.key == other.key)
+
+    def __hash__(self) -> int:
+        return self._hash
+
+    def nbytes(self, capacity: int) -> int:
+        return capacity * sum(dt.itemsize * r
+                              for dt, r in zip(self.dtypes, self.rows))
+
+
+class PackedFields(Mapping):
+    """``BatchTPU.fields`` of a staged batch: name -> column over one
+    device buffer per dtype group. A pytree node whose leaves are the
+    group buffers, so a jitted program takes ``len(groups)`` arguments
+    where it took one per column, and inside it ``fields[name]`` is a
+    static slice XLA fuses into its consumer. Outside a program the same
+    index MATERIALISES (and caches) a device slice: the slow path of a
+    host reader, counted on the staging operator's
+    ``Stage_unpacked_columns`` so a hot path that falls onto it is seen.
+    Read-only; a group of one column is that column, no slice."""
+
+    __slots__ = ("bufs", "layout", "_cols", "_counters")
+
+    def __init__(self, bufs: Tuple[Any, ...], layout: PackedLayout,
+                 counters: Optional[StageCounters] = None) -> None:
+        _device_side()
+        self.bufs = bufs
+        self.layout = layout
+        self._cols: Dict[str, Any] = {}
+        self._counters = counters
+
+    def __getitem__(self, name: str) -> Any:
+        col = self._cols.get(name)
+        if col is None:
+            g, row = self.layout.index[name]
+            col = self.bufs[g]
+            rows = self.layout.rows[g]
+            if rows > 1:
+                cap = col.shape[-1] // rows
+                if self._counters is not None \
+                        and not isinstance(col, _device_side()[0]):
+                    self._counters.unpacked_columns += 1
+                col = col[..., row * cap:(row + 1) * cap]
+            self._cols[name] = col
+        return col
+
+    def __contains__(self, name) -> bool:
+        return name in self.layout.index
+
+    def __iter__(self) -> Iterator[str]:
+        return iter(self.layout.index)
+
+    def __len__(self) -> int:
+        return len(self.layout.index)
+
+    def host_columns(self, names=None) -> Dict[str, np.ndarray]:
+        """Columns ``names`` (default: all) on the host from ONE read per
+        group (row views of it): what a host reader of a whole batch
+        wants, and no device slice is made."""
+        host: Dict[int, np.ndarray] = {}
+        out = {}
+        for name in (self if names is None else names):
+            g, row = self.layout.index[name]
+            h = host.get(g)
+            if h is None:
+                h = host[g] = np.asarray(self.bufs[g])
+            cap = h.shape[0] // self.layout.rows[g]
+            out[name] = h[row * cap:(row + 1) * cap]
+        return out
+
+    def gather(self, idx: Any) -> "PackedFields":
+        """Rows ``idx`` of every column: one gather a group, and the
+        result is packed like this one."""
+        gather = _device_side()[1]
+        return PackedFields(
+            tuple(gather(b, idx, rows)
+                  for b, rows in zip(self.bufs, self.layout.rows)),
+            self.layout, self._counters)
+
+
+@functools.cache
+def _device_side() -> Tuple[type, Any]:
+    """``(jax.core.Tracer, the jitted per-group gather)``, and registers
+    ``PackedFields`` as a pytree node — on first use, because importing
+    this module must not import jax (the CPU plane never pays for it)."""
+    import jax
+
+    jax.tree_util.register_pytree_node(
+        PackedFields, lambda f: (f.bufs, f.layout),
+        lambda layout, bufs: PackedFields(tuple(bufs), layout))
+    gather = jax.jit(
+        lambda buf, idx, rows: buf.reshape(rows, -1)[:, idx].reshape(-1),
+        static_argnums=2)
+    return jax.core.Tracer, gather
+
+
+def field_dtype(fields: Mapping, name: str) -> np.dtype:
+    """Device dtype of one column without touching it."""
+    if isinstance(fields, PackedFields):
+        return fields.layout.dtypes[fields.layout.index[name][0]]
+    return np.dtype(fields[name].dtype)
+
+
+def host_columns(fields: Mapping, names=None) -> Dict[str, np.ndarray]:
+    """Columns ``names`` (default: all) of a batch's ``fields`` as host
+    arrays."""
+    if isinstance(fields, PackedFields):
+        return fields.host_columns(names)
+    return {name: np.asarray(fields[name])
+            for name in (fields if names is None else names)}
+
+
+def gather_columns(fields: Mapping, idx: Any) -> Mapping:
+    """Rows ``idx`` (a device index vector) of every column, on the
+    device: one gather per column, per dtype group of a packed batch."""
+    if isinstance(fields, PackedFields):
+        return fields.gather(idx)
+    return {name: v[idx] for name, v in fields.items()}
+
+
+class StagingBuffers:
+    """The host side of one staged batch: one contiguous buffer per dtype
+    group (``groups``) and ``cols``, a row view of it per column, which is
+    what the staging copies write. With an enabled ``recycler`` (an
+    ``InFlightRecycler``) the buffers come from its pool and return to it
+    once the transfer is committed. Buffers come UNINITIALISED: ``put``
+    zeroes the pad rows ``[n, cap)`` of every column before the transfer,
+    so each byte is written once and the pad rows read zero on the device
+    as they always have."""
+
+    __slots__ = ("layout", "capacity", "groups", "cols", "_recycler")
+
+    def __init__(self, schema: TupleSchema, capacity: int,
+                 recycler=None) -> None:
+        lay = self.layout = PackedLayout.of(schema)
+        self.capacity = capacity
+        if recycler is not None and not recycler.enabled:
+            recycler = None
+        self._recycler = recycler
+        pool = recycler.pool if recycler is not None else None
+        self.groups = [
+            (pool.acquire(dt, rows * capacity) if pool is not None
+             else np.empty(rows * capacity, dtype=dt))
+            for dt, rows in zip(lay.dtypes, lay.rows)]
+        self.cols = {name: self.groups[g][row * capacity:
+                                          (row + 1) * capacity]
+                     for name, (g, row) in lay.index.items()}
+
+    def fill(self, cols: Dict[str, np.ndarray], n: int) -> "StagingBuffers":
+        """Copy the first ``n`` rows of each column in (one vectorized
+        copy a column)."""
+        for name, buf in self.cols.items():
+            buf[:n] = cols[name][:n]
+        return self
+
+    def put(self, n: int,
+            counters: Optional[StageCounters] = None) -> PackedFields:
+        """Zero the pad rows, ``device_put`` each group (async dispatch;
+        counted on ``counters`` as ``Stage_h2d_puts``) and hand the
+        buffers to the recycler. The caller must not touch them again:
+        ``device_put`` may alias the host buffer, and its read of it can
+        complete asynchronously once the dispatch queue deepens, so
+        premature reuse corrupts in-flight batches (the hazard the
+        reference tracks with in-transit counters, ``batch_gpu_t.hpp:66``;
+        pinned staging + async H2D, ``keyby_emitter_gpu.hpp:443-505``)."""
+        import jax
+
+        cap = self.capacity
+        if n < cap:
+            for buf, rows in zip(self.groups, self.layout.rows):
+                buf.reshape(rows, cap)[:, n:] = 0
+        dev = tuple(jax.device_put(buf) for buf in self.groups)
+        if counters is not None:
+            counters.h2d_puts += len(dev)
+        if self._recycler is not None:
+            self._recycler.track(dev, self.groups)
+        return PackedFields(dev, self.layout, counters)
+
+
 class BatchTPU(StreamMsg):
     __slots__ = ("fields", "ts_host", "size", "capacity", "wm", "is_punct",
                  "stream_tag", "id", "schema", "host_keys", "key_slots",
@@ -65,7 +290,9 @@ class BatchTPU(StreamMsg):
                  host_keys: Optional[List[Any]] = None,
                  key_slots: Any = None,
                  slot_of_key: Optional[Dict[Any, int]] = None) -> None:
-        self.fields = fields  # name -> jax.Array (capacity,)
+        # name -> jax.Array (capacity,): a PackedFields on a batch staged
+        # from the host, a dict on one a device program made
+        self.fields = fields
         self.ts_host = ts_host  # np.int64 (capacity,)
         self.size = size
         self.capacity = len(ts_host)
@@ -99,87 +326,60 @@ class BatchTPU(StreamMsg):
         return self.size
 
     def nbytes(self) -> int:
+        f = self.fields
+        if isinstance(f, PackedFields):  # no column is touched for this
+            return f.layout.nbytes(self.capacity)
         return sum(int(np.dtype(v.dtype).itemsize) * self.capacity
-                   for v in self.fields.values())
+                   for v in f.values())
 
     # -- construction ------------------------------------------------------
+    # CPU->TPU, three ways in and one way across: ``stage_prefilled``.
+    # ``recycler`` pools the staging buffers, ``counters`` (the staging
+    # operator's) counts the transfers and later host-side unpacking.
     @staticmethod
     def stage(rows: Sequence[Tuple[Any, int]], schema: TupleSchema,
               wm: int, keys: Optional[List[Any]] = None,
-              capacity: Optional[int] = None,
-              recycler=None) -> "BatchTPU":
-        """CPU->TPU: columnarize and device_put (async dispatch; the
-        reference's pinned staging + async H2D, ``keyby_emitter_gpu.hpp:
-        443-505``). With ``recycler`` (an ``InFlightRecycler``) the column
-        buffers come from its pool and are returned once the transfer is
-        committed — device_put's host read can complete asynchronously
-        once the dispatch queue deepens, so premature reuse corrupts
-        in-flight batches (the hazard the reference tracks with in-transit
-        counters, ``batch_gpu_t.hpp:66``)."""
-        import jax
-
-        cap = capacity or bucket_capacity(len(rows))
-        pooled = recycler is not None and recycler.enabled
-        cols, ts = schema.to_columns(rows, cap,
-                                     recycler.pool if pooled else None)
-        dev_fields = {name: jax.device_put(col) for name, col in cols.items()}
-        if pooled:
-            recycler.track(dev_fields.values(), cols.values())
-        # per-batch slot ids are computed by the consuming keyed operator
-        # (TPUReplicaBase.batch_slots); host_keys is the canonical metadata
-        return BatchTPU(dev_fields, ts, len(rows), schema, wm, keys)
+              capacity: Optional[int] = None, recycler=None,
+              counters: Optional[StageCounters] = None) -> "BatchTPU":
+        """From ROWS: columnarize, then copy into staging buffers.
+        Per-batch slot ids are computed by the consuming keyed operator
+        (TPUReplicaBase.batch_slots); host_keys is the canonical
+        metadata."""
+        n = len(rows)
+        cap = capacity or bucket_capacity(n)
+        cols, ts = schema.to_columns(rows, cap)
+        staging = StagingBuffers(schema, cap, recycler).fill(cols, n)
+        return BatchTPU.stage_prefilled(staging, ts, n, schema, wm, keys,
+                                        counters)
 
     @staticmethod
     def stage_columns(cols: Dict[str, np.ndarray], ts: np.ndarray,
                       schema: TupleSchema, wm: int,
-                      keys: Optional[List[Any]] = None,
-                      recycler=None) -> "BatchTPU":
-        """CPU->TPU from COLUMNS (push_columns fast path): pad each numpy
-        column to the capacity bucket and device_put — no per-tuple
-        Python at all."""
-        import jax
-
+                      keys: Optional[List[Any]] = None, recycler=None,
+                      counters: Optional[StageCounters] = None
+                      ) -> "BatchTPU":
+        """From COLUMNS (push_columns fast path; no per-tuple Python): one
+        vectorized copy of each column into private staging buffers at
+        the capacity bucket, so the caller may freely reuse its arrays."""
         n = len(ts)
         cap = bucket_capacity(n)
-        pooled = recycler is not None and recycler.enabled
-        dev_fields = {}
-        staged = []
-        for name, dt in schema.fields.items():
-            src = cols[name]
-            # one vectorized copy into a private buffer: the caller may
-            # freely reuse its arrays (device_put can defer-read/alias the
-            # host buffer, see InFlightRecycler)
-            buf = (recycler.pool.acquire(dt, cap) if pooled
-                   else np.zeros(cap, dtype=dt))
-            buf[:n] = src
-            dev_fields[name] = jax.device_put(buf)
-            staged.append(buf)
-        if pooled:
-            recycler.track(dev_fields.values(), staged)
+        staging = StagingBuffers(schema, cap, recycler).fill(cols, n)
         ts2 = np.zeros(cap, dtype=np.int64)
         ts2[:n] = ts
-        return BatchTPU(dev_fields, ts2, n, schema, wm, keys)
+        return BatchTPU.stage_prefilled(staging, ts2, n, schema, wm, keys,
+                                        counters)
 
     @staticmethod
-    def stage_prefilled(cols: Dict[str, np.ndarray], ts: np.ndarray,
-                        n: int, schema: TupleSchema, wm: int,
+    def stage_prefilled(staging: StagingBuffers, ts: np.ndarray, n: int,
+                        schema: TupleSchema, wm: int,
                         keys: Optional[Any] = None,
-                        recycler=None) -> "BatchTPU":
-        """CPU->TPU from staging buffers ALREADY padded to the capacity
-        bucket and filled in place (TPUStageEmitter's block-append path):
-        just ``device_put`` — the single host copy per column happened at
-        append time. Ownership of ``cols``/``ts`` transfers to the batch:
-        the caller must not touch them again (device_put may alias the
-        host buffer); with ``recycler`` the field buffers return to its
-        pool once the H2D commits."""
-        import jax
-
-        dev_fields = {name: jax.device_put(cols[name])
-                      for name in schema.fields}
-        if recycler is not None and recycler.enabled:
-            recycler.track(dev_fields.values(),
-                           [cols[name] for name in schema.fields])
-        return BatchTPU(dev_fields, ts, n, schema, wm, keys)
+                        counters: Optional[StageCounters] = None
+                        ) -> "BatchTPU":
+        """From staging buffers whose first ``n`` rows were filled in
+        place (TPUStageEmitter's block-append path): one ``device_put``
+        per dtype group (``StagingBuffers.put``). Ownership of
+        ``staging``/``ts`` transfers to the batch."""
+        return BatchTPU(staging.put(n, counters), ts, n, schema, wm, keys)
 
     # -- exit to host ------------------------------------------------------
     def prefetch_host(self) -> None:
@@ -197,8 +397,8 @@ class BatchTPU(StreamMsg):
     def to_rows(self) -> List[Tuple[Any, int]]:
         """TPU->CPU (the reference's ``transfer2CPU``,
         ``batch_gpu_t.hpp:154-165``)."""
-        host_cols = {name: np.asarray(v) for name, v in self.fields.items()}
-        return self.schema.from_columns(host_cols, self.ts_host, self.size)
+        return self.schema.from_columns(host_columns(self.fields),
+                                        self.ts_host, self.size)
 
     def copy_trace_from(self, src: "BatchTPU") -> "BatchTPU":
         """Propagate origin stamps and timeline identity from the batch
@@ -229,7 +429,7 @@ class BatchTPU(StreamMsg):
 
     def copy_for_dest(self) -> "BatchTPU":
         """Broadcast copy: device arrays are immutable, sharing is safe."""
-        b = BatchTPU(dict(self.fields), self.ts_host, self.size, self.schema,
+        b = BatchTPU(self.fields, self.ts_host, self.size, self.schema,
                      self.wm, self.host_keys, self.key_slots,
                      self.slot_of_key)
         b.stream_tag = self.stream_tag

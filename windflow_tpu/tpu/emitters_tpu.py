@@ -3,10 +3,12 @@
 ``wf/broadcast_emitter_gpu.hpp``, template cases <inputGPU, outputGPU>).
 
 - TPUStageEmitter  (CPU -> TPU): accumulates rows + keys into columnar
-  staging and ships a ``BatchTPU`` per ``output_batch_size`` tuples. JAX
-  ``device_put`` dispatch is async, which provides the copy/compute overlap
-  the reference gets from double-buffered pinned staging
-  (``keyby_emitter_gpu.hpp:443-505``). KEYBY routing hashes on the host and
+  staging and ships a ``BatchTPU`` per ``output_batch_size`` tuples: one
+  host buffer and one ``device_put`` per dtype group of the schema, the
+  columns packed end to end (``tpu/batch.py`` ``StagingBuffers`` /
+  ``PackedFields``). JAX ``device_put`` dispatch is async, which provides
+  the copy/compute overlap the reference gets from double-buffered pinned
+  staging (``keyby_emitter_gpu.hpp:443-505``). KEYBY routing hashes on the host and
   keeps one staging buffer per destination; partial batches flush on
   punctuation/EOS (pad+mask instead of variable shapes).
 - TPUForward/Broadcast/KeyByEmitter (TPU -> TPU): batches pass by
@@ -33,7 +35,8 @@ from ..basic import ExecutionMode, WindFlowError
 from ..message import Batch
 from ..monitoring.tracing import StageCounters, next_batch_id, stamp_ns
 from ..runtime.emitters import BasicEmitter
-from .batch import BatchTPU, bucket_capacity
+from .batch import (BatchTPU, StagingBuffers, bucket_capacity,
+                    gather_columns)
 from .schema import TupleSchema
 
 
@@ -60,13 +63,13 @@ class TPUStageEmitter(BasicEmitter):
         self._rows: List[list] = [[] for _ in range(n_bufs)]
         self._keys: List[list] = [[] for _ in range(n_bufs)]
         self._wms: List[int] = [0] * n_bufs
-        # block-native staging (append_columns): per-destination column
-        # buffers filled IN PLACE by array-slice copies — the columnar
-        # twin of ``_rows``. A buffer holds row-staged OR block-staged
-        # data, never both (the append paths ship the other form first,
-        # preserving order). Key slices accumulate as parts and are
-        # concatenated once per flush.
-        self._cbuf: List[Optional[dict]] = [None] * n_bufs
+        # block-native staging (append_columns): per-destination
+        # ``StagingBuffers`` whose column views are filled IN PLACE by
+        # array-slice copies — the columnar twin of ``_rows``. A buffer
+        # holds row-staged OR block-staged data, never both (the append
+        # paths ship the other form first, preserving order). Key slices
+        # accumulate as parts and are concatenated once per flush.
+        self._cbuf: List[Optional[StagingBuffers]] = [None] * n_bufs
         self._cts: List[Optional[np.ndarray]] = [None] * n_bufs
         self._ckparts: List[list] = [[] for _ in range(n_bufs)]
         self._ccount: List[int] = [0] * n_bufs
@@ -108,6 +111,8 @@ class TPUStageEmitter(BasicEmitter):
     def _bind_stages(self, owner: StageCounters) -> None:
         self._st_stage = owner.stage("stage")
         self._st_h2d = owner.stage("h2d")
+        # Stage_h2d_puts / Stage_unpacked_columns of the batches shipped
+        self._counters = owner
 
     def set_stats(self, stats) -> None:
         super().set_stats(stats)
@@ -223,23 +228,24 @@ class TPUStageEmitter(BasicEmitter):
                 bucket_capacity(self.output_batch_size
                                 if len(rows) <= self.output_batch_size
                                 else len(rows)),
-                recycler=self.recycler)
+                self.recycler, self._counters)
         n = len(rows)
         self._rows[buf] = []
         self._keys[buf] = []
         self._dispatch_batch(buf, batch, n)
 
     def _ship_cbuf(self, buf: int) -> None:
-        """Ship a block-staged buffer: the staging arrays were filled in
-        place by ``_append_part`` (already padded to the capacity bucket),
-        so the only work left is the key-part concatenation — ONE
-        ``np.concatenate`` per flush — and the device_put."""
+        """Ship a block-staged buffer: the staging buffers were filled in
+        place by ``_append_part`` (at the capacity bucket), so the only
+        work left is the key-part concatenation — ONE ``np.concatenate``
+        per flush — zeroing a partial batch's pad rows, and one
+        device_put per dtype group."""
         n = self._ccount[buf]
         if not n:
             return
         # buffers already filled in place (wf:stage), so this stage is
-        # the key concat + the device_put calls (issue time: the copy
-        # itself is asynchronous)
+        # the key concat + the device_put of each group (issue time: the
+        # copy itself is asynchronous)
         with self._st_h2d(self._bids[buf]):
             kparts = self._ckparts[buf]
             keys = None
@@ -248,7 +254,7 @@ class TPUStageEmitter(BasicEmitter):
                         else np.concatenate(kparts))
             batch = BatchTPU.stage_prefilled(
                 self._cbuf[buf], self._cts[buf], n, self.schema,
-                self._wms[buf], keys, self.recycler)
+                self._wms[buf], keys, self._counters)
         # ownership of the staging buffers moved to the batch/recycler:
         # a fresh set is allocated at the next append (device_put may
         # alias the host buffer on the CPU backend)
@@ -422,13 +428,13 @@ class TPUStageEmitter(BasicEmitter):
             bid = self._bids[buf] = next_batch_id()
             with self._st_h2d(bid):
                 batch = BatchTPU.stage_columns(pcols, pts, self.schema, wm,
-                                               pkeys, self.recycler)
+                                               pkeys, self.recycler,
+                                               self._counters)
             if t_trace and (tmask is None or tmask.any()):
                 self._trace_lo[buf] = self._trace_hi[buf] = t_trace
             self._wms[buf] = wm
             self._dispatch_batch(buf, batch, n)
             return
-        names = list(self.schema.fields)
         off = 0
         while off < n:
             cnt = self._ccount[buf]
@@ -447,8 +453,8 @@ class TPUStageEmitter(BasicEmitter):
                 cb = self._cbuf[buf]
                 if cb is None:
                     cb = self._cbuf_alloc(buf)
-                for name in names:
-                    cb[name][cnt:cnt + take] = pcols[name][off:end]
+                for name, col in cb.cols.items():
+                    col[cnt:cnt + take] = pcols[name][off:end]
                 self._cts[buf][cnt:cnt + take] = pts[off:end]
                 if pkeys is not None:
                     self._ckparts[buf].append(pkeys[off:end])
@@ -462,18 +468,16 @@ class TPUStageEmitter(BasicEmitter):
             if cnt + take >= obs:
                 self._ship_cbuf(buf)
 
-    def _cbuf_alloc(self, buf: int) -> dict:
+    def _cbuf_alloc(self, buf: int) -> StagingBuffers:
+        """One pooled host buffer per dtype group, a row view of it per
+        column; uninitialised (the pad rows are zeroed at ship time)."""
         cap = self._ccap
         if cap == 0:
             cap = self._ccap = bucket_capacity(self.output_batch_size)
-        pooled = self.recycler.enabled
-        pool = self.recycler.pool
-        cb = {name: (pool.acquire(dt, cap) if pooled
-                     else np.zeros(cap, dtype=dt))
-              for name, dt in self.schema.fields.items()}
-        self._cbuf[buf] = cb
+        cb = self._cbuf[buf] = StagingBuffers(self.schema, cap,
+                                              self.recycler)
         # ts is NEVER pooled: it becomes the batch's ts_host metadata and
-        # lives as long as the batch itself (see BatchTPU.stage_columns)
+        # lives as long as the batch itself
         self._cts[buf] = np.zeros(cap, dtype=np.int64)
         return cb
 
@@ -895,16 +899,16 @@ def _int_keys_hashable_as_identity(kcol: np.ndarray, n: int) -> bool:
 def gather_sub_batch(batch: BatchTPU, idx: np.ndarray,
                      host_keys=None) -> BatchTPU:
     """Gather ``idx`` rows of a device batch into a new (smaller) device
-    batch without leaving HBM: one XLA gather per column from a
-    host-computed index vector. Shared by the keyed re-shard and the
-    device-plane splitting emitter."""
+    batch without leaving HBM: one XLA gather per column (per dtype group
+    of a packed batch) from a host-computed index vector. Shared by the
+    keyed re-shard and the device-plane splitting emitter."""
     import jax
 
     cap = bucket_capacity(idx.size)
     gather = np.zeros(cap, dtype=np.int32)
     gather[:idx.size] = idx
     gidx = jax.device_put(gather)
-    sub_fields = {k: v[gidx] for k, v in batch.fields.items()}
+    sub_fields = gather_columns(batch.fields, gidx)
     ts2 = batch.ts_host[gather]
     if host_keys is None and batch.host_keys is not None:
         hk = batch.host_keys

@@ -52,7 +52,7 @@ import numpy as np
 
 from ..basic import OpType, RoutingMode, WinType, WindFlowError
 from ..monitoring.tracing import next_batch_id
-from .batch import BatchTPU, bucket_capacity
+from .batch import BatchTPU, bucket_capacity, field_dtype
 from .ops_tpu import TPUOperatorBase, TPUReplicaBase
 from .schema import TupleSchema, broadcast_scalar_fields
 
@@ -300,8 +300,11 @@ class FfatTPUReplica(TPUReplicaBase):
         """The lift entry every program traces. The fused-chain replica
         overrides it to compose the chain's stateless map/filter prefix
         IN FRONT of the user lift, so ``source -> map -> filter ->
-        Ffat_Windows`` runs as ONE program per batch."""
-        return self.op.lift
+        Ffat_Windows`` runs as ONE program per batch. The user's lift
+        gets a dict of its own (a staged batch's packed mapping, returned
+        as it is, would not be a dict of columns)."""
+        lift = self.op.lift
+        return lambda fields: lift(dict(fields))
 
     def _prefix_mask(self, batch: BatchTPU):
         """Keep mask of a fused prefix filter over ``batch`` (None when
@@ -775,7 +778,7 @@ class FfatTPUReplica(TPUReplicaBase):
             return None
         self._ensure_forest(batch.fields)
         if op.key_field is not None and op.key_field in batch.fields:
-            self._key_dtype = np.dtype(batch.fields[op.key_field].dtype)
+            self._key_dtype = field_dtype(batch.fields, op.key_field)
         # fused prefix filter (FusedFfatReplica): rows it drops must not
         # exist for the control plane AT ALL — no key registration, no
         # max_leaf/next_fire advance, no CB count — exactly the rows the
@@ -1152,11 +1155,11 @@ class FfatTPUReplica(TPUReplicaBase):
         if rb is not None:
             self.trees, self.tvalid = rb(self.trees, self.tvalid)
 
-    def _prewarm_schema(self):
-        """Schema of the batches this replica receives (the fused-chain
-        replica receives the CHAIN ENTRY's schema, not the window op's
-        declared one)."""
-        return self.op.schema
+    def _prewarm_entry(self):
+        """The operator whose schema and input edge this replica's batches
+        have (the fused-chain replica receives the CHAIN ENTRY's, not the
+        window op's declared one)."""
+        return self.op
 
     def prewarm(self, caps) -> Optional[int]:
         """``PipeGraph.with_prewarm`` hook: compile every program
@@ -1166,7 +1169,8 @@ class FfatTPUReplica(TPUReplicaBase):
         pay a mid-stream compile. Needs a declared schema — the forest
         shape comes from ``eval_shape`` of the lift over schema-dtyped
         zeros, and the key dtype from the schema's key column."""
-        sch = self._prewarm_schema()
+        entry = self._prewarm_entry()
+        sch = entry.schema
         if sch is None:
             return None
         from .ops_tpu import prewarm_zero_fields
@@ -1175,7 +1179,7 @@ class FfatTPUReplica(TPUReplicaBase):
             self._key_dtype = np.dtype(sch.fields[kf])
         warmed = 0
         for cap in caps:
-            fields = prewarm_zero_fields(sch, cap)
+            fields = prewarm_zero_fields(entry, cap)
             self._ensure_forest(fields)
             ckey, ikey = self._step_keys(cap)
             if ckey in self._prog_cache and ikey in self._prog_cache:

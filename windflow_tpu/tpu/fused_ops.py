@@ -210,7 +210,7 @@ class FusedTPUReplica(TPUReplicaBase):
                     with jax.named_scope(program_name(spec.kind,
                                                       spec.op.name)):
                         fields, valid, _ = spec.kernel(fields, valid, None)
-                    if not isinstance(fields, dict):
+                    if spec.kind == "map" and not isinstance(fields, dict):
                         raise WindFlowError(
                             f"{fused_name}: Map_TPU function must return "
                             "a dict of columns")
@@ -356,7 +356,7 @@ class FusedTPUReplica(TPUReplicaBase):
             kk <<= 1
         warmed = 0
         for cap in caps:
-            fields = prewarm_zero_fields(sch, cap)
+            fields = prewarm_zero_fields(self.op, cap)
             hargs = tuple(
                 ((jax.device_put(np.arange(cap, dtype=np.int32)),
                   jax.device_put(np.zeros(cap, dtype=np.int32)))
@@ -620,7 +620,7 @@ class FusedFfatReplica(FfatTPUReplica):
 
         kernels = self._prefix_kernels
         scopes = self._prefix_scopes
-        lift = self.op.lift
+        lift = super()._lift_fn()
         if not kernels:
             return lift
 
@@ -668,10 +668,10 @@ class FusedFfatReplica(FfatTPUReplica):
                                 program=program_name(_PROG_MASK, *self._tag))
 
     # -- prewarm -----------------------------------------------------------
-    def _prewarm_schema(self):
+    def _prewarm_entry(self):
         # batches arrive with the CHAIN ENTRY's schema (the prefix maps
         # transform columns in-program)
-        return self.ops[0].schema
+        return self.ops[0]
 
     def prewarm(self, caps) -> Optional[int]:
         warmed = super().prewarm(caps)
@@ -679,12 +679,12 @@ class FusedFfatReplica(FfatTPUReplica):
             return warmed
         import jax
 
-        sch = self._prewarm_schema()
+        entry = self._prewarm_entry()
         for cap in caps:
             prog = cached_compile(self._prog_cache, self.op._prog_lock,
                                   ("fmask", cap, self._tag),
                                   self._make_mask)
-            jax.block_until_ready(prog(prewarm_zero_fields(sch, cap), 0))
+            jax.block_until_ready(prog(prewarm_zero_fields(entry, cap), 0))
             warmed += 1
         return warmed
 

@@ -38,7 +38,8 @@ from ..monitoring.flightrec import instrumented_jit
 from ..monitoring.tracing import program_name
 from ..operators.base import BasicOperator, BasicReplica
 from ..runtime.dispatch import DeviceDispatchQueue
-from .batch import BatchTPU, key_column_np, key_column_to_list
+from .batch import (BatchTPU, StagingBuffers, key_column_np,
+                    key_column_to_list)
 from .schema import TupleSchema
 
 
@@ -50,17 +51,16 @@ _PROG_SMAP, _PROG_SFILTER = "smap", "sfilter"
 SCOPE_GRID_SCAN = "grid_scan"
 
 
-def prewarm_zero_fields(schema: "TupleSchema", cap: int):
-    """Zero-valued device columns at one bucket capacity — the dummy
-    input the compile-stability pre-warm feeds a program so its
-    (shape, dtype) signature traces before any real batch arrives.
-    ``device_put`` of schema-dtyped numpy matches the staging emitters'
-    transfer path, so the traced signature is byte-for-byte the one the
-    stream will present."""
-    import jax
-
-    return {name: jax.device_put(np.zeros(cap, dtype=dt))
-            for name, dt in schema.fields.items()}
+def prewarm_zero_fields(op: "TPUOperatorBase", cap: int):
+    """A zero-valued batch's ``fields`` of ``op``'s declared schema at one
+    bucket capacity — the dummy input the compile-stability pre-warm
+    feeds a program so its signature traces before any real batch
+    arrives. In the form ``op``'s stream will present: fed from the host,
+    the staging emitters' own transfer (``StagingBuffers.put``: one packed
+    buffer per dtype group); fed by a device operator, a dict of
+    columns."""
+    fields = StagingBuffers(op.schema, cap).put(0)
+    return fields if op.staged_input else dict(fields)
 
 
 def _compact_order(keep):
@@ -494,6 +494,9 @@ class TPUReplicaBase(BasicReplica):
 class TPUOperatorBase(BasicOperator):
     op_type = OpType.TPU
     is_tpu = True
+    # set where the graph wires a CPU -> TPU edge into this operator: its
+    # batches then arrive staged (packed), not as a device program's dict
+    staged_input = False
 
     def __init__(self, name: str, parallelism: int, input_routing: RoutingMode,
                  key_extractor, output_batch_size: int,
@@ -571,7 +574,10 @@ class Map_TPU(TPUOperatorBase):
         func = self.func
 
         def kernel(fields, valid, carry):
-            return func(fields), valid, carry
+            # the user's function gets a dict of its own (a staged
+            # batch's packed mapping is read-only, and returned as it is
+            # it would not be a dict of columns)
+            return func(dict(fields)), valid, carry
 
         return kernel
 
@@ -613,7 +619,7 @@ class MapTPUReplica(TPUReplicaBase):
             return None
         for cap in caps:
             jax.block_until_ready(
-                self._jitted(prewarm_zero_fields(sch, cap)))
+                self._jitted(prewarm_zero_fields(self.op, cap)))
         return len(caps)
 
 
@@ -1145,7 +1151,7 @@ class FilterTPUReplica(TPUReplicaBase):
             return None
         for cap in caps:
             jax.block_until_ready(
-                self._jitted(prewarm_zero_fields(sch, cap), 0))
+                self._jitted(prewarm_zero_fields(self.op, cap), 0))
         return len(caps)
 
     # empty batches are dropped entirely (the reference shrinks to zero and
@@ -1215,7 +1221,7 @@ class GlobalReduceTPUReplica(TPUReplicaBase):
             return None
         for cap in caps:
             jax.block_until_ready(
-                self._jitted(prewarm_zero_fields(sch, cap), 0))
+                self._jitted(prewarm_zero_fields(self.op, cap), 0))
         return len(caps)
 
     def process_device_batch(self, batch: BatchTPU) -> None:
@@ -1278,7 +1284,7 @@ class ReduceTPUReplica(TPUReplicaBase):
             order = jax.device_put(np.arange(cap, dtype=np.int32))
             slots = jax.device_put(np.zeros(cap, dtype=np.int32))
             jax.block_until_ready(
-                self._jitted(prewarm_zero_fields(sch, cap), order, slots))
+                self._jitted(prewarm_zero_fields(self.op, cap), order, slots))
         return len(caps)
 
     def _order_and_slots(self, batch: BatchTPU):

@@ -68,19 +68,11 @@ class TupleSchema:
             "use dataclass/dict tuples or pass an explicit TupleSchema")
 
     # ------------------------------------------------------------------
-    def to_columns(self, rows: Sequence[Tuple[Any, int]], capacity: int,
-                   pool=None) -> Tuple[Dict[str, np.ndarray], np.ndarray]:
-        """Rows [(payload, ts)] -> padded columnar arrays + int64 ts.
-        With ``pool`` (an ``ArrayPool``) buffers come from its free lists;
-        the caller owns returning them once the H2D transfer commits."""
-        if pool is not None:
-            cols = {name: pool.acquire(dt, capacity)
-                    for name, dt in self.fields.items()}
-        else:
-            cols = {name: np.zeros(capacity, dtype=dt)
-                    for name, dt in self.fields.items()}
-        # ts stays out of the pool: it becomes the batch's ts_host metadata
-        # and lives as long as the batch itself, not just the transfer
+    def to_columns(self, rows: Sequence[Tuple[Any, int]], capacity: int
+                   ) -> Tuple[Dict[str, np.ndarray], np.ndarray]:
+        """Rows [(payload, ts)] -> padded columnar arrays + int64 ts."""
+        cols = {name: np.zeros(capacity, dtype=dt)
+                for name, dt in self.fields.items()}
         ts = np.zeros(capacity, dtype=np.int64)
         n = len(rows)
         if n and self._try_native(rows, cols, ts, n):
