@@ -273,3 +273,45 @@ def test_two_stage_alignment_stall_recorded(tmp_path):
     # the fast channel's barrier waited on the slow channel
     assert sum(r["Checkpoint_align_stall_usec_total"]
                for r in red_reps) > 0
+
+
+def test_wait_committed_waits_through_the_commit_write(tmp_path):
+    """``_finalize`` takes the epoch out of the pending table, writes the
+    commit outside the lock, and only then publishes it as completed. A
+    ``wait_committed`` that looks in between (a live rescale on a loaded
+    machine) must keep waiting, not report the epoch as dropped."""
+    import threading
+
+    from windflow_tpu.checkpoint import CheckpointStore
+    from windflow_tpu.checkpoint.coordinator import CheckpointCoordinator
+
+    store = CheckpointStore(str(tmp_path / "store"))
+    in_commit, release = threading.Event(), threading.Event()
+    commit = store.commit
+
+    def slow_commit(cid, meta):
+        in_commit.set()
+        assert release.wait(30)
+        return commit(cid, meta)
+
+    store.commit = slow_commit
+    coord = CheckpointCoordinator(store, "race")
+    coord.expected_acks = 1
+    cid = coord.trigger(force=True)
+    acker = threading.Thread(
+        target=coord.ack, args=(cid, "w0", {("op", 0): {"x": 1}}))
+    acker.start()
+    assert in_commit.wait(30)
+    waited = []
+    waiter = threading.Thread(
+        target=lambda: waited.append(coord.wait_committed(cid, 30)))
+    waiter.start()
+    waiter.join(0.3)
+    assert waiter.is_alive() and not waited     # still waiting, no raise
+    release.set()
+    acker.join(30)
+    waiter.join(30)
+    assert waited == [None] and coord.last_completed_id == cid
+    # an epoch nobody knows is still reported as dropped
+    with pytest.raises(Exception, match="dropped without committing"):
+        coord.wait_committed(cid + 7, 1)

@@ -112,6 +112,10 @@ def _find_op(g, name=None, kind=None):
 def run_late_replay(engine, monkeypatch):
     """Replay the deterministic late stream through one window engine;
     returns the window operator's late-accounting counters."""
+    # the device plane decides lateness per batch, and the model assumes
+    # batches of OBS rows: no partial batch may ship by wall-clock age
+    # (a loaded machine then moves the batch boundaries and the counts)
+    monkeypatch.setenv("WF_MAX_STAGING_MS", "0")
     g = PipeGraph(f"evt_health_{engine}", ExecutionMode.DEFAULT,
                   TimePolicy.EVENT_TIME)
     src = Source_Builder(late_src).with_output_batch_size(OBS).build()

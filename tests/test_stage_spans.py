@@ -150,12 +150,14 @@ RUNS_ON = {
     "ingest": {"src"}, "stage": {"src"}, "h2d": {"src"},
     "prep": set(DEVICE), "queue": set(DEVICE), "commit": set(DEVICE),
     "launch": set(DEVICE), "emit": set(DEVICE),
+    # the window operator plans its fires inside its prep
+    "fireplan": {"win"},
     # the window operator's commit emits its fired batch without a
     # blocking read: its readback counter reads 0
     "readback": {CHAIN},
     # the chain's keyed re-shard to ONE destination passes batches on
     # without its FIFO; the window operator's columnar exit queues them
-    "fifo": {"win"}, "d2h": {"snk"}, "sink": {"snk"},
+    "fifo": {"win"}, "exit": {"win"}, "d2h": {"snk"}, "sink": {"snk"},
 }
 FIELDS = sorted(
     (field, stage) for stage, sdef in tracing.STAGES.items()
@@ -187,6 +189,33 @@ def test_commit_holds_its_children(served, op):
             + rep["Dispatch_emit_total_usec"]
             <= rep["Dispatch_commit_total_usec"])
     assert rep["Dispatch_batches"] == rep["Device_batches_in"] == BLOCKS
+
+
+def test_window_operator_counts_its_fires_and_plans(served):
+    win, snk = served["stats"]["win"], served["stats"]["snk"]
+    # a row per fired window, empty ones too, each from a program that
+    # answered window queries (a step's fire block or a fire-only program)
+    assert win["Windows_fired"] == snk["Inputs_received"] > 0
+    assert 0 < win["Fire_programs"] == win["Device_batches_out"] \
+        <= win["Device_programs_run"]
+    assert 0 < win["Fire_plan_total_usec"] <= \
+        win["Dispatch_host_prep_total_usec"]
+    for op, rep in served["stats"].items():
+        if op != "win":
+            assert rep["Windows_fired"] == 0 == rep["Fire_programs"], op
+
+
+def test_fire_plan_is_a_span_inside_the_window_prep(served):
+    log = served["log"]
+    plans = log.named("wf:fireplan:win")
+    assert len(plans) == BLOCKS
+    for s in plans:
+        assert s["parent"] == "wf:prep:win" and s["b"] > 0
+        assert len(log.named("wf:prep:win", b=s["b"])) == 1
+    # and in a dumped trace (the ring), under the same name and ids
+    ring = {e["args"]["b"] for e in served["ring"]
+            if e["name"] == "wf:fireplan:win"}
+    assert ring == {s["b"] for s in plans}
 
 
 def test_unknown_stage_raises_where_it_is_bound():

@@ -69,6 +69,10 @@ class CheckpointCoordinator:
         # _store_lock outside _lock, never the reverse.
         self._store_lock = threading.Lock()
         self._pending: Dict[int, Dict[str, Any]] = {}
+        # epochs ``_finalize`` has taken out of ``_pending`` and is
+        # writing the commit of (outside the lock): no longer pending, not
+        # yet ``last_completed_id`` — ``wait_committed`` must keep waiting
+        self._committing: Set[int] = set()
         # workers that exited cleanly, with their final state blobs: a
         # finished worker's state is frozen, so its final snapshot is
         # valid for every later epoch (Flink's finished-task semantics —
@@ -359,21 +363,29 @@ class CheckpointCoordinator:
             ent = self._pending.pop(ckpt_id, None)
             if ent is None:
                 return  # raced another finalize
+            self._committing.add(ckpt_id)
             # any older still-open checkpoint can no longer matter: the
             # newer one strictly supersedes it
             for old in [c for c in self._pending if c < ckpt_id]:
                 self._pending.pop(old, None)
             listeners = list(self._listeners)
         duration = time.monotonic() - ent["t0"]
-        with self._store_lock:
-            self.store.commit(ckpt_id, {
-                "graph": self.graph_name,
-                "created_unix": time.time(),
-                "duration_sec": round(duration, 6),
-                "n_workers": self.expected_acks,
-                "bytes": ent["bytes"],
-            })
+        try:
+            with self._store_lock:
+                self.store.commit(ckpt_id, {
+                    "graph": self.graph_name,
+                    "created_unix": time.time(),
+                    "duration_sec": round(duration, 6),
+                    "n_workers": self.expected_acks,
+                    "bytes": ent["bytes"],
+                })
+        except BaseException:
+            with self._lock:
+                self._committing.discard(ckpt_id)
+                self._commit_cond.notify_all()
+            raise
         with self._lock:
+            self._committing.discard(ckpt_id)
             self.completed += 1
             self.last_completed_id = ckpt_id
             self.last_duration_s = duration
@@ -485,7 +497,7 @@ class CheckpointCoordinator:
                     return
                 if cid in self._failed:
                     raise WindFlowError(self._failed[cid])
-                if cid not in self._pending:
+                if cid not in self._pending and cid not in self._committing:
                     raise WindFlowError(
                         f"checkpoint epoch {cid} was dropped without "
                         "committing (superseded by a newer checkpoint)")

@@ -46,6 +46,10 @@ class StatsRecord(StageCounters):
         "service_time_us", "eff_service_time_us",
         "device_batches_in", "device_batches_out",
         "device_bytes_h2d", "device_bytes_d2h", "device_programs_run",
+        # window operators (tpu/ffat_tpu.py): windows fired (a row each,
+        # empty ones too) and the programs that answered them (a full
+        # step with its fire block, or a fire-only program)
+        "windows_fired", "fire_programs",
         "staging_pool_hits", "staging_pool_misses",
         "dispatch_host_prep_us", "dispatch_commit_us",
         "dispatch_stalls", "dispatch_depth_max",
@@ -173,6 +177,8 @@ class StatsRecord(StageCounters):
         self.device_bytes_h2d = 0
         self.device_bytes_d2h = 0
         self.device_programs_run = 0
+        self.windows_fired = 0
+        self.fire_programs = 0
         self.staging_pool_hits = 0  # recycled staging buffers (ArrayPool)
         self.staging_pool_misses = 0
         # device-ahead dispatch pipeline (runtime/dispatch.py): per-stage
@@ -515,6 +521,8 @@ class StatsRecord(StageCounters):
             "Device_bytes_H2D": self.device_bytes_h2d,
             "Device_bytes_D2H": self.device_bytes_d2h,
             "Device_programs_run": self.device_programs_run,
+            "Windows_fired": self.windows_fired,
+            "Fire_programs": self.fire_programs,
             "Fused_ops": self.fused_ops,
             "Staging_pool_hits": self.staging_pool_hits,
             "Staging_pool_misses": self.staging_pool_misses,

@@ -148,6 +148,9 @@ STAGES: Dict[str, StageDef] = {
     # device operator's worker
     "prep": StageDef("wf", _DISPATCH, "Dispatch_host_prep_total_usec",
                      "Dispatch_batches", note="note_host_prep", scope=True),
+    # the window operator's fire planning, inside its prep (``count``
+    # stays None: the plan runs once per batch, Dispatch_batches counts)
+    "fireplan": StageDef("wf", _DISPATCH, "Fire_plan_total_usec", None),
     "queue": StageDef("wait", _DISPATCH, "Dispatch_queue_wait_total_usec",
                       None),
     "commit": StageDef("wf", _DISPATCH, "Dispatch_commit_total_usec", None,
@@ -160,7 +163,7 @@ STAGES: Dict[str, StageDef] = {
     # exit edge and sink
     "fifo": StageDef("wait", _EXIT, "Exit_fifo_wait_total_usec",
                      "Exit_fifo_batches"),
-    "exit": StageDef("wf", _EXIT, None, None),
+    "exit": StageDef("wf", _EXIT, "Exit_process_total_usec", None),
     "d2h": StageDef("wf", _EXIT, "Sink_d2h_wait_total_usec", None),
     "sink": StageDef("wf", _EXIT, "Sink_functor_total_usec", None),
 }

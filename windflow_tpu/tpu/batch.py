@@ -280,6 +280,25 @@ class StagingBuffers:
         return PackedFields(dev, self.layout, counters)
 
 
+def row_schema(fields: Mapping, schema: Optional[TupleSchema]
+               ) -> TupleSchema:
+    """The schema of a batch an operator made from one with ``schema``.
+    An operator that adds, drops or renames columns changes what a row
+    is: the schema then follows the columns (rows leave the device as
+    dicts), else a row exit would rebuild the INPUT's rows and lose what
+    the operator made. Rows of a user's type stay of that type while the
+    columns still hold its fields (more columns are then helpers of the
+    device plane)."""
+    names = fields.keys()
+    if schema is not None and (
+            names == schema.fields.keys()
+            or (schema.constructor is not None
+                and names >= schema.fields.keys())):
+        return schema
+    return TupleSchema({name: np.dtype(v.dtype)
+                        for name, v in fields.items()})
+
+
 class BatchTPU(StreamMsg):
     __slots__ = ("fields", "ts_host", "size", "capacity", "wm", "is_punct",
                  "stream_tag", "id", "schema", "host_keys", "key_slots",
@@ -420,7 +439,8 @@ class BatchTPU(StreamMsg):
 
     def with_fields(self, new_fields: Dict[str, Any]) -> "BatchTPU":
         """Same metadata, new device columns (in-place operator output)."""
-        b = BatchTPU(new_fields, self.ts_host, self.size, self.schema,
+        schema = row_schema(new_fields, self.schema)
+        b = BatchTPU(new_fields, self.ts_host, self.size, schema,
                      self.wm, self.host_keys, self.key_slots,
                      self.slot_of_key)
         b.stream_tag = self.stream_tag

@@ -227,6 +227,8 @@ class FfatTPUReplica(TPUReplicaBase):
         self.trees = None  # dict field -> (K_cap, 2F)
         self.tvalid = None  # (K_cap, 2F) bool
         self._prog_cache = op._prog_cache  # shared across replicas
+        # wf:fireplan: the host's fire planning inside wf:prep
+        self._st_fireplan = self.stats.stage("fireplan")
         self.__host_seg = None  # resolved lazily: backend init is costly
         self.__on_accel = None  # same caching rationale (_on_accelerator)
         self._check_index_plane()
@@ -1238,6 +1240,22 @@ class FfatTPUReplica(TPUReplicaBase):
             self.dispatch.drain(forced=True)
             self._warm_programs(cap, ckey, ikey, fields, order_p, same_p,
                                 end_p, flat_p, ktable)
+        with self._st_fireplan(bid):
+            plan, total_fired = self._plan_fires(frontier)
+        # fast-rise / slow-decay: a burst switches to the wide tier on
+        # the very next batch (both tier shapes are already compiled),
+        # while decay back to the small tier is smoothed
+        if total_fired > self._fire_ewma:
+            self._fire_ewma = float(total_fired)
+        else:
+            self._fire_ewma += 0.25 * (total_fired - self._fire_ewma)
+        seg = (comp_p, order_p, same_p, end_p, flat_p)
+        return lambda: self._commit_step(fields, wm, seg, ktable,
+                                         ckey, ikey, plan, bid)
+
+    def _plan_fires(self, frontier):
+        """The batch's whole fire plan (the ``wf:fireplan`` stage): one
+        entry per program, ``None`` for the ingest-only one."""
         plan: List[Any] = []
         first = True
         total_fired = 0
@@ -1260,16 +1278,7 @@ class FfatTPUReplica(TPUReplicaBase):
             first = False
             if n_out < budget:
                 break
-        # fast-rise / slow-decay: a burst switches to the wide tier on
-        # the very next batch (both tier shapes are already compiled),
-        # while decay back to the small tier is smoothed
-        if total_fired > self._fire_ewma:
-            self._fire_ewma = float(total_fired)
-        else:
-            self._fire_ewma += 0.25 * (total_fired - self._fire_ewma)
-        seg = (comp_p, order_p, same_p, end_p, flat_p)
-        return lambda: self._commit_step(fields, wm, seg, ktable,
-                                         ckey, ikey, plan, bid)
+        return plan, total_fired
 
     def _commit_step(self, fields, wm, seg, ktable, ckey, ikey,
                      plan, bid: int) -> None:
@@ -1323,6 +1332,8 @@ class FfatTPUReplica(TPUReplicaBase):
         import jax
 
         op = self.op
+        self.stats.fire_programs += 1
+        self.stats.windows_fired += n_out
         fields = dict(qr)
         fields["valid"] = qv
         fields["wid"] = wid_dev  # built in-program: no device_put here

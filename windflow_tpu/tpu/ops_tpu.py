@@ -39,7 +39,7 @@ from ..monitoring.tracing import program_name
 from ..operators.base import BasicOperator, BasicReplica
 from ..runtime.dispatch import DeviceDispatchQueue
 from .batch import (BatchTPU, StagingBuffers, key_column_np,
-                    key_column_to_list)
+                    key_column_to_list, row_schema)
 from .schema import TupleSchema
 
 
@@ -462,8 +462,8 @@ class TPUReplicaBase(BasicReplica):
             keys_list = list(batch.host_keys)
             keys_arr = keys_list + [None] * (batch.capacity - len(keys_list))
             keys2 = [keys_arr[j] for j in order_np[:new_size]]
-        nb = BatchTPU(out_fields, ts2, new_size, batch.schema, batch.wm,
-                      keys2)
+        nb = BatchTPU(out_fields, ts2, new_size,
+                      row_schema(out_fields, batch.schema), batch.wm, keys2)
         nb.stream_tag = batch.stream_tag
         nb.copy_trace_from(batch)
         if new_size > 0:
