@@ -167,6 +167,40 @@ def test_counters_of_the_window_operator(sg2):
     assert sg2["stats"][sg2["roles"]["exit"]]["Exit_process_total_usec"] > 0
 
 
+@pytest.mark.parametrize("name,low,high", [
+    ("fire_grouped_share.sg2", 95.0, 100.0),
+    ("fire_groups_per_program.sg2", 1.0, 4.0)])
+def test_the_fire_query_goes_by_range_and_its_metrics_say_so(sg2, name, low,
+                                                             high):
+    """Every plug fires the same slides, so the programs answer by range,
+    one to four ranges each; the two metrics read the counters through the
+    reader that gives nothing, not 0, for a program without them."""
+    import types
+
+    from harness.cell import BENCH_DIR, load_module
+    from harness.stats import StatsWindow
+
+    cell = sg2["cell"]
+    entry, spec = [(m, f) for m, f in cell.metrics("per_layer")
+                   if m["name"] == name][0]
+    assert entry["workloads"] == ["sg2.saturated"]
+    assert entry["layer"] == spec["layer"] == "device programs"
+    assert spec["reader"] == "counter_ratio_present.py"
+    read = load_module(os.path.join(BENCH_DIR, "metrics", spec["reader"])).read
+    zeros = {op: dict.fromkeys(st, 0) for op, st in sg2["stats"].items()}
+
+    def ctx(end):
+        return types.SimpleNamespace(
+            trace=None, events=1, window_s=1.0,
+            stats=StatsWindow(zeros, end, sg2["roles"]))
+
+    assert low <= read(ctx(sg2["stats"]), spec["params"]) <= high
+    old = {op: {k: v for k, v in st.items()
+                if k not in ("Fire_grouped_programs", "Fire_groups")}
+           for op, st in sg2["stats"].items()}
+    assert read(ctx(old), spec["params"]) is None
+
+
 @pytest.mark.parametrize("high,ok", [(1000, True), (1_000_000, False)])
 def test_make_stream_refuses_values_whose_window_sum_leaves_float32(high, ok):
     cell = Cell("sg2.saturated", rehearse=True)

@@ -48,8 +48,11 @@ class StatsRecord(StageCounters):
         "device_bytes_h2d", "device_bytes_d2h", "device_programs_run",
         # window operators (tpu/ffat_tpu.py): windows fired (a row each,
         # empty ones too) and the programs that answered them (a full
-        # step with its fire block, or a fire-only program)
+        # step with its fire block, or a fire-only program); of those,
+        # the programs that answered per distinct ring range over every
+        # key slot at once, and the ranges summed over them
         "windows_fired", "fire_programs",
+        "fire_grouped_programs", "fire_groups",
         "staging_pool_hits", "staging_pool_misses",
         "dispatch_host_prep_us", "dispatch_commit_us",
         "dispatch_stalls", "dispatch_depth_max",
@@ -179,6 +182,8 @@ class StatsRecord(StageCounters):
         self.device_programs_run = 0
         self.windows_fired = 0
         self.fire_programs = 0
+        self.fire_grouped_programs = 0
+        self.fire_groups = 0
         self.staging_pool_hits = 0  # recycled staging buffers (ArrayPool)
         self.staging_pool_misses = 0
         # device-ahead dispatch pipeline (runtime/dispatch.py): per-stage
@@ -523,6 +528,8 @@ class StatsRecord(StageCounters):
             "Device_programs_run": self.device_programs_run,
             "Windows_fired": self.windows_fired,
             "Fire_programs": self.fire_programs,
+            "Fire_grouped_programs": self.fire_grouped_programs,
+            "Fire_groups": self.fire_groups,
             "Fused_ops": self.fused_ops,
             "Staging_pool_hits": self.staging_pool_hits,
             "Staging_pool_misses": self.staging_pool_misses,

@@ -23,10 +23,13 @@ TPU-first redesign:
     the user combine -> gather segment tails -> scatter-combine into the
     leaves of a FlatFAT FOREST (K_cap keys x 2F nodes, one segment tree
     per key slot, circular leaf addressing ``pane mod F``) -> vectorized
-    level rebuild (log F fused passes over the whole forest) -> vmapped
-    iterative range queries for up to W_cap fired windows (each walks
-    <= 2 log F nodes with ordered left/right accumulators, safe for
-    non-commutative combines) -> leaf eviction;
+    level rebuild (log F fused passes over the whole forest) -> iterative
+    range queries for up to W_cap fired windows (each walks <= 2 log F
+    nodes with ordered left/right accumulators, safe for non-commutative
+    combines): ONE walk per distinct ring range over every key slot at
+    once where the program's windows share a few ranges (time-based
+    windows planned by rounds do), else one walk a window under vmap
+    -> leaf eviction;
 - all shapes are static per (cap, K_cap, F, segmentation-mode) bucket;
   key capacity and ring length grow by doubling with a device-side rebuild
   (the reference resizes its pending-pane ring on demand,
@@ -66,6 +69,33 @@ PROG_STEP, PROG_FIRE, PROG_REBUILD = "step", "fire", "rebuild"
 SCOPE_SORT, SCOPE_SCAN, SCOPE_SCATTER = ("ingest_sort", "segmented_scan",
                                          "leaf_scatter")
 SCOPE_REBUILD, SCOPE_FIRE, SCOPE_EVICT = "level_rebuild", "fire", "evict"
+
+# the most distinct ring ranges (start_phys, length) whose windows one
+# program answers by range (one tree walk over every key slot at once,
+# see _query_fns); a program whose lanes hold more takes the lane walk.
+# The answers are a (G_CAP, K_cap) table per tree field
+G_CAP = 16
+
+
+def fire_pack_len(W: int, slide_units: int) -> int:
+    """Words of the ONE int32 buffer that carries a program's fire plan
+    (see ``fire_pack_views``) for a budget of ``W`` windows."""
+    return (6 + 3 * slide_units) * W + 2 * (G_CAP + 1)
+
+
+def fire_pack_views(pack, slide_units: int):
+    """``(fire, groups, evict)`` views of a program's flat fire plan, on
+    the host (numpy, to fill it) and inside the program (static slices):
+    ``fire`` (6, W) rows slot, start, len, wid, mask, group; ``groups``
+    (G_CAP + 1, 2) the distinct ``(start_phys, length)`` pairs of the
+    lanes and, in row ``G_CAP``, their count; ``evict`` (3, W *
+    slide_units) rows slot, leaf, mask. One buffer, so one transfer a
+    program: a launch pays for every host argument it is handed."""
+    n_g = 2 * (G_CAP + 1)
+    W = (pack.shape[0] - n_g) // (6 + 3 * slide_units)
+    return (pack[:6 * W].reshape(6, W),
+            pack[6 * W:6 * W + n_g].reshape(G_CAP + 1, 2),
+            pack[6 * W + n_g:].reshape(3, W * slide_units))
 
 
 def xla_rebuild_levels(combine: Callable, F: int):
@@ -169,7 +199,8 @@ class FfatTPUReplica(TPUReplicaBase):
         # (wf/builders_gpu.hpp has no analog; growth still works past it)
         self.K_cap = 1 << max(2, math.ceil(math.log2(op.key_capacity)))
         # two fire-budget tiers: W_step keeps the full per-batch
-        # program's vmapped-query block small, W_cap is the wide budget
+        # program's query block small (the lane walk costs by the lane,
+        # see _first_budget), W_cap is the wide budget
         # used by drain iterations and data-less firing so backlogs
         # clear in few programs
         self.W_cap = op.num_win_per_batch
@@ -327,16 +358,46 @@ class FfatTPUReplica(TPUReplicaBase):
     # the per-batch device program
     # ==================================================================
     def _query_fns(self):
-        """Closures shared by the full step and the fire-only step:
-        validity-aware ordered combine + ring window query."""
+        """``fire_block(trees, tvalid, fire_plan, ktable) -> (tvalid,
+        values, valid, wid column, key column)``: what the full step and
+        the fire-only step do with a program's fire plan
+        (``fire_pack_views``): answer its fired windows, evict the leaves
+        they consumed, build the ``wid`` and key columns. Two queries
+        answer the windows, chosen inside the program by the count in the
+        plan's group table (see _pack_fire_arrays):
+
+        - the LANE walk (``window_query`` under ``vmap``): every lane
+          walks its own slot's tree, a node read is a gather of one
+          scalar a lane, so it costs by the lane, live or masked;
+        - the GROUP walk (``group_query``): the same walk once per
+          distinct ring range ``(start_phys, length)`` among the lanes,
+          over every key slot at once: ``l``, ``r`` and the take
+          predicates are scalars of the range, the accumulators
+          ``(K_cap,)`` vectors, a node read one column slice of the
+          forest. Lane ``i`` then picks ``table[group[i], slot[i]]``.
+          Time-based windows number from absolute time 0, so the lanes
+          of a program planned by rounds (_fireable) share one to three
+          ranges.
+
+        Both run the same ``l``/``r`` recurrence with the same left and
+        right accumulators through ``comb_valid``: the same order of
+        combination, so the same bits for any combine, commutative or
+        not (where ``valid`` is False the values are whatever the walk
+        left, in both)."""
         import jax
         import jax.numpy as jnp
 
         combine = self.op.combine
         F = self.F
+        K_cap = self.K_cap
+        slide_units = self.slide_units
+        use_ktable = self._use_ktable()
         NNODES = 2 * F
         LOGQ = NNODES.bit_length()  # enough iterations for the tree walk
         tmap = jax.tree_util.tree_map
+        # count-based windows start at a per-key arrival index: no two
+        # keys share a ring range, so their programs hold no group walk
+        grouped = self.op.win_type is WinType.TB
 
         def comb_valid(va, a, vb, b):
             """Ordered combine with validity: an invalid side passes the
@@ -347,41 +408,113 @@ class FfatTPUReplica(TPUReplicaBase):
                        merged, a, b)
             return va | vb, out
 
-        def range_query(tree_row, vrow, lo, length):
-            """Ordered combine of physical leaf range [lo, lo+length) of one
-            tree row: iterative segment-tree walk, left/right accumulators
-            keep combine order (reference prefix/suffix arrays,
-            ``wf/flatfat.hpp:85-132``)."""
-            zero = tmap(lambda a: jnp.zeros((), a.dtype), tree_row)
+        def range_query(node, lo, length):
+            """Ordered combine of physical leaf range [lo, lo+length):
+            iterative segment-tree walk, left/right accumulators keep
+            combine order (reference prefix/suffix arrays,
+            ``wf/flatfat.hpp:85-132``). ``node(i) -> (valid, values)``
+            reads tree node ``i``: of one tree row (scalars) or of every
+            row at once (``(K_cap,)`` vectors)."""
+            none, zero = tmap(jnp.zeros_like, node(0))
 
             def body(_, st):
                 l, r, lv, la, rv, ra = st
                 take_l = ((l & 1) == 1) & (l < r)
-                il = jnp.clip(l, 0, NNODES - 1)
-                node_l = tmap(lambda a: a[il], tree_row)
-                lv, la = comb_valid(lv, la, vrow[il] & take_l, node_l)
+                vl, node_l = node(jnp.clip(l, 0, NNODES - 1))
+                lv, la = comb_valid(lv, la, vl & take_l, node_l)
                 l = jnp.where(take_l, l + 1, l)
                 take_r = ((r & 1) == 1) & (l < r)
-                ir = jnp.clip(r - 1, 0, NNODES - 1)
-                node_r = tmap(lambda a: a[ir], tree_row)
-                rv, ra = comb_valid(vrow[ir] & take_r, node_r, rv, ra)
+                vr, node_r = node(jnp.clip(r - 1, 0, NNODES - 1))
+                rv, ra = comb_valid(vr & take_r, node_r, rv, ra)
                 r = jnp.where(take_r, r - 1, r)
                 return (l >> 1, r >> 1, lv, la, rv, ra)
 
-            init = (lo + F, lo + length + F,
-                    jnp.zeros((), bool), zero, jnp.zeros((), bool), zero)
+            init = (lo + F, lo + length + F, none, zero, none, zero)
             st = jax.lax.fori_loop(0, LOGQ, body, init)
             return comb_valid(st[2], st[3], st[4], st[5])
 
         def window_query(tree_row, vrow, start_phys, length):
-            """Logical ring range -> <=2 physical ranges, combined in order."""
+            """One lane: logical ring range of one tree row -> <=2
+            physical ranges, combined in order."""
+            def node(i):
+                return vrow[i], tmap(lambda a: a[i], tree_row)
+
             len1 = jnp.minimum(length, F - start_phys)
-            v1, r1 = range_query(tree_row, vrow, start_phys, len1)
-            v2, r2 = range_query(tree_row, vrow, jnp.zeros_like(start_phys),
+            v1, r1 = range_query(node, start_phys, len1)
+            v2, r2 = range_query(node, jnp.zeros_like(start_phys),
                                  length - len1)
             return comb_valid(v1, r1, v2, r2)
 
-        return comb_valid, window_query
+        def group_query(trees, tvalid, start_phys, length):
+            """One ring range (scalars) of EVERY tree row: the lane's
+            walk with column slices for node reads. The second walk of a
+            range that does not wrap the ring is skipped, not walked
+            masked (it could only add to an invalid answer)."""
+            def column(t, i):
+                return jax.lax.dynamic_slice_in_dim(t, i, 1, axis=1)[:, 0]
+
+            def node(i):
+                return column(tvalid, i), tmap(lambda t: column(t, i), trees)
+
+            len1 = jnp.minimum(length, F - start_phys)
+            v1, r1 = range_query(node, start_phys, len1)
+            return jax.lax.cond(
+                length > len1,
+                lambda: comb_valid(v1, r1, *range_query(
+                    node, jnp.zeros_like(start_phys), length - len1)),
+                lambda: (v1, r1))
+
+        def by_lane(trees, tvalid, slots, starts, lens):
+            ftrees = tmap(lambda t: t[slots], trees)
+            return jax.vmap(window_query)(ftrees, tvalid[slots], starts, lens)
+
+        def by_group(trees, tvalid, slots, group, g_table):
+            def one(g, tabs):
+                v, r = group_query(trees, tvalid, g_table[g, 0],
+                                   g_table[g, 1])
+                return (tabs[0].at[g].set(v),
+                        tmap(lambda t, x: t.at[g].set(x), tabs[1], r))
+
+            tabs = (jnp.zeros((G_CAP, K_cap), bool),
+                    tmap(lambda t: jnp.zeros((G_CAP, K_cap), t.dtype),
+                         trees))
+            tv, tr = jax.lax.fori_loop(0, g_table[G_CAP, 0], one, tabs)
+            return tv[group, slots], tmap(lambda t: t[group, slots], tr)
+
+        def fire_block(trees, tvalid, fire_plan, ktable):
+            fire, g_table, evict = fire_pack_views(fire_plan, slide_units)
+            slots, starts, lens, wids, mask_i, group = fire
+            mask = mask_i != 0
+            with jax.named_scope(SCOPE_FIRE):
+                if grouped:
+                    qv, qr = jax.lax.cond(
+                        g_table[G_CAP, 0] > 0,
+                        lambda: by_group(trees, tvalid, slots, group,
+                                         g_table),
+                        lambda: by_lane(trees, tvalid, slots, starts, lens))
+                else:
+                    qv, qr = by_lane(trees, tvalid, slots, starts, lens)
+                qv = qv & mask
+            # evict leaves consumed by the fired windows
+            with jax.named_scope(SCOPE_EVICT):
+                evict_slots, evict_leaves, evict_mask_i = evict
+                eflat = jnp.where(
+                    evict_mask_i != 0,
+                    evict_slots * NNODES + (F + evict_leaves),
+                    K_cap * NNODES)  # masked lanes: out of bounds, dropped
+                tvalid = tvalid.reshape(-1).at[eflat].set(
+                    False, mode="drop").reshape(tvalid.shape)
+            # output wid/key columns built ON DEVICE: they ride the
+            # program's batched argument transfer instead of costing one
+            # device_put round trip each at emit time
+            if use_ktable:
+                key_out = jnp.where(mask, ktable[slots],
+                                    jnp.zeros((), ktable.dtype))
+            else:
+                key_out = jnp.zeros((1,), jnp.int32)
+            return tvalid, qr, qv, jnp.asarray(wids), key_out
+
+        return fire_block
 
     def _rebuild_fn(self):
         """Returns the full-forest internal-level rebuild callable — the
@@ -422,7 +555,6 @@ class FfatTPUReplica(TPUReplicaBase):
         import jax.numpy as jnp
 
         host_seg = self._host_seg
-        use_ktable = self._use_ktable()
 
         lift = self._lift_fn()
         combine = self.op.combine
@@ -432,19 +564,13 @@ class FfatTPUReplica(TPUReplicaBase):
         OOB = K_cap * NNODES  # scatter target for masked lanes (mode=drop)
 
         tmap = jax.tree_util.tree_map
-        comb_valid, window_query = self._query_fns()
+        fire_block = self._query_fns()
         # shared rebuild body (routes through the WF_PALLAS=1 VMEM
         # kernel when enabled; see _rebuild_fn)
         rebuild_levels = self._rebuild_fn()
 
         def step(fields, comp, h_order, h_same, h_end,
-                 h_flat, trees, tvalid,
-                 fire_pack, ktable, evict_pack):
-            (fire_slots, fire_starts, fire_lens, fire_wids,
-             fire_mask_i) = fire_pack
-            fire_mask = fire_mask_i != 0
-            evict_slots, evict_leaves, evict_mask_i = evict_pack
-            evict_mask = evict_mask_i != 0
+                 h_flat, trees, tvalid, fire_plan, ktable):
             # 1. lift + sort + segmented scan. WHERE the sort happens is
             # backend-dependent: on accelerators it runs in-program (device
             # work overlaps the host control plane); on the CPU backend the
@@ -519,32 +645,9 @@ class FfatTPUReplica(TPUReplicaBase):
             with jax.named_scope(SCOPE_REBUILD):
                 trees, tvalid = rebuild_levels(trees, tvalid)
 
-            # 4. fired-window queries (vmapped over W_cap)
-            with jax.named_scope(SCOPE_FIRE):
-                ftrees = tmap(lambda t: t[fire_slots], trees)
-                fvalid = tvalid[fire_slots]
-                qv, qr = jax.vmap(window_query)(ftrees, fvalid,
-                                                fire_starts, fire_lens)
-                qv = qv & fire_mask
-
-            # 5. evict leaves consumed by the fired windows
-            with jax.named_scope(SCOPE_EVICT):
-                eflat = jnp.where(
-                    evict_mask,
-                    evict_slots * NNODES + (F + evict_leaves), OOB)
-                tvalid = tvalid.reshape(-1).at[eflat].set(
-                    False, mode="drop").reshape(tvalid.shape)
-
-            # 6. output wid/key columns built ON DEVICE: they ride the
-            # program's batched argument transfer instead of costing one
-            # device_put round trip each at emit time
-            wid_out = jnp.asarray(fire_wids)
-            if use_ktable:
-                key_out = jnp.where(fire_mask, ktable[fire_slots],
-                                    jnp.zeros((), ktable.dtype))
-            else:
-                key_out = jnp.zeros((1,), jnp.int32)
-            return trees, tvalid, qr, qv, wid_out, key_out
+            # 4.-6. fired-window queries, eviction of the leaves they
+            # consumed, output wid/key columns (_query_fns)
+            return (trees,) + fire_block(trees, tvalid, fire_plan, ktable)
 
         # trees/tvalid are DONATED: the leaf scatter and level rebuild
         # update the forest in place in HBM instead of copying the whole
@@ -562,8 +665,8 @@ class FfatTPUReplica(TPUReplicaBase):
             program=PROG_STEP, donate_argnums=(6, 7) if donate else ())
 
     def _make_fire_step(self):
-        """Fire-only program: vmapped window queries + leaf eviction, no
-        lift/scan/scatter/rebuild. Used for drain iterations after the
+        """Fire-only program: window queries (_query_fns) + leaf eviction,
+        no lift/scan/scatter/rebuild. Used for drain iterations after the
         first per-batch step and for data-less firing (punctuation/EOS).
 
         Soundness of skipping the level rebuild: internal nodes are stale
@@ -574,46 +677,19 @@ class FfatTPUReplica(TPUReplicaBase):
         pane's ring slot can only be re-queried at pane p_evicted + F >
         max_leaf — excluded because _pack_fire_arrays clips every query
         to the data extent. The clip is also what keeps the invariant
-        robust if F sizing ever changes (regression-tested)."""
-        import jax
-        import jax.numpy as jnp
+        robust if F sizing ever changes (regression-tested).
 
-        F = self.F
-        NNODES = 2 * F
-        OOB = self.K_cap * NNODES
-        tmap = jax.tree_util.tree_map
-        _, window_query = self._query_fns()
-        use_ktable = self._use_ktable()
-
-        def fire(trees, tvalid, fire_pack, ktable, evict_pack):
-            (fire_slots, fire_starts, fire_lens, fire_wids,
-             fire_mask_i) = fire_pack
-            fire_mask = fire_mask_i != 0
-            evict_slots, evict_leaves, evict_mask_i = evict_pack
-            evict_mask = evict_mask_i != 0
-            with jax.named_scope(SCOPE_FIRE):
-                ftrees = tmap(lambda t: t[fire_slots], trees)
-                fvalid = tvalid[fire_slots]
-                qv, qr = jax.vmap(window_query)(ftrees, fvalid,
-                                                fire_starts, fire_lens)
-                qv = qv & fire_mask
-            with jax.named_scope(SCOPE_EVICT):
-                eflat = jnp.where(
-                    evict_mask,
-                    evict_slots * NNODES + (F + evict_leaves), OOB)
-                tvalid = tvalid.reshape(-1).at[eflat].set(
-                    False, mode="drop").reshape(tvalid.shape)
-            wid_out = jnp.asarray(fire_wids)
-            if use_ktable:
-                key_out = jnp.where(fire_mask, ktable[fire_slots],
-                                    jnp.zeros((), ktable.dtype))
-            else:
-                key_out = jnp.zeros((1,), jnp.int32)
-            return tvalid, qr, qv, wid_out, key_out
-
+        The argument is about a slot's RANGES, not about which program
+        or which walk answers them, so it holds for the plan by rounds
+        and for the walk by range as it did for slot order and the lane
+        walk: a walk reads only nodes wholly inside its clipped range,
+        a slot's window ``w + 1`` starts past every pane its window ``w``
+        evicted (a program earlier, where the rounds split them), and
+        the walk by range reads the other slots' nodes of the same
+        columns only into table rows that no lane of theirs picks."""
         # tvalid donated (in-place eviction); trees is read-only here
         from ..monitoring.flightrec import instrumented_jit
-        return instrumented_jit(fire, self.stats,
+        return instrumented_jit(self._query_fns(), self.stats,
                                 label=f"{self.stats.op_name}:fire",
                                 program=PROG_FIRE, donate_argnums=(1,))
 
@@ -945,10 +1021,24 @@ class FfatTPUReplica(TPUReplicaBase):
     # ------------------------------------------------------------------
     def _fireable(self, frontier, partial: bool, budget: int):
         """Fire-eligible windows as per-slot chunk ARRAYS
-        (slots, start0, k, wid0, max_leaf), each chunk covering the slot's
-        consecutive eligible windows, truncated to ``budget``.
+        (slots, start0, k, wid0, max_leaf), each chunk covering the
+        slot's next ``k`` consecutive eligible windows, ``budget`` windows
+        in all. Where more are eligible than the budget holds:
 
-        Fully vectorized: one numpy pass over the live slot table per call
+        - time-based windows leave by ROUNDS: every firing slot gives its
+          first ``min(k, r)`` windows for the largest ``r`` that fits,
+          and what is left of the budget goes to round ``r + 1`` in slot
+          order (so a program is full while windows remain, and where
+          even one round overflows, this is the slot-order clip within
+          it). Window ``w`` of every key is the same ring range, so a
+          program holds one to three distinct ranges, in the steady state
+          and in the end-of-stream flush alike, and the fire query walks
+          each once (_query_fns);
+        - count-based windows in slot order, clipped where the cumulative
+          sum crosses the budget (their ranges are per key anyway).
+
+        A slot's windows leave in ``wid`` order either way. Fully
+        vectorized: one numpy pass over the live slot table per call
         (C-speed even at 10^5 keys; the reference instead walks its key
         descriptor map in a host loop, ``ffat_replica_gpu.hpp:870-1019``).
         Advances next_fire/fired for the windows taken."""
@@ -976,11 +1066,14 @@ class FfatTPUReplica(TPUReplicaBase):
         if slots.size == 0:
             return empty
         k = k[slots]
-        # budget: clip the chunk sequence where the cumsum crosses
-        before = np.cumsum(k) - k
-        k = np.minimum(k, budget - before)
-        keep = k > 0
-        slots, k = slots[keep], k[keep]
+        if int(k.sum()) > budget:
+            if self.op.win_type is WinType.TB:
+                k = self._take_by_rounds(k, budget)
+            else:
+                # clip the chunk sequence where the cumsum crosses
+                k = np.minimum(k, budget - (np.cumsum(k) - k))
+            keep = k > 0
+            slots, k = slots[keep], k[keep]
         start0 = self.next_fire[slots].copy()
         wid0 = self.fired[slots].copy()
         self.next_fire[slots] += k * self.slide_units
@@ -989,6 +1082,24 @@ class FfatTPUReplica(TPUReplicaBase):
             # firing advances bookkeeping and evicts ring panes
             self._ckpt_dirty.update(slots.tolist())
         return slots, start0, k, wid0, self.max_leaf[slots].copy()
+
+    @staticmethod
+    def _take_by_rounds(k: np.ndarray, budget: int) -> np.ndarray:
+        """Windows taken of each slot's ``k`` eligible ones when their
+        sum exceeds ``budget``: ``min(k, r)`` for the largest ``r`` with
+        ``sum(min(k, r)) <= budget``, then one more for the first slots
+        that still have one, until the budget is full."""
+        ks = np.sort(k)
+        n = ks.size
+        csum = np.cumsum(ks)
+        # sum(min(k, ks[i])): non-decreasing in i
+        full = csum + (n - 1 - np.arange(n)) * ks
+        i = int(np.searchsorted(full, budget, side="right"))  # < n
+        r = (budget - (int(csum[i - 1]) if i else 0)) // (n - i)
+        take = np.minimum(k, r)
+        more = np.nonzero(k > r)[0][:budget - int(take.sum())]
+        take[more] += 1
+        return take
 
     @staticmethod
     def _segmented_arange(k: np.ndarray) -> np.ndarray:
@@ -1001,14 +1112,18 @@ class FfatTPUReplica(TPUReplicaBase):
         """Chunk arrays -> padded fire/evict arrays for the device
         programs (shaped for budget ``W``; jit re-traces per shape). Pure
         numpy (repeat + segmented arange): zero per-window or per-chunk
-        Python. Fire metadata is PACKED into one (5, W) int32 array
-        (rows: slot, start, len, wid, mask) and evictions into one
-        (3, E) (rows: slot, leaf, mask) — fewer program arguments means
-        fewer per-call transfer enqueues."""
+        Python. The whole plan is PACKED into one flat int32 buffer
+        (``fire_pack_views``: fire rows, group table, evict rows) — one
+        program argument from the host, so one transfer a launch. The
+        group table holds the distinct ``(start_phys, length)`` pairs of
+        the lanes and their count, the ``group`` row each lane's index
+        into it. A count of 0 tells the program to walk by lane:
+        count-based windows, or more than ``G_CAP`` distinct ranges among
+        the lanes (keys that arrive ragged). Returns the buffer and that
+        count."""
         c_slots, c_start0, c_k, c_wid0, c_ml = chunks
-        E = max(1, W * self.slide_units)
-        f_pack = np.zeros((5, W), dtype=np.int32)
-        e_pack = np.zeros((3, E), dtype=np.int32)
+        pack = np.zeros(fire_pack_len(W, self.slide_units), dtype=np.int32)
+        f_pack, g_table, e_pack = fire_pack_views(pack, self.slide_units)
         ar = self._segmented_arange(c_k)
         starts = np.repeat(c_start0, c_k) + ar * self.slide_units
         f_pack[0, :n_out] = np.repeat(c_slots, c_k)
@@ -1026,6 +1141,16 @@ class FfatTPUReplica(TPUReplicaBase):
         f_pack[4, :n_out] = 1  # mask row: rides the SAME transfer as the
         # spec rows (one H2D enqueue per pack instead of pack+mask pairs)
         f_pack[3, :n_out] = np.repeat(c_wid0, c_k) + ar
+        if self.op.win_type is WinType.TB:
+            # lens <= win_units < F, so (start, len) packs into one word
+            pairs, group = np.unique(
+                f_pack[1, :n_out].astype(np.int64) * self.F
+                + f_pack[2, :n_out], return_inverse=True)
+            if pairs.size <= G_CAP:
+                g_table[:pairs.size, 0] = pairs // self.F
+                g_table[:pairs.size, 1] = pairs % self.F
+                g_table[G_CAP, 0] = pairs.size
+                f_pack[5, :n_out] = group
         # evicted panes: one contiguous range per chunk
         ne = np.maximum(
             0, np.minimum(c_start0 + c_k * self.slide_units, c_ml + 1)
@@ -1036,7 +1161,7 @@ class FfatTPUReplica(TPUReplicaBase):
             e_pack[0, :tot_e] = np.repeat(c_slots, ne)
             e_pack[1, :tot_e] = ep % self.F
             e_pack[2, :tot_e] = 1
-        return f_pack, e_pack
+        return pack, int(g_table[G_CAP, 0])
 
     def _use_ktable(self) -> bool:
         """Whether programs gather the output key column from a
@@ -1067,21 +1192,23 @@ class FfatTPUReplica(TPUReplicaBase):
         is overlapped device work there and saves two host dispatches per
         batch, while on the CPU backend the drain path's fire-only
         program (no lift/sort/rebuild) is much cheaper than widening the
-        full program."""
+        full program. The small tier exists because a block costs by the
+        lane, live or masked: that holds for the lane walk only (a
+        program that goes by range costs by the range, PERF.md section
+        6, PR 28), so for streams that fire by range the tiers buy
+        nothing; they are left as they are until that is measured."""
         if not self._on_accelerator() or self._fire_ewma * 1.25 <= self.W_step:
             return self.W_step
         return self.W_cap
 
     def _zero_fire(self, W: int):
-        """Device-resident all-zero fire/evict args for non-firing steps
+        """Device-resident all-zero fire plan for non-firing steps
         (cached per budget: zero steady-state transfer)."""
         z = self._zero_fire_cache.get(W)
         if z is None:
             import jax
-            E = max(1, W * self.slide_units)
-            z = self._zero_fire_cache[W] = (
-                jax.device_put(np.zeros((5, W), dtype=np.int32)),
-                jax.device_put(np.zeros((3, E), dtype=np.int32)))
+            z = self._zero_fire_cache[W] = jax.device_put(np.zeros(
+                fire_pack_len(W, self.slide_units), dtype=np.int32))
         return z
 
     def _fire_step(self):
@@ -1101,14 +1228,11 @@ class FfatTPUReplica(TPUReplicaBase):
         if ("fire", self.K_cap, self.F, self._use_ktable(),
                 str(self._key_dtype)) in self._prog_cache:
             return  # already compiled (e.g. a new batch-capacity bucket)
-        W = self.W_cap
-        E = max(1, W * self.slide_units)
-        # all-masked no-op run; tvalid is DONATED, so reassign it
+        # all-masked no-op run (both queries compile with the program,
+        # whichever runs); tvalid is DONATED, so reassign it
         self.tvalid, *_ = self._fire_step()(
-            self.trees, self.tvalid,
-            np.zeros((5, W), dtype=np.int32),
-            self._ktable_arg(),
-            np.zeros((3, E), dtype=np.int32))
+            self.trees, self.tvalid, self._zero_fire(self.W_cap),
+            self._ktable_arg())
 
     def _warm_programs(self, cap, ckey, ikey, fields,
                        order_p, same_p, end_p, flat_p, ktable) -> None:
@@ -1147,13 +1271,12 @@ class FfatTPUReplica(TPUReplicaBase):
         if self._on_accelerator():
             tiers.add(self.W_cap)
         for W in tiers:
-            zf, ze = self._zero_fire(W)
             (self.trees, self.tvalid, *_) = step(
                 fields, comp_s, *seg, self.trees, self.tvalid,
-                zf, ktable, ze)
-        zf, ze = self._zero_fire(self.W_step)
+                self._zero_fire(W), ktable)
         (self.trees, self.tvalid, *_) = istep(
-            fields, comp_s, *seg, self.trees, self.tvalid, zf, ktable, ze)
+            fields, comp_s, *seg, self.trees, self.tvalid,
+            self._zero_fire(self.W_step), ktable)
         if rb is not None:
             self.trees, self.tvalid = rb(self.trees, self.tvalid)
 
@@ -1272,8 +1395,8 @@ class FfatTPUReplica(TPUReplicaBase):
                 # firing/rebuild program
                 plan.append(None)
                 break
-            f_pack, e_pack = self._pack_fire_arrays(chunks, n_out, budget)
-            plan.append((first, chunks, n_out, f_pack, e_pack, budget))
+            pack, n_groups = self._pack_fire_arrays(chunks, n_out, budget)
+            plan.append((first, chunks, n_out, pack, n_groups, budget))
             total_fired += n_out
             first = False
             if n_out < budget:
@@ -1298,42 +1421,45 @@ class FfatTPUReplica(TPUReplicaBase):
                 # the low-cardinality small-batch regime). Fire args are
                 # unused in this variant but still traced: pin the
                 # W_step shape so tier switches never retrace it
-                zf, ze = self._zero_fire(self.W_step)
                 (self.trees, self.tvalid, *_) = self._prog_cache[ikey](
                     fields, comp_p, order_p, same_p, end_p, flat_p,
-                    self.trees, self.tvalid, zf, ktable, ze)
+                    self.trees, self.tvalid,
+                    self._zero_fire(self.W_step), ktable)
                 self._rebuild_dirty = True
                 self.stats.device_programs_run += 1
                 continue
-            is_first, chunks, n_out, f_pack, e_pack, budget = entry
+            is_first, chunks, n_out, pack, n_groups, budget = entry
             if is_first:
                 # full program: lift + scan + scatter + rebuild + fire
                 (self.trees, self.tvalid, qr, qv, wid_dev,
                  key_dev) = self._prog_cache[ckey](
                     fields, comp_p, order_p, same_p,
-                    end_p, flat_p, self.trees, self.tvalid,
-                    f_pack, ktable, e_pack)
+                    end_p, flat_p, self.trees, self.tvalid, pack, ktable)
                 self._rebuild_dirty = False  # in-program rebuild covers
                 # every deferred ingest-only batch (full-forest rebuild)
                 self._dirty_all = True  # ... and rewrote internal rows
             else:
                 # drain iterations: fire-only program (no rebuild)
                 self.tvalid, qr, qv, wid_dev, key_dev = self._fire_step()(
-                    self.trees, self.tvalid,
-                    f_pack, ktable, e_pack)
+                    self.trees, self.tvalid, pack, ktable)
             self.stats.device_programs_run += 1
-            self._emit_windows(wm, chunks, n_out, qr, qv,
-                               wid_dev, key_dev, budget, bid)
+            self._emit_windows(wm, chunks, n_out, qr, qv, wid_dev, key_dev,
+                               budget, n_groups, bid)
 
-    def _emit_windows(self, wm, chunks, n_out, qr, qv,
-                      wid_dev, key_dev, W: int, cause: int = 0) -> None:
-        """``cause``: the id of the input batch whose commit fired these
-        windows (0 for a dataless fire: a punctuation or EOS made them)."""
+    def _emit_windows(self, wm, chunks, n_out, qr, qv, wid_dev, key_dev,
+                      W: int, n_groups: int, cause: int = 0) -> None:
+        """``n_groups``: the distinct ring ranges the program answered by
+        range, 0 where it walked by lane. ``cause``: the id of the input
+        batch whose commit fired these windows (0 for a dataless fire: a
+        punctuation or EOS made them)."""
         import jax
 
         op = self.op
         self.stats.fire_programs += 1
         self.stats.windows_fired += n_out
+        if n_groups:
+            self.stats.fire_grouped_programs += 1
+            self.stats.fire_groups += n_groups
         fields = dict(qr)
         fields["valid"] = qv
         fields["wid"] = wid_dev  # built in-program: no device_put here
@@ -1383,14 +1509,13 @@ class FfatTPUReplica(TPUReplicaBase):
             if not n_out:
                 return
             self._ensure_rebuilt()
-            f_pack, e_pack = self._pack_fire_arrays(
+            pack, n_groups = self._pack_fire_arrays(
                 chunks, n_out, self.W_cap)
             self.tvalid, qr, qv, wid_dev, key_dev = self._fire_step()(
-                self.trees, self.tvalid, f_pack,
-                self._ktable_arg(), e_pack)
+                self.trees, self.tvalid, pack, self._ktable_arg())
             self.stats.device_programs_run += 1
-            self._emit_windows(self.cur_wm, chunks, n_out, qr, qv,
-                               wid_dev, key_dev, self.W_cap)
+            self._emit_windows(self.cur_wm, chunks, n_out, qr, qv, wid_dev,
+                               key_dev, self.W_cap, n_groups)
             if n_out < self.W_cap:
                 return
 
