@@ -17,8 +17,6 @@ reference of the same semantics:
      reduce (segmented scan), a device split edge with a TPU->TPU keyed
      re-shard, and the fused filter -> project -> tumbling-count chain of
      examples/ysb.py;
-  C. the Pallas forest rebuild, compiled by Mosaic (not interpreted),
-     bit-equal to the XLA rebuild, alone and inside the served path;
   D. with four or more chips: stage A's stream through ``.with_mesh(
      n_devices=4)`` and a mesh-sharded stateful map. On fewer chips the
      stage prints ``skipped`` and is not a pass.
@@ -51,7 +49,6 @@ B_BATCH = 16_384      # stage B batch rows
 B_BATCHES = 16
 B_KEYS = 1_024
 SAMPLE_KEYS = 64
-PALLAS_BATCHES = 8    # stage C served-path run (full geometry, fewer batches)
 # examples/ysb.py's shape
 YSB_CAMPAIGNS = 100
 YSB_ADS_PER_CAMPAIGN = 10
@@ -328,8 +325,6 @@ def stage_a(seed: int, n_keys: int, batch: int, n_batches: int,
         "Staging_pool_hits": stat_sum(replica_stats(g), "Staging_pool_hits"),
         "forest_devices": sorted(str(d) for d in device_set(
             (rep.trees, rep.tvalid))),
-        "in_program_segmentation": not rep._host_seg,
-        "two_tier_fire_budgets": rep._on_accelerator(),
     }
     say(f"stage A: {n_events} events -> {n_win} windows exact; "
         f"K_cap={rep.K_cap} F={rep.F} W_cap={rep.W_cap} "
@@ -563,70 +558,6 @@ def stage_b(seed: int, n_keys: int, batch: int, n_batches: int) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# stage C: the Pallas rebuild, compiled
-# ---------------------------------------------------------------------------
-def pallas_rebuild_check(seed: int, k_cap: int, F: int,
-                         interpret: bool) -> dict:
-    """One forest rebuild through the Pallas kernel, bit-equal to the XLA
-    rebuild on the same random forest (stale internal nodes included)."""
-    import jax
-    import jax.numpy as jnp
-
-    from windflow_tpu.tpu.ffat_tpu import xla_rebuild_levels
-    from windflow_tpu.tpu.pallas_kernels import make_forest_rebuild
-
-    combine = lambda a, b: {"value": a["value"] + b["value"]}
-    rng = np.random.default_rng(seed + F)
-    trees = {"value": jnp.asarray(
-        rng.integers(-1000, 1000, (k_cap, 2 * F)).astype(np.int32))}
-    valid = np.zeros((k_cap, 2 * F), bool)
-    valid[:, F:] = rng.random((k_cap, F)) < 0.6
-    valid[:, :F] = rng.random((k_cap, F)) < 0.5   # stale internal flags
-    tvalid = jnp.asarray(valid)
-    fn = jax.jit(make_forest_rebuild(combine, ["value"], F,
-                                     interpret=interpret))
-    if not interpret:
-        text = fn.lower(trees, tvalid).as_text()
-        check("tpu_custom_call" in text,
-              f"pallas F={F}: no Mosaic custom call in the lowered module")
-    got_t, got_v = fn(trees, tvalid)
-    want_t, want_v = jax.jit(xla_rebuild_levels(combine, F))(trees, tvalid)
-    check(bool((np.asarray(got_v) == np.asarray(want_v)).all()),
-          f"pallas F={F}: validity plane differs from the XLA rebuild")
-    check(bool((np.asarray(got_t["value"])
-                == np.asarray(want_t["value"])).all()),
-          f"pallas F={F}: tree values differ from the XLA rebuild")
-    return {"K_cap": k_cap, "F": F, "bit_equal": True,
-            "mosaic": not interpret}
-
-
-def stage_c(seed: int, n_keys: int, batch: int, n_batches: int,
-            k_cap: int, interpret: bool = False) -> dict:
-    out = {"kernels": [pallas_rebuild_check(seed, k_cap, F, interpret)
-                       for F in (32, 8)]}
-    say(f"stage C kernel: K_cap={k_cap} F=32 and F=8 "
-        f"{'interpreted' if interpret else 'compiled by Mosaic'}, "
-        "bit-equal to the XLA rebuild")
-    # the kernel inside the served path's donated step programs
-    blocks = ffat_stream(seed, n_keys, batch, n_batches)
-    prev = os.environ.get("WF_PALLAS")
-    os.environ["WF_PALLAS"] = "1"
-    try:
-        got, _, _, _ = run_ffat_graph(blocks, n_keys, batch)
-    finally:
-        if prev is None:
-            del os.environ["WF_PALLAS"]
-        else:
-            os.environ["WF_PALLAS"] = prev
-    out["served_windows"] = same_rows(
-        "stage C served path (WF_PALLAS=1) vs numpy fold", got,
-        numpy_window_fold(blocks, bench.WIN_US, bench.SLIDE_US))
-    say(f"stage C served path: WF_PALLAS=1, {batch * n_batches} events -> "
-        f"{out['served_windows']} windows exact")
-    return out
-
-
-# ---------------------------------------------------------------------------
 # stage D: four chips
 # ---------------------------------------------------------------------------
 def stage_d(seed: int, n_keys: int, batch: int, n_batches: int,
@@ -732,12 +663,7 @@ def main(argv=None) -> int:
           "stage A: the staging-buffer recycler never hit its pool")
     check(a["forest_devices"] == [str(devs[0])],
           f"stage A: forest on {a['forest_devices']}, want [{devs[0]}]")
-    check(a["in_program_segmentation"] and a["two_tier_fire_budgets"],
-          "stage A: the accelerator segmentation / fire-budget paths were "
-          "not selected on a TPU")
     stages["B"] = stage_b(args.seed, B_KEYS, B_BATCH, B_BATCHES)
-    stages["C"] = stage_c(args.seed, bench.HC_KEYS, bench.BATCH,
-                          PALLAS_BATCHES, k_cap=a["K_cap"])
     if len(devs) >= 4:
         stages["D"] = stage_d(args.seed, bench.HC_KEYS, bench.BATCH,
                               bench.N_BATCHES, B_KEYS, B_BATCH, B_BATCHES)

@@ -39,12 +39,6 @@ def test_stage_b_small():
     assert out["stateful_map"]["rows_equal"] == 4 * BATCH
 
 
-def test_stage_c_interpreted_small():
-    out = chip_smoke.stage_c(0, KEYS, BATCH, 12, k_cap=KEYS, interpret=True)
-    assert [k["F"] for k in out["kernels"]] == [32, 8]
-    assert out["served_windows"] >= KEYS
-
-
 @pytest.mark.mesh
 def test_stage_d_small():
     out = chip_smoke.stage_d(0, KEYS, BATCH, 12, KEYS, BATCH, 4,

@@ -116,6 +116,15 @@ def run_late_replay(engine, monkeypatch):
     # batches of OBS rows: no partial batch may ship by wall-clock age
     # (a loaded machine then moves the batch boundaries and the counts)
     monkeypatch.setenv("WF_MAX_STAGING_MS", "0")
+    # nor by a punctuation: an emitter that has sent DEFAULT_WM_AMOUNT
+    # (64) more tuples and finds 100 ms of wall clock gone flushes its
+    # partial batch ahead of the punctuation, 14 rows into a batch of
+    # 50; every later batch then straddles a watermark advance, carries
+    # the older one, and counts fewer rows late. Watermarks still ride
+    # the batches and end-of-stream still flushes
+    monkeypatch.setattr(
+        "windflow_tpu.runtime.emitters.DEFAULT_WM_INTERVAL_USEC",
+        float("inf"))
     g = PipeGraph(f"evt_health_{engine}", ExecutionMode.DEFAULT,
                   TimePolicy.EVENT_TIME)
     src = Source_Builder(late_src).with_output_batch_size(OBS).build()

@@ -191,6 +191,17 @@ def test_graft_entry_reexecutes():
     import __graft_entry__ as g
 
     fn, args = g.entry()
+    # the served step itself (FfatTPUReplica._make_step), handed what
+    # _commit_step hands it: the batch's columns, the packed composite
+    # the program sorts, the forest, the fire plan, the key table
+    import inspect
+    assert list(inspect.signature(fn._wrapped_jit).parameters) == [
+        "fields", "comp", "trees", "tvalid", "fire_plan", "ktable"]
+    fields, comp, trees, tvalid, fire_plan, ktable = args
+    rep = g._ffat_replica()
+    assert comp.shape == fields["key"].shape
+    assert comp.dtype == rep._comp_dtype()[1]
+    assert tvalid.shape == trees["value"].shape == (rep.K_cap, 2 * rep.F)
     jax.block_until_ready(fn(*args))
     jax.block_until_ready(fn(*args))  # donated args would fail here
 

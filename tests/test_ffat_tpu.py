@@ -43,7 +43,7 @@ def sum_or_none(vals):
 
 def run_ffat_tpu(win, slide, win_type_cb, n_keys=N_KEYS,
                  stream_len=STREAM_LEN, src_par=1, op_par=1, nwpb=8,
-                 lateness=0, obs=32):
+                 lateness=0, obs=32, key_capacity=None):
     coll = DictWinCollector()
     graph = PipeGraph("ffat_tpu", ExecutionMode.DEFAULT,
                       TimePolicy.EVENT_TIME)
@@ -56,9 +56,12 @@ def run_ffat_tpu(win, slide, win_type_cb, n_keys=N_KEYS,
          .with_num_win_per_batch(nwpb))
     b = (b.with_cb_windows(win, slide) if win_type_cb
          else b.with_tb_windows(win, slide))
+    if key_capacity is not None:
+        b = b.with_key_capacity(key_capacity)
     op = b.with_parallelism(op_par).build()
     graph.add_source(src).add(op).add_sink(Sink_Builder(coll.sink).build())
     graph.run()
+    coll.op = op
     return coll
 
 
@@ -168,36 +171,53 @@ def test_ffat_tpu_noncommutative_minmax():
     assert res == raw
 
 
-def test_ffat_tpu_device_mode_segmentation():
-    """The accelerator path (in-program sort/segmentation) must produce
-    exactly the host path's windows; CPU CI otherwise only exercises the
-    host branch. Forcing _host_seg=False runs the device branch on the CPU
-    backend."""
-    import windflow_tpu.tpu.ffat_tpu as ft
-    expected = expected_windows(model_seqs(N_KEYS, STREAM_LEN), WIN_US,
+# K_cap * F against 2**15 - 1, the largest composite an int16 column holds
+# beside its sentinel: 512 * 32 below it, 1024 * 32 = 2**15 the first
+# product past it, and a key table that doubles from the one to the other
+# in mid-stream (600 keys into 512 slots)
+@pytest.mark.parametrize("key_capacity,n_keys,dtypes", [
+    (512, 40, ["int16"]), (1024, 40, ["int32"]),
+    (512, 600, ["int16", "int32"])])
+def test_ffat_tpu_packed_composite_dtypes(key_capacity, n_keys, dtypes,
+                                          monkeypatch):
+    """The program sorts ONE packed composite column (slot * F + leaf)
+    in the narrowest integer dtype that holds ``K_cap * F``
+    (``_comp_dtype``): windows are exact at both dtypes, and across the
+    growth that changes the dtype under a running stream."""
+    import numpy as np
+    from windflow_tpu.tpu.ffat_tpu import FfatTPUReplica
+    seen = []
+    orig = FfatTPUReplica._commit_step
+
+    def spy(self, fields, wm, comp_p, *rest):
+        assert comp_p.dtype == self._comp_dtype()[1]
+        if not seen or seen[-1] != comp_p.dtype.name:
+            seen.append(comp_p.dtype.name)
+        return orig(self, fields, wm, comp_p, *rest)
+
+    monkeypatch.setattr(FfatTPUReplica, "_commit_step", spy)
+    stream_len = 24
+    expected = expected_windows(model_seqs(n_keys, stream_len), WIN_US,
                                 SLIDE_US, False, sum_or_none)
-    orig_init = ft.FfatTPUReplica.__init__
-
-    def forced(self, op, idx):
-        orig_init(self, op, idx)
-        self._host_seg = False
-
-    ft.FfatTPUReplica.__init__ = forced
-    try:
-        coll = run_ffat_tpu(WIN_US, SLIDE_US, win_type_cb=False)
-    finally:
-        ft.FfatTPUReplica.__init__ = orig_init
+    coll = run_ffat_tpu(WIN_US, SLIDE_US, win_type_cb=False, n_keys=n_keys,
+                        stream_len=stream_len, obs=256,
+                        key_capacity=key_capacity)
+    rep = coll.op.replicas[0]
+    assert rep.F == 32 and seen == dtypes
+    assert rep._comp_dtype() == (rep.K_cap * 32, np.dtype(dtypes[-1]))
     assert coll.dups == 0
     assert coll.results == expected
 
 
-@pytest.mark.parametrize("host_seg", [True, False])
-def test_ffat_tpu_ring_alias_after_drain_iterations(host_seg):
+@pytest.mark.parametrize("count_based", [False, True])
+def test_ffat_tpu_ring_alias_after_drain_iterations(count_based):
     """Regression: fire-only drain programs skip the level rebuild; window
     queries must clip to the data extent so ring slots aliasing panes
     evicted after the last rebuild never contribute (W_cap=2 forces long
-    drain chains; 3x ring wraparound exercises aliasing). Runs in BOTH
-    segmentation modes — device mode is what executes on a real TPU."""
+    drain chains; 3x ring wraparound exercises aliasing). Time-based
+    windows are answered by the walk by range (both keys share a ring
+    range), count-based ones (a pane = one arrival of the key) by the
+    lane walk."""
     import jax
     import numpy as np
     from windflow_tpu.basic import WinType
@@ -205,17 +225,17 @@ def test_ffat_tpu_ring_alias_after_drain_iterations(host_seg):
     from windflow_tpu.tpu.ffat_tpu import Ffat_Windows_TPU
     from windflow_tpu.tpu.schema import TupleSchema
 
-    PANE = 1000
+    PANE = 1 if count_based else 1000
     N_PANES = 100  # F is 32 -> wraps 3x
     op = Ffat_Windows_TPU(
         lift=lambda f: {"v": f["v"]},
         combine=lambda a, b: {"v": a["v"] + b["v"]},
         key_extractor="key", win_len=4 * PANE, slide_len=PANE,
-        win_type=WinType.TB, num_win_per_batch=2, key_capacity=2,
-        name="alias")
+        win_type=WinType.CB if count_based else WinType.TB,
+        num_win_per_batch=2, key_capacity=2, name="alias")
     op.build_replicas()
     rep = op.replicas[0]
-    rep._host_seg = host_seg
+    assert rep.F == 32
     got = {}
 
     class Cap:
@@ -251,6 +271,11 @@ def test_ffat_tpu_ring_alias_after_drain_iterations(host_seg):
         rep.handle_msg(0, b)
     rep.flush_on_termination()
 
+    st = rep.stats
+    # two windows a program: three drain programs behind each of the 25 steps
+    assert st.fire_programs >= N_PANES - 3
+    assert st.fire_grouped_programs == (0 if count_based
+                                        else st.fire_programs)
     for k in range(2):
         for w in range(N_PANES - 3):
             expect = sum(p + 1 for p in range(w, min(w + 4, N_PANES)))
@@ -372,22 +397,61 @@ def test_ffat_tpu_gap_windows_late_first_key_reanchor():
     assert coll.results.get((0, 30_000_000)) == 9
 
 
-@pytest.mark.parametrize("force_device_seg", [False, True])
-def test_ffat_tpu_adaptive_fire_tiers(force_device_seg, monkeypatch):
+@pytest.mark.parametrize("win,slide,count_based", [
+    (WIN_US, SLIDE_US, False), (WIN_CB, SLIDE_CB, True)])
+def test_ffat_tpu_adaptive_fire_tiers(win, slide, count_based, monkeypatch):
     """Exercise the adaptive two-tier fire budget (W_cap > W_step): a
     stream firing more than W_step windows per batch must switch to the
-    wide tier (device mode), warm both program shapes eagerly, and keep
-    exact window results on both tiers and both seg modes."""
-    if force_device_seg:
-        monkeypatch.setenv("WF_FORCE_DEVICE_SEG", "1")
+    wide tier, warm both program shapes eagerly (no compile after the
+    first batch's), and keep exact window results on both tiers, for
+    the walk by range (time-based) and the lane walk (count-based)."""
+    from windflow_tpu.tpu.ffat_tpu import FfatTPUReplica
+    budgets = []
+    orig = FfatTPUReplica._first_budget
+
+    def spy(self):
+        budgets.append(orig(self))
+        return budgets[-1]
+
+    monkeypatch.setattr(FfatTPUReplica, "_first_budget", spy)
     n_keys, stream_len = 96, 60
-    expected = expected_windows(model_seqs(n_keys, stream_len), WIN_US,
-                                SLIDE_US, False, sum_or_none)
-    coll = run_ffat_tpu(WIN_US, SLIDE_US, win_type_cb=False,
+    expected = expected_windows(model_seqs(n_keys, stream_len), win,
+                                slide, count_based, sum_or_none)
+    coll = run_ffat_tpu(win, slide, win_type_cb=count_based,
                         n_keys=n_keys, stream_len=stream_len,
                         nwpb=256, obs=512)
+    rep = coll.op.replicas[0]
+    assert (rep.W_step, rep.W_cap) == (64, 256)
+    assert set(budgets) == {64, 256} and budgets[0] == 64
+    # step at both tiers, ingest-only, fire-only, rebuild: all at warm-up
+    assert rep.stats.compile_count == 5
     assert coll.dups == 0
     assert coll.results == expected
+
+
+def test_ffat_tpu_programs_chosen_by_nothing_but_shapes():
+    """One step-program family on every backend: ``tpu/ffat_tpu.py``
+    reads neither the backend nor an environment switch (its one look
+    at the environment, ``checkpoint.delta.env_ckpt_delta``, marks rows
+    for snapshots), so a test on the CPU backend runs the programs the
+    chip runs; and the cache keys of a capacity bucket carry shapes, key
+    dtype and chain tag, no mode."""
+    import re
+    import windflow_tpu.tpu.ffat_tpu as ft
+    with open(ft.__file__) as fh:
+        src = fh.read()
+    found = re.findall(r"default_backend|jax\.devices|\.platform\b|environ"
+                       r"|getenv|env_flag|env_int|WF_[A-Z_]+=", src)
+    assert not found, found
+    op = ft.Ffat_Windows_TPU(
+        lift=lambda f: {"v": f["v"]},
+        combine=lambda a, b: {"v": a["v"] + b["v"]},
+        key_extractor="key", win_len=1000, slide_len=400, key_capacity=4)
+    op.build_replicas()
+    rep = op.replicas[0]
+    assert rep._step_keys(64) == (
+        ("step", 64, rep.K_cap, rep.F, True, "int32", None),
+        ("ingest", 64, rep.K_cap, rep.F, None))
 
 
 def test_ffat_tpu_scalar_constant_lift_field():

@@ -14,12 +14,11 @@ TPU-first redesign:
   window-fire decisions and eviction lists are numpy — no D2H of data at
   all (the reference pays a D2H of its unique arrays every batch,
   ``ffat_replica_gpu.hpp:945-988``). Segmentation (sort order + run
-  detection) is backend-dependent: precomputed with numpy on the CPU
-  backend (where the XLA program competes with the host for cores), and
-  computed IN-PROGRAM on accelerators (where device work overlaps the
-  host control plane);
+  detection) happens IN-PROGRAM, on one packed composite column the
+  host ships per batch, so it overlaps the host control plane;
 - the data plane is ONE jitted XLA program per batch:
-    lift(columns) -> gather(sort order) -> segmented associative scan with
+    lift(columns) -> sort of the packed (slot, leaf) composite ->
+    gather(sort order) -> segmented associative scan with
     the user combine -> gather segment tails -> scatter-combine into the
     leaves of a FlatFAT FOREST (K_cap keys x 2F nodes, one segment tree
     per key slot, circular leaf addressing ``pane mod F``) -> vectorized
@@ -30,7 +29,7 @@ TPU-first redesign:
     once where the program's windows share a few ranges (time-based
     windows planned by rounds do), else one walk a window under vmap
     -> leaf eviction;
-- all shapes are static per (cap, K_cap, F, segmentation-mode) bucket;
+- all shapes are static per (cap, K_cap, F) bucket;
   key capacity and ring length grow by doubling with a device-side rebuild
   (the reference resizes its pending-pane ring on demand,
   ``ffat_replica_gpu.hpp:219-260``).
@@ -102,8 +101,9 @@ def xla_rebuild_levels(combine: Callable, F: int):
     """``rebuild(trees, tvalid) -> (trees, tvalid)``: recompute internal
     nodes ``[1, F)`` of every (K_cap, 2F) tree row from its leaves, one
     fused XLA pass per level (an invalid child passes the other
-    through). Also the reference the Pallas kernel is held bit-equal
-    to."""
+    through). The ONE definition traced by the step's in-program
+    rebuild and by the standalone settle program (divergence would make
+    deferred batches aggregate differently from direct ones)."""
     import jax
     import jax.numpy as jnp
 
@@ -162,7 +162,7 @@ class Ffat_Windows_TPU(TPUOperatorBase):
         self.num_win_per_batch = max(1, num_win_per_batch)
         self.pane_len = math.gcd(win_len, slide_len)
         # compiled programs shared ACROSS replicas: cache keys carry every
-        # shape parameter (cap, K_cap, F, seg mode), so equal-config
+        # shape parameter (cap, K_cap, F), so equal-config
         # replicas reuse one compile instead of paying parallelism x
         # (lock: replica worker threads race their first batch)
         import threading
@@ -205,7 +205,7 @@ class FfatTPUReplica(TPUReplicaBase):
         # clear in few programs
         self.W_cap = op.num_win_per_batch
         self.W_step = min(self.W_cap, 64)
-        # adaptive two-tier first-iteration fire budget (device mode):
+        # adaptive two-tier first-iteration fire budget:
         # an EWMA of fired-windows-per-batch picks W_step (small always-
         # paid query block) or W_cap (high-cardinality streams fire in
         # ONE program per batch); both shapes compile eagerly, see
@@ -229,9 +229,8 @@ class FfatTPUReplica(TPUReplicaBase):
         self._saw_new_key = False
         self._leaf_frontier = 0  # max leaf ever accepted (fast-path guard)
         # device-resident constant program args (avoid re-transferring
-        # numpy zeros/dummies every batch)
+        # numpy zeros every batch)
         self._zero_fire_cache: Dict[int, Any] = {}
-        self._seg_dummy = None
         # deferred-rebuild flag: True while internal tree levels are
         # stale w.r.t. leaves (ingest-only batches ran since the last
         # rebuild); every fire path rebuilds first (see _make_step)
@@ -260,22 +259,20 @@ class FfatTPUReplica(TPUReplicaBase):
         self._prog_cache = op._prog_cache  # shared across replicas
         # wf:fireplan: the host's fire planning inside wf:prep
         self._st_fireplan = self.stats.stage("fireplan")
-        self.__host_seg = None  # resolved lazily: backend init is costly
-        self.__on_accel = None  # same caching rationale (_on_accelerator)
         self._check_index_plane()
 
     def _comp_dtype(self):
         """(sentinel M, dtype) of the packed composite — the SINGLE
-        definition shared by staging, dummies, and the driver entry
+        definition shared by staging, warm-up, and the driver entry
         (the traced and runtime dtypes must stay bit-identical)."""
         M = self.K_cap * self.F
         return M, (np.int16 if M < 2**15 - 1 else np.int32)
 
     def _check_index_plane(self, k_cap: int = 0, f: int = 0) -> None:
-        """Every forest index (host composite sort, device scatter/evict
+        """Every forest index (packed composite, device scatter/evict
         flat ids) lives in int32; enforced at init and BEFORE any growth
-        commits — in BOTH segmentation modes. ``k_cap``/``f`` check a
-        PROSPECTIVE capacity/ring before mutating toward it (growth must
+        commits. ``k_cap``/``f`` check a PROSPECTIVE capacity/ring
+        before mutating toward it (growth must
         raise-before-mutate: a caught refusal mid-growth would leave a
         wrapped index plane that no later per-batch guard re-checks)."""
         k = k_cap or self.K_cap
@@ -285,46 +282,6 @@ class FfatTPUReplica(TPUReplicaBase):
                 f"{self.op.name}: K_cap*2F = {k * 2 * ff} "
                 "overflows the int32 index plane; reduce key_capacity or "
                 "the window/slide ratio")
-
-    @property
-    def _host_seg(self) -> bool:
-        if self.__host_seg is None:
-            import jax
-
-            from ..basic import env_flag
-            if env_flag("WF_FORCE_DEVICE_SEG"):
-                # CI lever: exercise the accelerator segmentation path
-                # (in-program sort) on the CPU backend across the suite
-                self.__host_seg = False
-            elif env_flag("WF_FORCE_HOST_SEG"):
-                # perf lever: host radix segmentation on an accelerator —
-                # TPU sorts are bitonic O(n log^2 n); the host's int16
-                # radix argsort overlapped with device compute can win
-                self.__host_seg = True
-            else:
-                self.__host_seg = jax.default_backend() == "cpu"
-        return self.__host_seg
-
-    @_host_seg.setter
-    def _host_seg(self, v) -> None:
-        self.__host_seg = v
-
-    def _on_accelerator(self) -> bool:
-        """Backend test for policy decisions (two-tier fire budgets).
-        NOT the same as ``not _host_seg``: WF_FORCE_HOST_SEG runs host
-        segmentation on an accelerator, where the wide-tier budget
-        rationale (dispatches are the cost, wide queries are overlapped
-        device work) still applies. WF_FORCE_DEVICE_SEG keeps implying
-        accelerator policy so CI exercises the two-tier path on CPU.
-        Cached: called per batch on the hot dispatch path."""
-        if self.__on_accel is None:
-            import jax
-
-            from ..basic import env_flag
-
-            self.__on_accel = (env_flag("WF_FORCE_DEVICE_SEG")
-                               or jax.default_backend() != "cpu")
-        return self.__on_accel
 
     # ==================================================================
     # fused-chain seams (overridden by fused_ops.FusedFfatReplica)
@@ -516,28 +473,6 @@ class FfatTPUReplica(TPUReplicaBase):
 
         return fire_block
 
-    def _rebuild_fn(self):
-        """Returns the full-forest internal-level rebuild callable — the
-        ONE definition shared by the in-program rebuild and the
-        standalone settle program (divergence here would make deferred
-        batches aggregate differently from direct ones). ``WF_PALLAS=1``
-        swaps the XLA body for the Pallas kernel: compiled on a TPU, in
-        interpret mode elsewhere, never the XLA path."""
-        from .pallas_kernels import make_forest_rebuild, pallas_enabled
-
-        combine = self.op.combine
-        F = self.F
-        if not pallas_enabled():
-            return xla_rebuild_levels(combine, F)
-
-        def rebuild_levels(trees, tvalid):
-            import jax
-            return make_forest_rebuild(
-                combine, list(trees), F,
-                interpret=jax.default_backend() != "tpu")(trees, tvalid)
-
-        return rebuild_levels
-
     def _make_step(self, cap: int, donate: bool = True,
                    ingest_only: bool = False):
         """``ingest_only=True`` builds the DEFERRED-REBUILD variant: lift
@@ -554,8 +489,6 @@ class FfatTPUReplica(TPUReplicaBase):
         import jax
         import jax.numpy as jnp
 
-        host_seg = self._host_seg
-
         lift = self._lift_fn()
         combine = self.op.combine
         F = self.F
@@ -565,41 +498,28 @@ class FfatTPUReplica(TPUReplicaBase):
 
         tmap = jax.tree_util.tree_map
         fire_block = self._query_fns()
-        # shared rebuild body (routes through the WF_PALLAS=1 VMEM
-        # kernel when enabled; see _rebuild_fn)
-        rebuild_levels = self._rebuild_fn()
+        rebuild_levels = xla_rebuild_levels(combine, F)
 
-        def step(fields, comp, h_order, h_same, h_end,
-                 h_flat, trees, tvalid, fire_plan, ktable):
-            # 1. lift + sort + segmented scan. WHERE the sort happens is
-            # backend-dependent: on accelerators it runs in-program (device
-            # work overlaps the host control plane); on the CPU backend the
-            # host precomputes the order/run metadata with numpy (h_* args;
-            # ``comp`` is a dummy then, and vice versa — the cache key
-            # includes the mode). In device mode the host ships ONE packed
-            # composite array (slot*F+leaf, sentinel K_cap*F for late and
-            # padding lanes) in the narrowest int dtype — a third of the
-            # transfer volume of separate slot/leaf/live arrays.
+        def step(fields, comp, trees, tvalid, fire_plan, ktable):
+            # 1. lift + sort + segmented scan. The host ships ONE packed
+            # composite column (slot*F+leaf, sentinel K_cap*F for late and
+            # padding lanes) in the narrowest int dtype (_comp_dtype); the
+            # sort order and the run boundaries are computed here, so they
+            # overlap the host control plane of the next batch.
             vals = broadcast_scalar_fields(
                 lift(fields), next(iter(fields.values())).shape[0])
-            if host_seg:
-                order = h_order
-                same_prev = h_same
-                is_end = h_end
-                flat_idx = h_flat
-            else:
-                with jax.named_scope(SCOPE_SORT):
-                    big = jnp.int32(K_cap * F)  # sentinel: late + padding
-                    order = jnp.argsort(comp, stable=True)
-                    sc = comp[order].astype(jnp.int32)
-                    same_prev = jnp.concatenate(
-                        [jnp.zeros((1,), bool), sc[1:] == sc[:-1]])
-                    is_end = jnp.concatenate(
-                        [sc[1:] != sc[:-1],
-                         jnp.ones((1,), bool)]) & (sc < big)
-                    # decode slot/leaf from the sorted composite (F is a
-                    # power of two, so these lower to shift/mask)
-                    flat_idx = (sc // F) * NNODES + (F + sc % F)
+            with jax.named_scope(SCOPE_SORT):
+                big = jnp.int32(K_cap * F)  # sentinel: late + padding
+                order = jnp.argsort(comp, stable=True)
+                sc = comp[order].astype(jnp.int32)
+                same_prev = jnp.concatenate(
+                    [jnp.zeros((1,), bool), sc[1:] == sc[:-1]])
+                is_end = jnp.concatenate(
+                    [sc[1:] != sc[:-1],
+                     jnp.ones((1,), bool)]) & (sc < big)
+                # decode slot/leaf from the sorted composite (F is a
+                # power of two, so these lower to shift/mask)
+                flat_idx = (sc // F) * NNODES + (F + sc % F)
             svals = tmap(lambda a: a[order], vals)
 
             def seg_op(a, b):
@@ -662,7 +582,7 @@ class FfatTPUReplica(TPUReplicaBase):
         from ..monitoring.flightrec import instrumented_jit
         return instrumented_jit(
             step, self.stats, label=f"{self.stats.op_name}:step",
-            program=PROG_STEP, donate_argnums=(6, 7) if donate else ())
+            program=PROG_STEP, donate_argnums=(2, 3) if donate else ())
 
     def _make_fire_step(self):
         """Fire-only program: window queries (_query_fns) + leaf eviction,
@@ -697,10 +617,11 @@ class FfatTPUReplica(TPUReplicaBase):
         """Standalone full-forest level rebuild: settles deferred
         (ingest-only) batches before a DATALESS fire — the fire-only
         program skips the rebuild by design and is only sound over a
-        freshly rebuilt forest (see _make_fire_step). Shares the rebuild
-        body (and the Pallas fast path) with the full program."""
+        freshly rebuilt forest (see _make_fire_step). Traces the same
+        ``xla_rebuild_levels`` as the full program."""
         from ..monitoring.flightrec import instrumented_jit
-        return instrumented_jit(self._rebuild_fn(), self.stats,
+        return instrumented_jit(xla_rebuild_levels(self.op.combine, self.F),
+                                self.stats,
                                 label=f"{self.stats.op_name}:rebuild",
                                 program=PROG_REBUILD,
                                 donate_argnums=(0, 1))
@@ -985,11 +906,9 @@ class FfatTPUReplica(TPUReplicaBase):
         cap = batch.capacity
         # packed composite (slot*F + leaf, sentinel M = late/padding) in
         # the narrowest int dtype: ONE array instead of separate
-        # slot/leaf/live planes — numpy's argsort takes a radix path for
-        # int16 (~12x the int64 comparison sort) on the host-seg branch,
-        # and in device mode it is the only 16k-sized program argument
-        # (a third of the previous H2D volume; int32 is guaranteed by
-        # _check_index_plane at init/growth for BOTH seg modes).
+        # slot/leaf/live planes, the only batch-sized argument the host
+        # builds for the program (int32 always holds it:
+        # _check_index_plane at init/growth); the program sorts it.
         M, cdt = self._comp_dtype()
         comp_p = np.full(cap, M, dtype=cdt)
         packed = slots * self.F + (leaves & (self.F - 1))  # F is pow-2
@@ -1001,21 +920,10 @@ class FfatTPUReplica(TPUReplicaBase):
             # prefix-dropped rows keep the sentinel: the in-program
             # segment plane treats them exactly like late/padding lanes
             comp_p[rowsel] = packed
-        if self._host_seg:
-            big = cdt(M)
-            order_p = np.argsort(comp_p, kind="stable").astype(np.int32)
-            sc = comp_p[order_p].astype(np.int32)
-            same_p = np.r_[False, sc[1:] == sc[:-1]]
-            end_p = np.r_[sc[1:] != sc[:-1], True] & (sc < big)
-            flat_p = (sc // self.F) * (2 * self.F) + self.F + sc % self.F
-            comp_p = np.zeros(1, dtype=cdt)  # device arg shrinks to dummy
-        else:
-            order_p = same_p = end_p = flat_p = None
 
         frontier = (max(0, batch.wm - op.lateness) // op.pane_len
                     if op.win_type is WinType.TB else None)
-        return self._prep_step(batch.fields, batch.wm, cap, comp_p,
-                               order_p, same_p, end_p, flat_p, frontier,
+        return self._prep_step(batch.fields, batch.wm, cap, comp_p, frontier,
                                batch.bid)
 
     # ------------------------------------------------------------------
@@ -1188,16 +1096,15 @@ class FfatTPUReplica(TPUReplicaBase):
         """Fire budget for the first (full) program of a batch — one of
         exactly TWO tiers (both compiled eagerly, so no mid-stream
         retrace ever): the small W_step block, or W_cap when the recent
-        fire rate overflows it. Accelerators only: the wide query block
-        is overlapped device work there and saves two host dispatches per
-        batch, while on the CPU backend the drain path's fire-only
-        program (no lift/sort/rebuild) is much cheaper than widening the
-        full program. The small tier exists because a block costs by the
-        lane, live or masked: that holds for the lane walk only (a
-        program that goes by range costs by the range, PERF.md section
-        6, PR 28), so for streams that fire by range the tiers buy
-        nothing; they are left as they are until that is measured."""
-        if not self._on_accelerator() or self._fire_ewma * 1.25 <= self.W_step:
+        fire rate (the EWMA of windows fired a batch) overflows it, so a
+        stream that fires many windows a batch answers them in the step
+        itself, not in fire-only programs behind it. The small tier
+        exists because a block costs by the lane, live or masked: that
+        holds for the lane walk only (a program that goes by range costs
+        by the range, PERF.md section 6, PR 28), so for streams that
+        fire by range the tiers buy nothing; they are left as they are
+        until that is measured (PERF.md section 7, Second (a))."""
+        if self._fire_ewma * 1.25 <= self.W_step:
             return self.W_step
         return self.W_cap
 
@@ -1234,15 +1141,14 @@ class FfatTPUReplica(TPUReplicaBase):
             self.trees, self.tvalid, self._zero_fire(self.W_cap),
             self._ktable_arg())
 
-    def _warm_programs(self, cap, ckey, ikey, fields,
-                       order_p, same_p, end_p, flat_p, ktable) -> None:
+    def _warm_programs(self, cap, ckey, ikey, fields, ktable) -> None:
         """Compile every program variant of a capacity bucket with no-op
-        sentinel runs (masked rows, zero fire args): the full step (both
-        fire-budget tiers on accelerators), the ingest-only deferred-
-        rebuild step, the fire-only drain step, and the standalone
-        rebuild. All runs are semantic no-ops on the forest (sentinel
-        rows drop, rebuild is idempotent); trees/tvalid are DONATED, so
-        each run reassigns them."""
+        sentinel runs (every lane the composite's sentinel, zero fire
+        args): the full step at both fire-budget tiers, the ingest-only
+        deferred-rebuild step, the fire-only drain step, and the
+        standalone rebuild. All runs are semantic no-ops on the forest
+        (sentinel rows drop, rebuild is idempotent); trees/tvalid are
+        DONATED, so each run reassigns them."""
         from .ops_tpu import cached_compile
         step = cached_compile(self._prog_cache, self.op._prog_lock,
                               ckey, lambda: self._make_step(cap))
@@ -1255,27 +1161,14 @@ class FfatTPUReplica(TPUReplicaBase):
             self._prog_cache, self.op._prog_lock, rkey,
             self._make_rebuild_step)  # cap-independent: a later capacity
         # bucket must not pay a redundant full-forest rebuild execution
-        if self._host_seg:
-            # host-segmentation no-op: no segment ends -> scatter drops.
-            # dtypes must MATCH the real call site (int32 order/flat,
-            # bool same/end) or the warm compiles a shape nobody reuses
-            comp_s = np.zeros(1, self._comp_dtype()[1])
-            seg = (np.arange(cap, dtype=np.int32), np.zeros(cap, bool),
-                   np.zeros(cap, bool),
-                   np.zeros(cap, dtype=np.int32))
-        else:
-            _M, cdt = self._comp_dtype()
-            comp_s = np.full(cap, _M, dtype=cdt)  # all-sentinel lanes
-            seg = (order_p, same_p, end_p, flat_p)
-        tiers = {self.W_step}
-        if self._on_accelerator():
-            tiers.add(self.W_cap)
-        for W in tiers:
+        M, cdt = self._comp_dtype()
+        comp_s = np.full(cap, M, dtype=cdt)  # all-sentinel lanes
+        for W in {self.W_step, self.W_cap}:
             (self.trees, self.tvalid, *_) = step(
-                fields, comp_s, *seg, self.trees, self.tvalid,
+                fields, comp_s, self.trees, self.tvalid,
                 self._zero_fire(W), ktable)
         (self.trees, self.tvalid, *_) = istep(
-            fields, comp_s, *seg, self.trees, self.tvalid,
+            fields, comp_s, self.trees, self.tvalid,
             self._zero_fire(self.W_step), ktable)
         if rb is not None:
             self.trees, self.tvalid = rb(self.trees, self.tvalid)
@@ -1309,19 +1202,7 @@ class FfatTPUReplica(TPUReplicaBase):
             ckey, ikey = self._step_keys(cap)
             if ckey in self._prog_cache and ikey in self._prog_cache:
                 continue
-            if self._host_seg:
-                seg = (None, None, None, None)  # _warm_programs builds
-                # its own host-seg no-op arrays per cap
-            else:
-                if self._seg_dummy is None:
-                    import jax
-                    self._seg_dummy = tuple(jax.device_put(a) for a in (
-                        np.zeros(1, dtype=np.int32),
-                        np.zeros(1, dtype=bool), np.zeros(1, dtype=bool),
-                        np.zeros(1, dtype=np.int32)))
-                seg = self._seg_dummy
-            self._warm_programs(cap, ckey, ikey, fields, *seg,
-                                self._ktable_arg())
+            self._warm_programs(cap, ckey, ikey, fields, self._ktable_arg())
             warmed += 1
         return warmed
 
@@ -1332,26 +1213,18 @@ class FfatTPUReplica(TPUReplicaBase):
         nobody reuses and defeat the compile-flat guarantee). The chain
         tag pins fused-prefix variants to their own cache rows."""
         tag = self._chain_tag()
-        ckey = ("step", cap, self.K_cap, self.F, self._host_seg,
+        ckey = ("step", cap, self.K_cap, self.F,
                 self._use_ktable(), str(self._key_dtype), tag)
-        ikey = ("ingest", cap, self.K_cap, self.F, self._host_seg, tag)
+        ikey = ("ingest", cap, self.K_cap, self.F, tag)
         return ckey, ikey
 
-    def _prep_step(self, fields, wm, cap, comp_p,
-                   order_p, same_p, end_p, flat_p, frontier, bid: int = 0):
+    def _prep_step(self, fields, wm, cap, comp_p, frontier, bid: int = 0):
         """Host half of the per-batch step: program warm-up, the ENTIRE
         fire plan — every drain iteration's chunk arrays and packed
         fire/evict args, computed up front because ``_fireable`` reads
         host metadata only (no control decision ever waits on a device
         result) — and the fire-rate EWMA. Returns the device-commit
         thunk for the dispatch pipeline."""
-        if order_p is None:  # device mode: cached 1-elem dummies
-            if self._seg_dummy is None:
-                import jax
-                self._seg_dummy = tuple(jax.device_put(a) for a in (
-                    np.zeros(1, dtype=np.int32), np.zeros(1, dtype=bool),
-                    np.zeros(1, dtype=bool), np.zeros(1, dtype=np.int32)))
-            order_p, same_p, end_p, flat_p = self._seg_dummy
         ktable = self._ktable_arg()
         ckey, ikey = self._step_keys(cap)
         if ckey not in self._prog_cache or ikey not in self._prog_cache:
@@ -1361,8 +1234,7 @@ class FfatTPUReplica(TPUReplicaBase):
             # pays a mid-stream compile. The warm-up's no-op runs consume
             # the live forest (donation), so in-flight commits land first
             self.dispatch.drain(forced=True)
-            self._warm_programs(cap, ckey, ikey, fields, order_p, same_p,
-                                end_p, flat_p, ktable)
+            self._warm_programs(cap, ckey, ikey, fields, ktable)
         with self._st_fireplan(bid):
             plan, total_fired = self._plan_fires(frontier)
         # fast-rise / slow-decay: a burst switches to the wide tier on
@@ -1372,8 +1244,7 @@ class FfatTPUReplica(TPUReplicaBase):
             self._fire_ewma = float(total_fired)
         else:
             self._fire_ewma += 0.25 * (total_fired - self._fire_ewma)
-        seg = (comp_p, order_p, same_p, end_p, flat_p)
-        return lambda: self._commit_step(fields, wm, seg, ktable,
+        return lambda: self._commit_step(fields, wm, comp_p, ktable,
                                          ckey, ikey, plan, bid)
 
     def _plan_fires(self, frontier):
@@ -1403,7 +1274,7 @@ class FfatTPUReplica(TPUReplicaBase):
                 break
         return plan, total_fired
 
-    def _commit_step(self, fields, wm, seg, ktable, ckey, ikey,
+    def _commit_step(self, fields, wm, comp_p, ktable, ckey, ikey,
                      plan, bid: int) -> None:
         """Device half: runs the planned program sequence in order and
         emits each iteration's windows. Reads ``self.trees``/
@@ -1412,7 +1283,6 @@ class FfatTPUReplica(TPUReplicaBase):
         updates: they must land in DEVICE order (a later batch's prep
         running before this commit must not see, or clobber, a stale
         flag)."""
-        comp_p, order_p, same_p, end_p, flat_p = seg
         for entry in plan:
             if entry is None:
                 # ingest-only: leaves current, internal nodes stale until
@@ -1422,8 +1292,7 @@ class FfatTPUReplica(TPUReplicaBase):
                 # unused in this variant but still traced: pin the
                 # W_step shape so tier switches never retrace it
                 (self.trees, self.tvalid, *_) = self._prog_cache[ikey](
-                    fields, comp_p, order_p, same_p, end_p, flat_p,
-                    self.trees, self.tvalid,
+                    fields, comp_p, self.trees, self.tvalid,
                     self._zero_fire(self.W_step), ktable)
                 self._rebuild_dirty = True
                 self.stats.device_programs_run += 1
@@ -1433,8 +1302,7 @@ class FfatTPUReplica(TPUReplicaBase):
                 # full program: lift + scan + scatter + rebuild + fire
                 (self.trees, self.tvalid, qr, qv, wid_dev,
                  key_dev) = self._prog_cache[ckey](
-                    fields, comp_p, order_p, same_p,
-                    end_p, flat_p, self.trees, self.tvalid, pack, ktable)
+                    fields, comp_p, self.trees, self.tvalid, pack, ktable)
                 self._rebuild_dirty = False  # in-program rebuild covers
                 # every deferred ingest-only batch (full-forest rebuild)
                 self._dirty_all = True  # ... and rewrote internal rows
@@ -1668,4 +1536,3 @@ class FfatTPUReplica(TPUReplicaBase):
         self._ktable_kd = None
         self._ktable_dirty = True
         self._zero_fire_cache = {}
-        self._seg_dummy = None
