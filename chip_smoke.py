@@ -319,6 +319,7 @@ def stage_a(seed: int, n_keys: int, batch: int, n_batches: int,
     out = {
         "events": n_events, "windows": n_win, "run_s": wall,
         "K_cap": rep.K_cap, "F": rep.F, "W_cap": rep.W_cap,
+        "W_wide": rep.W_wide,
         "Compile_count": stat_sum(win, "Compile_count"),
         "Device_programs_run": stat_sum(win, "Device_programs_run"),
         "Programs_per_batch": win[0]["Programs_per_batch"],
@@ -328,6 +329,7 @@ def stage_a(seed: int, n_keys: int, batch: int, n_batches: int,
     }
     say(f"stage A: {n_events} events -> {n_win} windows exact; "
         f"K_cap={rep.K_cap} F={rep.F} W_cap={rep.W_cap} "
+        f"W_wide={rep.W_wide} "
         f"Compile_count={out['Compile_count']} "
         f"Device_programs_run={out['Device_programs_run']} "
         f"Programs_per_batch={out['Programs_per_batch']} "

@@ -2,9 +2,14 @@
 walk per distinct ring range over every key slot at once, against the
 lane walk (one walk a fired window). The replica is driven directly, on
 the CPU backend; the lane walk is forced from the test's side by blanking
-the group table in the plan that ``_pack_fire_arrays`` hands the programs,
+the group table in the plan that ``_pack_plan`` hands the programs,
 which is exactly what the planner does for a program with more than
-``G_CAP`` distinct ranges."""
+``G_CAP`` distinct ranges where a budget was given. From ``(l)`` on: an
+operator with no budget given (time-based windows) sizes the width of
+its fire programs by its plans (``_programs_by_plan``).
+
+Counters pinned "at the parent" were read on commit 20733da (PR 29),
+the last before the width by the plan."""
 
 import numpy as np
 import pytest
@@ -54,8 +59,10 @@ class Rows:
 
     def __init__(self):
         self.rows = []
+        self.widths = []    # lanes of the program behind each batch
 
     def emit_device_batch(self, b):
+        self.widths.append(b.capacity)
         cols = {n: np.asarray(c)[:b.size] for n, c in b.fields.items()}
         names = sorted(n for n in cols if n not in ("key", "wid", "valid"))
         for i in range(b.size):
@@ -85,16 +92,16 @@ def make_replica(lane_only=False, win=4, slide=1, budget=8, keys=4,
     rep = op.replicas[0]
     rep.emitter = Rows()
     if lane_only:
-        pack = rep._pack_fire_arrays
+        pack = rep._pack_plan
 
-        def by_lane(chunks, n_out, W):
-            plan, _n_groups = pack(chunks, n_out, W)
+        def by_lane(chunks, W, lanes, ranges):
+            plan, _n_groups = pack(chunks, W, lanes, ranges)
             fire, groups, _evict = fire_pack_views(plan, rep.slide_units)
             fire[5] = 0
             groups[:] = 0
             return plan, 0
 
-        rep._pack_fire_arrays = by_lane
+        rep._pack_plan = by_lane
     return rep
 
 
@@ -109,10 +116,11 @@ def batch(keys, panes, vals, wm_pane):
     return b
 
 
-def aligned_stream(n_keys, n_panes, per_batch, rng, skip=()):
+def aligned_stream(n_keys, n_panes, per_batch, rng, skip=(), whole=False):
     """Batches of ``per_batch`` panes, every key a reading in every pane
     (but ``skip``: (key, pane) pairs left out), the watermark at the end
-    of each batch."""
+    of each batch. ``whole``: whole numbers, whose float32 sums are exact
+    in any order of combination."""
     out = []
     for base in range(0, n_panes, per_batch):
         ks, ps = [], []
@@ -121,7 +129,8 @@ def aligned_stream(n_keys, n_panes, per_batch, rng, skip=()):
                 if (k, p) not in skip:
                     ks.append(k)
                     ps.append(p)
-        out.append(batch(ks, ps, rng.random(len(ks)) * 100,
+        vals = rng.random(len(ks)) * 100
+        out.append(batch(ks, ps, np.floor(vals) if whole else vals,
                          min(base + per_batch, n_panes)))
     return out
 
@@ -131,13 +140,15 @@ def run(rep, batches, flush=True):
         rep.handle_msg(0, b)
     if flush:
         rep.flush_on_termination()
+    else:
+        rep.dispatch.drain(forced=True)     # commits are deferred
     return rep.emitter.rows
 
 
-def both(batches_fn, **kw):
+def both(batches_fn, flush=True, **kw):
     """The same stream through the planner's choice and the lane walk."""
     reps = [make_replica(lane_only=lane, **kw) for lane in (False, True)]
-    rows = [run(rep, batches_fn()) for rep in reps]
+    rows = [run(rep, batches_fn(), flush) for rep in reps]
     return reps[0], reps[1], rows[0], rows[1]
 
 
@@ -203,22 +214,25 @@ def test_grouped_equals_lane_walk(case):
 
 
 def test_more_ranges_than_the_table_holds_take_the_lane_walk():
-    """(g) two keys and a budget of 64: the flush's rounds give a program
-    32 slides of each key, 32 distinct ranges, more than ``G_CAP``: the
-    planner blanks the table and the program walks by lane."""
-    assert G_CAP < 32
+    """(g) a budget GIVEN, two keys and ``4 * G_CAP`` lanes: the flush's
+    rounds give a program ``2 * G_CAP`` slides of each key, more distinct
+    ranges than ``G_CAP``: the planner of a given budget does not cut,
+    it blanks the table, and the program walks by lane at its width."""
+    n = 5 * G_CAP
 
     def stream():
-        bs = aligned_stream(2, 80, 80, np.random.default_rng(3))
+        bs = aligned_stream(2, n, n, np.random.default_rng(3))
         bs[0].wm = 0
         return bs
 
-    grouped, lane, got, want = both(stream, budget=64, keys=2, win=8)
-    assert got == want and len(got) == 160
+    grouped, lane, got, want = both(stream, budget=4 * G_CAP, keys=2, win=8)
+    assert got == want and len(got) == 2 * n
     st = grouped.stats
-    # 64 + 64 by lane, then 16 slides of each key: 16 ranges, by range
-    assert (st.fire_programs, st.fire_grouped_programs, st.fire_groups) \
-        == (3, 1, 16)
+    # twice 4 * G_CAP lanes by lane, then G_CAP slides of each key: G_CAP
+    # ranges, by range
+    assert (st.fire_programs, st.fire_grouped_programs, st.fire_groups,
+            st.fire_range_cuts) == (3, 1, G_CAP, 0)
+    assert set(grouped.emitter.widths) == {4 * G_CAP}
 
 
 def test_count_based_windows_never_take_the_grouped_query():
@@ -324,30 +338,296 @@ def test_fireable_by_rounds(budget):
 
 def test_counters_in_get_stats_and_no_compile_when_the_query_switches():
     """(k) ``Fire_grouped_programs`` and ``Fire_groups`` beside
-    ``Fire_programs``; a stream whose programs go by range, then by lane,
-    then by range again compiles nothing after its first batch."""
-    rep = make_replica(budget=64, keys=2, win=8)
-    bs = aligned_stream(2, 80, 4, np.random.default_rng(2))
+    ``Fire_programs``; a stream (budget given) whose programs go by
+    range, then by lane, then by range again compiles nothing after its
+    first batch."""
+    parked = G_CAP // 4          # batches of four panes the watermark sits out
+    rep = make_replica(budget=2 * G_CAP, keys=2, win=20)    # F = 64
+    bs = aligned_stream(1, 4 * (parked + 16), 4, np.random.default_rng(2))
     rep.handle_msg(0, bs[0])
     compiled = rep.stats.compile_count
     assert compiled > 0
-    for b in bs[1:10]:                    # four windows a key a batch
+    for b in bs[1:10]:                    # four windows a batch
         rep.handle_msg(0, b)
     rep.dispatch.drain(forced=True)       # commits are deferred
     by_range = rep.stats.fire_grouped_programs
     assert by_range == rep.stats.fire_programs > 0
-    for b in bs[10:15]:                   # parked: nothing fires
+    for b in bs[10:10 + parked]:          # parked: nothing fires
         b.wm = bs[9].wm
         rep.handle_msg(0, b)
-    rep.handle_msg(0, bs[15])             # 24 slides a key in one program
+    rep.handle_msg(0, bs[10 + parked])    # G_CAP + 4 slides at once
     rep.dispatch.drain(forced=True)
     by_lane = rep.stats.fire_programs - by_range
     assert by_lane == 1 and rep.stats.fire_grouped_programs == by_range
-    for b in bs[16:]:
+    for b in bs[11 + parked:]:
         rep.handle_msg(0, b)
     rep.flush_on_termination()
     st = rep.stats.to_dict()
     assert st["Fire_programs"] - by_lane == st["Fire_grouped_programs"] \
         > by_range
     assert st["Fire_groups"] > st["Fire_grouped_programs"]
+    assert st["Fire_range_cuts"] == 0     # a budget given is never cut
     assert st["Compile_count"] == compiled
+
+
+# ----------------------------------------------------------------------
+# the width by the plan: time-based windows, no budget given
+# ----------------------------------------------------------------------
+def ordered_fold(batches, n_keys, win, slide, lift, combine):
+    """{(key, wid): value bytes}: every window that holds a reading, its
+    panes combined in pane order, one after the other (what the CPU
+    plane's FlatFAT answers; whole numbers, so any grouping of an
+    associative combine gives these bits)."""
+    import jax
+    panes = {}
+    for b in batches:
+        cols = jax.device_get(lift({n: np.asarray(c)
+                                    for n, c in b.fields.items()}))
+        for i, (k, ts) in enumerate(zip(b.host_keys, b.ts_host)):
+            val = {n: c[i] for n, c in cols.items()}
+            cell = panes.setdefault((int(k), int(ts) // PANE), val)
+            if cell is not val:
+                panes[int(k), int(ts) // PANE] = combine(cell, val)
+    last = max(p for _, p in panes)
+    out = {}
+    for k in range(n_keys):
+        for w in range(last // slide + 1):
+            acc = None
+            for p in range(w * slide, w * slide + win):
+                if (k, p) in panes:
+                    acc = panes[k, p] if acc is None else combine(
+                        acc, panes[k, p])
+            if acc is not None:
+                out[k, w] = tuple(np.asarray(acc[n]).tobytes()
+                                  for n in sorted(acc))
+    return out
+
+
+def fed(rep, batches):
+    """Programs each batch ran: the replica fed batch by batch, commits
+    landed, ``Fire_programs`` read after each."""
+    per_batch = []
+    for b in batches:
+        before = rep.stats.fire_programs
+        rep.handle_msg(0, b)
+        rep.dispatch.drain(forced=True)
+        per_batch.append(rep.stats.fire_programs - before)
+    return per_batch
+
+
+BY_PLAN = {
+    # (l) 40 keys, eight slides a batch: 320 windows a batch
+    "dense_sum": dict(stream=dict(n_keys=40, n_panes=64, per_batch=8),
+                      kw=dict(keys=40, win=12)),
+    "dense_matrix_product": dict(
+        stream=dict(n_keys=40, n_panes=64, per_batch=8),
+        kw=dict(keys=40, win=12, combine=mat2, lift=lift_mat2)),
+    # (m) F = 32 and 96 panes: the ring wraps three times, and every
+    # program holds eight consecutive windows of every slot, each
+    # evicting panes the next one reads
+    "wrapped_ring_eight_rounds": dict(
+        stream=dict(n_keys=5, n_panes=96, per_batch=8),
+        kw=dict(keys=5, win=13, combine=mat2, lift=lift_mat2)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(BY_PLAN))
+def test_a_batchs_whole_plan_leaves_in_one_program(case):
+    spec = BY_PLAN[case]
+    n_keys, per_batch = spec["stream"]["n_keys"], spec["stream"]["per_batch"]
+
+    def stream():
+        return aligned_stream(rng=np.random.default_rng(7), whole=True,
+                              **spec["stream"])
+
+    reps = [make_replica(lane_only=lane, budget=None, **spec["kw"])
+            for lane in (False, True)]
+    rep, lane = reps
+    assert rep._by_plan and rep.W_wide == rep.W_cap == max(16, n_keys)
+    per_batch_programs = fed(rep, stream())
+    rep.flush_on_termination()
+    got = rep.emitter.rows
+    assert got == run(lane, stream()) and len(got) > 20
+    # ONE program a batch, the step itself, by range; only the flush
+    # (more windows than the widest batch) takes a second one
+    assert set(per_batch_programs) == {0, 1}
+    assert per_batch_programs[2:] == [1] * (len(per_batch_programs) - 2)
+    st = rep.stats
+    assert st.fire_grouped_programs == st.fire_programs \
+        == sum(per_batch_programs) + 2
+    assert lane.stats.fire_grouped_programs == 0
+    assert lane.stats.fire_programs == st.fire_programs
+    # a steady batch: eight rounds of every slot in its one program
+    assert max(rep.emitter.widths) == rep.W_wide == n_keys * per_batch
+    steady = st.windows_fired / st.fire_programs
+    assert steady > 0.75 * n_keys * per_batch
+    # and the answers are the ordered fold's, bit for bit
+    kw = spec["kw"]
+    want = ordered_fold(stream(), n_keys, kw["win"], 1,
+                        kw.get("lift", lift_v), kw.get("combine", add))
+    assert {(k, w): tuple(vals) for k, w, ok, *vals in got if ok} == want
+    assert len(got) == len(want)
+
+
+def test_a_plan_over_the_table_is_cut_at_a_whole_round_and_stays_by_range():
+    """(n) two keys flush ``2.5 * G_CAP`` slides each, a range a round:
+    programs of ``G_CAP`` whole rounds, none by lane."""
+    n = 5 * G_CAP // 2
+
+    def stream():
+        bs = aligned_stream(2, n, n, np.random.default_rng(3))
+        bs[0].wm = 0
+        return bs
+
+    grouped, lane, got, want = both(stream, budget=None, keys=2, win=8)
+    assert got == want and len(got) == 2 * n
+    st = grouped.stats
+    assert (st.fire_programs, st.fire_grouped_programs, st.fire_groups,
+            st.fire_range_cuts) == (3, 3, n, 2)
+    # the width: the bucket of the plan, held to the batch's capacity
+    assert set(grouped.emitter.widths) == {2 * n} == {grouped.W_wide}
+    assert grouped.stats.to_dict()["Fire_range_cuts"] == 2
+    assert lane.stats.fire_range_cuts == 2    # cut alike, walked by lane
+
+
+def test_a_ragged_plan_walks_by_lane_at_the_narrow_width():
+    """(o) more keys than ``G_CAP``, each silent from a pane of its own
+    on, so that no two share a (clipped) range in any round: the first
+    round alone holds more ranges than the table, and the programs walk
+    by lane, at the width they had at the parent (the key capacity's)."""
+    n_keys, win = G_CAP + 8, 100            # F = 128
+
+    def stream():
+        rng = np.random.default_rng(5)
+        last = 40 + np.arange(n_keys)       # key k is silent after this
+        ks = np.repeat(np.arange(n_keys), last + 1)
+        ps = np.concatenate([np.arange(n + 1) for n in last])
+        # the watermark closes windows 0..30 of every key: 31 rounds,
+        # window j of key k clipped to 41 + k - j panes
+        return [batch(ks, ps, rng.random(len(ks)) * 100, win + 30)]
+
+    grouped, lane, got, want = both(stream, flush=False, budget=None,
+                                    keys=n_keys, win=win)
+    assert got == want and len(got) == 31 * n_keys
+    st = grouped.stats
+    assert (st.fire_programs, st.fire_grouped_programs,
+            st.fire_range_cuts) == (31, 0, 0)
+    # the plan outgrew the width and the width grew: no program used it
+    assert grouped.W_wide == 2048 > grouped.W_cap == n_keys
+    assert set(grouped.emitter.widths) == {grouped.W_cap}
+
+
+def test_the_width_grows_with_the_plan_and_compiles_nothing_after():
+    """(p) the width follows the plans up to the capacity of the input
+    batch, each growth warms its shapes once, and ``Compile_count`` is
+    flat from then on, the flush included."""
+    rep = make_replica(budget=None, keys=40, win=12)
+    bs = aligned_stream(40, 96, 8, np.random.default_rng(9))
+    widths, compiled = [], []
+    for b in bs:
+        rep.handle_msg(0, b)
+        rep.dispatch.drain(forced=True)
+        widths.append(rep.W_wide)
+        compiled.append(rep.stats.compile_count)
+    # batch 2 fires five slides of 40 keys (200 windows: the bucket of
+    # 256), batch 3 eight (320: the batch's capacity, under the bucket of
+    # 512); nothing later is wider
+    assert widths == [40, 256] + [320] * 10
+    assert compiled[2] > compiled[1] > compiled[0] > 0
+    assert compiled[2:] == [compiled[2]] * 10
+    rep.flush_on_termination()
+    assert rep.stats.compile_count == compiled[2]
+    assert rep.W_wide == 320 == bs[0].capacity
+    # step at two widths today (the narrow one and W_wide), like the tiers
+    assert sorted(W for key, W in rep._warm_shapes
+                  if key[0] == "step") == [40, 256, 320]
+
+
+def test_snapshot_and_restore_between_two_programs_of_a_wide_plan():
+    """(q) the flush of (n), cut after its first program: the restored
+    replica (its width back at the start) fires the other rounds, every
+    (key, wid) once and bit-equal to the uninterrupted run."""
+    n = 5 * G_CAP // 2
+
+    def stream():
+        bs = aligned_stream(2, n, n, np.random.default_rng(11))
+        bs[0].wm = 0
+        return bs
+
+    whole = make_replica(budget=None, keys=2, win=8)
+    want = list(run(whole, stream()))
+    assert len(want) == 2 * n and whole.stats.fire_programs == 3
+
+    class Cut(Exception):
+        pass
+
+    cut = make_replica(budget=None, keys=2, win=8)
+    run(cut, stream(), flush=False)
+    plan_program, calls = cut._plan_program, []
+
+    def once(slots, k):
+        calls.append(int(k.sum()))
+        if len(calls) > 1:
+            raise Cut
+        return plan_program(slots, k)
+
+    cut._plan_program = once
+    with pytest.raises(Cut):
+        cut.flush_on_termination()
+    assert len(cut.emitter.rows) == 2 * G_CAP   # G_CAP whole rounds are out
+    state = cut.snapshot_state()
+
+    rest = make_replica(budget=None, keys=2, win=8)
+    rest.restore_state(state)
+    # a width is a compiled shape, not state: it starts over, and with no
+    # input batch seen it stays at the key capacity's
+    assert rest.W_wide == rest.W_cap == 16
+    rest.flush_on_termination()
+    got = cut.emitter.rows + rest.emitter.rows
+    assert sorted(got) == sorted(want) and len(set(got)) == len(want)
+    for key in (0, 1):      # a key's windows in wid order, as in one run
+        assert [r for r in got if r[0] == key] \
+            == [r for r in want if r[0] == key]
+    assert rest.stats.fire_grouped_programs == rest.stats.fire_programs \
+        == (2 * n - 2 * G_CAP) // 16
+
+
+# (r) who keeps the parent's path: (Fire_programs, Fire_grouped_programs,
+# Fire_groups, Windows_fired, Device_programs_run, Compile_count) and the
+# widths of the programs as commit 20733da (PR 29) reads them on these
+# streams
+AS_AT_THE_PARENT = {
+    "time_based_budget_given": dict(
+        kw=dict(budget=8, keys=4, win=6), widths={8},
+        counters=(13, 13, 24, 96, 19, 4)),
+    "count_based_budget_given": dict(
+        kw=dict(budget=8, keys=4, win=6, slide=2, win_type=WinType.CB),
+        widths={8}, counters=(6, 0, 0, 48, 7, 4)),
+    "count_based_no_budget": dict(
+        kw=dict(budget=None, keys=4, win=6, slide=2, win_type=WinType.CB),
+        widths={16}, counters=(6, 0, 0, 48, 7, 4)),
+    # 96 windows a batch: the EWMA moves the step from the small tier to
+    # the budget's
+    "time_based_two_tiers": dict(
+        kw=dict(budget=96, keys=24, win=6), widths={64, 96},
+        counters=(8, 8, 27, 576, 14, 5)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(AS_AT_THE_PARENT))
+def test_a_given_budget_and_count_based_windows_plan_as_at_the_parent(case):
+    spec = AS_AT_THE_PARENT[case]
+    rep = make_replica(**spec["kw"])
+    bs = aligned_stream(spec["kw"]["keys"], 24, 4, np.random.default_rng(13))
+    for i, b in enumerate(bs):
+        if i % 3:
+            b.wm = bs[i - i % 3].wm       # the watermark moves in steps
+    run(rep, bs)
+    st = rep.stats
+    assert not rep._by_plan
+    assert rep.W_wide == rep.W_cap and rep.W_step == min(rep.W_cap, 64)
+    assert set(rep.emitter.widths) == spec["widths"]
+    assert (st.fire_programs, st.fire_grouped_programs, st.fire_groups,
+            st.windows_fired, st.device_programs_run,
+            st.compile_count) == spec["counters"]
+    assert st.fire_range_cuts == 0

@@ -400,11 +400,14 @@ def test_ffat_tpu_gap_windows_late_first_key_reanchor():
 @pytest.mark.parametrize("win,slide,count_based", [
     (WIN_US, SLIDE_US, False), (WIN_CB, SLIDE_CB, True)])
 def test_ffat_tpu_adaptive_fire_tiers(win, slide, count_based, monkeypatch):
-    """Exercise the adaptive two-tier fire budget (W_cap > W_step): a
-    stream firing more than W_step windows per batch must switch to the
-    wide tier, warm both program shapes eagerly (no compile after the
-    first batch's), and keep exact window results on both tiers, for
-    the walk by range (time-based) and the lane walk (count-based)."""
+    """Exercise the adaptive two-tier fire budget (W_cap > W_step) that
+    a GIVEN ``num_win_per_batch`` keeps, for time-based windows too
+    (without one they size their width by the plan,
+    tests/test_ffat_grouped_fire.py): a stream firing more than W_step
+    windows per batch must switch to the wide tier, warm both program
+    shapes eagerly (no compile after the first batch's), and keep exact
+    window results on both tiers, for the walk by range (time-based) and
+    the lane walk (count-based)."""
     from windflow_tpu.tpu.ffat_tpu import FfatTPUReplica
     budgets = []
     orig = FfatTPUReplica._first_budget
@@ -421,7 +424,8 @@ def test_ffat_tpu_adaptive_fire_tiers(win, slide, count_based, monkeypatch):
                         n_keys=n_keys, stream_len=stream_len,
                         nwpb=256, obs=512)
     rep = coll.op.replicas[0]
-    assert (rep.W_step, rep.W_cap) == (64, 256)
+    assert (rep.W_step, rep.W_cap, rep.W_wide) == (64, 256, 256)
+    assert not rep._by_plan and rep.stats.fire_range_cuts == 0
     assert set(budgets) == {64, 256} and budgets[0] == 64
     # step at both tiers, ingest-only, fire-only, rebuild: all at warm-up
     assert rep.stats.compile_count == 5

@@ -139,22 +139,26 @@ def test_the_reference_keys_events_as_the_pack_operator_does(sg2):
 
 def test_more_windows_than_one_fire_block_gives_the_same_rows(sg2):
     """A 1,024-row block closes 13 or 14 slides of 37 plugs, about 500
-    windows. The operator's own fire block (the key capacity: 37 windows
-    a program) needs a dozen fire-only programs a batch after its step;
-    a block of 1,024 takes them all in the step."""
-    wide = run_sg2(num_win_per_batch=1024)
+    windows. With no ``num_win_per_batch`` the operator sizes its fire
+    programs by its plans, and a block's windows leave in its step; with
+    the key capacity given as the budget (37 windows a program: the
+    operator's own sizing before PR 30) a block needs a dozen fire-only
+    programs after its step. The rows are the same."""
+    narrow = run_sg2(num_win_per_batch=37)
     assert sg2["cell"].cfg["num_win_per_batch"] is None
-    assert sg2["stats"]["win"]["Fire_programs"] > \
-        3 * wide["stats"]["win"]["Fire_programs"]
-    assert wide["stats"]["win"]["Windows_fired"] == \
-        sg2["stats"]["win"]["Windows_fired"]
+    win = sg2["stats"]["win"]
+    assert narrow["stats"]["win"]["Fire_programs"] > 3 * win["Fire_programs"]
+    assert narrow["stats"]["win"]["Windows_fired"] == win["Windows_fired"]
+    # a firing batch is one program, and the flush a few
+    assert win["Fire_programs"] <= BLOCKS + 8
+    assert win["Fire_grouped_programs"] == win["Fire_programs"]
 
     def as_set(run):
         r = valid_rows(run)
         return set(zip(*(r[k].tolist() for k in
                          ("key", "wid", "sum", "count", "avg", "plug",
                           "household", "house"))))
-    assert as_set(wide) == as_set(sg2)
+    assert as_set(narrow) == as_set(sg2)
 
 
 def test_counters_of_the_window_operator(sg2):
@@ -167,14 +171,19 @@ def test_counters_of_the_window_operator(sg2):
     assert sg2["stats"][sg2["roles"]["exit"]]["Exit_process_total_usec"] > 0
 
 
-@pytest.mark.parametrize("name,low,high", [
-    ("fire_grouped_share.sg2", 95.0, 100.0),
-    ("fire_groups_per_program.sg2", 1.0, 4.0)])
+@pytest.mark.parametrize("name,low,high,since", [
+    ("fire_grouped_share.sg2", 95.0, 100.0, "PR 28"),
+    ("fire_groups_per_program.sg2", 1.0, 32.0, "PR 28"),
+    ("windows_per_fire_program.sg2", 100.0, 1024.0, None),
+    ("fire_range_cut_share.sg2", 0.0, 50.0, "PR 30")])
 def test_the_fire_query_goes_by_range_and_its_metrics_say_so(sg2, name, low,
-                                                             high):
+                                                             high, since):
     """Every plug fires the same slides, so the programs answer by range,
-    one to four ranges each; the two metrics read the counters through the
-    reader that gives nothing, not 0, for a program without them."""
+    a block's 13 or 14 slides in one program of at most 32 ranges (more
+    than a few hundred windows each), and only the flush's programs are
+    cut at the table's size; the four metrics read the counters through
+    the reader that gives nothing, not 0, for a program without them
+    (``since``: the PR that brought the counter; None: it always was)."""
     import types
 
     from harness.cell import BENCH_DIR, load_module
@@ -185,6 +194,8 @@ def test_the_fire_query_goes_by_range_and_its_metrics_say_so(sg2, name, low,
                    if m["name"] == name][0]
     assert entry["workloads"] == ["sg2.saturated"]
     assert entry["layer"] == spec["layer"] == "device programs"
+    assert entry["moves"] == spec["moves"] == "events_per_s"
+    assert entry["unit"] == spec["unit"]
     assert spec["reader"] == "counter_ratio_present.py"
     read = load_module(os.path.join(BENCH_DIR, "metrics", spec["reader"])).read
     zeros = {op: dict.fromkeys(st, 0) for op, st in sg2["stats"].items()}
@@ -195,10 +206,16 @@ def test_the_fire_query_goes_by_range_and_its_metrics_say_so(sg2, name, low,
             stats=StatsWindow(zeros, end, sg2["roles"]))
 
     assert low <= read(ctx(sg2["stats"]), spec["params"]) <= high
-    old = {op: {k: v for k, v in st.items()
-                if k not in ("Fire_grouped_programs", "Fire_groups")}
+    newer = {"PR 28": ("Fire_grouped_programs", "Fire_groups",
+                       "Fire_range_cuts"),
+             "PR 30": ("Fire_range_cuts",), None: ()}[since]
+    old = {op: {k: v for k, v in st.items() if k not in newer}
            for op, st in sg2["stats"].items()}
-    assert read(ctx(old), spec["params"]) is None
+    if since:
+        assert read(ctx(old), spec["params"]) is None
+    else:
+        assert read(ctx(old), spec["params"]) \
+            == read(ctx(sg2["stats"]), spec["params"])
 
 
 @pytest.mark.parametrize("high,ok", [(1000, True), (1_000_000, False)])

@@ -50,9 +50,11 @@ class StatsRecord(StageCounters):
         # empty ones too) and the programs that answered them (a full
         # step with its fire block, or a fire-only program); of those,
         # the programs that answered per distinct ring range over every
-        # key slot at once, and the ranges summed over them
+        # key slot at once, and the ranges summed over them; and the
+        # programs the planner closed at a whole round because one more
+        # would have passed G_CAP distinct ranges
         "windows_fired", "fire_programs",
-        "fire_grouped_programs", "fire_groups",
+        "fire_grouped_programs", "fire_groups", "fire_range_cuts",
         "staging_pool_hits", "staging_pool_misses",
         "dispatch_host_prep_us", "dispatch_commit_us",
         "dispatch_stalls", "dispatch_depth_max",
@@ -184,6 +186,7 @@ class StatsRecord(StageCounters):
         self.fire_programs = 0
         self.fire_grouped_programs = 0
         self.fire_groups = 0
+        self.fire_range_cuts = 0
         self.staging_pool_hits = 0  # recycled staging buffers (ArrayPool)
         self.staging_pool_misses = 0
         # device-ahead dispatch pipeline (runtime/dispatch.py): per-stage
@@ -530,6 +533,7 @@ class StatsRecord(StageCounters):
             "Fire_programs": self.fire_programs,
             "Fire_grouped_programs": self.fire_grouped_programs,
             "Fire_groups": self.fire_groups,
+            "Fire_range_cuts": self.fire_range_cuts,
             "Fused_ops": self.fused_ops,
             "Staging_pool_hits": self.staging_pool_hits,
             "Staging_pool_misses": self.staging_pool_misses,

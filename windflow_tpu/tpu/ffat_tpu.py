@@ -23,13 +23,23 @@ TPU-first redesign:
     leaves of a FlatFAT FOREST (K_cap keys x 2F nodes, one segment tree
     per key slot, circular leaf addressing ``pane mod F``) -> vectorized
     level rebuild (log F fused passes over the whole forest) -> iterative
-    range queries for up to W_cap fired windows (each walks <= 2 log F
+    range queries for the program's fired windows (each walks <= 2 log F
     nodes with ordered left/right accumulators, safe for non-commutative
     combines): ONE walk per distinct ring range over every key slot at
     once where the program's windows share a few ranges (time-based
     windows planned by rounds do), else one walk a window under vmap
     -> leaf eviction;
-- all shapes are static per (cap, K_cap, F) bucket;
+- the width of a program's fire block (its lanes) is the user's
+  ``num_win_per_batch`` where one was given, honoured as given, in two
+  tiers (_first_budget); count-based windows default it to the key
+  capacity. Time-based windows with no budget given answer by range and
+  cost by the range, not by the lane, so they size the width by what
+  their plans hold (_programs_by_plan): a batch's whole plan leaves in
+  ONE program, the step itself, at a width that grows to the capacity
+  bucket that holds the plan, up to the capacity of the input batch; a
+  plan over ``G_CAP`` ranges is cut at a whole round and stays by range,
+  and only a ragged plan walks by lane, at the narrow width;
+- all shapes are static per (cap, K_cap, F) bucket and fire width;
   key capacity and ring length grow by doubling with a device-side rebuild
   (the reference resizes its pending-pane ring on demand,
   ``ffat_replica_gpu.hpp:219-260``).
@@ -71,9 +81,14 @@ SCOPE_REBUILD, SCOPE_FIRE, SCOPE_EVICT = "level_rebuild", "fire", "evict"
 
 # the most distinct ring ranges (start_phys, length) whose windows one
 # program answers by range (one tree walk over every key slot at once,
-# see _query_fns); a program whose lanes hold more takes the lane walk.
-# The answers are a (G_CAP, K_cap) table per tree field
-G_CAP = 16
+# see _query_fns). A program planned by the plan's own width is closed
+# at the last whole round that keeps it within G_CAP ranges
+# (_plan_program); any other program whose lanes hold more takes the lane
+# walk. The answers are a (G_CAP, K_cap) table per tree field. 32 (PR 30,
+# probe at a 302 MB forest, PERF.md section 6): a block of eight slides
+# of every key is ~19 ranges at 0.075 ms each; at 64 the end-of-stream
+# flush needs half the programs, and every step takes 0.35 ms longer
+G_CAP = 32
 
 
 def fire_pack_len(W: int, slide_units: int) -> int:
@@ -152,6 +167,11 @@ class Ffat_Windows_TPU(TPUOperatorBase):
         self.win_type = win_type
         self.lateness = lateness
         self.key_capacity = max(1, key_capacity)
+        # a budget the user gave is honoured as given; without one,
+        # time-based windows size their fire programs by what their
+        # plans hold (FfatTPUReplica._programs_by_plan) and start from
+        # the default below
+        self.budget_given = num_win_per_batch is not None
         if num_win_per_batch is None:
             # fired windows per step scale with key count (each key slides
             # its own windows): default the fire-batch budget to the key
@@ -168,6 +188,9 @@ class Ffat_Windows_TPU(TPUOperatorBase):
         import threading
         self._prog_cache: Dict[Any, Any] = {}
         self._prog_lock = threading.Lock()
+        # (program cache key, fire width) pairs some replica has run
+        # once: a width is a traced shape of a cached program
+        self._warm_shapes: set = set()
 
     @property
     def fusion_role(self) -> Optional[str]:
@@ -198,19 +221,31 @@ class FfatTPUReplica(TPUReplicaBase):
         # pre-sizing the key table avoids growth recompiles
         # (wf/builders_gpu.hpp has no analog; growth still works past it)
         self.K_cap = 1 << max(2, math.ceil(math.log2(op.key_capacity)))
-        # two fire-budget tiers: W_step keeps the full per-batch
-        # program's query block small (the lane walk costs by the lane,
-        # see _first_budget), W_cap is the wide budget
-        # used by drain iterations and data-less firing so backlogs
-        # clear in few programs
+        # The widths (lanes) of the fire programs. W_cap is the budget:
+        # the user's, or the key capacity's default. What else there is
+        # depends on who sized it:
+        # - time-based windows with no budget given size the width BY
+        #   THE PLAN (_programs_by_plan): their programs answer by range
+        #   and cost by the range, not by the lane, so a batch's whole
+        #   plan leaves in one program of W_wide lanes, which grows to
+        #   the capacity bucket that holds the plan, up to the capacity
+        #   of the input batch (_fit_width); W_cap stays the width of
+        #   the programs that walk by lane. No small tier: W_step is
+        #   W_cap;
+        # - count-based windows, and any operator with a budget given,
+        #   keep two tiers of the budget: W_step keeps the step's query
+        #   block small (the lane walk costs by the lane, masked or
+        #   live), W_cap is used when the recent fire rate overflows it
+        #   (_first_budget: an EWMA of windows fired a batch, 0 at the
+        #   start so low-fire streams begin on the small tier), by drain
+        #   iterations and by data-less firing. W_wide is W_cap.
         self.W_cap = op.num_win_per_batch
-        self.W_step = min(self.W_cap, 64)
-        # adaptive two-tier first-iteration fire budget:
-        # an EWMA of fired-windows-per-batch picks W_step (small always-
-        # paid query block) or W_cap (high-cardinality streams fire in
-        # ONE program per batch); both shapes compile eagerly, see
-        # _first_budget. Starts at 0 so low-fire streams begin on the
-        # small tier.
+        self._by_plan = (not op.budget_given
+                         and op.win_type is WinType.TB)
+        self.W_step = (self.W_cap if self._by_plan
+                       else min(self.W_cap, 64))
+        self.W_wide = self.W_cap
+        self._cap_seen = 0  # widest input batch so far: W_wide's bound
         self._fire_ewma = 0.0
         from .keymap import KeySlotMap
         self._keymap = KeySlotMap(on_new=self._on_new_key)
@@ -257,6 +292,7 @@ class FfatTPUReplica(TPUReplicaBase):
         self.trees = None  # dict field -> (K_cap, 2F)
         self.tvalid = None  # (K_cap, 2F) bool
         self._prog_cache = op._prog_cache  # shared across replicas
+        self._warm_shapes = op._warm_shapes
         # wf:fireplan: the host's fire planning inside wf:prep
         self._st_fireplan = self.stats.stage("fireplan")
         self._check_index_plane()
@@ -595,7 +631,7 @@ class FfatTPUReplica(TPUReplicaBase):
         pane satisfies p <= max_leaf < next_fire_at_rebuild + F (the
         _grow_ring span guard enforces this at arrival), so an evicted
         pane's ring slot can only be re-queried at pane p_evicted + F >
-        max_leaf — excluded because _pack_fire_arrays clips every query
+        max_leaf — excluded because _lanes clips every query
         to the data extent. The clip is also what keeps the invariant
         robust if F sizing ever changes (regression-tested).
 
@@ -604,9 +640,12 @@ class FfatTPUReplica(TPUReplicaBase):
         and for the walk by range as it did for slot order and the lane
         walk: a walk reads only nodes wholly inside its clipped range,
         a slot's window ``w + 1`` starts past every pane its window ``w``
-        evicted (a program earlier, where the rounds split them), and
-        the walk by range reads the other slots' nodes of the same
-        columns only into table rows that no lane of theirs picks."""
+        evicted (a program earlier, where the rounds split them; in the
+        SAME program, which a width sized by the plan makes the common
+        case, all queries read the forest as it stood before the
+        program's one eviction scatter), and the walk by range reads
+        the other slots' nodes of the same columns only into table rows
+        that no lane of theirs picks."""
         # tvalid donated (in-place eviction); trees is read-only here
         from ..monitoring.flightrec import instrumented_jit
         return instrumented_jit(self._query_fns(), self.stats,
@@ -927,31 +966,15 @@ class FfatTPUReplica(TPUReplicaBase):
                                batch.bid)
 
     # ------------------------------------------------------------------
-    def _fireable(self, frontier, partial: bool, budget: int):
-        """Fire-eligible windows as per-slot chunk ARRAYS
-        (slots, start0, k, wid0, max_leaf), each chunk covering the
-        slot's next ``k`` consecutive eligible windows, ``budget`` windows
-        in all. Where more are eligible than the budget holds:
-
-        - time-based windows leave by ROUNDS: every firing slot gives its
-          first ``min(k, r)`` windows for the largest ``r`` that fits,
-          and what is left of the budget goes to round ``r + 1`` in slot
-          order (so a program is full while windows remain, and where
-          even one round overflows, this is the slot-order clip within
-          it). Window ``w`` of every key is the same ring range, so a
-          program holds one to three distinct ranges, in the steady state
-          and in the end-of-stream flush alike, and the fire query walks
-          each once (_query_fns);
-        - count-based windows in slot order, clipped where the cumulative
-          sum crosses the budget (their ranges are per key anyway).
-
-        A slot's windows leave in ``wid`` order either way. Fully
-        vectorized: one numpy pass over the live slot table per call
+    def _eligible(self, frontier, partial: bool):
+        """``(slots, k)``: the slots with windows to fire now and the
+        count of consecutive eligible windows of each, from its
+        ``next_fire`` on. One numpy pass over the live slot table
         (C-speed even at 10^5 keys; the reference instead walks its key
-        descriptor map in a host loop, ``ffat_replica_gpu.hpp:870-1019``).
-        Advances next_fire/fired for the windows taken."""
+        descriptor map in a host loop,
+        ``ffat_replica_gpu.hpp:870-1019``). Reads, advances nothing."""
         ns = len(self.slot_of_key)
-        empty = (np.zeros(0, np.int64),) * 5
+        empty = (np.zeros(0, np.int64),) * 2
         if ns == 0:
             return empty
         nf = self.next_fire[:ns]
@@ -971,25 +994,58 @@ class FfatTPUReplica(TPUReplicaBase):
             k = np.minimum((ml - nf) // self.slide_units + 1, k_cnt)
         k = np.where(has_data, k, 0)
         slots = np.nonzero(k > 0)[0]
-        if slots.size == 0:
-            return empty
-        k = k[slots]
-        if int(k.sum()) > budget:
-            if self.op.win_type is WinType.TB:
-                k = self._take_by_rounds(k, budget)
-            else:
-                # clip the chunk sequence where the cumsum crosses
-                k = np.minimum(k, budget - (np.cumsum(k) - k))
-            keep = k > 0
+        return slots, k[slots]
+
+    def _take(self, slots, k):
+        """Take the next ``k`` windows of each of ``slots``: advances
+        ``next_fire``/``fired`` and returns the chunk ARRAYS (slots,
+        start0, k, wid0, max_leaf), a chunk a slot with ``k > 0``."""
+        keep = k > 0
+        if not keep.all():
             slots, k = slots[keep], k[keep]
-        start0 = self.next_fire[slots].copy()
-        wid0 = self.fired[slots].copy()
+        start0 = self.next_fire[slots]
+        wid0 = self.fired[slots]
         self.next_fire[slots] += k * self.slide_units
         self.fired[slots] += k
         if self._ckpt_dirty or self._delta_base is not None:
             # firing advances bookkeeping and evicts ring panes
             self._ckpt_dirty.update(slots.tolist())
-        return slots, start0, k, wid0, self.max_leaf[slots].copy()
+        return slots, start0, k, wid0, self.max_leaf[slots]
+
+    def _fireable(self, frontier, partial: bool, budget: int):
+        """Fire-eligible windows as per-slot chunk ARRAYS
+        (slots, start0, k, wid0, max_leaf), each chunk covering the
+        slot's next ``k`` consecutive eligible windows, ``budget`` windows
+        in all. Where more are eligible than the budget holds:
+
+        - time-based windows leave by ROUNDS: every firing slot gives its
+          first ``min(k, r)`` windows for the largest ``r`` that fits,
+          and what is left of the budget goes to round ``r + 1`` in slot
+          order (so a program is full while windows remain, and where
+          even one round overflows, this is the slot-order clip within
+          it). Window ``w`` of every key is the same ring range, so a
+          program holds a few distinct ranges, in the steady state
+          and in the end-of-stream flush alike, and the fire query walks
+          each once (_query_fns);
+        - count-based windows in slot order, clipped where the cumulative
+          sum crosses the budget (their ranges are per key anyway).
+
+        A slot's windows leave in ``wid`` order either way. Advances
+        next_fire/fired for the windows taken."""
+        slots, k = self._eligible(frontier, partial)
+        if slots.size == 0:
+            return (np.zeros(0, np.int64),) * 5
+        return self._take(slots, self._clip(k, budget))
+
+    def _clip(self, k: np.ndarray, budget: int) -> np.ndarray:
+        """Windows taken of each slot's ``k`` eligible ones under a
+        budget: by rounds (time-based) or in slot order (count-based)."""
+        if int(k.sum()) <= budget:
+            return k
+        if self.op.win_type is WinType.TB:
+            return self._take_by_rounds(k, budget)
+        # clip the chunk sequence where the cumsum crosses
+        return np.maximum(0, np.minimum(k, budget - (np.cumsum(k) - k)))
 
     @staticmethod
     def _take_by_rounds(k: np.ndarray, budget: int) -> np.ndarray:
@@ -1016,49 +1072,59 @@ class FfatTPUReplica(TPUReplicaBase):
         before = np.cumsum(k) - k
         return np.arange(tot, dtype=np.int64) - np.repeat(before, k)
 
-    def _pack_fire_arrays(self, chunks, n_out, W: int):
-        """Chunk arrays -> padded fire/evict arrays for the device
-        programs (shaped for budget ``W``; jit re-traces per shape). Pure
-        numpy (repeat + segmented arange): zero per-window or per-chunk
-        Python. The whole plan is PACKED into one flat int32 buffer
-        (``fire_pack_views``: fire rows, group table, evict rows) — one
-        program argument from the host, so one transfer a launch. The
-        group table holds the distinct ``(start_phys, length)`` pairs of
-        the lanes and their count, the ``group`` row each lane's index
-        into it. A count of 0 tells the program to walk by lane:
-        count-based windows, or more than ``G_CAP`` distinct ranges among
-        the lanes (keys that arrive ragged). Returns the buffer and that
-        count."""
+    def _lanes(self, start0, k, ml):
+        """Per-lane ``(round, start, length)`` of chunks that start at
+        pane ``start0``, hold ``k`` windows and end their data at pane
+        ``ml``: a lane a window, a chunk's lanes in ``wid`` order
+        (``round``: 0 for a chunk's first window). The length is ALWAYS
+        clipped to the slot's data extent (max_leaf): panes beyond it
+        hold no current data, and their ring slots may alias panes
+        evicted after the last level rebuild — clipping is what makes
+        the rebuild-free fire-only program sound (every slot inside the
+        clipped range was valid at the last rebuild and is untouched by
+        this drain sequence's evictions; aliases land at pane+F >
+        max_leaf, which is excluded here, and _grow_ring guarantees live
+        spans stay below F)."""
+        rnd = self._segmented_arange(k)
+        starts = np.repeat(start0, k) + rnd * self.slide_units
+        lens = np.minimum(self.win_units, np.repeat(ml, k) + 1 - starts)
+        return rnd, starts, lens
+
+    def _ranges(self, starts, lens):
+        """``(pairs, group)``: the distinct ring ranges ``(start_phys,
+        length)`` of a program's lanes, each packed into one word
+        (lens <= win_units < F), and every lane's index into them."""
+        return np.unique((starts % self.F) * self.F + lens,
+                         return_inverse=True)
+
+    def _pack_plan(self, chunks, W: int, lanes, ranges):
+        """A program's flat int32 fire plan (``fire_pack_views``: fire
+        rows, group table, evict rows) for width ``W``, from its chunks,
+        their lanes (_lanes) and the ranges to answer them by (_ranges;
+        None: the group table stays blank, its count 0, and the program
+        walks by lane). Pure numpy (repeat + segmented arange): zero
+        per-window or per-chunk Python. ONE buffer, so one program
+        argument from the host and one transfer a launch. Returns the
+        buffer and the count of ranges in its table."""
         c_slots, c_start0, c_k, c_wid0, c_ml = chunks
+        rnd, starts, lens = lanes
+        n_out = rnd.size
         pack = np.zeros(fire_pack_len(W, self.slide_units), dtype=np.int32)
         f_pack, g_table, e_pack = fire_pack_views(pack, self.slide_units)
-        ar = self._segmented_arange(c_k)
-        starts = np.repeat(c_start0, c_k) + ar * self.slide_units
         f_pack[0, :n_out] = np.repeat(c_slots, c_k)
         f_pack[1, :n_out] = starts % self.F
-        # ALWAYS clip the query to the slot's data extent (max_leaf):
-        # panes beyond it hold no current data, and their ring slots may
-        # alias panes evicted after the last level rebuild — clipping is
-        # what makes the rebuild-free fire-only program sound (every slot
-        # inside the clipped range was valid at the last rebuild and is
-        # untouched by this drain sequence's evictions; aliases land at
-        # pane+F > max_leaf, which is excluded here, and _grow_ring
-        # guarantees live spans stay below F)
-        f_pack[2, :n_out] = np.minimum(self.win_units,
-                                       np.repeat(c_ml, c_k) + 1 - starts)
+        f_pack[2, :n_out] = lens
+        f_pack[3, :n_out] = np.repeat(c_wid0, c_k) + rnd
         f_pack[4, :n_out] = 1  # mask row: rides the SAME transfer as the
         # spec rows (one H2D enqueue per pack instead of pack+mask pairs)
-        f_pack[3, :n_out] = np.repeat(c_wid0, c_k) + ar
-        if self.op.win_type is WinType.TB:
-            # lens <= win_units < F, so (start, len) packs into one word
-            pairs, group = np.unique(
-                f_pack[1, :n_out].astype(np.int64) * self.F
-                + f_pack[2, :n_out], return_inverse=True)
-            if pairs.size <= G_CAP:
-                g_table[:pairs.size, 0] = pairs // self.F
-                g_table[:pairs.size, 1] = pairs % self.F
-                g_table[G_CAP, 0] = pairs.size
-                f_pack[5, :n_out] = group
+        n_groups = 0
+        if ranges is not None:
+            pairs, group = ranges
+            n_groups = pairs.size
+            g_table[:n_groups, 0] = pairs // self.F
+            g_table[:n_groups, 1] = pairs % self.F
+            g_table[G_CAP, 0] = n_groups
+            f_pack[5, :n_out] = group
         # evicted panes: one contiguous range per chunk
         ne = np.maximum(
             0, np.minimum(c_start0 + c_k * self.slide_units, c_ml + 1)
@@ -1069,7 +1135,96 @@ class FfatTPUReplica(TPUReplicaBase):
             e_pack[0, :tot_e] = np.repeat(c_slots, ne)
             e_pack[1, :tot_e] = ep % self.F
             e_pack[2, :tot_e] = 1
-        return pack, int(g_table[G_CAP, 0])
+        return pack, n_groups
+
+    def _pack_fire_arrays(self, chunks, W: int):
+        """Chunk arrays -> the packed fire plan of a program of width
+        ``W`` (_pack_plan; jit re-traces per shape) and the count of
+        ranges it is answered by. The group table holds the distinct
+        ``(start_phys, length)`` pairs of the lanes where there are at
+        most ``G_CAP``; else, and for count-based windows, the count is
+        0 and the program walks by lane."""
+        _slots, c_start0, c_k, _wid0, c_ml = chunks
+        lanes = self._lanes(c_start0, c_k, c_ml)
+        ranges = None
+        if self.op.win_type is WinType.TB:
+            ranges = self._ranges(lanes[1], lanes[2])
+            if ranges[0].size > G_CAP:
+                ranges = None
+        return self._pack_plan(chunks, W, lanes, ranges)
+
+    def _plan_program(self, slots, k):
+        """ONE program of an operator that sizes its width by the plan
+        (time-based windows, no budget given), from the eligible
+        windows ``k`` of ``slots``: ``(chunks, n_out, pack, n_groups,
+        W)``, the windows taken advanced past, and how many of each
+        slot's it took.
+
+        The program is ``W_wide`` lanes and takes all that is eligible,
+        by rounds where even that width overflows. It stays a program
+        BY RANGE: where its lanes hold more than ``G_CAP`` distinct
+        ranges it is closed at the last WHOLE round that keeps it
+        within ``G_CAP`` (a round: window ``j`` of every firing slot;
+        the rounds left make the next program), and
+        ``Fire_range_cuts`` counts it. Only where the first round alone
+        holds more (a ragged plan: sparse keys, every ``max_leaf``
+        different, about as many ranges as lanes) does the program walk
+        by lane, and then at the width it has today, ``W_cap``: a lane
+        walk costs by the lane, masked or live, so it is never widened.
+
+        The costs behind the rule (my chip runs, PR 30, PERF.md section
+        6; a 302 MB forest): a program by range 1.4 ms whatever it
+        holds, 0.075 ms a range, and its picks and eviction 0.05 us a
+        lane of its width, masked or live (1.6 ms at 32,768); by lane
+        2.5 us a lane (PR 28). So one whole round by range beats the
+        lane walk from about a thousand firing slots on, and below that
+        the device's difference (under 3 ms a program) is less than a
+        program costs the host (2 to 4 ms of Python): fewer programs
+        win either way."""
+        su = self.slide_units
+        start0, end = self.next_fire[slots], self.max_leaf[slots] + 1
+        for W in dict.fromkeys((self.W_wide, self.W_cap)):
+            take = self._clip(k, W)
+            # CLASSES of chunks whose lanes hold the same ranges round
+            # for round: same start, same data end (as far as it clips
+            # a lane), same count. Ranges are counted over the classes'
+            # lanes, a few hundred where the plugs fire in step, not
+            # over the program's tens of thousands
+            reach = (int(take.max()) - 1) * su + self.win_units
+            _, i_s = np.unique(start0, return_inverse=True)
+            _, i_e = np.unique(np.minimum(end - start0, reach),
+                               return_inverse=True)
+            _, rep, cls = np.unique(
+                (i_s * (int(i_e.max()) + 1) + i_e) * (W + 1) + take,
+                return_index=True, return_inverse=True)
+            q_take = take[rep]
+            q_rnd, q_starts, q_lens = self._lanes(
+                start0[rep], q_take, end[rep] - 1)
+            pairs, q_group = self._ranges(q_starts, q_lens)
+            if pairs.size <= G_CAP:
+                break
+            # the round in which each range first appears: rounds below
+            # the (G_CAP + 1)-th smallest hold at most G_CAP ranges
+            first = np.full(pairs.size, W, dtype=np.int64)
+            np.minimum.at(first, q_group, q_rnd)
+            r = int(np.partition(first, G_CAP)[G_CAP])
+            if r:
+                kept = first < r
+                pairs = pairs[kept]
+                q_group = (np.cumsum(kept) - 1)[q_group]
+                take = np.minimum(take, r)
+                self.stats.fire_range_cuts += 1
+                break
+        else:
+            pairs = None  # ragged: by lane, at the narrow width
+        # a lane is its chunk's class's lane of the same round
+        rnd = self._segmented_arange(take)
+        lane = np.repeat((np.cumsum(q_take) - q_take)[cls], take) + rnd
+        chunks = self._take(slots, take)
+        pack, n_groups = self._pack_plan(
+            chunks, W, (rnd, q_starts[lane], q_lens[lane]),
+            None if pairs is None else (pairs, q_group[lane]))
+        return (chunks, rnd.size, pack, n_groups, W), take
 
     def _use_ktable(self) -> bool:
         """Whether programs gather the output key column from a
@@ -1093,20 +1248,33 @@ class FfatTPUReplica(TPUReplicaBase):
         return self._ktable_dev
 
     def _first_budget(self) -> int:
-        """Fire budget for the first (full) program of a batch — one of
-        exactly TWO tiers (both compiled eagerly, so no mid-stream
-        retrace ever): the small W_step block, or W_cap when the recent
-        fire rate (the EWMA of windows fired a batch) overflows it, so a
-        stream that fires many windows a batch answers them in the step
-        itself, not in fire-only programs behind it. The small tier
-        exists because a block costs by the lane, live or masked: that
-        holds for the lane walk only (a program that goes by range costs
-        by the range, PERF.md section 6, PR 28), so for streams that
-        fire by range the tiers buy nothing; they are left as they are
-        until that is measured (PERF.md section 7, Second (a))."""
+        """Fire budget for the first (full) program of a batch of an
+        operator that keeps the two tiers (count-based windows, or a
+        budget given: see __init__) — one of exactly TWO (both compiled
+        eagerly, so no mid-stream retrace ever): the small W_step block,
+        or W_cap when the recent fire rate (the EWMA of windows fired a
+        batch) overflows it, so a stream that fires many windows a batch
+        answers them in the step itself, not in fire-only programs
+        behind it. The small tier exists because a block costs by the
+        lane, live or masked: that holds for the lane walk only, so
+        operators that size their width by the plan have no tiers
+        (_programs_by_plan)."""
         if self._fire_ewma * 1.25 <= self.W_step:
             return self.W_step
         return self.W_cap
+
+    def _fit_width(self, total: int) -> bool:
+        """Grow ``W_wide`` for a plan of ``total`` windows that has
+        outgrown it: to the capacity bucket that holds the plan, never
+        past the capacity of the widest input batch (a result batch no
+        wider than the batch that made it). Like ``K_cap`` and ``F`` it
+        only grows, and the caller warms the new shapes (a width is a
+        compiled shape). True where it grew."""
+        W = min(bucket_capacity(total), max(self.W_cap, self._cap_seen))
+        if W <= self.W_wide:
+            return False
+        self.W_wide = W
+        return True
 
     def _zero_fire(self, W: int):
         """Device-resident all-zero fire plan for non-firing steps
@@ -1118,37 +1286,43 @@ class FfatTPUReplica(TPUReplicaBase):
                 fire_pack_len(W, self.slide_units), dtype=np.int32))
         return z
 
+    def _fire_key(self):
+        return ("fire", self.K_cap, self.F, self._use_ktable(),
+                str(self._key_dtype))
+
     def _fire_step(self):
         from .ops_tpu import cached_compile
         return cached_compile(self._prog_cache, self.op._prog_lock,
-                              ("fire", self.K_cap, self.F,
-                               self._use_ktable(), str(self._key_dtype)),
-                              self._make_fire_step)
+                              self._fire_key(), self._make_fire_step)
 
     def _warm_fire_step(self) -> None:
-        """Compile the fire-only program EAGERLY (masked no-op run):
-        its first real use is mid-stream on a fire burst, and a ~0.5s
-        compile there would land inside the measured/latency-critical
-        path instead of startup."""
+        """Compile the fire-only program EAGERLY (masked no-op runs) at
+        the widths it is run at, W_cap and W_wide: its first real use is
+        mid-stream on a fire burst, and a ~0.5s compile there would land
+        inside the measured/latency-critical path instead of startup."""
         if self.trees is None:
             return
-        if ("fire", self.K_cap, self.F, self._use_ktable(),
-                str(self._key_dtype)) in self._prog_cache:
-            return  # already compiled (e.g. a new batch-capacity bucket)
-        # all-masked no-op run (both queries compile with the program,
-        # whichever runs); tvalid is DONATED, so reassign it
-        self.tvalid, *_ = self._fire_step()(
-            self.trees, self.tvalid, self._zero_fire(self.W_cap),
-            self._ktable_arg())
+        fkey = self._fire_key()
+        for W in sorted({self.W_cap, self.W_wide}):
+            if (fkey, W) in self._warm_shapes:
+                continue  # e.g. a new batch-capacity bucket
+            # all-masked no-op run (both queries compile with the
+            # program, whichever runs); tvalid is DONATED: reassign it
+            self.tvalid, *_ = self._fire_step()(
+                self.trees, self.tvalid, self._zero_fire(W),
+                self._ktable_arg())
+            self._warm_shapes.add((fkey, W))
 
     def _warm_programs(self, cap, ckey, ikey, fields, ktable) -> None:
         """Compile every program variant of a capacity bucket with no-op
         sentinel runs (every lane the composite's sentinel, zero fire
-        args): the full step at both fire-budget tiers, the ingest-only
-        deferred-rebuild step, the fire-only drain step, and the
-        standalone rebuild. All runs are semantic no-ops on the forest
-        (sentinel rows drop, rebuild is idempotent); trees/tvalid are
-        DONATED, so each run reassigns them."""
+        args): the full step at each fire width (W_step, W_cap, W_wide:
+        two distinct ones), the ingest-only deferred-rebuild step, the
+        fire-only drain step, and the standalone rebuild; called again
+        when W_wide has grown, it runs the new shapes alone. All runs
+        are semantic no-ops on the forest (sentinel rows drop, rebuild
+        is idempotent); trees/tvalid are DONATED, so each run reassigns
+        them."""
         from .ops_tpu import cached_compile
         step = cached_compile(self._prog_cache, self.op._prog_lock,
                               ckey, lambda: self._make_step(cap))
@@ -1163,13 +1337,18 @@ class FfatTPUReplica(TPUReplicaBase):
         # bucket must not pay a redundant full-forest rebuild execution
         M, cdt = self._comp_dtype()
         comp_s = np.full(cap, M, dtype=cdt)  # all-sentinel lanes
-        for W in {self.W_step, self.W_cap}:
+        for W in sorted({self.W_step, self.W_cap, self.W_wide}):
+            if (ckey, W) in self._warm_shapes:
+                continue
             (self.trees, self.tvalid, *_) = step(
                 fields, comp_s, self.trees, self.tvalid,
                 self._zero_fire(W), ktable)
-        (self.trees, self.tvalid, *_) = istep(
-            fields, comp_s, self.trees, self.tvalid,
-            self._zero_fire(self.W_step), ktable)
+            self._warm_shapes.add((ckey, W))
+        if (ikey, self.W_step) not in self._warm_shapes:
+            (self.trees, self.tvalid, *_) = istep(
+                fields, comp_s, self.trees, self.tvalid,
+                self._zero_fire(self.W_step), ktable)
+            self._warm_shapes.add((ikey, self.W_step))
         if rb is not None:
             self.trees, self.tvalid = rb(self.trees, self.tvalid)
 
@@ -1220,59 +1399,95 @@ class FfatTPUReplica(TPUReplicaBase):
 
     def _prep_step(self, fields, wm, cap, comp_p, frontier, bid: int = 0):
         """Host half of the per-batch step: program warm-up, the ENTIRE
-        fire plan — every drain iteration's chunk arrays and packed
-        fire/evict args, computed up front because ``_fireable`` reads
-        host metadata only (no control decision ever waits on a device
-        result) — and the fire-rate EWMA. Returns the device-commit
-        thunk for the dispatch pipeline."""
+        fire plan — every program's chunk arrays and packed fire/evict
+        args, computed up front because the planner reads host metadata
+        only (no control decision ever waits on a device result) — and,
+        for the operators that keep the tiers, the fire-rate EWMA.
+        Returns the device-commit thunk for the dispatch pipeline."""
         ktable = self._ktable_arg()
         ckey, ikey = self._step_keys(cap)
-        if ckey not in self._prog_cache or ikey not in self._prog_cache:
-            # first batch of this capacity bucket: compile EVERY program
-            # variant now (full both tiers, ingest-only, fire-only,
-            # standalone rebuild) so no later batch — firing or not —
-            # pays a mid-stream compile. The warm-up's no-op runs consume
-            # the live forest (donation), so in-flight commits land first
+        self._cap_seen = max(self._cap_seen, cap)
+
+        def warm():
+            # the warm-up's no-op runs consume the live forest
+            # (donation), so in-flight commits land first
             self.dispatch.drain(forced=True)
             self._warm_programs(cap, ckey, ikey, fields, ktable)
-        with self._st_fireplan(bid):
-            plan, total_fired = self._plan_fires(frontier)
-        # fast-rise / slow-decay: a burst switches to the wide tier on
-        # the very next batch (both tier shapes are already compiled),
-        # while decay back to the small tier is smoothed
-        if total_fired > self._fire_ewma:
-            self._fire_ewma = float(total_fired)
-        else:
-            self._fire_ewma += 0.25 * (total_fired - self._fire_ewma)
-        return lambda: self._commit_step(fields, wm, comp_p, ktable,
-                                         ckey, ikey, plan, bid)
 
-    def _plan_fires(self, frontier):
-        """The batch's whole fire plan (the ``wf:fireplan`` stage): one
-        entry per program, ``None`` for the ingest-only one."""
-        plan: List[Any] = []
-        first = True
-        total_fired = 0
-        first_budget = self._first_budget()
+        if (ikey not in self._prog_cache
+                or (ckey, self.W_wide) not in self._warm_shapes):
+            # first batch of this capacity bucket (or of a width grown
+            # by a dataless fire): compile EVERY program variant now
+            # (full at each width, ingest-only, fire-only, standalone
+            # rebuild) so no later batch — firing or not — pays a
+            # mid-stream compile
+            warm()
+        with self._st_fireplan(bid):
+            plan = [(i == 0,) + prog for i, prog in enumerate(
+                self._programs(frontier, False, self._first_budget(), warm))]
+        if not self._by_plan:
+            # fast-rise / slow-decay: a burst switches to the wide tier
+            # on the very next batch (both tier shapes are already
+            # compiled), while decay back to the small tier is smoothed
+            total_fired = sum(entry[2] for entry in plan)
+            if total_fired > self._fire_ewma:
+                self._fire_ewma = float(total_fired)
+            else:
+                self._fire_ewma += 0.25 * (total_fired - self._fire_ewma)
+        # nothing fireable: the ingest-only program (None in the plan),
+        # its rebuild DEFERRED to the next firing/rebuild program
+        return lambda: self._commit_step(fields, wm, comp_p, ktable,
+                                         ckey, ikey, plan or [None], bid)
+
+    def _programs(self, frontier, partial: bool, first_budget: int, warm):
+        """The programs that fire what is eligible now, one
+        ``(chunks, n_out, pack, n_groups, W)`` at a time, each advanced
+        past as it is yielded (so a caller may run one, and a snapshot
+        between two is consistent). ``warm()`` compiles the shapes of a
+        width that has just grown.
+
+        With the tiers: ``first_budget`` windows in the first program,
+        ``W_cap`` in those behind it, until one is not full. By the
+        plan: see _programs_by_plan."""
+        if self._by_plan:
+            yield from self._programs_by_plan(frontier, partial, warm)
+            return
+        budget = first_budget
         while True:
-            budget = first_budget if first else self.W_cap
-            chunks = self._fireable(frontier, False, budget)
+            chunks = self._fireable(frontier, partial, budget)
             n_out = int(chunks[2].sum())
-            if not first and not n_out:
-                break
-            if first and not n_out:
-                # nothing fireable: ingest-only program (None sentinel
-                # in the plan), rebuild DEFERRED to the next
-                # firing/rebuild program
-                plan.append(None)
-                break
-            pack, n_groups = self._pack_fire_arrays(chunks, n_out, budget)
-            plan.append((first, chunks, n_out, pack, n_groups, budget))
-            total_fired += n_out
-            first = False
+            if not n_out:
+                return
+            yield (chunks, n_out) + self._pack_fire_arrays(
+                chunks, budget) + (budget,)
             if n_out < budget:
-                break
-        return plan, total_fired
+                return
+            budget = self.W_cap
+
+    def _programs_by_plan(self, frontier, partial: bool, warm):
+        """Time-based windows with no budget given: everything eligible
+        leaves in ONE program where it fits the width and ``G_CAP``
+        ranges (_plan_program), in the step itself where there is one,
+        and the width follows the plans (_fit_width).
+
+        Soundness of many rounds in one program (eight consecutive
+        windows of every slot, each evicting what the next would have
+        read): a program's queries ALL read the forest as it stood
+        before the program's one eviction scatter, and past programs'
+        evictions are no concern of window ``w + 1`` whichever program
+        holds it, because its clipped range starts a slide past where
+        ``w`` started and ``w`` evicted only the panes before that
+        (see _make_fire_step: the argument is about ranges, not about
+        widths)."""
+        slots, k = self._eligible(frontier, partial)
+        total = int(k.sum())
+        if total > self.W_wide and self._fit_width(total):
+            warm()
+        while slots.size:
+            prog, take = self._plan_program(slots, k)
+            yield prog
+            k = k - take
+            slots, k = slots[k > 0], k[k > 0]
 
     def _commit_step(self, fields, wm, comp_p, ktable, ckey, ikey,
                      plan, bid: int) -> None:
@@ -1371,21 +1586,14 @@ class FfatTPUReplica(TPUReplicaBase):
         # dataless firing (handle_msg/terminate drain already, but
         # direct drivers — bench, profile scripts — reach here too)
         self.dispatch.drain(forced=True)
-        while True:
-            chunks = self._fireable(frontier, partial, self.W_cap)
-            n_out = int(chunks[2].sum())
-            if not n_out:
-                return
+        for chunks, n_out, pack, n_groups, W in self._programs(
+                frontier, partial, self.W_cap, self._warm_fire_step):
             self._ensure_rebuilt()
-            pack, n_groups = self._pack_fire_arrays(
-                chunks, n_out, self.W_cap)
             self.tvalid, qr, qv, wid_dev, key_dev = self._fire_step()(
                 self.trees, self.tvalid, pack, self._ktable_arg())
             self.stats.device_programs_run += 1
             self._emit_windows(self.cur_wm, chunks, n_out, qr, qv, wid_dev,
-                               key_dev, self.W_cap, n_groups)
-            if n_out < self.W_cap:
-                return
+                               key_dev, W, n_groups)
 
     def on_punctuation(self, wm: int) -> None:
         if self.op.win_type is WinType.TB:
