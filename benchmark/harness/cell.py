@@ -28,15 +28,20 @@ def load_module(path: str):
 
 
 class Cell:
-    def __init__(self, name: str, rehearse: bool = False):
+    def __init__(self, name: str, rehearse: bool = False,
+                 index: str = os.path.join(ROOT, "BENCHMARK.json"),
+                 workloads: str = os.path.join(BENCH_DIR, "workloads")):
+        """``index`` and ``workloads`` are the benchmark's own; the
+        harness's tests give a ``BENCHMARK.json``-shaped file and a
+        directory of their own for a configuration that is never
+        measured."""
         self.name, self.rehearse = name, rehearse
-        self.bench = load_json(os.path.join(ROOT, "BENCHMARK.json"))
+        self.bench = load_json(index)
         entry = [w for w in self.bench["workloads"] if w["name"] == name]
         if not entry:
             raise SystemExit(f"run.py: no cell {name!r} in BENCHMARK.json")
         self.entry = entry[0]
-        self.traffic = load_json(os.path.join(
-            BENCH_DIR, "workloads", name + ".json"))
+        self.traffic = load_json(os.path.join(workloads, name + ".json"))
         if self.traffic["config"] != self.entry["config"]:
             raise SystemExit(f"run.py: {name}: workload file and "
                              "BENCHMARK.json name different configurations")

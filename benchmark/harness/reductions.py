@@ -5,7 +5,8 @@ from __future__ import annotations
 
 import numpy as np
 
-from .windows import compare_results, table_rows
+from .windows import (compare_results, table_rows,
+                      time_windows_per_event)
 
 
 class Context:
@@ -27,8 +28,10 @@ class Context:
         self.offered_s = run.t_source_end - run.t_start
         self.events = run.offered.n_window * run.clock.rows
         self.blocks = run.offered.n_window
-        w = run.cfg["window"]
-        self.windows_per_event = max(1, w["win_us"] // w["slide_us"])
+        # result rows one counted event contributes to (it sizes ``failed``)
+        self.windows_per_event = int(getattr(
+            self.module, "windows_per_event", time_windows_per_event)(
+                run.cfg))
         self._run = run
         (self.at, self.key, self.wid, self.value,
          self.valid) = run.sink.columns()

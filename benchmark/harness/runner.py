@@ -16,14 +16,12 @@ import tempfile
 import threading
 import time
 
-import numpy as np
-
 from . import stats as stats_mod
 from . import trace as trace_mod
 from .cell import BENCH_DIR, ROOT, Cell, load_module
 from .sink import RecordingSink
 from .traffic import EventClock, Offered, Pusher
-from .windows import table_rows
+from .windows import time_results_due
 
 QUIET_S = 0.4            # no counter moved for this long: warm-up is done
 QUIET_TIMEOUT_S = 1100.0  # a cell's first run compiles
@@ -49,20 +47,24 @@ class Run:
         self.source_error = None
         self.quiet_timeout_s = QUIET_TIMEOUT_S
         self.stream = None
+        self.warm_due = None     # results the warm-up waited for
         self.mutate = None       # a test's fault on the window's blocks
 
     # -- set-up, in the source thread ---------------------------------
     def warm_results_due(self) -> int:
-        """Results the warm-up's own watermarks close: windows that hold a
-        warm-up event and end at or before the newest watermark a warm-up
-        block carried. Counted with the configuration's reference."""
-        off, w = self.offered, self.cfg["window"]
+        """Results the warm-up's own watermarks close: of the rows of the
+        configuration's reference over the warm-up blocks, those a correct
+        system has delivered once the watermark stands at the newest one
+        a warm-up block carried, the stream still open. The
+        configuration's ``results_due`` says which where its module has
+        one; else the rule of a time-based window."""
+        off = self.offered
         blocks = list(off.blocks())          # warm-up blocks only, so far
         table = self.cell.module.reference(iter(blocks), self.cfg,
                                            self.stream, off.last_ts)
-        _, wid, _ = table_rows(table)
-        end_us = wid * w["slide_us"] + w["win_us"]
-        return int((end_us <= int(blocks[-1][1][0]) - 1).sum())
+        due = getattr(self.cell.module, "results_due", time_results_due)
+        return int(due(table, blocks, self.cfg, self.stream,
+                       int(blocks[-1][1][0]) - 1))
 
     def wait_quiet(self, pushed_rows: int) -> None:
         """Warm-up is done when the first operator has taken every
@@ -70,7 +72,7 @@ class Run:
         close (so each program variant has run, and a compile, during
         which no counter moves, is not mistaken for quiet), and no
         counter has moved for ``QUIET_S``."""
-        due = self.warm_results_due()
+        due = self.warm_due = self.warm_results_due()
         deadline = time.perf_counter() + self.quiet_timeout_s
         last, since = None, time.perf_counter()
         while time.perf_counter() < deadline:
@@ -215,7 +217,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                 f"{tot['_last_compile']}")
     log(f"phases: imports+stream {t_stream - t_proc0:.2f} s, build "
         f"{t_built - t_stream:.2f} s, start+warm-up "
-        f"{run.t_start - t_built:.2f} s, offered "
+        f"{run.t_start - t_built:.2f} s ({run.warm_due} results due), "
+        f"offered "
         f"{run.t_source_end - run.t_start:.2f} s, last result "
         f"{t_end - run.t_source_end:+.2f} s, drained "
         f"{t_drained - run.t_source_end:+.2f} s after the source ended")

@@ -4,6 +4,9 @@ reference uses, and the comparison that decides ``correct``.
 Window ``w`` of every key is ``[w*slide, w*slide + win)`` from absolute
 time 0 (the program numbers time-based windows the same way), and at end
 of stream every window that holds a counted event has fired once.
+``time_results_due`` and ``time_windows_per_event`` are what the harness
+takes for a configuration whose module does not say them itself: the
+only two places outside a configuration that read ``cfg["window"]``.
 Nothing here imports the program.
 """
 
@@ -56,6 +59,23 @@ def table_rows(table):
     one per window that holds an event."""
     k, w = np.nonzero(table["count"])
     return k, w, table["value"][k, w]
+
+
+def time_results_due(table, blocks, cfg: dict, stream: dict,
+                     wm_us: int) -> int:
+    """The default ``results_due``, the rule of a time-based window: of
+    ``table``'s rows, those whose window ends at or before the watermark
+    ``wm_us`` (the others wait for the end-of-stream flush)."""
+    w = cfg["window"]
+    _, wid, _ = table_rows(table)
+    return int((wid * w["slide_us"] + w["win_us"] <= wm_us).sum())
+
+
+def time_windows_per_event(cfg: dict) -> int:
+    """The default ``windows_per_event``: a time-based sliding window
+    holds an event in ``win / slide`` windows."""
+    w = cfg["window"]
+    return max(1, w["win_us"] // w["slide_us"])
 
 
 def compare_results(expected, key, wid, value, valid) -> dict:

@@ -2,8 +2,12 @@
 least bytes the algorithm needs per batch (harness/roofline.py) over the
 chip's peak, against the device time of the operator's XLA modules per
 batch in the traced window. Bytes-bound (see roofline.py).
-params: {"modules": <regex over XLA module names>}. Nothing when no such
-module ran in the trace."""
+params: {"modules": <regex over XLA module names>, "fields": <4-byte words
+an aggregate holds, 1 where not given>}. Nothing when no such module ran
+in the trace. A reader for cells of TIME-based windows, which list it:
+it reads the configuration's ``window`` (``win_us``, ``slide_us``), the
+key a configuration that says ``results_due`` and ``windows_per_event``
+itself need not have."""
 
 import math
 
@@ -31,6 +35,7 @@ def read(ctx, params):
         rows=rows, keys_touched=min(n_keys, rows),
         panes_per_batch=block_us / pane + 1,
         fired=ctx.fired_in_window() / batches,
-        ring=roofline.ring_size(wu, su), win_units=wu)
+        ring=roofline.ring_size(wu, su), win_units=wu,
+        fields=params.get("fields", 1))
     peak = roofline.peaks(ctx.device["kind"])["hbm_bytes_per_s"]
     return need / peak / per_batch_s * 100.0

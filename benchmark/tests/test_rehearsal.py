@@ -2,7 +2,13 @@
 the result's shape, ``correct`` true, the control not correct, and
 ``correct`` false with the timed path broken underneath the harness. No
 number here is a device number (every metric name ends in
-``.cpu_rehearsal``)."""
+``.cpu_rehearsal``).
+
+Beside the benchmark's cells runs ``cb8.saturated``, a test-only
+deployment under ``tests/data`` with an index and a workload file of its
+own: a count-based window and a filter after it, whose module says
+``results_due`` and ``windows_per_event`` and whose file has no ``window``
+key. It proves that such a deployment is files alone."""
 
 import json
 import os
@@ -16,13 +22,21 @@ import pytest
 from harness.cell import BENCH_DIR, ROOT, Cell
 from harness.runner import run_cell
 
+DATA = os.path.join(BENCH_DIR, "tests", "data")
+# test-only cells: their index and the directory of their workload files
+TEST_ONLY = {"cb8.saturated": (os.path.join(DATA, "index.json"),
+                               os.path.join(DATA, "workloads"))}
 with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-    CELLS = [w["name"] for w in json.load(f)["workloads"]]
+    CELLS = [w["name"] for w in json.load(f)["workloads"]] + list(TEST_ONLY)
 KEYS = ["correct", "attempted", "failed", "metrics", "device"]
 
 
+def make_cell(name, rehearse=False):
+    return Cell(name, rehearse, *TEST_ONLY.get(name, ()))
+
+
 def rehearse(name, seed=2_147_483_659, seconds=1.5, trace=False, **kw):
-    return run_cell(Cell(name, rehearse=True), seed, seconds, trace,
+    return run_cell(make_cell(name, True), seed, seconds, trace,
                     time.perf_counter(), log=lambda m: None, **kw)
 
 
@@ -31,7 +45,7 @@ def test_cell_is_correct_and_control_is_not(name):
     r = rehearse(name, control=True)
     assert list(r)[:5] == KEYS and list(r)[-1] == "compared"
     assert r["correct"] is True and r["failed"] == 0 and r["attempted"] > 0
-    cell = Cell(name)
+    cell = make_cell(name)
     assert set(r["metrics"]) == {m["name"] + ".cpu_rehearsal"
                                  for m, _ in cell.metrics("end_to_end")}
     for c, limit in cell.cfg["limits"].items():
@@ -43,7 +57,7 @@ def test_cell_is_correct_and_control_is_not(name):
 def test_traced_run_reports_per_layer_metrics(name):
     r = rehearse(name, seed=11, seconds=2.0, trace=True)
     assert r["correct"] is True
-    names = {m["name"] + ".cpu_rehearsal" for m, _ in Cell(name).metrics("per_layer")}
+    names = {m["name"] + ".cpu_rehearsal" for m, _ in make_cell(name).metrics("per_layer")}
     # trace-sourced metrics read nothing on the CPU backend and are left
     # out; a reader never returns 0 for a share
     assert set(r["metrics"]) <= names
@@ -53,7 +67,7 @@ def test_traced_run_reports_per_layer_metrics(name):
 
 
 def _alter_value(cell_name):
-    col = Cell(cell_name).cfg["result"]["value"]
+    col = make_cell(cell_name).cfg["result"]["value"]
     seen = []
 
     def fault(cols):

@@ -53,13 +53,28 @@ def test_counter_ratio_present_is_the_ratio_or_nothing():
 
 
 def test_sg2_metrics_are_reported_by_the_sg2_cell_alone():
+    """Follows ``BENCHMARK.json``: however many ``.sg2`` metrics it lists,
+    each has its file, lists ``sg2.saturated`` alone and moves
+    ``events_per_s``."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     sg2 = [m for m in bench["per_layer"] if m["name"].endswith(".sg2")]
-    assert len(sg2) == 9
+    assert sg2
     for m in sg2:
         assert m["workloads"] == ["sg2.saturated"]
         assert m["moves"] == "events_per_s"
+        with open(os.path.join(BENCH_DIR, "metrics",
+                               m["name"] + ".json")) as f:
+            spec = json.load(f)
+        assert spec["name"] == m["name"]
+        assert os.path.exists(os.path.join(BENCH_DIR, "metrics",
+                                           spec["reader"]))
+    # and no metric file is left without its entry
+    listed = {m["name"] for g in ("end_to_end", "per_layer")
+              for m in bench[g]}
+    files = {f[:-5] for f in os.listdir(os.path.join(BENCH_DIR, "metrics"))
+             if f.endswith(".json")}
+    assert files == listed
     mine = {m["name"] for m, _ in Cell("sg2.saturated").metrics("per_layer")}
     other = {m["name"] for m, _ in Cell("ysb.saturated").metrics("per_layer")}
     assert {m["name"] for m in sg2} <= mine
@@ -69,3 +84,31 @@ def test_sg2_metrics_are_reported_by_the_sg2_cell_alone():
             "launch_us_per_program.sat"} <= mine & other
     for w in bench["workloads"]:
         assert len(w["why"]) <= 200, w["name"]
+
+
+def test_window_step_roofline_counts_the_fields_its_file_names():
+    """``fields`` of the metric's params reaches ``window_step_bytes``:
+    with sg2's sizes the share of two words a node is the one-word share
+    times the ratio of the bytes, and ``.sg2``'s file says 2."""
+    from harness import roofline
+    read = reader("window_step_roofline.py").read
+    cfg = Cell("sg2.saturated").cfg
+    c = ctx({"window_s": 3.0, "modules": [["jit_step", 2.75]]},
+            {"win": {}}, {"win": {"Device_batches_in": 2400,
+                                  "Inputs_received": 2400 * 32768}},
+            {"window": "win"})
+    c.cfg, c.offered_s, c.device = cfg, 30.0, {"kind": "TPU v5 lite"}
+    c.clock = types.SimpleNamespace(rows=32768, rate=4250)
+    c.fired_in_window = lambda: 2400 * 16400
+    params = {"modules": "^jit_(step|fire|rebuild)"}
+    one, two = read(c, params), read(c, {**params, "fields": 2})
+    kw = dict(rows=32768, keys_touched=2125,
+              panes_per_batch=32768 / 4250 + 1, fired=16400,
+              ring=roofline.ring_size(3600, 1), win_units=3600)
+    assert two / one == pytest.approx(
+        roofline.window_step_bytes(**kw, fields=2)
+        / roofline.window_step_bytes(**kw))
+    assert 1.6 < two / one < 2.0 and 0 < two < 1.0
+    with open(os.path.join(BENCH_DIR, "metrics",
+                           "window_step_roofline.sg2.json")) as f:
+        assert json.load(f)["params"]["fields"] == 2
