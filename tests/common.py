@@ -14,6 +14,8 @@ import random
 import threading
 from dataclasses import dataclass
 
+import numpy as np
+
 
 @dataclass
 class TupleT:
@@ -160,3 +162,51 @@ class DictWinCollector:
             if k in self.results:
                 self.dups += 1
             self.results[k] = r["value"] if r["valid"] else None
+
+
+class ColumnRows:
+    """Columnar sink that keeps every column of every call."""
+
+    def __init__(self):
+        self.calls, self.eos = [], 0
+
+    def __call__(self, cols, ts):
+        if cols is None:
+            self.eos += 1
+        else:
+            self.calls.append({k: np.array(v) for k, v in cols.items()})
+
+    def columns(self):
+        return {k: np.concatenate([c[k] for c in self.calls])
+                for k in self.calls[0]}
+
+
+def run_benchmark_config(cell_name: str, blocks: int, seed: int, **config):
+    """``blocks`` blocks of a benchmark cell at its rehearsal sizes (with
+    ``config`` laid over) through the configuration's own graph, on the
+    harness's own clock and pusher; the delivered columns, the cell, its
+    stream, what was offered and the replicas' stats by operator. The
+    caller has put ``benchmark/`` on ``sys.path``."""
+    from harness.cell import Cell
+    from harness.traffic import EventClock, Offered, Pusher
+
+    cell = Cell(cell_name, rehearse=True)
+    cell.cfg.update(config)
+    stream = cell.module.make_stream(seed, cell.cfg, cell.traffic)
+    clock = EventClock(cell.cfg["batch_rows"], cell.traffic)
+    offered = Offered(stream["pool"], clock)
+
+    def source(shipper, ctx=None):
+        pusher = Pusher(shipper)
+        for b in range(blocks):
+            pusher.push(offered.cols(b), clock.warm_ts(b))
+            offered.n_warm = b + 1
+
+    out = ColumnRows()
+    graph, roles = cell.module.build_graph(source, out, cell.cfg, stream)
+    graph.run()
+    stats = {o["name"]: o["replicas"][0]
+             for o in graph.get_stats()["Operators"]}
+    return {"cols": out.columns(), "cell": cell, "stream": stream,
+            "offered": offered, "stats": stats, "roles": roles,
+            "eos": out.eos}

@@ -17,52 +17,17 @@ if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
 from harness.cell import Cell  # noqa: E402
-from harness.traffic import EventClock, Offered, Pusher  # noqa: E402
 from harness.windows import table_rows  # noqa: E402
 
+from common import run_benchmark_config  # noqa: E402
+
 BLOCKS, SEED = 14, 2_147_483_659
-
-
-class Rows:
-    """Columnar sink that keeps every column of every call."""
-
-    def __init__(self):
-        self.calls, self.eos = [], 0
-
-    def __call__(self, cols, ts):
-        if cols is None:
-            self.eos += 1
-        else:
-            self.calls.append({k: np.array(v) for k, v in cols.items()})
-
-    def columns(self):
-        return {k: np.concatenate([c[k] for c in self.calls])
-                for k in self.calls[0]}
 
 
 def run_sg2(**config):
     """BLOCKS blocks through the configuration's own graph; the delivered
     columns, the cell, its stream and what was offered."""
-    cell = Cell("sg2.saturated", rehearse=True)
-    cell.cfg.update(config)
-    stream = cell.module.make_stream(SEED, cell.cfg, cell.traffic)
-    clock = EventClock(cell.cfg["batch_rows"], cell.traffic)
-    offered = Offered(stream["pool"], clock)
-
-    def source(shipper, ctx=None):
-        pusher = Pusher(shipper)
-        for b in range(BLOCKS):
-            pusher.push(offered.cols(b), clock.warm_ts(b))
-            offered.n_warm = b + 1
-
-    out = Rows()
-    graph, roles = cell.module.build_graph(source, out, cell.cfg, stream)
-    graph.run()
-    stats = {o["name"]: o["replicas"][0]
-             for o in graph.get_stats()["Operators"]}
-    return {"cols": out.columns(), "cell": cell, "stream": stream,
-            "offered": offered, "stats": stats, "roles": roles,
-            "eos": out.eos}
+    return run_benchmark_config("sg2.saturated", BLOCKS, SEED, **config)
 
 
 @pytest.fixture(scope="module")

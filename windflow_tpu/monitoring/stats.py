@@ -48,12 +48,14 @@ class StatsRecord(StageCounters):
         "device_bytes_h2d", "device_bytes_d2h", "device_programs_run",
         # window operators (tpu/ffat_tpu.py): windows fired (a row each,
         # empty ones too) and the programs that answered them (a full
-        # step with its fire block, or a fire-only program); of those,
+        # step with its fire block, or a fire-only program) with their
+        # widths summed (the lanes a program runs, live or masked: what a
+        # walk by lane costs by); of those,
         # the programs that answered per distinct ring range over every
         # key slot at once, and the ranges summed over them; and the
         # programs the planner closed at a whole round because one more
         # would have passed G_CAP distinct ranges
-        "windows_fired", "fire_programs",
+        "windows_fired", "fire_programs", "fire_lanes",
         "fire_grouped_programs", "fire_groups", "fire_range_cuts",
         "staging_pool_hits", "staging_pool_misses",
         "dispatch_host_prep_us", "dispatch_commit_us",
@@ -184,6 +186,7 @@ class StatsRecord(StageCounters):
         self.device_programs_run = 0
         self.windows_fired = 0
         self.fire_programs = 0
+        self.fire_lanes = 0
         self.fire_grouped_programs = 0
         self.fire_groups = 0
         self.fire_range_cuts = 0
@@ -531,6 +534,7 @@ class StatsRecord(StageCounters):
             "Device_programs_run": self.device_programs_run,
             "Windows_fired": self.windows_fired,
             "Fire_programs": self.fire_programs,
+            "Fire_lanes": self.fire_lanes,
             "Fire_grouped_programs": self.fire_grouped_programs,
             "Fire_groups": self.fire_groups,
             "Fire_range_cuts": self.fire_range_cuts,

@@ -1531,7 +1531,8 @@ class FfatTPUReplica(TPUReplicaBase):
 
     def _emit_windows(self, wm, chunks, n_out, qr, qv, wid_dev, key_dev,
                       W: int, n_groups: int, cause: int = 0) -> None:
-        """``n_groups``: the distinct ring ranges the program answered by
+        """``W``: the width of the program that ran (its lanes, live or
+        masked). ``n_groups``: the distinct ring ranges it answered by
         range, 0 where it walked by lane. ``cause``: the id of the input
         batch whose commit fired these windows (0 for a dataless fire: a
         punctuation or EOS made them)."""
@@ -1539,6 +1540,7 @@ class FfatTPUReplica(TPUReplicaBase):
 
         op = self.op
         self.stats.fire_programs += 1
+        self.stats.fire_lanes += W
         self.stats.windows_fired += n_out
         if n_groups:
             self.stats.fire_grouped_programs += 1
