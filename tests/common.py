@@ -185,7 +185,7 @@ def run_benchmark_config(cell_name: str, blocks: int, seed: int, **config):
     """``blocks`` blocks of a benchmark cell at its rehearsal sizes (with
     ``config`` laid over) through the configuration's own graph, on the
     harness's own clock and pusher; the delivered columns, the cell, its
-    stream, what was offered and the replicas' stats by operator. The
+    stream, what was offered, the replicas' stats by operator and the graph. The
     caller has put ``benchmark/`` on ``sys.path``."""
     from harness.cell import Cell
     from harness.traffic import EventClock, Offered, Pusher
@@ -209,4 +209,4 @@ def run_benchmark_config(cell_name: str, blocks: int, seed: int, **config):
              for o in graph.get_stats()["Operators"]}
     return {"cols": out.columns(), "cell": cell, "stream": stream,
             "offered": offered, "stats": stats, "roles": roles,
-            "eos": out.eos}
+            "eos": out.eos, "graph": graph}

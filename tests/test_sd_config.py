@@ -201,7 +201,9 @@ def test_counters_of_the_window_operator(sd):
     assert win["Fire_lanes"] >= win["Windows_fired"] > 0
     assert 0 < win["Fire_programs"] <= win["Device_programs_run"]
     # count-based windows start at a per-mote arrival index: no two motes
-    # share a ring range, so every program walks by lane
+    # share a ring range, so no program walks by range; a mote's windows
+    # of a program are consecutive ranges of its own ring, answered by
+    # sliding scan where the rule says so (PR 33)
     assert win["Fire_grouped_programs"] == 0 == win["Fire_groups"]
     assert win["Fire_range_cuts"] == 0
     assert win["Fire_plan_total_usec"] > 0
@@ -210,16 +212,38 @@ def test_counters_of_the_window_operator(sd):
     assert sd["stats"]["narrow"]["Fire_lanes"] == 0
 
 
+def test_sliding_programs_are_the_ones_the_rule_names(sd):
+    """``Fire_sliding_programs`` is ``Fire_programs`` minus the programs
+    the rule left on the lane walk. At the rehearsal sizes (8 slots, a
+    ring grown from 128 leaves to 256) both tiers of the budget slide;
+    at the cell's sizes they do too (16,384 and 64 lanes over 64 x 2,048
+    leaves), and a 4,096-slot operator's 64-lane program does not."""
+    from windflow_tpu.tpu.ffat_tpu import fire_slides
+
+    win = sd["stats"]["win"]
+    rep = [n for w in sd["graph"]._workers for n in w.chain
+           if getattr(getattr(n, "op", None), "name", None) == "win"][0]
+    assert (rep.K_cap, rep.F, rep.W_step, rep.W_cap) == (8, 256, 64, 512)
+    assert all(fire_slides(W, 8, F) for W in (64, 512) for F in (128, 256))
+    assert win["Fire_sliding_programs"] == win["Fire_programs"] > 4
+    assert all(fire_slides(W, 64, F) for W in (64, 16384)
+               for F in (1024, 2048))
+    assert not fire_slides(64, 4096, 2048)
+    assert sd["stats"]["narrow"]["Fire_sliding_programs"] == 0
+
+
 @pytest.mark.parametrize("name,low,high,new", [
-    ("fire_lane_occupancy.sd", 80.0, 100.0, True),
-    ("fire_lanes_per_program.sd", 64.0, 512.0, True),
-    ("windows_per_fire_program.sd", 64.0, 512.0, False),
-    ("fire_programs_per_batch.sd", 1.0, 2.0, False),
-    ("filter_pass_share.sd", 2.0, 8.0, False)])
+    ("fire_lane_occupancy.sd", 80.0, 100.0, "Fire_lanes"),
+    ("fire_lanes_per_program.sd", 64.0, 512.0, "Fire_lanes"),
+    ("fire_sliding_share.sd", 100.0, 100.0, "Fire_sliding_programs"),
+    ("windows_per_fire_program.sd", 64.0, 512.0, None),
+    ("fire_programs_per_batch.sd", 1.0, 2.0, None),
+    ("filter_pass_share.sd", 2.0, 8.0, None)])
 def test_the_sd_metrics_read_the_counters(sd, name, low, high, new):
     """The counter metrics of the cell read this run's stats inside their
-    range; the two that read ``Fire_lanes`` (``new``) give nothing, not
-    0, for a program from before the counter existed."""
+    range; those that read a counter a later PR brought (``new``:
+    ``Fire_lanes``, PR 32; ``Fire_sliding_programs``, PR 33) give
+    nothing, not 0, for a program from before the counter existed."""
     cell = sd["cell"]
     entry, spec = [(m, f) for m, f in cell.metrics("per_layer")
                    if m["name"] == name][0]
@@ -237,7 +261,7 @@ def test_the_sd_metrics_read_the_counters(sd, name, low, high, new):
             stats=StatsWindow(zeros, end, sd["roles"]))
 
     assert low <= read(ctx(sd["stats"]), spec["params"]) <= high
-    old = {op: {k: v for k, v in st.items() if k != "Fire_lanes"}
+    old = {op: {k: v for k, v in st.items() if k != new}
            for op, st in sd["stats"].items()}
     if new:
         assert spec["reader"] == "counter_ratio_present.py"
@@ -251,7 +275,7 @@ def test_every_sd_metric_has_its_file_and_lists_the_cell_alone():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     mine = [m for m in bench["per_layer"] if m["name"].endswith(".sd")]
-    assert len(mine) == 9
+    assert len(mine) == 10 and mine[-1]["name"] == "fire_sliding_share.sd"
     assert not any(m["name"].startswith("fire_grouped_share")
                    for m in mine)
     for m in mine:

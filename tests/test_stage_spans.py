@@ -200,9 +200,13 @@ def test_window_operator_counts_its_fires_and_plans(served):
         <= win["Device_programs_run"]
     assert 0 < win["Fire_plan_total_usec"] <= \
         win["Dispatch_host_prep_total_usec"]
+    # the sliding scan is count-based windows' (PR 33): a time-based
+    # operator has the counter and never moves it
+    assert win["Fire_sliding_programs"] == 0
     for op, rep in served["stats"].items():
         if op != "win":
             assert rep["Windows_fired"] == 0 == rep["Fire_programs"], op
+            assert rep["Fire_sliding_programs"] == 0, op
 
 
 def test_fire_plan_is_a_span_inside_the_window_prep(served):
@@ -378,6 +382,47 @@ def test_window_programs_keep_the_names_the_roofline_metric_reads(served):
     assert names == {"step", "fire", "rebuild"}
     # XLA names a module jit_<function name>
     assert all(pattern.search("jit_" + n) for n in names)
+
+
+def test_time_based_programs_take_the_pack_and_the_arguments_they_took(
+        served):
+    """PR 33 gave count-based windows a third query and the pack's row 5
+    a second reading (their lanes' rounds); a time-based operator's plan
+    is the words it was (``(6 + 3 slide) W + 2 (G_CAP + 1)``, row 5 the
+    lane's group), its programs take the same arguments, and the rule
+    that chooses the scan is never asked about it."""
+    import inspect
+
+    from windflow_tpu.tpu import ffat_tpu
+
+    replica = _replica(served["graph"], "win")
+    assert replica.slide_units == 1 and ffat_tpu.G_CAP == 32
+    for W in (8, 64, 32768):
+        assert ffat_tpu.fire_pack_len(W, 1) == 9 * W + 66
+        assert ffat_tpu.fire_pack_len(W, 3) == 15 * W + 66
+        fire, groups, evict = ffat_tpu.fire_pack_views(
+            np.zeros(ffat_tpu.fire_pack_len(W, 3), np.int32), 3)
+        assert (fire.shape, groups.shape, evict.shape) == (
+            (6, W), (33, 2), (3, 3 * W))
+    by_name = {p._wrapped_jit.__name__: p._wrapped_jit
+               for p in replica._prog_cache.values()
+               if hasattr(p, "_wrapped_jit")}
+    args = {n: list(inspect.signature(f).parameters)
+            for n, f in by_name.items()}
+    assert args == {
+        "step": ["fields", "comp", "trees", "tvalid", "fire_plan", "ktable"],
+        "fire": ["trees", "tvalid", "fire_plan", "ktable"],
+        "rebuild": ["trees", "tvalid"]}
+    # a plan of this operator: two keys' next windows, one ring range
+    for k in range(2):
+        replica._keymap.slot(1000 + k)
+    chunks = (np.arange(2), np.full(2, 40), np.ones(2, np.int64),
+              np.full(2, 10), np.full(2, 43))
+    pack, n_groups = replica._pack_fire_arrays(chunks, 8)
+    fire, groups, _ = ffat_tpu.fire_pack_views(pack, 1)
+    assert pack.dtype == np.int32 and pack.size == 9 * 8 + 66
+    assert n_groups == 1 == groups[32, 0]
+    assert fire[5].tolist() == [0] * 8 and fire[4].tolist() == [1, 1] + [0] * 6
 
 
 def test_fused_chain_program_carries_its_operators_names(served):

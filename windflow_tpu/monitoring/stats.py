@@ -54,9 +54,12 @@ class StatsRecord(StageCounters):
         # the programs that answered per distinct ring range over every
         # key slot at once, and the ranges summed over them; and the
         # programs the planner closed at a whole round because one more
-        # would have passed G_CAP distinct ranges
+        # would have passed G_CAP distinct ranges; and the programs of
+        # count-based windows that answered by sliding scan (two block
+        # scans over every leaf) and not by lane
         "windows_fired", "fire_programs", "fire_lanes",
         "fire_grouped_programs", "fire_groups", "fire_range_cuts",
+        "fire_sliding_programs",
         "staging_pool_hits", "staging_pool_misses",
         "dispatch_host_prep_us", "dispatch_commit_us",
         "dispatch_stalls", "dispatch_depth_max",
@@ -190,6 +193,7 @@ class StatsRecord(StageCounters):
         self.fire_grouped_programs = 0
         self.fire_groups = 0
         self.fire_range_cuts = 0
+        self.fire_sliding_programs = 0
         self.staging_pool_hits = 0  # recycled staging buffers (ArrayPool)
         self.staging_pool_misses = 0
         # device-ahead dispatch pipeline (runtime/dispatch.py): per-stage
@@ -538,6 +542,7 @@ class StatsRecord(StageCounters):
             "Fire_grouped_programs": self.fire_grouped_programs,
             "Fire_groups": self.fire_groups,
             "Fire_range_cuts": self.fire_range_cuts,
+            "Fire_sliding_programs": self.fire_sliding_programs,
             "Fused_ops": self.fused_ops,
             "Staging_pool_hits": self.staging_pool_hits,
             "Staging_pool_misses": self.staging_pool_misses,

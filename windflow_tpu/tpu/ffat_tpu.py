@@ -27,7 +27,12 @@ TPU-first redesign:
     nodes with ordered left/right accumulators, safe for non-commutative
     combines): ONE walk per distinct ring range over every key slot at
     once where the program's windows share a few ranges (time-based
-    windows planned by rounds do), else one walk a window under vmap
+    windows planned by rounds do), else one walk a window under vmap;
+    count-based windows, whose ranges are per key but CONSECUTIVE within
+    a key, leave the walk where a program holds many of them: two block
+    scans over every ring's leaves answer every window of every key at
+    once (a sliding aggregate; ``fire_slides`` is the rule, by the
+    program's lanes against the forest's leaves)
     -> leaf eviction;
 - the width of a program's fire block (its lanes) is the user's
   ``num_win_per_batch`` where one was given, honoured as given, in two
@@ -90,6 +95,31 @@ SCOPE_REBUILD, SCOPE_FIRE, SCOPE_EVICT = "level_rebuild", "fire", "evict"
 # flush needs half the programs, and every step takes 0.35 ms longer
 G_CAP = 32
 
+# count-based windows: a program of W lanes over a (K_cap, 2F) forest
+# answers BY SLIDING SCAN (two block scans over every leaf, see
+# _query_fns) where ``W * SLIDE_X >= K_cap * F``, else by lane. The walk
+# costs by the lane, the scan by the forest's leaves; SLIDE_X is how many
+# leaves the scan passes in the time of one lane's walk. 2048 (PR 33,
+# probe of the fire-only program on the chip, three tree fields, PERF.md
+# section 6; ms a call, by lane / by scan): 64 slots x 2,048 leaves at
+# 64 / 1,024 / 16,384 lanes 0.83 / 2.44 / 46.2 against 1.05 / 0.94 /
+# 1.03 (even at ~150 lanes: X ~900, mostly the programs' fixed costs);
+# 4,096 slots x 2,048 at 64 / 4,096 / 16,384 lanes 0.87 / 12.0 / 47.7
+# against 12.7 / 13.0 / 13.5 (even at ~4,250 lanes: X ~2,000). The walk
+# is 2.8-2.9 us a lane, the scan 1.5-1.6 ns a leaf over a big forest. At
+# 2048 the worse choice costs x1.26 at most (0.2 ms, a 64-lane program
+# over the small forest); at 1024 an 8,000-lane program over the big
+# forest would walk for 23 ms where the scan takes 13
+SLIDE_X = 2048
+
+
+def fire_slides(W: int, K_cap: int, F: int) -> bool:
+    """Whether a count-based program of ``W`` lanes over ``K_cap`` rings
+    of ``F`` leaves answers by sliding scan (``SLIDE_X``): one comparison
+    of static shapes, made where the program is traced and where its
+    counters are kept."""
+    return W * SLIDE_X >= K_cap * F
+
 
 def fire_pack_len(W: int, slide_units: int) -> int:
     """Words of the ONE int32 buffer that carries a program's fire plan
@@ -100,11 +130,13 @@ def fire_pack_len(W: int, slide_units: int) -> int:
 def fire_pack_views(pack, slide_units: int):
     """``(fire, groups, evict)`` views of a program's flat fire plan, on
     the host (numpy, to fill it) and inside the program (static slices):
-    ``fire`` (6, W) rows slot, start, len, wid, mask, group; ``groups``
-    (G_CAP + 1, 2) the distinct ``(start_phys, length)`` pairs of the
-    lanes and, in row ``G_CAP``, their count; ``evict`` (3, W *
-    slide_units) rows slot, leaf, mask. One buffer, so one transfer a
-    program: a launch pays for every host argument it is handed."""
+    ``fire`` (6, W) rows slot, start, len, wid, mask, group (of a
+    count-based plan, which has no groups: the lane's round, its window's
+    place in its slot's chunk); ``groups`` (G_CAP + 1, 2) the distinct
+    ``(start_phys, length)`` pairs of the lanes and, in row ``G_CAP``,
+    their count; ``evict`` (3, W * slide_units) rows slot, leaf, mask.
+    One buffer, so one transfer a program: a launch pays for every host
+    argument it is handed."""
     n_g = 2 * (G_CAP + 1)
     W = (pack.shape[0] - n_g) // (6 + 3 * slide_units)
     return (pack[:6 * W].reshape(6, W),
@@ -355,9 +387,13 @@ class FfatTPUReplica(TPUReplicaBase):
         values, valid, wid column, key column)``: what the full step and
         the fire-only step do with a program's fire plan
         (``fire_pack_views``): answer its fired windows, evict the leaves
-        they consumed, build the ``wid`` and key columns. Two queries
-        answer the windows, chosen inside the program by the count in the
-        plan's group table (see _pack_fire_arrays):
+        they consumed, build the ``wid`` and key columns. Three queries
+        answer the windows. A program holds the ones its operator can
+        take: time-based windows the first two, chosen inside the
+        program by the count in the plan's group table (see
+        _pack_fire_arrays); count-based windows ONE of the first and the
+        third, chosen where the program is traced by its static shapes
+        (``fire_slides``):
 
         - the LANE walk (``window_query`` under ``vmap``): every lane
           walks its own slot's tree, a node read is a gather of one
@@ -370,13 +406,33 @@ class FfatTPUReplica(TPUReplicaBase):
           forest. Lane ``i`` then picks ``table[group[i], slot[i]]``.
           Time-based windows number from absolute time 0, so the lanes
           of a program planned by rounds (_fireable) share one to three
-          ranges.
+          ranges;
+        - the SLIDING SCAN (``by_scan``), count-based windows only. No
+          two keys share a ring range, but a program holds ONE chunk a
+          slot, ``k`` consecutive windows ``start0 + j * slide``
+          (_take): a sliding aggregate over the slot's leaves (van Herk,
+          Gil-Werman). Every ring is rotated so that its chunk's first
+          leaf is column 0, the row is cut into blocks of ``win_units``
+          columns, and two block scans give the prefix aggregate ``P``
+          and the suffix aggregate ``S`` of every column within its
+          block; round ``j``'s window ``[o, o + win)``, ``o = j *
+          slide``, is ``S[o]`` then ``P[o + win - 1]``, or one of them
+          alone where the window is a block or the row's end cuts it.
+          Lane ``i`` picks ``table[slot[i], round[i]]``. It costs by the
+          forest's leaves, not by the lane, and reads LEAVES only: the
+          leaves past a slot's data are invalid (evicted, or never
+          written: _grow_ring keeps a slot's live span under ``F``), so
+          validity bounds a partial window and the plan's lengths are
+          not read.
 
-        Both run the same ``l``/``r`` recurrence with the same left and
-        right accumulators through ``comb_valid``: the same order of
+        The walks run the same ``l``/``r`` recurrence with the same left
+        and right accumulators through ``comb_valid``: the same order of
         combination, so the same bits for any combine, commutative or
         not (where ``valid`` is False the values are whatever the walk
-        left, in both)."""
+        left, in both). The scan combines the same leaves in the same
+        ORDER under another parenthesisation, which an associative
+        combine allows: exact for exact arithmetic (whole-number float32
+        sums too), within rounding for float sums."""
         import jax
         import jax.numpy as jnp
 
@@ -391,6 +447,7 @@ class FfatTPUReplica(TPUReplicaBase):
         # count-based windows start at a per-key arrival index: no two
         # keys share a ring range, so their programs hold no group walk
         grouped = self.op.win_type is WinType.TB
+        win_units = self.win_units
 
         def comb_valid(va, a, vb, b):
             """Ordered combine with validity: an invalid side passes the
@@ -474,6 +531,81 @@ class FfatTPUReplica(TPUReplicaBase):
             tv, tr = jax.lax.fori_loop(0, g_table[G_CAP, 0], one, tabs)
             return tv[group, slots], tmap(lambda t: t[group, slots], tr)
 
+        def by_scan(trees, tvalid, slots, rounds, starts, mask):
+            # 1. every ring in the order of its chunk: column ``o`` of
+            # row ``slot`` is leaf ``start0 + o``. ``base`` is the
+            # chunk's first window's place in the ring, from its lane of
+            # round 0 (0 for a slot that fires nothing here); the
+            # rotation is log2 F conditional rolls, dense passes
+            first = mask & (rounds == 0)
+            base = jnp.zeros((K_cap,), jnp.int32).at[
+                jnp.where(first, slots, K_cap)].set(starts, mode="drop")
+            rows = tmap(lambda t: t[:, F:], trees)
+            rvalid = tvalid[:, F:]
+            bit = 1
+            while bit < F:
+                turn = ((base & bit) != 0)[:, None]
+                rows, rvalid = tmap(
+                    lambda t: jnp.where(turn, jnp.roll(t, -bit, axis=1), t),
+                    (rows, rvalid))
+                bit <<= 1
+            # 2. the two block scans, blocks of ``win_units`` columns
+            # from column 0 (the last one short: F is no multiple of
+            # win), by doubling: in step ``d`` a column takes in the fold
+            # that ends (starts) ``d`` columns before (after) it, where
+            # that column is still in its block. The blocks are static,
+            # so the masks are constants and no edge flag rides a carry
+            # (log2 win passes; an unrolled ``associative_scan`` does
+            # less work and is x1.3 faster over a 109 MB forest, but its
+            # program is four times the size: sd's warm-up loaded it
+            # 2.4 s longer at each ring, PERF.md section 6)
+            col = np.arange(F)
+            before = col % win_units
+            after = np.minimum((col // win_units + 1) * win_units,
+                               F) - 1 - col
+
+            def shifted(t, d):  # column i holds column i - d
+                pad = jnp.zeros((K_cap, abs(d)), t.dtype)
+                return jnp.concatenate(
+                    [pad, t[:, :-d]] if d > 0 else [t[:, -d:], pad], axis=1)
+
+            pv, pre, sv, suf = rvalid, rows, rvalid, rows
+            d = 1
+            while d < win_units:
+                reach_b, reach_a = (jnp.asarray(r >= d)[None, :]
+                                    for r in (before, after))
+                pv, pre = comb_valid(
+                    shifted(pv, d) & reach_b,
+                    tmap(lambda t: shifted(t, d), pre), pv, pre)
+                sv, suf = comb_valid(
+                    sv, suf, shifted(sv, -d) & reach_a,
+                    tmap(lambda t: shifted(t, -d), suf))
+                d *= 2
+            # 3. round j's window [o, e], o = j * slide (every o < F: a
+            # slot's live span is under F), static: strided slices
+            o = np.arange(0, F, slide_units)
+            e = np.minimum(o + win_units - 1, F - 1)
+            edge = o % win_units == 0        # a block (or the cut last)
+            split = e // win_units != o // win_units  # S[o] then P[e]
+            n_cut = int((o + win_units - 1 >= F).sum())
+
+            def at_e(t):
+                return jnp.concatenate(
+                    [t[:, win_units - 1::slide_units],
+                     jnp.broadcast_to(t[:, F - 1:], (K_cap, n_cut))], axis=1)
+
+            def at_o(t):
+                return t[:, ::slide_units]
+
+            tv, tr = comb_valid(
+                at_o(sv) & jnp.asarray(~edge)[None, :], tmap(at_o, suf),
+                at_e(pv) & jnp.asarray(edge | split)[None, :],
+                tmap(at_e, pre))
+            # 4. lane i picks (slot_i, round_i)
+            pick = slots * o.size + rounds
+            return tv.reshape(-1)[pick], tmap(lambda t: t.reshape(-1)[pick],
+                                              tr)
+
         def fire_block(trees, tvalid, fire_plan, ktable):
             fire, g_table, evict = fire_pack_views(fire_plan, slide_units)
             slots, starts, lens, wids, mask_i, group = fire
@@ -485,6 +617,10 @@ class FfatTPUReplica(TPUReplicaBase):
                         lambda: by_group(trees, tvalid, slots, group,
                                          g_table),
                         lambda: by_lane(trees, tvalid, slots, starts, lens))
+                elif fire_slides(slots.shape[0], K_cap, F):
+                    # a count-based plan's row 5 is the lanes' rounds
+                    qv, qr = by_scan(trees, tvalid, slots, group, starts,
+                                     mask)
                 else:
                     qv, qr = by_lane(trees, tvalid, slots, starts, lens)
                 qv = qv & mask
@@ -645,7 +781,12 @@ class FfatTPUReplica(TPUReplicaBase):
         case, all queries read the forest as it stood before the
         program's one eviction scatter), and the walk by range reads
         the other slots' nodes of the same columns only into table rows
-        that no lane of theirs picks."""
+        that no lane of theirs picks.
+
+        None of it concerns a count-based program that answers by
+        sliding scan (_query_fns): the scan reads leaves and their
+        validity, never an internal node, so no stale node can reach it,
+        and validity alone ends a partial window."""
         # tvalid donated (in-place eviction); trees is read-only here
         from ..monitoring.flightrec import instrumented_jit
         return instrumented_jit(self._query_fns(), self.stats,
@@ -1125,6 +1266,8 @@ class FfatTPUReplica(TPUReplicaBase):
             g_table[:n_groups, 1] = pairs % self.F
             g_table[G_CAP, 0] = n_groups
             f_pack[5, :n_out] = group
+        elif self.op.win_type is WinType.CB:
+            f_pack[5, :n_out] = rnd  # what the sliding scan picks by
         # evicted panes: one contiguous range per chunk
         ne = np.maximum(
             0, np.minimum(c_start0 + c_k * self.slide_units, c_ml + 1)
@@ -1533,15 +1676,20 @@ class FfatTPUReplica(TPUReplicaBase):
                       W: int, n_groups: int, cause: int = 0) -> None:
         """``W``: the width of the program that ran (its lanes, live or
         masked). ``n_groups``: the distinct ring ranges it answered by
-        range, 0 where it walked by lane. ``cause``: the id of the input
-        batch whose commit fired these windows (0 for a dataless fire: a
-        punctuation or EOS made them)."""
+        range, 0 where it walked by lane or answered by sliding scan
+        (count-based windows: by the rule the program was traced by,
+        ``fire_slides``). ``cause``: the id of the input batch whose
+        commit fired these windows (0 for a dataless fire: a punctuation
+        or EOS made them)."""
         import jax
 
         op = self.op
         self.stats.fire_programs += 1
         self.stats.fire_lanes += W
         self.stats.windows_fired += n_out
+        if (op.win_type is WinType.CB
+                and fire_slides(W, self.K_cap, self.F)):
+            self.stats.fire_sliding_programs += 1
         if n_groups:
             self.stats.fire_grouped_programs += 1
             self.stats.fire_groups += n_groups
