@@ -295,7 +295,7 @@ def test_every_sd_metric_has_its_file_and_lists_the_cell_alone():
         <= reported
     assert not any(n.endswith(".sg2") for n in reported)
     e2e = [e for e in bench["end_to_end"] if e["name"] == "events_per_s"][0]
-    assert e2e["workloads"][-1] == "sd.saturated"
+    assert "sd.saturated" in e2e["workloads"]    # later cells follow it
 
 
 def test_window_step_roofline_cb_by_hand():

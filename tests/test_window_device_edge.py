@@ -25,7 +25,12 @@ class InjectedCrash(Exception):
 
 def make_blocks():
     """Block ``p`` lies in pane ``p``; key 3 is silent for eight panes, so
-    its windows over them are empty and fire with ``valid`` false."""
+    its windows over them are empty and fire with ``valid`` false. One
+    reading of key 3 in the block before the silence is stamped past it
+    (pane 12): the key still holds an event when the fires reach the
+    silent panes. Without it the key's slot would be given back once its
+    last reading had fired (PR 34) and it would return as a new key, with
+    no window over the silence at all (tests/test_ffat_key_reclaim.py)."""
     rng = np.random.default_rng(11)
     out = []
     for p in range(BLOCKS):
@@ -34,6 +39,8 @@ def make_blocks():
             k[k == QUIET[0]] = 0
         v = rng.integers(1, 50, ROWS).astype(np.int32)
         ts = p * PANE_US + np.arange(ROWS, dtype=np.int64)
+        if p == QUIET[1][0] - 1:
+            k[1], ts[1] = QUIET[0], QUIET[1][-1] * PANE_US + PANE_US
         out.append(({"k": k, "v": v}, ts))
     return out
 

@@ -354,7 +354,7 @@ class _MeshReplicaBase(TPUReplicaBase):
                      "least the checkpointed count")
         self._keymap.slot_of_key.clear()
         self._keymap.slot_of_key.update(d["slot_of_key"])
-        self._keymap._lut = None
+        self._keymap.reset_index()
         kbs = np.asarray(d["key_by_slot"])
         self._key_by_slot[:] = 0
         n_copy = min(len(kbs), op.key_capacity)

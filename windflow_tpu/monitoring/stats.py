@@ -60,6 +60,12 @@ class StatsRecord(StageCounters):
         "windows_fired", "fire_programs", "fire_lanes",
         "fire_grouped_programs", "fire_groups", "fire_range_cuts",
         "fire_sliding_programs",
+        # key turnover of a time-based window operator: keys given a
+        # slot, slots given back (a key none of whose windows holds an
+        # event any more), slots in use now (a gauge), doublings of the
+        # key capacity (each reallocates the forest and recompiles)
+        "keys_admitted", "keys_reclaimed", "key_slots_live",
+        "key_capacity_growths",
         "staging_pool_hits", "staging_pool_misses",
         "dispatch_host_prep_us", "dispatch_commit_us",
         "dispatch_stalls", "dispatch_depth_max",
@@ -194,6 +200,10 @@ class StatsRecord(StageCounters):
         self.fire_groups = 0
         self.fire_range_cuts = 0
         self.fire_sliding_programs = 0
+        self.keys_admitted = 0
+        self.keys_reclaimed = 0
+        self.key_slots_live = 0
+        self.key_capacity_growths = 0
         self.staging_pool_hits = 0  # recycled staging buffers (ArrayPool)
         self.staging_pool_misses = 0
         # device-ahead dispatch pipeline (runtime/dispatch.py): per-stage
@@ -543,6 +553,10 @@ class StatsRecord(StageCounters):
             "Fire_groups": self.fire_groups,
             "Fire_range_cuts": self.fire_range_cuts,
             "Fire_sliding_programs": self.fire_sliding_programs,
+            "Keys_admitted": self.keys_admitted,
+            "Keys_reclaimed": self.keys_reclaimed,
+            "Key_slots_live": self.key_slots_live,
+            "Key_capacity_growths": self.key_capacity_growths,
             "Fused_ops": self.fused_ops,
             "Staging_pool_hits": self.staging_pool_hits,
             "Staging_pool_misses": self.staging_pool_misses,

@@ -151,6 +151,9 @@ STAGES: Dict[str, StageDef] = {
     # the window operator's fire planning, inside its prep (``count``
     # stays None: the plan runs once per batch, Dispatch_batches counts)
     "fireplan": StageDef("wf", _DISPATCH, "Fire_plan_total_usec", None),
+    # the window operator's key turnover, inside its prep (or a dataless
+    # fire): new keys given a slot, dead slots given back
+    "keys": StageDef("wf", _DISPATCH, "Key_turnover_total_usec", None),
     "queue": StageDef("wait", _DISPATCH, "Dispatch_queue_wait_total_usec",
                       None),
     "commit": StageDef("wf", _DISPATCH, "Dispatch_commit_total_usec", None,

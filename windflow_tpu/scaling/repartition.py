@@ -260,6 +260,11 @@ def _split_ffat_tpu(ffats: List[dict], new_n: int,
             "fire_ewma": max(d["fire_ewma"] for d in ffats),
             "rebuild_dirty": True,  # level caches are stale by definition
             "ignored": sum(d["ignored"] for d in ffats) if j == 0 else 0,
+            # the live keys are packed from slot 0: no free slot below
+            # them; a key a source had forgotten stays forgotten, and a
+            # new key's first window keeps the sources' highest floor
+            "free_slots": [],
+            "reclaimed_wid": max(d.get("reclaimed_wid", 0) for d in ffats),
         }
         for field in ("next_fire", "fired", "max_leaf", "count", "keys_np"):
             protos = np.asarray(proto[field])

@@ -302,7 +302,8 @@ def row_schema(fields: Mapping, schema: Optional[TupleSchema]
 class BatchTPU(StreamMsg):
     __slots__ = ("fields", "ts_host", "size", "capacity", "wm", "is_punct",
                  "stream_tag", "id", "schema", "host_keys", "key_slots",
-                 "slot_of_key", "trace_min", "trace_max", "bid", "cause")
+                 "slot_of_key", "trace_min", "trace_max", "bid", "cause",
+                 "key_origin")
 
     def __init__(self, fields: Dict[str, Any], ts_host: np.ndarray, size: int,
                  schema: TupleSchema, wm: int = 0,
@@ -324,6 +325,12 @@ class BatchTPU(StreamMsg):
         self.host_keys = host_keys  # list of python keys, len == size
         self.key_slots = key_slots  # jax int32 (capacity,): dense slot ids
         self.slot_of_key = slot_of_key  # key -> slot id for this batch
+        # the field (or tuple of fields) ``host_keys`` are the values of,
+        # where the operator that made this batch keyed it by its OWN
+        # key (a window or reduce result): a keyed consumer that names
+        # another field reads that column (``keys_for``). None: staged
+        # for the consumer's key, whatever it is
+        self.key_origin = None
         # latency-tracing origin stamps: min/max over traced constituents
         # (0 = none traced; monitoring/tracing.py)
         self.trace_min = 0
@@ -421,7 +428,10 @@ class BatchTPU(StreamMsg):
 
     def copy_trace_from(self, src: "BatchTPU") -> "BatchTPU":
         """Propagate origin stamps and timeline identity from the batch
-        this one derives from (operator outputs, compactions, copies)."""
+        this one derives from (operator outputs, compactions, copies),
+        and whose key its host keys are (a batch that replaces them says
+        so itself, after this)."""
+        self.key_origin = src.key_origin
         self.trace_min = src.trace_min
         self.trace_max = src.trace_max
         self.bid = src.bid
@@ -436,6 +446,21 @@ class BatchTPU(StreamMsg):
         self.bid = next_batch_id()
         self.cause = src.bid
         return self
+
+    def keys_for(self, mine):
+        """``host_keys`` where they are the keys of a consumer keyed by
+        ``mine`` (a field name, a tuple of them, or None for a callable
+        extractor), else None: the consumer then reads its own
+        column(s). Keys a producer made by its own key (``key_origin``)
+        are another operator's unless the consumer names the same
+        field(s), or names none this batch holds."""
+        origin = self.key_origin
+        if origin is None or not mine or mine == origin:
+            return self.host_keys
+        names = (mine,) if isinstance(mine, str) else mine
+        if all(f in self.fields for f in names):
+            return None
+        return self.host_keys
 
     def with_fields(self, new_fields: Dict[str, Any]) -> "BatchTPU":
         """Same metadata, new device columns (in-place operator output)."""

@@ -493,10 +493,11 @@ def _async_copy(arr: Any) -> None:
 def _maybe_prefetch_key(batch: BatchTPU, field: Optional[str]) -> None:
     """Start an async host copy of the key column when the downstream
     keyed device op will have to read it (no host key metadata on the
-    batch — e.g. the key was computed ON DEVICE by an upstream Map_TPU).
-    Without this, the consumer's key read is a synchronous D2H of a fresh
-    buffer."""
-    if field is None or batch.host_keys is not None:
+    batch that is ITS key — e.g. the key was computed ON DEVICE by an
+    upstream Map_TPU, or the batch is a window's result keyed by another
+    field). Without this, the consumer's key read is a synchronous D2H
+    of a fresh buffer."""
+    if field is None or batch.keys_for(field) is not None:
         return
     if field in batch.fields:
         _async_copy(batch.fields[field])
@@ -941,8 +942,9 @@ class TPUKeyByEmitter(BasicEmitter, _D2HPipeline):
         self._pipe_init("WF_KEYBY_PIPELINE_DEPTH", 2, depth)
 
     def _keys_of(self, batch: BatchTPU):
-        if batch.host_keys is not None:
-            return batch.host_keys
+        keys = batch.keys_for(self.key_field or self.key_fields)
+        if keys is not None:
+            return keys
         if self.key_field is not None:
             from .batch import key_column_to_list
             return key_column_to_list(batch, self.key_field)
@@ -953,7 +955,12 @@ class TPUKeyByEmitter(BasicEmitter, _D2HPipeline):
             "key extractor (with_key_by('field') or a tuple of fields)")
 
     def emit_device_batch(self, batch: BatchTPU) -> None:
+        mine = self.key_field or self.key_fields
         if self.num_dests == 1:
+            if batch.host_keys is not None:
+                # where they are another operator's keys (a window's
+                # result), the one consumer reads its own key column
+                _maybe_prefetch_key(batch, self.key_field)
             self._drain()
             batch.id = self._next_ids[0]
             self._next_ids[0] += 1
@@ -961,8 +968,7 @@ class TPUKeyByEmitter(BasicEmitter, _D2HPipeline):
                 self.stats.outputs_sent += batch.size
             self.ports[0].send(batch)
             return
-        if batch.host_keys is None and (self.key_field is not None
-                                        or self.key_fields):
+        if mine and batch.keys_for(mine) is None:
             for f in ((self.key_field,) if self.key_field is not None
                       else self.key_fields):
                 _async_copy(batch.fields.get(f))
@@ -1007,6 +1013,7 @@ class TPUKeyByEmitter(BasicEmitter, _D2HPipeline):
                 batch, idx,
                 host_keys[idx] if isinstance(host_keys, np.ndarray)
                 else [host_keys[j] for j in idx])
+            sub.key_origin = None    # keyed for the consumer, by its key
             sub.id = self._next_ids[d]
             self._next_ids[d] += 1
             if self.stats is not None:

@@ -51,13 +51,31 @@ TPU-first redesign:
 
 Window semantics match the CPU ``Ffat_Windows``: pane = gcd(win, slide)
 time units (TB) or one tuple (CB, leaf = per-key arrival index); TB windows
-fire when the watermark minus lateness passes their end; empty windows fire
-with ``valid=False``; late tuples behind the eviction frontier are counted
-as ignored; EOS flushes partial windows.
+fire when the watermark minus lateness passes their end; empty windows
+between two of a key's windows that hold events fire with ``valid=False``;
+late tuples behind the eviction frontier are counted as ignored; EOS
+flushes partial windows.
+
+A key of a TIME-BASED operator holds its slot only while one of its
+windows holds an event: once the fires have passed its last event
+(``max_leaf < next_fire``: every leaf of its row evicted) the slot goes
+back to a free list (_reclaim) and the next new key takes it, so the
+forest is sized by the keys that are live, not by the keys ever seen. A
+key that comes back is a new key, anchored at the first window that
+holds its first event, and never below ``_reclaimed_wid``, the furthest
+window any forgotten key had reached: a late event of a forgotten key
+is counted and dropped like any event behind its key's fired windows,
+and no (key, window) is delivered twice. Count-based operators keep
+their keys (a key's arrival index is its state).
 
 Output batches carry one row per fired window: the combined value columns,
 ``wid`` (per-key window id), ``valid`` (False for empty windows), and the
-key column when the key is a field name.
+key column when the key is a field name. A time-based window's row is
+stamped with the last instant inside the window (``wid * slide + win -
+1``), and a batch's watermark stays below the earliest window end among
+its own rows and the rows its drain still owes (_emit_windows), so a
+window operator downstream takes every row in time; count-based rows
+carry the watermark at the fire.
 """
 
 from __future__ import annotations
@@ -70,7 +88,7 @@ import numpy as np
 from ..basic import OpType, RoutingMode, WinType, WindFlowError
 from ..monitoring.tracing import next_batch_id
 from .batch import BatchTPU, bucket_capacity, field_dtype
-from .ops_tpu import TPUOperatorBase, TPUReplicaBase
+from .ops_tpu import TPUOperatorBase, TPUReplicaBase, own_key_spec
 from .schema import TupleSchema, broadcast_scalar_fields
 
 
@@ -280,9 +298,21 @@ class FfatTPUReplica(TPUReplicaBase):
         self._cap_seen = 0  # widest input batch so far: W_wide's bound
         self._fire_ewma = 0.0
         from .keymap import KeySlotMap
-        self._keymap = KeySlotMap(on_new=self._on_new_key)
+        # wf:keys: key turnover (admission, slots given back)
+        self._st_keys = self.stats.stage("keys")
+        self._bid = 0  # the batch in prep: its id names the wf:keys spans
+        self._keymap = KeySlotMap(
+            on_new=self._on_new_key, on_new_many=self._on_new_keys,
+            admit_span=lambda: self._st_keys(self._bid))
         self.slot_of_key = self._keymap.slot_of_key  # shared dict
-        self._out_keys_by_slot: List[Any] = []
+        # time-based windows give a dead key's slot back (_reclaim);
+        # _reclaimed_wid: the furthest next window of any forgotten key,
+        # the floor of a new key's first window
+        self._reclaims = op.win_type is WinType.TB
+        self._reclaimed_wid = 0
+        # original keys by slot where some key is no int (else _keys_np
+        # holds them: see _out_keys_by_slot)
+        self._obj_keys: Optional[List[Any]] = None
         # per-slot host bookkeeping (numpy, grown with K_cap)
         self.next_fire = np.zeros(self.K_cap, dtype=np.int64)
         self.fired = np.zeros(self.K_cap, dtype=np.int64)  # == next gwid
@@ -318,7 +348,8 @@ class FfatTPUReplica(TPUReplicaBase):
         self._dirty_all = False
         self._delta_base = None  # epoch id of the last full snapshot
         self._snaps_since_full = 0
-        self._base_nkeys = None  # key count at the last full snapshot
+        self._dir_version = 0  # bumped by every admission and reclaim
+        self._base_dirver = None  # ... as it stood at the last full one
         self._base_geom = None  # (K_cap, F, trees-allocated) at base
         # device forest (lazily shaped once the lift output is known)
         self.trees = None  # dict field -> (K_cap, 2F)
@@ -826,27 +857,109 @@ class FfatTPUReplica(TPUReplicaBase):
     # ==================================================================
     # host control plane
     # ==================================================================
+    @property
+    def _out_keys_by_slot(self) -> List[Any]:
+        """The original key of every slot below the high-water mark (a
+        free slot: the key that held it last)."""
+        if self._obj_keys is not None:
+            return self._obj_keys
+        return self._keys_np[:self._keymap.n_slots].tolist()
+
     def _on_new_key(self, key, s: int) -> None:
-        """KeySlotMap callback: per-slot bookkeeping for a fresh key.
+        """KeySlotMap callback: per-slot bookkeeping for a fresh key (the
+        per-key path: keys that are no ints, direct ``slot`` calls).
         RAISE-BEFORE-MUTATE: KeySlotMap.slot registers the key only when
         this returns, so a refusal (index-plane overflow on growth) must
         fire before any bookkeeping mutates — a caught-and-retried batch
-        would otherwise double-append ``_out_keys_by_slot`` and shift
-        every later slot's original-key mapping."""
+        would otherwise find the key table shifted."""
         if s >= self.K_cap:
-            # slots are sequential (s == len(map)), so one doubling
-            # always covers s; validate the doubled plane FIRST, and
-            # grow BEFORE any bookkeeping mutates (growth itself can
-            # fail, e.g. device OOM reallocating the doubled forest)
+            # ``s`` is a free slot or the high-water mark, so one
+            # doubling always covers it; validate the doubled plane
+            # FIRST, and grow BEFORE any bookkeeping mutates (growth
+            # itself can fail, e.g. device OOM reallocating the forest)
             self._check_index_plane(self.K_cap * 2)
             self._grow_keys()
         self._saw_new_key = True
-        self._out_keys_by_slot.append(key)
         if self._keys_all_int and isinstance(key, int):
             self._keys_np[s] = key
         else:
-            self._keys_all_int = False
+            if self._obj_keys is None:   # the first key that is no int
+                self._obj_keys = self._out_keys_by_slot
+                self._keys_all_int = False
+            self._place_obj_key(s, key)
         self._ktable_dirty = True
+        self._note_admitted(1)
+
+    def _place_obj_key(self, s: int, key) -> None:
+        keys = self._obj_keys
+        if s < len(keys):
+            keys[s] = key
+        else:
+            keys.extend([None] * (s - len(keys)) + [key])
+
+    def _on_new_keys(self, keys: np.ndarray, slots: np.ndarray) -> None:
+        """KeySlotMap callback for a batch's new int keys, all at once
+        (no Python call a key): grows the key table until it holds the
+        highest slot, refusing BEFORE anything mutates, then writes the
+        keys into the slot table. The device key table is re-staged only
+        where a slot's key changed (a key that takes back the slot it
+        had, as the one key of a keyed-by-constant stage does after every
+        fire, changes nothing)."""
+        cap = self.K_cap
+        while cap <= int(slots.max()):
+            cap *= 2
+        if cap > self.K_cap:
+            self._check_index_plane(cap)
+            while self.K_cap < cap:
+                self._grow_keys()
+        self._saw_new_key = True
+        if self._obj_keys is not None:
+            for s, k in zip(slots.tolist(), keys.tolist()):
+                self._place_obj_key(s, k)
+        if (self._keys_np[slots] != keys).any():
+            self._keys_np[slots] = keys
+            self._ktable_dirty = True
+        self._note_admitted(len(keys))
+
+    def _note_admitted(self, n: int) -> None:
+        self.stats.keys_admitted += n
+        self.stats.key_slots_live = len(self.slot_of_key) + n
+        self._dir_version += 1
+
+    def _reclaim(self, slots: np.ndarray) -> None:
+        """Give back the slots among ``slots`` (just fired) none of whose
+        windows that hold an event is left: ``max_leaf < next_fire``,
+        _eligible's own ``has_data``. The fires that consumed its events
+        evicted every leaf of such a row (a chunk evicts ``[start0,
+        start0 + k * slide)`` up to ``max_leaf``), in programs that run
+        before any step that could write a new key's leaves there, so
+        nothing on the device needs clearing. ``fired``/``next_fire``
+        stay as they are: a new key's registration re-anchors them."""
+        dead = slots[self.max_leaf[slots] < self.next_fire[slots]]
+        if dead.size:
+            with self._st_keys(self._bid):
+                self._reclaimed_wid = max(self._reclaimed_wid,
+                                          int(self.fired[dead].max()))
+                self._give_back(dead)
+
+    def _give_back(self, dead: np.ndarray) -> None:
+        """Forget the keys of ``dead`` slots and free the slots (rows a
+        delta snapshot already holds dirty: a batch's rows, a fire's)."""
+        self.max_leaf[dead] = -1
+        self._keymap.release(
+            self._keys_np[dead] if self._obj_keys is None
+            else [self._obj_keys[s] for s in dead.tolist()])
+        self.stats.keys_reclaimed += int(dead.size)
+        self.stats.key_slots_live = len(self.slot_of_key)
+        self._dir_version += 1
+
+    def _chunk_keys(self, c_slots: np.ndarray):
+        """The original keys of a plan's chunks, read when the plan is
+        made: by the time its commit emits them a later batch's new key
+        may hold a slot the plan gave back."""
+        if self._obj_keys is None:
+            return self._keys_np[c_slots]
+        return [self._obj_keys[s] for s in c_slots.tolist()]
 
     def _slots_of(self, keys, keys_arr: np.ndarray, n: int) -> np.ndarray:
         return self._keymap.slots_of(keys, keys_arr, n)
@@ -886,6 +999,7 @@ class FfatTPUReplica(TPUReplicaBase):
             self.trees, self.tvalid = new_trees, new_tvalid
         self._ktable_dirty = True
         self._dirty_all = True  # geometry changed under the delta base
+        self.stats.key_capacity_growths += 1
 
     def _grow_ring(self, needed_span: int) -> None:
         """BUILD-THEN-COMMIT, like ``_grow_keys`` (F and the migrated
@@ -975,6 +1089,7 @@ class FfatTPUReplica(TPUReplicaBase):
                 if n_kept == 0:
                     return None
                 rowsel = np.nonzero(keep)[0]
+        self._bid = batch.bid
         keys, keys_arr = self.batch_keys_np(batch)
         if rowsel is not None:
             sub_arr = np.asarray(keys_arr)[rowsel]
@@ -1011,6 +1126,7 @@ class FfatTPUReplica(TPUReplicaBase):
         # the registering tuple can land in a gap and stay late, so the
         # alignment must re-run every batch (pre-gate behavior; regression
         # test: gap_windows_late_first_key_reanchor).
+        born = None     # slots registered (or still untouched) here
         if op.win_type is WinType.TB and (
                 self._saw_new_key or self.slide_units > self.win_units):
             self._saw_new_key = False
@@ -1024,11 +1140,16 @@ class FfatTPUReplica(TPUReplicaBase):
                 sel = np.unique(fslots)
                 new_mask = self.max_leaf[sel] < 0  # still untouched slots
                 sel = sel[new_mask]
+                # never below the furthest window a forgotten key had
+                # reached (_reclaim): were this key one of them, come
+                # back with a late event, its windows below are
+                # delivered already and the event is late for them
                 w0 = np.maximum(
-                    0, (first_leaf[sel] - self.win_units)
+                    self._reclaimed_wid, (first_leaf[sel] - self.win_units)
                     // self.slide_units + 1)
                 self.next_fire[sel] = w0 * self.slide_units
                 self.fired[sel] = w0
+                born = sel
         nf = self.next_fire[slots]
         live = leaves >= nf
         n_live = int(live.sum())
@@ -1082,6 +1203,15 @@ class FfatTPUReplica(TPUReplicaBase):
                 np.maximum.at(self.max_leaf, slots, masked_leaves)
                 self._leaf_frontier = max(self._leaf_frontier,
                                           int(masked_leaves.max()))
+        if born is not None and n_late:
+            # a key none of whose rows is live (all behind the floor of
+            # a new key's first window, or in a gap between windows)
+            # holds no event: it keeps no slot, and registers anew with
+            # its next row (the floor stays: no window of it fired)
+            still = born[self.max_leaf[born] < 0]
+            if still.size:
+                with self._st_keys(self._bid):
+                    self._give_back(still)
 
         cap = batch.capacity
         # packed composite (slot*F + leaf, sentinel M = late/padding) in
@@ -1114,7 +1244,7 @@ class FfatTPUReplica(TPUReplicaBase):
         (C-speed even at 10^5 keys; the reference instead walks its key
         descriptor map in a host loop,
         ``ffat_replica_gpu.hpp:870-1019``). Reads, advances nothing."""
-        ns = len(self.slot_of_key)
+        ns = self._keymap.n_slots    # free slots read as holding no data
         empty = (np.zeros(0, np.int64),) * 2
         if ns == 0:
             return empty
@@ -1151,7 +1281,10 @@ class FfatTPUReplica(TPUReplicaBase):
         if self._ckpt_dirty or self._delta_base is not None:
             # firing advances bookkeeping and evicts ring panes
             self._ckpt_dirty.update(slots.tolist())
-        return slots, start0, k, wid0, self.max_leaf[slots]
+        chunks = slots, start0, k, wid0, self.max_leaf[slots]
+        if self._reclaims:
+            self._reclaim(slots)
+        return chunks
 
     def _fireable(self, frontier, partial: bool, budget: int):
         """Fire-eligible windows as per-slot chunk ARRAYS
@@ -1300,8 +1433,8 @@ class FfatTPUReplica(TPUReplicaBase):
         """ONE program of an operator that sizes its width by the plan
         (time-based windows, no budget given), from the eligible
         windows ``k`` of ``slots``: ``(chunks, n_out, pack, n_groups,
-        W)``, the windows taken advanced past, and how many of each
-        slot's it took.
+        W, keys)`` (_programs), the windows taken advanced past, and how
+        many of each slot's it took.
 
         The program is ``W_wide`` lanes and takes all that is eligible,
         by rounds where even that width overflows. It stays a program
@@ -1367,7 +1500,8 @@ class FfatTPUReplica(TPUReplicaBase):
         pack, n_groups = self._pack_plan(
             chunks, W, (rnd, q_starts[lane], q_lens[lane]),
             None if pairs is None else (pairs, q_group[lane]))
-        return (chunks, rnd.size, pack, n_groups, W), take
+        return (chunks, rnd.size, pack, n_groups, W,
+                self._chunk_keys(chunks[0])), take
 
     def _use_ktable(self) -> bool:
         """Whether programs gather the output key column from a
@@ -1385,7 +1519,10 @@ class FfatTPUReplica(TPUReplicaBase):
         kd = self._key_dtype
         if (self._ktable_dev is None or self._ktable_dirty
                 or self._ktable_kd != kd):
-            self._ktable_dev = jax.device_put(self._keys_np.astype(kd))
+            # a NEW array a staging: programs queued with the table as
+            # it stood keep the keys their plans were made with
+            with self._st_keys(self._bid):
+                self._ktable_dev = jax.device_put(self._keys_np.astype(kd))
             self._ktable_kd = kd
             self._ktable_dirty = False
         return self._ktable_dev
@@ -1583,11 +1720,16 @@ class FfatTPUReplica(TPUReplicaBase):
                                          ckey, ikey, plan or [None], bid)
 
     def _programs(self, frontier, partial: bool, first_budget: int, warm):
-        """The programs that fire what is eligible now, one
-        ``(chunks, n_out, pack, n_groups, W)`` at a time, each advanced
+        """The programs that fire what is eligible now, one ``(chunks,
+        n_out, pack, n_groups, W, keys, owed)`` at a time, each advanced
         past as it is yielded (so a caller may run one, and a snapshot
-        between two is consistent). ``warm()`` compiles the shapes of a
-        width that has just grown.
+        between two is consistent). ``keys``: the chunks' original keys
+        (_chunk_keys). ``owed``: the lowest window id, over all slots,
+        that this drain still has to fire AFTER this program, None where
+        it is the last (what bounds the watermark of the batch the
+        program emits: _emit_windows; always None for count-based
+        windows, whose rows carry no window time). ``warm()`` compiles
+        the shapes of a width that has just grown.
 
         With the tiers: ``first_budget`` windows in the first program,
         ``W_cap`` in those behind it, until one is not full. By the
@@ -1595,15 +1737,21 @@ class FfatTPUReplica(TPUReplicaBase):
         if self._by_plan:
             yield from self._programs_by_plan(frontier, partial, warm)
             return
+        timed = self.op.win_type is WinType.TB
         budget = first_budget
         while True:
             chunks = self._fireable(frontier, partial, budget)
             n_out = int(chunks[2].sum())
             if not n_out:
                 return
+            owed = None
+            if timed and n_out == budget:   # full: more may be eligible
+                left, _k = self._eligible(frontier, partial)
+                if left.size:
+                    owed = int(self.fired[left].min())
             yield (chunks, n_out) + self._pack_fire_arrays(
-                chunks, budget) + (budget,)
-            if n_out < budget:
+                chunks, budget) + (budget, self._chunk_keys(chunks[0]), owed)
+            if n_out < budget or (timed and owed is None):
                 return
             budget = self.W_cap
 
@@ -1624,13 +1772,18 @@ class FfatTPUReplica(TPUReplicaBase):
         widths)."""
         slots, k = self._eligible(frontier, partial)
         total = int(k.sum())
-        if total > self.W_wide and self._fit_width(total):
+        # the end-of-stream flush keeps the width it has: nothing follows
+        # it that could use a new compiled shape, here or downstream (a
+        # wider result batch is a new shape of every program after this
+        # operator too), and the compiles would be the flush's whole cost
+        if not partial and total > self.W_wide and self._fit_width(total):
             warm()
         while slots.size:
             prog, take = self._plan_program(slots, k)
-            yield prog
             k = k - take
             slots, k = slots[k > 0], k[k > 0]
+            yield prog + (int(self.fired[slots].min()) if slots.size
+                          else None,)
 
     def _commit_step(self, fields, wm, comp_p, ktable, ckey, ikey,
                      plan, bid: int) -> None:
@@ -1655,7 +1808,7 @@ class FfatTPUReplica(TPUReplicaBase):
                 self._rebuild_dirty = True
                 self.stats.device_programs_run += 1
                 continue
-            is_first, chunks, n_out, pack, n_groups, budget = entry
+            is_first, chunks, n_out, pack, n_groups, budget, keys, owed = entry
             if is_first:
                 # full program: lift + scan + scatter + rebuild + fire
                 (self.trees, self.tvalid, qr, qv, wid_dev,
@@ -1669,18 +1822,31 @@ class FfatTPUReplica(TPUReplicaBase):
                 self.tvalid, qr, qv, wid_dev, key_dev = self._fire_step()(
                     self.trees, self.tvalid, pack, ktable)
             self.stats.device_programs_run += 1
-            self._emit_windows(wm, chunks, n_out, qr, qv, wid_dev, key_dev,
-                               budget, n_groups, bid)
+            self._emit_windows(wm, chunks, keys, owed, pack, n_out, qr, qv,
+                               wid_dev, key_dev, budget, n_groups, bid)
 
-    def _emit_windows(self, wm, chunks, n_out, qr, qv, wid_dev, key_dev,
-                      W: int, n_groups: int, cause: int = 0) -> None:
-        """``W``: the width of the program that ran (its lanes, live or
-        masked). ``n_groups``: the distinct ring ranges it answered by
-        range, 0 where it walked by lane or answered by sliding scan
-        (count-based windows: by the rule the program was traced by,
-        ``fire_slides``). ``cause``: the id of the input batch whose
-        commit fired these windows (0 for a dataless fire: a punctuation
-        or EOS made them)."""
+    def _emit_windows(self, wm, chunks, c_keys, owed, pack, n_out, qr, qv,
+                      wid_dev, key_dev, W: int, n_groups: int,
+                      cause: int = 0) -> None:
+        """``c_keys``, ``owed``: see _programs. ``W``: the width of the
+        program that ran (its lanes, live or masked). ``n_groups``: the
+        distinct ring ranges it answered by range, 0 where it walked by
+        lane or answered by sliding scan (count-based windows: by the
+        rule the program was traced by, ``fire_slides``). ``cause``: the
+        id of the input batch whose commit fired these windows (0 for a
+        dataless fire: a punctuation or EOS made them).
+
+        Event time of what leaves (this plane's own rule, PARITY.md): a
+        row of a time-based window carries the last instant inside its
+        window, ``wid * slide + win - 1``, and the batch's watermark is
+        ``wm`` lowered to one less than the earliest window END among
+        the batch's own rows and the rows the drain still owes: a row
+        is never behind the watermark it travels with, and no watermark
+        passes a window whose rows are still to come (rounds split over
+        programs, a budget given, ``G_CAP`` cuts), so a window operator
+        downstream drops none as late. The consumer learns ``wm`` itself
+        with the next batch or punctuation. Count-based rows have no
+        window time: they carry ``wm``."""
         import jax
 
         op = self.op
@@ -1696,16 +1862,16 @@ class FfatTPUReplica(TPUReplicaBase):
         fields = dict(qr)
         fields["valid"] = qv
         fields["wid"] = wid_dev  # built in-program: no device_put here
-        c_slots, _st, c_k, _w0, _ml = chunks
-        slot_per_win = np.repeat(c_slots, c_k)
-        if self._keys_all_int:
-            out_keys: Any = self._keys_np[slot_per_win]  # numpy, no boxing
+        _slots, _st, c_k, c_w0, _ml = chunks
+        if isinstance(c_keys, np.ndarray):
+            out_keys: Any = np.repeat(c_keys, c_k)  # numpy, no boxing
         else:
             # composite/object keys (callable extractors): host metadata
             # only — key_field is always a numeric column, so no key
             # COLUMN is built on this branch (a zero-padded asarray of
             # tuples would be ragged)
-            out_keys = [self._out_keys_by_slot[s] for s in slot_per_win]
+            out_keys = [key for key, n in zip(c_keys, c_k.tolist())
+                        for _ in range(n)]
         if op.key_field is not None:
             if self._use_ktable():
                 fields[op.key_field] = key_dev  # gathered in-program
@@ -1718,8 +1884,19 @@ class FfatTPUReplica(TPUReplicaBase):
                 fields[op.key_field] = jax.device_put(key_col)
         out_schema = TupleSchema(
             {name: np.dtype(v.dtype) for name, v in fields.items()})
+        if op.win_type is WinType.TB:
+            first = int(c_w0.min()) if owed is None else min(
+                int(c_w0.min()), owed)
+            wm = min(wm, first * op.slide_len + op.win_len - 1)
         ts = np.full(W, wm, dtype=np.int64)
+        if op.win_type is WinType.TB:
+            wids = fire_pack_views(pack, self.slide_units)[0][3, :n_out]
+            ts[:n_out] = (wids.astype(np.int64) * op.slide_len
+                          + (op.win_len - 1))
         out = BatchTPU(fields, ts, n_out, out_schema, wm, out_keys)
+        # the keys are this operator's: a consumer keyed by another
+        # field reads its own column (BatchTPU.keys_for)
+        out.key_origin = own_key_spec(op)
         out.bid = next_batch_id()  # a new batch: the fire made it
         out.cause = cause
         self._emit_batch(out)
@@ -1736,14 +1913,16 @@ class FfatTPUReplica(TPUReplicaBase):
         # dataless firing (handle_msg/terminate drain already, but
         # direct drivers — bench, profile scripts — reach here too)
         self.dispatch.drain(forced=True)
-        for chunks, n_out, pack, n_groups, W in self._programs(
+        self._bid = 0
+        ktable = self._ktable_arg()     # as the plans' keys stand now
+        for chunks, n_out, pack, n_groups, W, keys, owed in self._programs(
                 frontier, partial, self.W_cap, self._warm_fire_step):
             self._ensure_rebuilt()
             self.tvalid, qr, qv, wid_dev, key_dev = self._fire_step()(
-                self.trees, self.tvalid, pack, self._ktable_arg())
+                self.trees, self.tvalid, pack, ktable)
             self.stats.device_programs_run += 1
-            self._emit_windows(self.cur_wm, chunks, n_out, qr, qv, wid_dev,
-                               key_dev, W, n_groups)
+            self._emit_windows(self.cur_wm, chunks, keys, owed, pack, n_out,
+                               qr, qv, wid_dev, key_dev, W, n_groups)
 
     def on_punctuation(self, wm: int) -> None:
         if self.op.win_type is WinType.TB:
@@ -1778,6 +1957,8 @@ class FfatTPUReplica(TPUReplicaBase):
         st["ffat"] = {
             "slot_of_key": dict(self.slot_of_key),
             "out_keys_by_slot": list(self._out_keys_by_slot),
+            "free_slots": list(self._keymap.free),
+            "reclaimed_wid": self._reclaimed_wid,
             "K_cap": self.K_cap, "F": self.F,
             "next_fire": self.next_fire.copy(),
             "fired": self.fired.copy(),
@@ -1801,7 +1982,7 @@ class FfatTPUReplica(TPUReplicaBase):
             # post-drain, so no in-flight commit can race the reset)
             self._delta_base = ctx.ckpt_id
             self._base_geom = (self.K_cap, self.F, self.trees is not None)
-            self._base_nkeys = len(self.slot_of_key)
+            self._base_dirver = self._dir_version
             self._snaps_since_full = 0
             self._ckpt_dirty = set()
             self._dirty_all = False
@@ -1836,16 +2017,17 @@ class FfatTPUReplica(TPUReplicaBase):
                 "leaf_frontier": self._leaf_frontier,
                 "fire_ewma": self._fire_ewma,
                 "rebuild_dirty": self._rebuild_dirty,
-                "ignored": self.ignored}
+                "ignored": self.ignored,
+                "reclaimed_wid": self._reclaimed_wid}
         carry = []
-        if len(self.slot_of_key) == self._base_nkeys:
-            # slots are append-only between rebuilds (a rebuild sets
-            # _dirty_all, forcing a full snapshot), so an unchanged key
-            # count means an unchanged directory: zero-byte carry
-            carry += ["slot_of_key", "out_keys_by_slot"]
+        if self._dir_version == self._base_dirver:
+            # no key admitted and no slot given back since the base:
+            # an unchanged directory, a zero-byte carry
+            carry += ["slot_of_key", "out_keys_by_slot", "free_slots"]
         else:
             repl["slot_of_key"] = dict(self.slot_of_key)
             repl["out_keys_by_slot"] = list(self._out_keys_by_slot)
+            repl["free_slots"] = list(self._keymap.free)
         return ckpt_delta.make_delta(
             self._delta_base, rows=rows, replace=repl,
             carry=carry or None)
@@ -1858,7 +2040,7 @@ class FfatTPUReplica(TPUReplicaBase):
         self._delta_base = None
         self._snaps_since_full = 0
         self._base_geom = None
-        self._base_nkeys = None
+        self._base_dirver = None
         d = state.get("ffat")
         if d is None:
             return
@@ -1871,14 +2053,27 @@ class FfatTPUReplica(TPUReplicaBase):
         self._check_index_plane()
         self.slot_of_key.clear()  # shared alias with the KeySlotMap
         self.slot_of_key.update(d["slot_of_key"])
-        self._keymap._lut = None
-        self._out_keys_by_slot = list(d["out_keys_by_slot"])
+        self._keymap.reset_index()
+        self._keys_all_int = d["keys_all_int"]
+        self._obj_keys = (None if self._keys_all_int
+                          else list(d["out_keys_by_slot"]))
+        # the free list as it stood, so that a restored run hands out
+        # the slots an uninterrupted one would; a state without one
+        # (scaling/repartition.py packs the live keys from 0): every
+        # slot below the highest live one that no key holds
+        free = d.get("free_slots")
+        if free is None:
+            live = set(d["slot_of_key"].values())
+            free = [s for s in range(max(live, default=-1), -1, -1)
+                    if s not in live]
+        self._keymap.free = list(free)
+        self._reclaimed_wid = d.get("reclaimed_wid", 0)
+        self.stats.key_slots_live = len(self.slot_of_key)
         self.next_fire = d["next_fire"].copy()
         self.fired = d["fired"].copy()
         self.max_leaf = d["max_leaf"].copy()
         self.count = d["count"].copy()
         self._keys_np = d["keys_np"].copy()
-        self._keys_all_int = d["keys_all_int"]
         self._key_dtype = d["key_dtype"]
         self._saw_new_key = d["saw_new_key"]
         self._leaf_frontier = d["leaf_frontier"]

@@ -72,7 +72,7 @@ from .batch import BatchTPU
 from .ffat_tpu import Ffat_Windows_TPU, FfatTPUReplica
 from .ops_tpu import (Filter_TPU, Map_TPU, Reduce_TPU, TPUReplicaBase,
                       _compact_order, _grid_scan_core, _KeyedStateScan,
-                      cached_compile, masked_tree_reduce,
+                      cached_compile, masked_tree_reduce, own_key_spec,
                       prewarm_zero_fields, reduce_order_and_slots)
 
 
@@ -490,6 +490,7 @@ class FusedTPUReplica(TPUReplicaBase):
             nb = BatchTPU(tails, ts2, m, batch.schema, batch.wm, out_keys)
             nb.stream_tag = batch.stream_tag
             nb.copy_trace_from(batch)
+            nb.key_origin = own_key_spec(self.ops[-1])  # the reduce's keys
             self._emit_batch(nb)
         elif self._reduce_combine is not None:
             out, order, count = parts

@@ -10,6 +10,7 @@ keeps per-batch key maps rebuilt with device sort/unique kernels,
 
 from __future__ import annotations
 
+from contextlib import nullcontext
 from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
@@ -48,29 +49,108 @@ def distinct_batch_keys(keys, keys_arr: np.ndarray, n: int):
 
 
 class KeySlotMap:
-    LUT_MAX = 1 << 22  # 16 MiB int32 ceiling for the direct table
+    """``slot_of_key`` (a dict) is the directory; the int paths look a
+    batch up through an index built from it: a direct table over
+    ``[base, base + len)`` where the LIVE keys span at most ``LUT_MAX``
+    (``base`` 0 while they fit from 0: the fixed-key fast path; a base
+    that moves on with a churning key space, refitted from the live keys
+    when a batch leaves the table), else a sorted array of the live keys
+    and ``np.searchsorted``. Either costs by the live keys, not by the
+    largest id seen.
 
-    def __init__(self, on_new: Optional[Callable[[Any, int], None]] = None
-                 ) -> None:
+    Slots given back (``release``) join ``free`` and are handed out again
+    before a new one: every slot below ``n_slots`` is live or free. A
+    caller that never releases gets the insertion-order slots it always
+    got. ``on_new_many(keys, slots)`` admits a batch's new int keys in
+    one call (else ``on_new`` a key); both may refuse (capacity) and then
+    nothing is registered. ``admit_span()`` is opened around a batch's
+    admission (the owner's stage span)."""
+
+    LUT_MAX = 1 << 22  # 16 MiB int32 ceiling for the direct table
+    DENSE_MAX = 1 << 16  # ids below this are looked up from 0 (base 0)
+
+    def __init__(self, on_new: Optional[Callable[[Any, int], None]] = None,
+                 on_new_many: Optional[Callable] = None,
+                 admit_span: Optional[Callable] = None) -> None:
         self.slot_of_key: Dict[Any, int] = {}
         self._on_new = on_new  # called as on_new(key, slot) for each new key
+        self._on_new_many = on_new_many  # (int keys array, slots array)
+        # a context around a batch's admission (the owner's stage span)
+        self._admit_span = admit_span or nullcontext
+        self.free: List[int] = []   # slots given back, reused last-in first
         self._lut = None
+        self._base = 0
+        self._sorted = None         # (keys, slots) sorted by key, or None
 
     def __len__(self) -> int:
         return len(self.slot_of_key)
 
+    @property
+    def n_slots(self) -> int:
+        """The high-water mark: slots below it are live or free."""
+        return len(self.slot_of_key) + len(self.free)
+
+    def reset_index(self) -> None:
+        """Drop the lookup index (the directory was replaced under it,
+        e.g. by a restore); the next batch rebuilds it."""
+        self._lut = None
+        self._base = 0
+        self._sorted = None
+
     def slot(self, key) -> int:
         s = self.slot_of_key.get(key)
         if s is None:
-            s = len(self.slot_of_key)
+            s = self.free[-1] if self.free else len(self.slot_of_key)
             if self._on_new is not None:
                 # on_new may refuse the key (capacity); it must run BEFORE
                 # registration so a raise leaves no stale entry that a
                 # caught-and-retried batch would silently reuse with an
                 # out-of-range slot
                 self._on_new(key, s)
-            self.slot_of_key[key] = s
+            if self.free:
+                self.free.pop()
+            self.assign(key, s)
         return s
+
+    def _admit(self, new: np.ndarray) -> np.ndarray:
+        """Slots for the distinct unseen int keys ``new``: free ones
+        first, then past the high-water mark. One callback for all where
+        the owner takes a batch."""
+        m = len(new)
+        with self._admit_span():
+            if self._on_new_many is None:
+                return np.fromiter((self.slot(int(k)) for k in new),
+                                   dtype=np.int64, count=m)
+            n_free = min(m, len(self.free))
+            reused = self.free[len(self.free) - n_free:]
+            top = self.n_slots
+            slots = np.concatenate([
+                np.asarray(reused[::-1], dtype=np.int64),
+                np.arange(top, top + m - n_free, dtype=np.int64)])
+            self._on_new_many(new, slots)  # may refuse: nothing mutated yet
+            if n_free:
+                del self.free[len(self.free) - n_free:]
+            self.slot_of_key.update(zip(new.tolist(), slots.tolist()))
+            self._sorted = None
+            return slots
+
+    def release(self, keys) -> None:
+        """Forget ``keys`` (an int array or a list) and give their slots
+        back; a key that returns is a new key."""
+        ks = keys.tolist() if isinstance(keys, np.ndarray) else list(keys)
+        self.free.extend(map(self.slot_of_key.pop, ks))
+        if not (isinstance(keys, np.ndarray) and keys.dtype.kind in "iu"):
+            self._lut = self._sorted = None
+            return
+        ka = keys.astype(np.int64)
+        if self._lut is not None:
+            rel = ka - self._base
+            self._lut[rel[(rel >= 0) & (rel < len(self._lut))]] = -1
+        if self._sorted is not None:
+            sk, ss = self._sorted
+            keep = np.ones(len(sk), bool)
+            keep[np.searchsorted(sk, ka)] = False
+            self._sorted = (sk[keep], ss[keep])
 
     # -- tiered-store slot reuse (windflow_tpu.state.tiered) ---------------
     # The tiered key store recycles slots of demoted keys, so slot ids are
@@ -79,20 +159,91 @@ class KeySlotMap:
     def assign(self, key, slot: int) -> None:
         """Register ``key`` at an explicit ``slot`` (tier promote)."""
         self.slot_of_key[key] = slot
+        self._sorted = None
         lut = self._lut
         if lut is not None and isinstance(key, (int, np.integer)) \
-                and 0 <= key < len(lut):
-            lut[key] = slot
+                and 0 <= key - self._base < len(lut):
+            lut[key - self._base] = slot
 
     def evict(self, key) -> None:
         """Forget ``key`` (tier demote); its slot is the caller's to
         recycle. The LUT entry must clear too — a stale hit would route
         the key to a slot now owned by someone else."""
         self.slot_of_key.pop(key, None)
+        self._sorted = None
         lut = self._lut
         if lut is not None and isinstance(key, (int, np.integer)) \
-                and 0 <= key < len(lut):
-            lut[key] = -1
+                and 0 <= key - self._base < len(lut):
+            lut[key - self._base] = -1
+
+    def _live_int_keys(self):
+        """``(keys, slots)`` int64 arrays of the directory's int keys."""
+        d = self.slot_of_key
+        try:
+            return (np.fromiter(d.keys(), dtype=np.int64, count=len(d)),
+                    np.fromiter(d.values(), dtype=np.int64, count=len(d)))
+        except (TypeError, ValueError, OverflowError):
+            pass    # a mixed directory: its int64 keys only
+        ks = [k for k in d if isinstance(k, (int, np.integer))
+              and -2**63 <= k < 2**63]
+        return (np.asarray(ks, dtype=np.int64),
+                np.asarray([d[k] for k in ks], dtype=np.int64))
+
+    def _fit_lut(self, kmin: int, kmax: int) -> bool:
+        """Lay the direct table over the live keys and ``[kmin, kmax]``;
+        False where they span more than ``LUT_MAX``."""
+        if self._sorted is not None and len(self._sorted[0]):
+            # looked up by search so far: the live range without a walk
+            kmin = min(kmin, int(self._sorted[0][0]))
+            kmax = max(kmax, int(self._sorted[0][-1]))
+        if kmax - kmin >= self.LUT_MAX:
+            self._lut = None
+            return False
+        keys, slots = self._live_int_keys()
+        if len(keys):
+            kmin, kmax = min(kmin, int(keys.min())), max(kmax,
+                                                         int(keys.max()))
+        if kmax - kmin >= self.LUT_MAX:
+            self._lut = None
+            return False
+        if 0 <= kmin and kmax < self.DENSE_MAX:
+            base, span = 0, kmax + 1     # small ids: the table from 0
+        else:
+            base, span = kmin, kmax - kmin + 1
+        size = min(self.LUT_MAX, 1 << max(10, (2 * span - 1).bit_length()))
+        lut = np.full(size, -1, dtype=np.int32)
+        lut[keys - base] = slots
+        self._lut, self._base = lut, base
+        return True
+
+    def _slots_by_table(self, keys_arr: np.ndarray) -> np.ndarray:
+        lut, base = self._lut, self._base
+        rel = keys_arr if base == 0 else keys_arr - base
+        slots = lut[rel]
+        miss = slots < 0
+        if miss.any():
+            new = np.unique(keys_arr[miss])
+            lut[new - base] = self._admit(new)
+            slots = lut[rel]
+        return slots
+
+    def _slots_by_search(self, keys_arr: np.ndarray) -> np.ndarray:
+        if self._sorted is None:
+            keys, slots = self._live_int_keys()
+            order = np.argsort(keys, kind="stable")
+            self._sorted = (keys[order], slots[order])
+        sk, ss = self._sorted
+        ka = keys_arr.astype(np.int64)
+        pos = np.minimum(np.searchsorted(sk, ka), max(len(sk) - 1, 0))
+        hit = sk[pos] == ka if len(sk) else np.zeros(len(ka), bool)
+        if hit.all():
+            return ss[pos]
+        new = np.unique(ka[~hit])
+        got = self._admit(new)
+        at = np.searchsorted(sk, new)
+        sk, ss = np.insert(sk, at, new), np.insert(ss, at, got)
+        self._sorted = (sk, ss)
+        return ss[np.searchsorted(sk, ka)]
 
     def slots_of(self, keys, keys_arr: np.ndarray, n: int) -> np.ndarray:
         """Vectorized mapping of a whole batch; int result of length n
@@ -106,22 +257,13 @@ class KeySlotMap:
         if keys_arr.dtype.kind in "iu" and n:
             kmin = int(keys_arr.min())
             kmax = int(keys_arr.max())
-            if 0 <= kmin and kmax < self.LUT_MAX:
+            if kmax < 2**63:
                 lut = self._lut
-                if lut is None or kmax >= len(lut):
-                    size = min(self.LUT_MAX,
-                               1 << max(10, (kmax + 1).bit_length()))
-                    new = np.full(size, -1, dtype=np.int32)
-                    if lut is not None:
-                        new[:len(lut)] = lut
-                    lut = self._lut = new
-                slots = lut[keys_arr]
-                miss = slots < 0
-                if miss.any():
-                    for k in np.unique(keys_arr[miss]):
-                        lut[k] = self.slot(int(k))
-                    slots = lut[keys_arr]
-                return slots
+                if (lut is not None and kmin >= self._base
+                        and kmax - self._base < len(lut)) \
+                        or self._fit_lut(kmin, kmax):
+                    return self._slots_by_table(keys_arr)
+                return self._slots_by_search(keys_arr)
         if keys_arr.dtype.kind in "iu":
             uniq, inverse = np.unique(keys_arr, return_inverse=True)
             slot_map = np.fromiter((self.slot(int(k)) for k in uniq),

@@ -111,12 +111,20 @@ def cached_compile(cache: Dict, lock, key, make):
 # same traced math.
 
 
+def own_key_spec(op):
+    """The field (or tuple of fields) ``op`` is keyed by, None for a
+    callable extractor: what a batch of ITS results says of its host
+    keys (``BatchTPU.key_origin``)."""
+    return op.key_field or getattr(op, "key_fields", None)
+
+
 def op_batch_keys(op, batch: "BatchTPU"):
-    """Per-batch keys for ``op``: host metadata when staged keyed, else
-    the device key column named by a string key extractor. Module-level
-    so fused sub-ops resolve keys with THEIR OWN key fields, not the
-    chain head's."""
-    keys = batch.host_keys
+    """Per-batch keys for ``op``: host metadata where it IS ``op``'s key
+    (staged for it, or made by a producer keyed by the same field:
+    ``BatchTPU.keys_for``), else the device key column(s) ``op`` names.
+    Module-level so fused sub-ops resolve keys with THEIR OWN key
+    fields, not the chain head's."""
+    keys = batch.keys_for(own_key_spec(op))
     if keys is None:
         field = op.key_field
         if field is not None:
@@ -134,7 +142,7 @@ def op_batch_keys(op, batch: "BatchTPU"):
 def op_batch_keys_np(op, batch: "BatchTPU"):
     """``(keys, keys_arr)`` with at most ONE conversion — the host-prep
     stage's hot path (see ``TPUReplicaBase.batch_keys_np``)."""
-    keys = batch.host_keys
+    keys = batch.keys_for(own_key_spec(op))
     if keys is None and op.key_field is not None \
             and op.key_field in batch.fields:
         arr = key_column_np(batch, op.key_field)
@@ -951,7 +959,7 @@ class _KeyedStateScan:
                 "a dense table — rebuild the graph with tiering enabled")
         self.slot_of_key.clear()  # shared alias with the KeySlotMap
         self.slot_of_key.update(state.get("slot_of_key", {}))
-        self._keymap._lut = None
+        self._keymap.reset_index()
         table = state.get("table")
         if self.tier is not None:
             if tier_blob is not None:
@@ -1314,6 +1322,7 @@ class ReduceTPUReplica(TPUReplicaBase):
                           out_keys)
             nb.stream_tag = batch.stream_tag
             nb.copy_trace_from(batch)
+            nb.key_origin = own_key_spec(self.op)  # this reduce's keys
             self._emit_batch(nb)
 
         return commit
