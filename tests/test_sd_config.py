@@ -212,6 +212,20 @@ def test_counters_of_the_window_operator(sd):
     assert sd["stats"]["narrow"]["Fire_lanes"] == 0
 
 
+def test_the_host_plans_by_the_key_and_its_counters_say_so(sd):
+    """PR 35: a count-based operator's prep builds no batch-sized plane
+    but the rows' slots (every dispatched batch), and the plan the host
+    builds a fire program is its chunk rows, at most a row a mote (five
+    at the rehearsal sizes), where the program runs hundreds of lanes.
+    An operator with no count-based window reads 0 in both."""
+    win = sd["stats"]["win"]
+    assert win["Prep_by_key_batches"] == win["Dispatch_batches"] > 4
+    assert 0 < win["Fire_plan_rows"] <= 5 * win["Fire_programs"]
+    assert win["Fire_plan_rows"] * 10 < win["Fire_lanes"]
+    for other in (sd["stats"]["narrow"], exit_stats(sd)):
+        assert other["Prep_by_key_batches"] == 0 == other["Fire_plan_rows"]
+
+
 def test_sliding_programs_are_the_ones_the_rule_names(sd):
     """``Fire_sliding_programs`` is ``Fire_programs`` minus the programs
     the rule left on the lane walk. At the rehearsal sizes (8 slots, a
@@ -236,13 +250,16 @@ def test_sliding_programs_are_the_ones_the_rule_names(sd):
     ("fire_lane_occupancy.sd", 80.0, 100.0, "Fire_lanes"),
     ("fire_lanes_per_program.sd", 64.0, 512.0, "Fire_lanes"),
     ("fire_sliding_share.sd", 100.0, 100.0, "Fire_sliding_programs"),
+    ("fire_plan_rows_per_program.sd", 1.0, 5.0, "Fire_plan_rows"),
+    ("prep_by_key_share.sd", 100.0, 100.0, "Prep_by_key_batches"),
     ("windows_per_fire_program.sd", 64.0, 512.0, None),
     ("fire_programs_per_batch.sd", 1.0, 2.0, None),
     ("filter_pass_share.sd", 2.0, 8.0, None)])
 def test_the_sd_metrics_read_the_counters(sd, name, low, high, new):
     """The counter metrics of the cell read this run's stats inside their
     range; those that read a counter a later PR brought (``new``:
-    ``Fire_lanes``, PR 32; ``Fire_sliding_programs``, PR 33) give
+    ``Fire_lanes``, PR 32; ``Fire_sliding_programs``, PR 33;
+    ``Fire_plan_rows`` and ``Prep_by_key_batches``, PR 35) give
     nothing, not 0, for a program from before the counter existed."""
     cell = sd["cell"]
     entry, spec = [(m, f) for m, f in cell.metrics("per_layer")
@@ -275,7 +292,9 @@ def test_every_sd_metric_has_its_file_and_lists_the_cell_alone():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     mine = [m for m in bench["per_layer"] if m["name"].endswith(".sd")]
-    assert len(mine) == 10 and mine[-1]["name"] == "fire_sliding_share.sd"
+    assert len(mine) == 12 and [m["name"] for m in mine[-3:]] == [
+        "fire_sliding_share.sd", "fire_plan_rows_per_program.sd",
+        "prep_by_key_share.sd"]
     assert not any(m["name"].startswith("fire_grouped_share")
                    for m in mine)
     for m in mine:

@@ -299,9 +299,30 @@ def row_schema(fields: Mapping, schema: Optional[TupleSchema]
                         for name, v in fields.items()})
 
 
+class ChunkedKeys:
+    """The keys of a fired batch by CHUNK: ``counts[i]`` consecutive rows
+    hold ``keys[i]`` (an array of int keys, else a list). What a window
+    operator hands ``BatchTPU`` in place of a key a row: most consumers
+    of fired windows read no host keys, and the one that does expands
+    them on its first read (``BatchTPU.host_keys``)."""
+
+    __slots__ = ("keys", "counts")
+
+    def __init__(self, keys, counts: np.ndarray) -> None:
+        self.keys = keys
+        self.counts = counts
+
+    def expand(self):
+        if isinstance(self.keys, np.ndarray):
+            return np.repeat(self.keys, self.counts)  # numpy, no boxing
+        # composite/object keys (callable extractors)
+        return [key for key, n in zip(self.keys, self.counts.tolist())
+                for _ in range(n)]
+
+
 class BatchTPU(StreamMsg):
     __slots__ = ("fields", "ts_host", "size", "capacity", "wm", "is_punct",
-                 "stream_tag", "id", "schema", "host_keys", "key_slots",
+                 "stream_tag", "id", "schema", "_host_keys", "key_slots",
                  "slot_of_key", "trace_min", "trace_max", "bid", "cause",
                  "key_origin")
 
@@ -322,7 +343,9 @@ class BatchTPU(StreamMsg):
         self.id = 0
         self.schema = schema
         # keyed metadata (present on keyby-staged batches):
-        self.host_keys = host_keys  # list of python keys, len == size
+        # python keys (a list, or an array of ints), len == size; or
+        # ``ChunkedKeys``, expanded where ``host_keys`` is first read
+        self._host_keys = host_keys
         self.key_slots = key_slots  # jax int32 (capacity,): dense slot ids
         self.slot_of_key = slot_of_key  # key -> slot id for this batch
         # the field (or tuple of fields) ``host_keys`` are the values of,
@@ -438,6 +461,17 @@ class BatchTPU(StreamMsg):
         self.cause = src.cause
         return self
 
+    @property
+    def host_keys(self):
+        keys = self._host_keys
+        if isinstance(keys, ChunkedKeys):
+            keys = self._host_keys = keys.expand()
+        return keys
+
+    @host_keys.setter
+    def host_keys(self, keys) -> None:
+        self._host_keys = keys
+
     def caused_by(self, src: "BatchTPU") -> "BatchTPU":
         """A NEW batch made while committing ``src`` (one of several
         gathered from it): origin stamps travel, the identity is fresh
@@ -466,7 +500,7 @@ class BatchTPU(StreamMsg):
         """Same metadata, new device columns (in-place operator output)."""
         schema = row_schema(new_fields, self.schema)
         b = BatchTPU(new_fields, self.ts_host, self.size, schema,
-                     self.wm, self.host_keys, self.key_slots,
+                     self.wm, self._host_keys, self.key_slots,
                      self.slot_of_key)
         b.stream_tag = self.stream_tag
         b.id = self.id
@@ -475,7 +509,7 @@ class BatchTPU(StreamMsg):
     def copy_for_dest(self) -> "BatchTPU":
         """Broadcast copy: device arrays are immutable, sharing is safe."""
         b = BatchTPU(self.fields, self.ts_host, self.size, self.schema,
-                     self.wm, self.host_keys, self.key_slots,
+                     self.wm, self._host_keys, self.key_slots,
                      self.slot_of_key)
         b.stream_tag = self.stream_tag
         b.id = self.id

@@ -15,7 +15,13 @@ TPU-first redesign:
   all (the reference pays a D2H of its unique arrays every batch,
   ``ffat_replica_gpu.hpp:945-988``). Segmentation (sort order + run
   detection) happens IN-PROGRAM, on one packed composite column the
-  host ships per batch, so it overlaps the host control plane;
+  host ships per batch, so it overlaps the host control plane. For
+  COUNT-BASED windows the host's half goes by the key: what it knows of
+  a batch is per key slot (a slot's rows are numbered from its count in
+  arrival order, its fired windows are consecutive), so it ships the
+  rows' slots and a few words a slot (``cb_pack_views``), and the
+  program numbers its own rows in that sort (``cb_number_rows``) and
+  expands its own fire lanes (``cb_plan_lanes``);
 - the data plane is ONE jitted XLA program per batch:
     lift(columns) -> sort of the packed (slot, leaf) composite ->
     gather(sort order) -> segmented associative scan with
@@ -87,7 +93,7 @@ import numpy as np
 
 from ..basic import OpType, RoutingMode, WinType, WindFlowError
 from ..monitoring.tracing import next_batch_id
-from .batch import BatchTPU, bucket_capacity, field_dtype
+from .batch import BatchTPU, ChunkedKeys, bucket_capacity, field_dtype
 from .ops_tpu import TPUOperatorBase, TPUReplicaBase, own_key_spec
 from .schema import TupleSchema, broadcast_scalar_fields
 
@@ -160,6 +166,107 @@ def fire_pack_views(pack, slide_units: int):
     return (pack[:6 * W].reshape(6, W),
             pack[6 * W:6 * W + n_g].reshape(G_CAP + 1, 2),
             pack[6 * W + n_g:].reshape(3, W * slide_units))
+
+
+def cb_pack_len(W: int, K_cap: int) -> int:
+    """Words of the ONE int32 buffer a COUNT-BASED program takes from the
+    host (see ``cb_pack_views``) at a width of ``W`` lanes."""
+    return 2 * K_cap + 5 * min(K_cap, W) + 1
+
+
+def cb_pack_views(pack, K_cap: int):
+    """``(keyrows, chunks, total)`` views of a count-based program's flat
+    buffer, on the host (numpy, to fill it) and inside the program
+    (static slices). Everything in it is per KEY; what is per row of the
+    batch or per lane of the fire block the program derives:
+
+    - ``keyrows`` (2, K_cap), read by the step's ingest: ``base``, a
+      slot's arrival count before the batch (mod ``F``: the ring place
+      of its next leaf), and ``skip``, how many of its first arrivals
+      in the batch lie behind its ``next_fire`` and are dropped (gap
+      windows, a re-registered key). A fire-only program leaves them 0;
+    - ``chunks`` (5, C) rows slot, start0 (mod ``F``), k, wid0, span: a
+      slot's ``k`` consecutive windows from ring place ``start0`` and
+      window id ``wid0``, over ``span`` leaves of data from ``start0``
+      on (``max_leaf + 1 - start0``). A program holds one chunk a slot
+      and a lane a window, so ``C = min(K_cap, W)``; rows past the
+      plan's chunks are 0 (``k`` 0: no lane);
+    - ``total`` (1,), the buffer's first word: the plan's windows, the
+      sum of ``k``.
+
+    Lane ``i`` of chunk ``c``, round ``r = i - (windows of the chunks
+    before c)``: start ``start0 + r * slide``, length ``min(win, span -
+    r * slide)``, window id ``wid0 + r``; it evicts the ``slide``
+    leaves from its start that lie inside ``span``."""
+    n_k = 1 + 2 * K_cap
+    C = (pack.shape[0] - n_k) // 5
+    return (pack[1:n_k].reshape(2, K_cap),
+            pack[n_k:n_k + 5 * C].reshape(5, C), pack[:1])
+
+
+def cb_plan_lanes(fire_plan, W: int, K_cap: int, F: int, win_units: int,
+                  slide_units: int):
+    """In a program: the lanes of a count-based plan, expanded from its
+    chunk rows (``cb_pack_views``) at the static width ``W``: ``(slots,
+    starts, lens, wids, mask, rounds, eflat)``, a lane each but ``eflat``,
+    the flat forest indices of the ``W * slide_units`` leaves evicted
+    (out of bounds where there is none). A lane finds its chunk through
+    a mark at each chunk's first lane and one cumulative sum. Masked
+    lanes read 0 in every row, as in a plan the host lays out by lane."""
+    import jax.numpy as jnp
+
+    _keyrows, chunks, total = cb_pack_views(fire_plan, K_cap)
+    c_k = chunks[2]
+    before = jnp.cumsum(c_k) - c_k
+    marks = jnp.zeros((W,), jnp.int32).at[
+        jnp.where(c_k > 0, before, W)].add(1, mode="drop")
+    chunk = jnp.maximum(jnp.cumsum(marks) - 1, 0)
+    lane = jnp.arange(W, dtype=jnp.int32)
+    mask = lane < total[0]
+    # ONE gather of a lane's six words (what a gather from a small 1-D
+    # table costs in program text: cb_number_rows)
+    c_slot, c_start0, _k, c_wid0, c_span, c_before = jnp.where(
+        mask[None, :], jnp.concatenate([chunks, before[None]])[:, chunk], 0)
+    rounds = jnp.where(mask, lane - c_before, 0)
+    off = rounds * slide_units
+    starts = (c_start0 + off) & (F - 1)
+    lens = jnp.minimum(win_units, c_span - off)
+    # a lane evicts the ``slide`` leaves from its start, as far as the
+    # chunk's data goes: over a chunk's rounds, the range [start0,
+    # start0 + k * slide) clipped to the data
+    e_off = off[:, None] + jnp.arange(slide_units, dtype=jnp.int32)[None, :]
+    eflat = jnp.where(
+        mask[:, None] & (e_off < c_span[:, None]),
+        c_slot[:, None] * (2 * F) + (F + ((c_start0[:, None] + e_off)
+                                          & (F - 1))),
+        K_cap * 2 * F).reshape(-1)
+    return c_slot, starts, lens, c_wid0 + rounds, mask, rounds, eflat
+
+
+def cb_number_rows(slots, keyrows, K_cap: int, F: int):
+    """In a step: ``(order, sc)`` of a count-based batch, the stable
+    sort order of its rows' ``slots`` (sentinel ``K_cap``: padding, rows
+    a fused filter dropped) and the SORTED packed composite ``slot * F +
+    leaf`` (sentinel ``K_cap * F``: those rows and the late ones) that
+    the host ships ready-made for time-based windows. The stable sort
+    leaves a slot's rows in arrival order; a row's rank in its slot's
+    run numbers it from ``base``, the slot's count before the batch, and
+    the slot's first ``skip`` rows are late (``keyrows``:
+    ``cb_pack_views``)."""
+    import jax
+    import jax.numpy as jnp
+
+    order = jnp.argsort(slots, stable=True)
+    ss = slots[order].astype(jnp.int32)
+    pos = jnp.arange(ss.shape[0], dtype=jnp.int32)
+    run_start = jnp.concatenate([jnp.ones((1,), bool), ss[1:] != ss[:-1]])
+    rank = pos - jax.lax.cummax(jnp.where(run_start, pos, 0))
+    # ONE gather of both words: a gather from a small 1-D table unrolls
+    # to seven times the program text of this one (PERF.md section 6)
+    base, skip = keyrows[:, jnp.minimum(ss, K_cap - 1)]
+    return order, jnp.where(
+        (ss < K_cap) & (rank >= skip),
+        ss * F + ((base + rank) & (F - 1)), K_cap * F)
 
 
 def xla_rebuild_levels(combine: Callable, F: int):
@@ -361,10 +468,12 @@ class FfatTPUReplica(TPUReplicaBase):
         self._check_index_plane()
 
     def _comp_dtype(self):
-        """(sentinel M, dtype) of the packed composite — the SINGLE
-        definition shared by staging, warm-up, and the driver entry
-        (the traced and runtime dtypes must stay bit-identical)."""
-        M = self.K_cap * self.F
+        """(sentinel M, dtype) of the one batch-sized column the host
+        ships a step: the packed composite (time-based windows) or the
+        rows' slots (count-based: the step forms the composite itself) —
+        the SINGLE definition shared by staging, warm-up, and the driver
+        entry (the traced and runtime dtypes must stay bit-identical)."""
+        M = self.K_cap * (1 if self.op.win_type is WinType.CB else self.F)
         return M, (np.int16 if M < 2**15 - 1 else np.int32)
 
     def _check_index_plane(self, k_cap: int = 0, f: int = 0) -> None:
@@ -413,12 +522,17 @@ class FfatTPUReplica(TPUReplicaBase):
     # ==================================================================
     # the per-batch device program
     # ==================================================================
-    def _query_fns(self):
+    def _query_fns(self, W: Optional[int] = None):
         """``fire_block(trees, tvalid, fire_plan, ktable) -> (tvalid,
         values, valid, wid column, key column)``: what the full step and
-        the fire-only step do with a program's fire plan
-        (``fire_pack_views``): answer its fired windows, evict the leaves
-        they consumed, build the ``wid`` and key columns. Three queries
+        the fire-only step do with a program's fire plan: answer its
+        fired windows, evict the leaves they consumed, build the ``wid``
+        and key columns. A time-based plan is the host's lanes
+        (``fire_pack_views``) and its shape is the program's width; a
+        count-based plan is chunk rows (``cb_pack_views``) that the
+        program expands into the same lane arrays (``cb_plan_lanes``) at
+        ``W``, its static width, which the plan's shape does not tell
+        (None: the operator's budget). Three queries
         answer the windows. A program holds the ones its operator can
         take: time-based windows the first two, chosen inside the
         program by the count in the plan's group table (see
@@ -479,6 +593,8 @@ class FfatTPUReplica(TPUReplicaBase):
         # keys share a ring range, so their programs hold no group walk
         grouped = self.op.win_type is WinType.TB
         win_units = self.win_units
+        if W is None:
+            W = self.W_cap
 
         def comb_valid(va, a, vb, b):
             """Ordered combine with validity: an invalid side passes the
@@ -638,9 +754,14 @@ class FfatTPUReplica(TPUReplicaBase):
                                               tr)
 
         def fire_block(trees, tvalid, fire_plan, ktable):
-            fire, g_table, evict = fire_pack_views(fire_plan, slide_units)
-            slots, starts, lens, wids, mask_i, group = fire
-            mask = mask_i != 0
+            if grouped:
+                fire, g_table, evict = fire_pack_views(fire_plan, slide_units)
+                slots, starts, lens, wids, mask_i, group = fire
+                mask = mask_i != 0
+            else:
+                slots, starts, lens, wids, mask, group, eflat = \
+                    cb_plan_lanes(fire_plan, W, K_cap, F, win_units,
+                                  slide_units)
             with jax.named_scope(SCOPE_FIRE):
                 if grouped:
                     qv, qr = jax.lax.cond(
@@ -648,8 +769,8 @@ class FfatTPUReplica(TPUReplicaBase):
                         lambda: by_group(trees, tvalid, slots, group,
                                          g_table),
                         lambda: by_lane(trees, tvalid, slots, starts, lens))
-                elif fire_slides(slots.shape[0], K_cap, F):
-                    # a count-based plan's row 5 is the lanes' rounds
+                elif fire_slides(W, K_cap, F):
+                    # a count-based plan's ``group`` is the lanes' rounds
                     qv, qr = by_scan(trees, tvalid, slots, group, starts,
                                      mask)
                 else:
@@ -657,11 +778,12 @@ class FfatTPUReplica(TPUReplicaBase):
                 qv = qv & mask
             # evict leaves consumed by the fired windows
             with jax.named_scope(SCOPE_EVICT):
-                evict_slots, evict_leaves, evict_mask_i = evict
-                eflat = jnp.where(
-                    evict_mask_i != 0,
-                    evict_slots * NNODES + (F + evict_leaves),
-                    K_cap * NNODES)  # masked lanes: out of bounds, dropped
+                if grouped:
+                    evict_slots, evict_leaves, evict_mask_i = evict
+                    eflat = jnp.where(
+                        evict_mask_i != 0,
+                        evict_slots * NNODES + (F + evict_leaves),
+                        K_cap * NNODES)  # masked lanes: out of bounds
                 tvalid = tvalid.reshape(-1).at[eflat].set(
                     False, mode="drop").reshape(tvalid.shape)
             # output wid/key columns built ON DEVICE: they ride the
@@ -677,8 +799,11 @@ class FfatTPUReplica(TPUReplicaBase):
         return fire_block
 
     def _make_step(self, cap: int, donate: bool = True,
-                   ingest_only: bool = False):
-        """``ingest_only=True`` builds the DEFERRED-REBUILD variant: lift
+                   ingest_only: bool = False, W: Optional[int] = None):
+        """``W``: the width of a count-based program's fire block
+        (_query_fns).
+
+        ``ingest_only=True`` builds the DEFERRED-REBUILD variant: lift
         + segmented scan + leaf scatter only — no level rebuild, no
         window queries, no eviction. Used for batches the host control
         plane already knows fire NOTHING (chunks empty): leaves stay
@@ -700,21 +825,29 @@ class FfatTPUReplica(TPUReplicaBase):
         OOB = K_cap * NNODES  # scatter target for masked lanes (mode=drop)
 
         tmap = jax.tree_util.tree_map
-        fire_block = self._query_fns()
+        fire_block = self._query_fns(W)
         rebuild_levels = xla_rebuild_levels(combine, F)
+        counted = self.op.win_type is WinType.CB
 
         def step(fields, comp, trees, tvalid, fire_plan, ktable):
-            # 1. lift + sort + segmented scan. The host ships ONE packed
-            # composite column (slot*F+leaf, sentinel K_cap*F for late and
-            # padding lanes) in the narrowest int dtype (_comp_dtype); the
-            # sort order and the run boundaries are computed here, so they
-            # overlap the host control plane of the next batch.
+            # 1. lift + sort + segmented scan. The host ships ONE
+            # batch-sized column in the narrowest int dtype (_comp_dtype);
+            # the sort order and the run boundaries are computed here, so
+            # they overlap the host control plane of the next batch.
+            # Time-based windows: the packed composite slot*F+leaf,
+            # sentinel K_cap*F for late and padding lanes. Count-based
+            # windows: the rows' SLOTS, and the composite is formed here
+            # (cb_number_rows).
             vals = broadcast_scalar_fields(
                 lift(fields), next(iter(fields.values())).shape[0])
             with jax.named_scope(SCOPE_SORT):
                 big = jnp.int32(K_cap * F)  # sentinel: late + padding
-                order = jnp.argsort(comp, stable=True)
-                sc = comp[order].astype(jnp.int32)
+                if counted:
+                    order, sc = cb_number_rows(
+                        comp, cb_pack_views(fire_plan, K_cap)[0], K_cap, F)
+                else:
+                    order = jnp.argsort(comp, stable=True)
+                    sc = comp[order].astype(jnp.int32)
                 same_prev = jnp.concatenate(
                     [jnp.zeros((1,), bool), sc[1:] == sc[:-1]])
                 is_end = jnp.concatenate(
@@ -787,7 +920,7 @@ class FfatTPUReplica(TPUReplicaBase):
             step, self.stats, label=f"{self.stats.op_name}:step",
             program=PROG_STEP, donate_argnums=(2, 3) if donate else ())
 
-    def _make_fire_step(self):
+    def _make_fire_step(self, W: Optional[int] = None):
         """Fire-only program: window queries (_query_fns) + leaf eviction,
         no lift/scan/scatter/rebuild. Used for drain iterations after the
         first per-batch step and for data-less firing (punctuation/EOS).
@@ -820,7 +953,7 @@ class FfatTPUReplica(TPUReplicaBase):
         and validity alone ends a partial window."""
         # tvalid donated (in-place eviction); trees is read-only here
         from ..monitoring.flightrec import instrumented_jit
-        return instrumented_jit(self._query_fns(), self.stats,
+        return instrumented_jit(self._query_fns(W), self.stats,
                                 label=f"{self.stats.op_name}:fire",
                                 program=PROG_FIRE, donate_argnums=(1,))
 
@@ -1067,6 +1200,8 @@ class FfatTPUReplica(TPUReplicaBase):
         (growth, program warm-up) drain the pipeline first."""
         op = self.op
         n = batch.size
+        if op.win_type is WinType.CB:
+            self.stats.prep_by_key_batches += 1     # see _prep_by_key
         if n == 0:
             return None
         self._ensure_forest(batch.fields)
@@ -1097,23 +1232,19 @@ class FfatTPUReplica(TPUReplicaBase):
                     else [keys[i] for i in rowsel])
             keys_arr = sub_arr
             n_rows = len(rowsel)
-            ts_rows = batch.ts_host[:n][rowsel]
         else:
             n_rows = n
-            ts_rows = batch.ts_host[:n]
         slots = self._slots_of(keys, keys_arr, n_rows)
         from ..checkpoint.delta import env_ckpt_delta
         if env_ckpt_delta() and n_rows:
             # every row this batch touches is dirty vs the delta base
             self._ckpt_dirty.update(np.unique(slots).tolist())
-        if op.win_type is WinType.TB:
-            leaves = ts_rows // op.pane_len
-        else:
-            # CB: leaf = per-key arrival index (stable within the batch)
-            from .keymap import group_positions
-            _, within = group_positions(slots, self.K_cap)
-            leaves = self.count[slots] + within
-            np.add.at(self.count, slots, 1)
+        if op.win_type is WinType.CB:
+            return self._prep_by_key(batch, slots, rowsel)
+        ts_rows = batch.ts_host[:n]
+        if rowsel is not None:
+            ts_rows = ts_rows[rowsel]
+        leaves = ts_rows // op.pane_len
         # align brand-new keys to the first window containing their first
         # leaf: without this, an epoch-scale first timestamp would demand a
         # ring spanning all of absolute time (OOM via _grow_ring).
@@ -1127,8 +1258,7 @@ class FfatTPUReplica(TPUReplicaBase):
         # alignment must re-run every batch (pre-gate behavior; regression
         # test: gap_windows_late_first_key_reanchor).
         born = None     # slots registered (or still untouched) here
-        if op.win_type is WinType.TB and (
-                self._saw_new_key or self.slide_units > self.win_units):
+        if self._saw_new_key or self.slide_units > self.win_units:
             self._saw_new_key = False
             fresh = self.max_leaf[slots] < 0
             if fresh.any():
@@ -1157,22 +1287,18 @@ class FfatTPUReplica(TPUReplicaBase):
         # unified late accounting: this host-side mask is the SAME
         # late/sentinel classification the packed composite below encodes
         # for the device program — export it instead of discarding it.
-        # TB: every dropped row sits behind the fired-window frontier,
+        # Every dropped row sits behind the fired-window frontier,
         # hence behind the watermark, so late_records ⊇ late_dropped and
         # Late_admitted = records - dropped stays exact
         st = self.stats
-        if op.win_type is WinType.TB:
-            late_mask = ts_rows < batch.wm
-            if n_late:
-                late_mask = late_mask | ~live
-            n_late_seen = int(late_mask.sum())
-            if n_late_seen:
-                st.note_late(n_late_seen, n_late,
-                             batch.wm - ts_rows[late_mask]
-                             if st.hist_lateness is not None else None)
-        elif n_late:
-            # CB: order-based drops (gap windows / re-registered keys)
-            st.note_late(n_late, n_late)
+        late_mask = ts_rows < batch.wm
+        if n_late:
+            late_mask = late_mask | ~live
+        n_late_seen = int(late_mask.sum())
+        if n_late_seen:
+            st.note_late(n_late_seen, n_late,
+                         batch.wm - ts_rows[late_mask]
+                         if st.hist_lateness is not None else None)
         if n_late:
             self.ignored += n_late
             self.stats.inputs_ignored += n_late
@@ -1231,10 +1357,47 @@ class FfatTPUReplica(TPUReplicaBase):
             # segment plane treats them exactly like late/padding lanes
             comp_p[rowsel] = packed
 
-        frontier = (max(0, batch.wm - op.lateness) // op.pane_len
-                    if op.win_type is WinType.TB else None)
+        frontier = max(0, batch.wm - op.lateness) // op.pane_len
         return self._prep_step(batch.fields, batch.wm, cap, comp_p, frontier,
                                batch.bid)
+
+    def _prep_by_key(self, batch: BatchTPU, slots: np.ndarray, rowsel):
+        """The rest of a COUNT-BASED operator's prep, by the key: a
+        slot's rows are numbered ``count .. count + n - 1`` in arrival
+        order and the first of them that lie behind its ``next_fire``
+        (gap windows, a re-registered key) are dropped, so two words a
+        slot say everything of the batch's rows that the step needs
+        (``cb_pack_views``: ``base``, ``skip``), and the step numbers
+        the rows itself, in the sort it does anyway. The one pass by
+        row here is the count of each slot's rows; ``slots`` (the
+        surviving rows', where ``rowsel`` names the rows a fused prefix
+        filter kept) is the one batch-sized plane built, with the
+        sentinel ``K_cap`` on padding and on dropped rows, which so take
+        no rank, no leaf and no count."""
+        n_k = np.bincount(slots, minlength=self.K_cap)
+        skip = np.clip(self.next_fire - self.count, 0, n_k)
+        n_late = int(skip.sum())
+        if n_late:
+            # order-based drops: behind no watermark, all of them dropped
+            self.stats.note_late(n_late, n_late)
+            self.ignored += n_late
+            self.stats.inputs_ignored += n_late
+        live = np.nonzero(n_k > skip)[0]
+        if live.size:
+            last = self.count[live] + n_k[live] - 1
+            span = int((last - self.next_fire[live]).max())
+            if span >= self.F:
+                self._grow_ring(span)
+            self.max_leaf[live] = last
+        keyrows = np.stack([self.count & (self.F - 1), skip]
+                           ).astype(np.int32)
+        self.count += n_k
+        cap = batch.capacity
+        M, cdt = self._comp_dtype()
+        slots_p = np.full(cap, M, dtype=cdt)
+        slots_p[slice(batch.size) if rowsel is None else rowsel] = slots
+        return self._prep_step(batch.fields, batch.wm, cap, slots_p, None,
+                               batch.bid, keyrows)
 
     # ------------------------------------------------------------------
     def _eligible(self, frontier, partial: bool):
@@ -1399,8 +1562,6 @@ class FfatTPUReplica(TPUReplicaBase):
             g_table[:n_groups, 1] = pairs % self.F
             g_table[G_CAP, 0] = n_groups
             f_pack[5, :n_out] = group
-        elif self.op.win_type is WinType.CB:
-            f_pack[5, :n_out] = rnd  # what the sliding scan picks by
         # evicted panes: one contiguous range per chunk
         ne = np.maximum(
             0, np.minimum(c_start0 + c_k * self.slide_units, c_ml + 1)
@@ -1415,18 +1576,25 @@ class FfatTPUReplica(TPUReplicaBase):
 
     def _pack_fire_arrays(self, chunks, W: int):
         """Chunk arrays -> the packed fire plan of a program of width
-        ``W`` (_pack_plan; jit re-traces per shape) and the count of
-        ranges it is answered by. The group table holds the distinct
-        ``(start_phys, length)`` pairs of the lanes where there are at
-        most ``G_CAP``; else, and for count-based windows, the count is
-        0 and the program walks by lane."""
-        _slots, c_start0, c_k, _wid0, c_ml = chunks
+        ``W`` and the count of ranges it is answered by. Time-based
+        windows: the plan by lane (_pack_plan; jit re-traces per shape);
+        the group table holds the distinct ``(start_phys, length)``
+        pairs of the lanes where there are at most ``G_CAP``, else the
+        count is 0 and the program walks by lane. Count-based windows:
+        the chunk rows themselves (``cb_pack_views``), which the program
+        expands; no ranges."""
+        c_slots, c_start0, c_k, c_wid0, c_ml = chunks
+        if self.op.win_type is WinType.CB:
+            pack = np.zeros(cb_pack_len(W, self.K_cap), dtype=np.int32)
+            _keyrows, rows, total = cb_pack_views(pack, self.K_cap)
+            rows[:, :c_k.size] = (c_slots, c_start0 & (self.F - 1), c_k,
+                                  c_wid0, c_ml + 1 - c_start0)
+            total[0] = c_k.sum()
+            return pack, 0
         lanes = self._lanes(c_start0, c_k, c_ml)
-        ranges = None
-        if self.op.win_type is WinType.TB:
-            ranges = self._ranges(lanes[1], lanes[2])
-            if ranges[0].size > G_CAP:
-                ranges = None
+        ranges = self._ranges(lanes[1], lanes[2])
+        if ranges[0].size > G_CAP:
+            ranges = None
         return self._pack_plan(chunks, W, lanes, ranges)
 
     def _plan_program(self, slots, k):
@@ -1556,24 +1724,47 @@ class FfatTPUReplica(TPUReplicaBase):
         self.W_wide = W
         return True
 
+    def _plan_len(self, W: int) -> int:
+        """Words of the plan buffer of a program ``W`` lanes wide."""
+        if self.op.win_type is WinType.CB:
+            return cb_pack_len(W, self.K_cap)
+        return fire_pack_len(W, self.slide_units)
+
     def _zero_fire(self, W: int):
-        """Device-resident all-zero fire plan for non-firing steps
-        (cached per budget: zero steady-state transfer)."""
-        z = self._zero_fire_cache.get(W)
+        """Device-resident all-zero fire plan (cached per length: zero
+        steady-state transfer): a time-based step that fires nothing,
+        and every warm-up run. A count-based step's buffer is never it:
+        it carries the batch's ``keyrows`` whatever fires."""
+        n = self._plan_len(W)
+        z = self._zero_fire_cache.get(n)
         if z is None:
             import jax
-            z = self._zero_fire_cache[W] = jax.device_put(np.zeros(
-                fire_pack_len(W, self.slide_units), dtype=np.int32))
+            z = self._zero_fire_cache[n] = jax.device_put(
+                np.zeros(n, dtype=np.int32))
         return z
+
+    def _wkey(self, key, W: int):
+        """Cache key of the program ``key`` at fire width ``W``: a
+        time-based program's width is its plan's shape (one jitted
+        function, traced per shape), a count-based program's a constant
+        it is built with (the chunk rows of its plan do not tell it)."""
+        return key + (W,) if self.op.win_type is WinType.CB else key
 
     def _fire_key(self):
         return ("fire", self.K_cap, self.F, self._use_ktable(),
                 str(self._key_dtype))
 
-    def _fire_step(self):
+    def _fire_step(self, W: int):
         from .ops_tpu import cached_compile
         return cached_compile(self._prog_cache, self.op._prog_lock,
-                              self._fire_key(), self._make_fire_step)
+                              self._wkey(self._fire_key(), W),
+                              lambda: self._make_fire_step(W))
+
+    def _full_step(self, ckey, cap: int, W: int):
+        from .ops_tpu import cached_compile
+        return cached_compile(self._prog_cache, self.op._prog_lock,
+                              self._wkey(ckey, W),
+                              lambda: self._make_step(cap, W=W))
 
     def _warm_fire_step(self) -> None:
         """Compile the fire-only program EAGERLY (masked no-op runs) at
@@ -1588,7 +1779,7 @@ class FfatTPUReplica(TPUReplicaBase):
                 continue  # e.g. a new batch-capacity bucket
             # all-masked no-op run (both queries compile with the
             # program, whichever runs); tvalid is DONATED: reassign it
-            self.tvalid, *_ = self._fire_step()(
+            self.tvalid, *_ = self._fire_step(W)(
                 self.trees, self.tvalid, self._zero_fire(W),
                 self._ktable_arg())
             self._warm_shapes.add((fkey, W))
@@ -1604,8 +1795,6 @@ class FfatTPUReplica(TPUReplicaBase):
         is idempotent); trees/tvalid are DONATED, so each run reassigns
         them."""
         from .ops_tpu import cached_compile
-        step = cached_compile(self._prog_cache, self.op._prog_lock,
-                              ckey, lambda: self._make_step(cap))
         istep = cached_compile(
             self._prog_cache, self.op._prog_lock, ikey,
             lambda: self._make_step(cap, ingest_only=True))
@@ -1620,7 +1809,7 @@ class FfatTPUReplica(TPUReplicaBase):
         for W in sorted({self.W_step, self.W_cap, self.W_wide}):
             if (ckey, W) in self._warm_shapes:
                 continue
-            (self.trees, self.tvalid, *_) = step(
+            (self.trees, self.tvalid, *_) = self._full_step(ckey, cap, W)(
                 fields, comp_s, self.trees, self.tvalid,
                 self._zero_fire(W), ktable)
             self._warm_shapes.add((ckey, W))
@@ -1659,7 +1848,8 @@ class FfatTPUReplica(TPUReplicaBase):
             fields = prewarm_zero_fields(entry, cap)
             self._ensure_forest(fields)
             ckey, ikey = self._step_keys(cap)
-            if ckey in self._prog_cache and ikey in self._prog_cache:
+            if (self._wkey(ckey, self.W_cap) in self._prog_cache
+                    and ikey in self._prog_cache):
                 continue
             self._warm_programs(cap, ckey, ikey, fields, self._ktable_arg())
             warmed += 1
@@ -1677,13 +1867,18 @@ class FfatTPUReplica(TPUReplicaBase):
         ikey = ("ingest", cap, self.K_cap, self.F, tag)
         return ckey, ikey
 
-    def _prep_step(self, fields, wm, cap, comp_p, frontier, bid: int = 0):
+    def _prep_step(self, fields, wm, cap, comp_p, frontier, bid: int = 0,
+                   keyrows=None):
         """Host half of the per-batch step: program warm-up, the ENTIRE
         fire plan — every program's chunk arrays and packed fire/evict
         args, computed up front because the planner reads host metadata
         only (no control decision ever waits on a device result) — and,
         for the operators that keep the tiers, the fire-rate EWMA.
-        Returns the device-commit thunk for the dispatch pipeline."""
+        ``keyrows``: a count-based batch's per-slot words
+        (_prep_by_key), which ride the step's plan buffer, an empty plan
+        where the step fires nothing (so the cached all-zero plan never
+        serves a count-based step). Returns the device-commit thunk for
+        the dispatch pipeline."""
         ktable = self._ktable_arg()
         ckey, ikey = self._step_keys(cap)
         self._cap_seen = max(self._cap_seen, cap)
@@ -1714,10 +1909,20 @@ class FfatTPUReplica(TPUReplicaBase):
                 self._fire_ewma = float(total_fired)
             else:
                 self._fire_ewma += 0.25 * (total_fired - self._fire_ewma)
-        # nothing fireable: the ingest-only program (None in the plan),
-        # its rebuild DEFERRED to the next firing/rebuild program
+        if not plan:
+            # nothing fireable: the ingest-only program (its entry in
+            # the plan is the buffer it takes, no tuple), its rebuild
+            # DEFERRED to the next firing/rebuild program. Fire args are
+            # unused in that variant but still traced: pin the W_step
+            # shape so tier switches never retrace it
+            plan = [self._zero_fire(self.W_step) if keyrows is None
+                    else np.zeros(self._plan_len(self.W_step), np.int32)]
+        if keyrows is not None:
+            first = plan[0]
+            cb_pack_views(first[3] if isinstance(first, tuple) else first,
+                          self.K_cap)[0][:] = keyrows
         return lambda: self._commit_step(fields, wm, comp_p, ktable,
-                                         ckey, ikey, plan or [None], bid)
+                                         ckey, ikey, plan, bid)
 
     def _programs(self, frontier, partial: bool, first_budget: int, warm):
         """The programs that fire what is eligible now, one ``(chunks,
@@ -1795,16 +2000,14 @@ class FfatTPUReplica(TPUReplicaBase):
         running before this commit must not see, or clobber, a stale
         flag)."""
         for entry in plan:
-            if entry is None:
-                # ingest-only: leaves current, internal nodes stale until
-                # the next firing/rebuild program (the rebuild cost is
+            if not isinstance(entry, tuple):
+                # ingest-only (the entry is its plan buffer: _prep_step):
+                # leaves current, internal nodes stale until the next
+                # firing/rebuild program (the rebuild cost is
                 # batch-size-independent — the dominant per-batch term of
-                # the low-cardinality small-batch regime). Fire args are
-                # unused in this variant but still traced: pin the
-                # W_step shape so tier switches never retrace it
+                # the low-cardinality small-batch regime)
                 (self.trees, self.tvalid, *_) = self._prog_cache[ikey](
-                    fields, comp_p, self.trees, self.tvalid,
-                    self._zero_fire(self.W_step), ktable)
+                    fields, comp_p, self.trees, self.tvalid, entry, ktable)
                 self._rebuild_dirty = True
                 self.stats.device_programs_run += 1
                 continue
@@ -1812,15 +2015,15 @@ class FfatTPUReplica(TPUReplicaBase):
             if is_first:
                 # full program: lift + scan + scatter + rebuild + fire
                 (self.trees, self.tvalid, qr, qv, wid_dev,
-                 key_dev) = self._prog_cache[ckey](
+                 key_dev) = self._prog_cache[self._wkey(ckey, budget)](
                     fields, comp_p, self.trees, self.tvalid, pack, ktable)
                 self._rebuild_dirty = False  # in-program rebuild covers
                 # every deferred ingest-only batch (full-forest rebuild)
                 self._dirty_all = True  # ... and rewrote internal rows
             else:
                 # drain iterations: fire-only program (no rebuild)
-                self.tvalid, qr, qv, wid_dev, key_dev = self._fire_step()(
-                    self.trees, self.tvalid, pack, ktable)
+                self.tvalid, qr, qv, wid_dev, key_dev = self._fire_step(
+                    budget)(self.trees, self.tvalid, pack, ktable)
             self.stats.device_programs_run += 1
             self._emit_windows(wm, chunks, keys, owed, pack, n_out, qr, qv,
                                wid_dev, key_dev, budget, n_groups, bid)
@@ -1853,25 +2056,24 @@ class FfatTPUReplica(TPUReplicaBase):
         self.stats.fire_programs += 1
         self.stats.fire_lanes += W
         self.stats.windows_fired += n_out
-        if (op.win_type is WinType.CB
-                and fire_slides(W, self.K_cap, self.F)):
-            self.stats.fire_sliding_programs += 1
+        _slots, _st, c_k, c_w0, _ml = chunks
+        if op.win_type is WinType.CB:
+            # the host's plan is the chunk rows; the program expands them
+            self.stats.fire_plan_rows += c_k.size
+            if fire_slides(W, self.K_cap, self.F):
+                self.stats.fire_sliding_programs += 1
+        else:
+            self.stats.fire_plan_rows += n_out  # laid out by lane
         if n_groups:
             self.stats.fire_grouped_programs += 1
             self.stats.fire_groups += n_groups
         fields = dict(qr)
         fields["valid"] = qv
         fields["wid"] = wid_dev  # built in-program: no device_put here
-        _slots, _st, c_k, c_w0, _ml = chunks
-        if isinstance(c_keys, np.ndarray):
-            out_keys: Any = np.repeat(c_keys, c_k)  # numpy, no boxing
-        else:
-            # composite/object keys (callable extractors): host metadata
-            # only — key_field is always a numeric column, so no key
-            # COLUMN is built on this branch (a zero-padded asarray of
-            # tuples would be ragged)
-            out_keys = [key for key, n in zip(c_keys, c_k.tolist())
-                        for _ in range(n)]
+        # a row's key is its chunk's: the batch carries them by chunk
+        # and a consumer that reads ``host_keys`` expands them then
+        # (most read their own column, or none)
+        out_keys = ChunkedKeys(c_keys, c_k)
         if op.key_field is not None:
             if self._use_ktable():
                 fields[op.key_field] = key_dev  # gathered in-program
@@ -1880,7 +2082,7 @@ class FfatTPUReplica(TPUReplicaBase):
                 # must not round-trip through int64)
                 kd = self._key_dtype
                 key_col = np.zeros(W, dtype=kd)
-                key_col[:n_out] = out_keys
+                key_col[:n_out] = out_keys.expand()
                 fields[op.key_field] = jax.device_put(key_col)
         out_schema = TupleSchema(
             {name: np.dtype(v.dtype) for name, v in fields.items()})
@@ -1918,7 +2120,7 @@ class FfatTPUReplica(TPUReplicaBase):
         for chunks, n_out, pack, n_groups, W, keys, owed in self._programs(
                 frontier, partial, self.W_cap, self._warm_fire_step):
             self._ensure_rebuilt()
-            self.tvalid, qr, qv, wid_dev, key_dev = self._fire_step()(
+            self.tvalid, qr, qv, wid_dev, key_dev = self._fire_step(W)(
                 self.trees, self.tvalid, pack, ktable)
             self.stats.device_programs_run += 1
             self._emit_windows(self.cur_wm, chunks, keys, owed, pack, n_out,

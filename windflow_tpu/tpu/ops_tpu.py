@@ -465,11 +465,12 @@ class TPUReplicaBase(BasicReplica):
             order_np = np.asarray(order)
         self.stats.inputs_ignored += batch.size - new_size
         ts2 = batch.ts_host[order_np]
-        keys2 = None
-        if batch.host_keys is not None:
-            keys_list = list(batch.host_keys)
-            keys_arr = keys_list + [None] * (batch.capacity - len(keys_list))
-            keys2 = [keys_arr[j] for j in order_np[:new_size]]
+        keys2 = batch.host_keys
+        if isinstance(keys2, np.ndarray):
+            # the kept rows' keys, one gather (kept rows lie below size)
+            keys2 = keys2[order_np[:new_size]]
+        elif keys2 is not None:     # object keys: a list
+            keys2 = [keys2[j] for j in order_np[:new_size].tolist()]
         nb = BatchTPU(out_fields, ts2, new_size,
                       row_schema(out_fields, batch.schema), batch.wm, keys2)
         nb.stream_tag = batch.stream_tag
