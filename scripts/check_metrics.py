@@ -144,7 +144,7 @@ REQUIRED_FAMILIES = (
 # the /doctor smoke below)
 _DOCTOR_VERDICTS = frozenset((
     "ingest-bound", "compute-bound", "dispatch-bound", "backpressured-by",
-    "event-time-stalled", "overloaded"))
+    "event-time-stalled", "overloaded", "interpreter-bound"))
 
 _SAMPLE_RE = re.compile(
     r'^[a-zA-Z_:][a-zA-Z0-9_:]*(\{[^{}]*\})?\s+'

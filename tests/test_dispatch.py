@@ -90,7 +90,11 @@ def test_queue_stall_and_stage_counters():
         q.submit(lambda: None, b)
     assert st.dispatch_batches == 2
     assert st.dispatch_host_prep_total_us > 0.0
-    assert st.dispatch_host_prep_us > 0.0  # the stage feeds the EWMA
+    # CPU beside wall on prep (the thread's CPU clock, read on the first
+    # of every CPU_EVERY spans and scaled: an estimate)
+    assert st.stage_cpu_usec("prep") >= 0.0
+    # the latency hook is bound only under sampling: none here
+    assert q._st_prep._note is None
     assert st.dispatch_depth_max == 2
     assert st.dispatch_stalls == 0
     q.drain(forced=True)  # ordering-point drain with entries = a stall
@@ -99,12 +103,16 @@ def test_queue_stall_and_stage_counters():
     q.drain(forced=True)  # empty forced drain is NOT a stall
     assert st.dispatch_stalls == 1
     d = st.to_dict()
-    for field in ("Dispatch_host_prep_usec", "Dispatch_commit_usec",
+    for field in ("Dispatch_host_prep_cpu_total_usec",
+                  "Dispatch_commit_cpu_total_usec",
                   "Dispatch_readback_stalls", "Dispatch_queue_depth_max",
                   "Dispatch_batches", "Dispatch_host_prep_total_usec",
                   "Dispatch_commit_total_usec",
                   "Dispatch_queue_wait_total_usec"):
         assert field in d
+    # the per-batch EWMAs went in PR 36: nothing read them
+    assert "Dispatch_host_prep_usec" not in d
+    assert "Dispatch_commit_usec" not in d
     assert d["Dispatch_batches"] == 2
     # both batches sat in the queue from submit until the drain
     assert d["Dispatch_queue_wait_total_usec"] > 0.0

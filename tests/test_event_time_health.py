@@ -401,6 +401,55 @@ def test_doctor_blames_slow_sink_backpressure():
     assert "snk" in d["summary"]
 
 
+def test_doctor_reads_the_workers_own_account():
+    """Records with a worker's account: ``backpressured-by`` goes to the
+    operator whose own thread stood blocked (``Worker_blocked_put_usec``),
+    not to everything upstream, and a worker whose unaccounted time is
+    above its CPU time is named interpreter-bound."""
+    acct = dict(Worker_blocked_put_usec=0, Worker_unaccounted_usec=0,
+                Thread_cpu_usec=0)
+    prev = _graph([("src", "Source", [_rep(**acct)]),
+                   ("map", "Map", [_rep(**acct)]),
+                   ("win", "Ffat_Windows_TPU", [_rep(**acct)]),
+                   ("snk", "Sink", [_rep(**acct)])])
+    cur = _graph([
+        # the source pushes into a channel that is never full
+        ("src", "Source", [_rep(Inputs_received=10_000,
+                                Thread_cpu_usec=300_000,
+                                Worker_blocked_put_usec=20_000,
+                                Worker_unaccounted_usec=50_000)]),
+        # map stood blocked on win's channel for 0.7 s of the second
+        ("map", "Map", [_rep(Inputs_received=10_000,
+                             Thread_cpu_usec=150_000,
+                             Worker_blocked_put_usec=700_000,
+                             Worker_unaccounted_usec=100_000)]),
+        # win: its producers blocked on it; 0.35 s on the CPU, 0.5 s
+        # runnable and not running
+        ("win", "Ffat_Windows_TPU", [_rep(Inputs_received=9_000,
+                                          Queue_blocked_put_usec=700_000,
+                                          Thread_cpu_usec=350_000,
+                                          Worker_blocked_put_usec=0,
+                                          Worker_unaccounted_usec=500_000)]),
+        ("snk", "Sink", [_rep(Inputs_received=100,
+                              Thread_cpu_usec=10_000,
+                              Worker_blocked_put_usec=0,
+                              Worker_unaccounted_usec=5_000)])])
+    d = diagnose(prev, cur, 1.0)
+    by_verdict = {}
+    for f in d["findings"]:
+        by_verdict.setdefault(f["verdict"], []).append(f)
+    assert d["bottleneck"]["operator"] == "win"
+    bp = by_verdict["backpressured-by"]
+    assert [f["operator"] for f in bp] == ["map"]  # not src: it never stood
+    assert bp[0]["by"] == "win"
+    assert bp[0]["evidence"]["blocked_put_frac_own"] == pytest.approx(0.7)
+    interp = by_verdict["interpreter-bound"]
+    assert [f["operator"] for f in interp] == ["win"]
+    assert interp[0]["evidence"]["unaccounted_frac"] == pytest.approx(0.5)
+    assert interp[0]["evidence"]["busy_frac"] == pytest.approx(0.35)
+    assert "interpreter-bound" in render_text(d)
+
+
 def test_doctor_flags_overload_shedding_above_backpressure():
     """Shedding outranks everything else: the graph is overloaded even
     when backpressure symptoms coexist."""

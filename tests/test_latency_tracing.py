@@ -244,25 +244,35 @@ def test_queue_gauges_slow_sink_backpressure():
 
 
 # ---------------------------------------------------------------------------
-# EWMA seeding (first-sample bias fix)
+# the prep / commit latency hook: bound only where sampling is on
 # ---------------------------------------------------------------------------
-def test_ewma_seeds_with_first_observation():
+def test_dispatch_latency_hook_is_bound_only_under_sampling():
+    # sampling off: no histogram, so the stage binds no per-batch hook
+    # and a prep or commit pays no Python call for one
     st = StatsRecord("op", 0)
-    # a legitimate first observation of 0.0 must SEED, not leave the
-    # EWMA "unseeded" so the next sample jumps to its full value
-    st.note_host_prep(0.0)
-    st.note_host_prep(100.0)
-    assert st.dispatch_host_prep_us == pytest.approx(10.0)
-    st2 = StatsRecord("op", 0)
-    st2.note_dispatch_commit(0.0)
-    st2.note_dispatch_commit(50.0)
-    assert st2.dispatch_commit_us == pytest.approx(5.0)
-    # normal seeding: first value becomes the EWMA
-    st3 = StatsRecord("op", 0)
-    st3.note_host_prep(40.0)
-    assert st3.dispatch_host_prep_us == pytest.approx(40.0)
-    st3.note_host_prep(60.0)
-    assert st3.dispatch_host_prep_us == pytest.approx(42.0)
+    assert st.hist_prep is None and st.hist_commit is None
+    assert st.stage("prep")._note is None
+    assert st.stage("commit")._note is None
+    with st.stage("prep")(1):
+        pass
+    assert st.stage_count("prep") == 1
+    assert st.dispatch_host_prep_total_us > 0.0
+    # sampling on: each duration lands in the stage's own histogram,
+    # beside the totals
+    st2 = StatsRecord("op", 0, sample_every=1)
+    prep, commit = st2.stage("prep"), st2.stage("commit")
+    for b in (1, 2, 3):
+        with prep(b):
+            pass
+    with commit(1):
+        pass
+    assert st2.hist_prep.count == 3 and st2.hist_commit.count == 1
+    d = st2.to_dict()
+    assert d["Latency_prep_samples"] == 3
+    assert d["Latency_commit_samples"] == 1
+    assert d["Dispatch_batches"] == 3
+    assert d["Dispatch_host_prep_total_usec"] > 0.0
+    assert d["Dispatch_commit_total_usec"] > 0.0
 
 
 # ---------------------------------------------------------------------------

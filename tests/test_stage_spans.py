@@ -470,3 +470,367 @@ def test_cpu_plane_times_nothing_per_tuple(monkeypatch):
     # only a channel's blocked branches may open a span there: a thread
     # that found its queue full or empty, never a tuple's processing
     assert set(used) <= {"put", "get"}, set(used)
+
+
+# ---------------------------------------------------------------------------
+# (g) the host's time, accounted from inside the program (PR 36): a
+# worker's wall split into CPU / backpressured / starved / on the device /
+# unaccounted, CPU beside wall on four stages, the process's stalls
+# ---------------------------------------------------------------------------
+ACCOUNT = ("Thread_cpu_usec", "Worker_blocked_put_usec",
+           "Worker_blocked_get_usec", "Worker_device_wait_usec",
+           "Worker_unaccounted_usec")
+
+
+@pytest.mark.parametrize("op", ["src", CHAIN, "win", "snk"])
+def test_worker_account_sums_to_its_wall(served, op):
+    rep = served["stats"][op]
+    parts = [rep[f] for f in ACCOUNT]
+    assert all(p >= 0 for p in parts), dict(zip(ACCOUNT, parts))
+    wall = rep["Thread_wall_usec"]
+    assert wall > 0
+    assert sum(parts) == pytest.approx(wall, rel=0.01), \
+        dict(zip(ACCOUNT, parts), wall=wall)
+    # frozen once the thread has ended
+    again = {o["name"]: o["replicas"][0]
+             for o in served["graph"].get_stats()["Operators"]}[op]
+    for f in ACCOUNT + ("Thread_wall_usec",):
+        assert again[f] == rep[f], f
+
+
+def test_worker_account_is_where_the_waits_are(served):
+    st = served["stats"]
+    # a source has no input channel and never starves; a sink puts into
+    # no channel and is never backpressured
+    assert st["src"]["Worker_blocked_get_usec"] == 0
+    assert st["snk"]["Worker_blocked_put_usec"] == 0
+    # every channel-fed worker waited for its first block at least
+    for op in (CHAIN, "win", "snk"):
+        assert st[op]["Worker_blocked_get_usec"] > 0, op
+    # a wait on the device is the wall less the CPU of the spans that
+    # read from it or launch on it: the sink's reads of device arrays,
+    assert st["snk"]["Sink_d2h_wait_total_usec"] > 0
+    assert st["snk"]["Worker_device_wait_usec"] == pytest.approx(max(
+        0, st["snk"]["Sink_d2h_wait_total_usec"]
+        - st["snk"]["Sink_d2h_cpu_total_usec"]), abs=1.0)
+    # the chain's readbacks and launches
+    c = st[CHAIN]
+    assert c["Dispatch_readback_wait_total_usec"] > 0
+    assert c["Worker_device_wait_usec"] == pytest.approx(max(
+        0, c["Dispatch_readback_wait_total_usec"]
+        - c["Dispatch_readback_cpu_total_usec"]
+        + c["Device_launch_total_usec"]
+        - c["Device_launch_cpu_total_usec"]), abs=1.0)
+
+
+def test_worker_account_counts_each_worker_once():
+    got = []
+
+    def src(shipper):
+        for i in range(200):
+            shipper.push({"v": i})
+
+    g = PipeGraph("one_account", ExecutionMode.DEFAULT,
+                  TimePolicy.INGRESS_TIME)
+    g.add_source(Source_Builder(src).with_name("s").build()) \
+        .chain(Map_Builder(lambda t: t).with_name("m").build()) \
+        .chain_sink(Sink_Builder(lambda t: got.append(t))
+                    .with_name("k").build())
+    g.run()
+    reps = [r for o in g.get_stats()["Operators"] for r in o["replicas"]]
+    assert len(g._workers) == 1 and len(reps) == 3
+    reporting = [r for r in reps if r["Thread_wall_usec"] > 0]
+    assert len(reporting) == 1
+    for r in reps:
+        if r is not reporting[0]:
+            assert all(r[f] == 0 for f in ACCOUNT), r["Operator_name"]
+
+
+def test_backpressure_is_counted_on_both_sides():
+    """One producer, a slow consumer: the spans of the blocked ``put``
+    are the consumer's ``Queue_blocked_put_usec`` and the producer's own
+    ``Worker_blocked_put_usec``."""
+    import time
+
+    def src(shipper):
+        for i in range(60):
+            shipper.push({"v": i})
+
+    def slow(t):
+        if t is not None:
+            time.sleep(0.002)
+
+    g = PipeGraph("both_sides", ExecutionMode.DEFAULT,
+                  TimePolicy.INGRESS_TIME, channel_capacity=4)
+    g.add_source(Source_Builder(src).with_name("s").build()) \
+        .add_sink(Sink_Builder(slow).with_name("k").build())
+    g.run()
+    st = {o["name"]: o["replicas"][0] for o in g.get_stats()["Operators"]}
+    assert st["k"]["Queue_puts_blocked"] > 0
+    assert st["s"]["Worker_blocked_put_usec"] == pytest.approx(
+        st["k"]["Queue_blocked_put_usec"], rel=0.02)
+    assert st["s"]["Worker_blocked_put_usec"] > 20_000  # most of 120 ms
+    assert st["k"]["Worker_blocked_put_usec"] == 0
+    # and the consumer's own record still says whose queue was full
+    assert st["s"]["Queue_blocked_put_usec"] == 0
+
+
+def test_cpu_beside_wall_on_the_stages_that_name_a_field(monkeypatch):
+    import time
+
+    from windflow_tpu.monitoring.stats import StatsRecord
+
+    calls = []
+    real = tracing._cpu_ns
+    monkeypatch.setattr(tracing, "_cpu_ns",
+                        lambda: calls.append(1) or real())
+    st = StatsRecord("op", 0)
+    with st.stage("prep")(1):
+        time.sleep(0.02)  # off the processor: wall, not CPU
+    d = st.to_dict()
+    assert d["Dispatch_host_prep_total_usec"] \
+        - d["Dispatch_host_prep_cpu_total_usec"] >= 18_000
+    assert len(calls) == 2
+    # a stage whose StageDef names no ``cpu`` field never reads that clock
+    plain = [n for n, sdef in tracing.STAGES.items() if sdef.cpu is None]
+    assert {"put", "get", "emit", "stage", "h2d", "sink", "exit",
+            "fireplan", "keys"} <= set(plain)
+    for name in plain:
+        with st.stage(name)(1):
+            pass
+    st.stage("queue").since(tracing.stamp_ns(), 1)
+    assert len(calls) == 2
+    # and a stage that names one reads it on one span in CPU_EVERY (a
+    # system call, 5.8 us on the benchmark's host), scaled up
+    commit = st.stage("commit")
+    for b in range(2 * tracing.CPU_EVERY):
+        with commit(b):
+            pass
+    assert len(calls) == 2 + 2 * 2
+    assert st.stage_count("commit") == 2 * tracing.CPU_EVERY
+    assert {sdef.cpu for sdef in tracing.STAGES.values() if sdef.cpu} == {
+        "Dispatch_host_prep_cpu_total_usec", "Dispatch_commit_cpu_total_usec",
+        "Device_launch_cpu_total_usec", "Ingest_cpu_total_usec",
+        "Dispatch_readback_cpu_total_usec", "Sink_d2h_cpu_total_usec"}
+
+
+def test_launch_wall_less_cpu_is_a_wait_on_the_device():
+    """``launch`` adds its wall less its CPU to the calling thread's
+    account; ``put`` its whole wall; a thread without an account (no
+    worker) is left alone."""
+    import time
+
+    acct = tracing.new_thread_account()
+    out = {}
+
+    def body():
+        tracing.set_thread_account(acct)
+        c = tracing.StageCounters("op")
+        with c.stage("launch")():
+            time.sleep(0.02)
+        out["cpu"] = c.stage_cpu_usec("launch")
+        out["wall"] = c.stage_usec("launch")
+        with c.stage("emit")():
+            time.sleep(0.005)  # not a wait the table marks
+
+    t = threading.Thread(target=body)
+    t.start()
+    t.join(30)
+    assert not t.is_alive()
+    assert acct[tracing.BACKPRESSURED] == 0 == acct[tracing.STARVED]
+    assert acct[tracing.DEVICE_WAIT] / 1e3 == pytest.approx(
+        out["wall"] - out["cpu"], abs=1.0)
+    assert acct[tracing.DEVICE_WAIT] >= 18_000_000
+    # this thread has no account: the same span adds nowhere
+    c = tracing.StageCounters("op")
+    with c.stage("launch")():
+        pass
+    assert acct[tracing.DEVICE_WAIT] / 1e3 == pytest.approx(
+        out["wall"] - out["cpu"], abs=1.0)
+
+
+def test_a_parked_worker_reads_starved_not_unaccounted():
+    """A wait still open at poll time is counted up to that instant: a
+    sink parked on its empty channel while the source holds back."""
+    import time
+
+    gate = threading.Event()
+
+    def src(shipper):
+        assert gate.wait(60)
+        shipper.push({"v": 1})
+
+    g = PipeGraph("parked", ExecutionMode.DEFAULT, TimePolicy.INGRESS_TIME)
+    g.add_source(Source_Builder(src).with_name("s").build()) \
+        .add_sink(Sink_Builder(lambda t: None).with_name("k").build())
+    g.start()
+    try:
+        time.sleep(0.3)
+        k = g.get_stats()["Operators"][1]["replicas"][0]
+    finally:
+        gate.set()
+        g.wait_end()
+    assert k["Operator_name"] == "k"
+    assert k["Worker_blocked_get_usec"] >= 0.8 * k["Thread_wall_usec"] \
+        > 100_000
+    assert k["Worker_unaccounted_usec"] <= 0.2 * k["Thread_wall_usec"]
+    end = g.get_stats()["Operators"][1]["replicas"][0]
+    assert end["Worker_blocked_get_usec"] >= k["Worker_blocked_get_usec"]
+
+
+def _watchdog(recorders=()):
+    import types
+
+    from windflow_tpu.monitoring.flightrec import StallWatchdog
+    graph = types.SimpleNamespace(name="g", _workers=[],
+                                  _recorders=list(recorders))
+    return StallWatchdog(graph)
+
+
+@pytest.mark.parametrize("late_ms,cpu_ms,gc_ms,stalls", [
+    (0.08, 0.01, 0.0, 0),     # timer slack: a tick, no stall
+    (4.9, 4.0, 0.0, 0),       # a turn on the interpreter lock: no stall
+    (250.0, 1.0, 0.0, 1),     # the host took the process off the CPU
+    (250.0, 255.0, 0.0, 1),   # a thread kept the interpreter
+    (400.0, 390.0, 380.0, 1),  # ... and it was the garbage collector
+])
+def test_watchdog_tick_is_a_pure_function_of_three_clocks(
+        late_ms, cpu_ms, gc_ms, stalls):
+    from windflow_tpu.monitoring.flightrec import TICK_NS
+    wd = _watchdog()
+    t0, c0, g0 = 5_000_000_000, 70_000_000, 3_000_000
+    assert wd._tick(t0, c0, g0) is None  # the first reading only arms
+    assert wd.ticks == 0
+    late = int(late_ms * 1e6)
+    stall = wd._tick(t0 + TICK_NS + late, c0 + int(cpu_ms * 1e6),
+                     g0 + int(gc_ms * 1e6))
+    f = wd.process_fields()
+    assert f["Process_ticks"] == 1
+    assert f["Process_tick_late_total_usec"] == pytest.approx(late / 1e3)
+    assert f["Process_stalls"] == stalls
+    if not stalls:
+        assert stall is None
+        assert f["Process_stall_usec"] == 0 == f["Process_stall_cpu_usec"]
+        return
+    assert stall == {"late_us": pytest.approx(late / 1e3),
+                     "cpu_us": pytest.approx(cpu_ms * 1e3),
+                     "gc_us": pytest.approx(gc_ms * 1e3)}
+    assert f["Process_stall_usec"] == pytest.approx(late / 1e3)
+    # the cause: CPU near 0 across the gap, or near the gap
+    assert f["Process_stall_cpu_usec"] == pytest.approx(cpu_ms * 1e3)
+    # an on-time tick after it counts a tick and no second stall
+    assert wd._tick(t0 + 2 * TICK_NS + late + 1000, c0, g0) is None
+    assert wd.process_fields()["Process_stalls"] == 1
+    assert wd.process_fields()["Process_ticks"] == 2
+
+
+def test_process_stall_is_reported_with_its_cause(capsys):
+    """A thread that keeps the interpreter for a quarter of a second (a
+    busy loop under a long switch interval): the watchdog's wake-up is
+    that late, and the stall is in ``Process_stalls``, on stderr and in
+    the ring, its CPU near the gap."""
+    import sys
+    import time
+
+    def src(shipper):
+        time.sleep(0.1)  # the watchdog is asleep in its tick by now
+        before = sys.getswitchinterval()
+        sys.setswitchinterval(2.0)
+        try:
+            end = time.perf_counter() + 0.25
+            while time.perf_counter() < end:
+                pass
+        finally:
+            sys.setswitchinterval(before)
+        shipper.push({"v": 1})
+        time.sleep(0.05)  # let the late wake-up be counted
+
+    g = PipeGraph("held", ExecutionMode.DEFAULT, TimePolicy.INGRESS_TIME)
+    g.with_flight_recorder()
+    g.add_source(Source_Builder(src).with_name("s").build()) \
+        .add_sink(Sink_Builder(lambda t: None).with_name("k").build())
+    g.run()
+    assert not g._watchdog.is_alive()  # stopped and joined with the graph
+    rep = g.get_stats()["Operators"][0]["replicas"][0]
+    assert rep["Process_stalls"] >= 1
+    assert rep["Process_stall_usec"] >= 100_000
+    # a thread kept the interpreter: the process was on the CPU
+    assert rep["Process_stall_cpu_usec"] >= 0.25 * rep["Process_stall_usec"]
+    assert rep["Process_ticks"] > 5
+    assert "the process stood still" in capsys.readouterr().err
+    events = [e for e in g.trace_document()["traceEvents"]
+              if e.get("name") == "stall:process"]
+    assert events and set(events[0]["args"]) == {"late_us", "cpu_us",
+                                                 "gc_us"}
+    assert events[0]["args"]["late_us"] >= 100_000
+
+
+def test_process_fields_sit_on_one_record_and_gc_is_counted():
+    import gc
+    import time
+
+    def src(shipper):
+        time.sleep(0.05)  # the watchdog thread has registered its callback
+        gc.collect()
+        for i in range(10):
+            shipper.push({"v": i})
+
+    g = PipeGraph("one_record", ExecutionMode.DEFAULT,
+                  TimePolicy.INGRESS_TIME)
+    g.add_source(Source_Builder(src).with_name("s").build()) \
+        .add(Map_Builder(lambda t: t).with_name("m").build()) \
+        .add_sink(Sink_Builder(lambda t: None).with_name("k").build())
+    n_callbacks = len(gc.callbacks)
+    g.run()
+    assert len(gc.callbacks) == n_callbacks  # taken back with the thread
+    reps = [r for o in g.get_stats()["Operators"] for r in o["replicas"]]
+    fields = ("Process_ticks", "Process_tick_late_total_usec",
+              "Process_stalls", "Process_stall_usec",
+              "Process_stall_cpu_usec", "Gc_pause_total_usec",
+              "Gc_collections_full")
+    holders = [r for r in reps if any(f in r for f in fields)]
+    assert len(holders) == 1 and holders[0]["Operator_name"] == "s"
+    assert all(f in holders[0] for f in fields)
+    assert holders[0]["Gc_collections_full"] >= 1
+    assert holders[0]["Gc_pause_total_usec"] > 0
+    assert holders[0]["Process_ticks"] >= 3
+
+
+# the eleven per-layer metrics that read the account (benchmark/metrics/):
+# data files through a reader that is there; each counter they name is a
+# field the program reports
+ACCOUNT_METRICS = (
+    "window_busy_share.sat", "window_input_wait_share.sat",
+    "window_backpressured_share.sat", "window_device_wait_share.sat",
+    "window_unaccounted_share.sat", "first_backpressured_share.sat",
+    "interp_wait_cores.sat", "interp_acquire_us.sat",
+    "process_stall_share.sat", "gc_pause_share.sat",
+    "dispatch_cpu_us_per_batch.sat")
+
+
+@pytest.mark.parametrize("name", ACCOUNT_METRICS)
+def test_account_metric_reads_a_counter_the_program_has(name):
+    from windflow_tpu.monitoring.stats import StatsRecord
+
+    root = os.path.join(os.path.dirname(__file__), "..")
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    with open(os.path.join(root, "benchmark", "metrics",
+                           name + ".json")) as f:
+        spec = json.load(f)
+    entry = [m for m in bench["per_layer"] if m["name"] == name]
+    assert len(entry) == 1 and "workloads" not in entry[0]  # every cell
+    assert bench["per_layer"].index(entry[0]) >= len(bench["per_layer"]) - 11
+    for k in ("unit", "layer", "source", "moves"):
+        assert entry[0][k] == spec[k], k
+    assert spec["source"] == "program_counter"
+    assert spec["moves"] == "events_per_s" and entry[0]["better"] == "lower"
+    # nothing, not 0, from a program without the counter
+    assert spec["reader"] == "counter_ratio_present.py"
+    fields = set(StatsRecord("op", 0).to_dict()) | set(
+        _watchdog().process_fields())
+    den = spec["params"]["den"]
+    pairs = spec["params"]["num"] + (den if isinstance(den, list) else [])
+    for role, field in pairs:
+        assert field in fields, (role, field)
+        assert role in ("window", "first", "*"), role

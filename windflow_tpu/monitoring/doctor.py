@@ -27,7 +27,21 @@ web client banner):
                           been frozen past ``WF_WM_STALL_SEC``;
 - ``ingest-bound``      — nobody is backpressured and every downstream
                           operator starves on an empty input channel:
-                          the sources cannot produce fast enough.
+                          the sources cannot produce fast enough;
+- ``interpreter-bound`` — the operator's worker thread could run and did
+                          not for longer than it ran: its unaccounted
+                          time (wall less CPU less its own waits:
+                          ``Worker_unaccounted_usec``) is above its CPU
+                          time: it waits its turn on the interpreter
+                          lock, or the host took it off the CPU (or its
+                          functor blocks: a sleep, I/O the program does
+                          not time).
+
+Where the records carry a worker's own account (``Worker_blocked_put_usec``:
+the time THIS operator's thread stood blocked on the next one's full
+channel), ``backpressured-by`` is said of the operators that did stand
+blocked, with that share as evidence; records without it (older dumps)
+get the verdict by position, upstream of the bottleneck.
 
 The analyzer never touches live objects: it consumes report dicts as
 they arrive over the monitoring port, so it runs equally against a live
@@ -45,6 +59,7 @@ STARVE_MIN_FRAC = 0.5     # consumer blocked-get time => starvation
 DISPATCH_MIN_FRAC = 0.5   # prep+commit share of the tick => device-bound
 COMMIT_SHARE = 0.6        # commit share of prep+commit => dispatch-bound
 COMPILE_STORM = 3         # recompiles per tick => dispatch-bound (storm)
+INTERP_MIN_FRAC = 0.15    # unaccounted worker time => interpreter-bound
 
 # score bands keep the ranking stable across mixed symptoms: an
 # overloaded graph is overloaded even when it is ALSO backpressured
@@ -66,13 +81,18 @@ def _op_rollup(op: Dict[str, Any]) -> Dict[str, float]:
             "Dispatch_host_prep_total_usec", "Dispatch_commit_total_usec",
             "Compile_count", "Checkpoint_cut_pause_usec_total",
             "Watermark_stalls", "Late_records", "Late_dropped",
-            "Late_admitted", "Queue_len", "Worker_idle_ticks")
+            "Late_admitted", "Queue_len", "Worker_idle_ticks",
+            "Thread_cpu_usec", "Worker_blocked_put_usec",
+            "Worker_unaccounted_usec")
     maxes = ("Service_time_usec", "Watermark_lag_usec", "Queue_capacity",
              "Watermark_event_lag_usec", "Tier_miss_rate")
     for f in sums:
         out[f] = sum(_num(r.get(f)) for r in reps)
     for f in maxes:
         out[f] = max((_num(r.get(f)) for r in reps), default=0.0)
+    # the worker's own account (PR 36): absent from older dumps
+    out["has_account"] = float(any("Worker_blocked_put_usec" in r
+                                   for r in reps))
     # idle only when EVERY replica is idle (any traffic => not idle)
     out["Watermark_idle"] = min((_num(r.get("Watermark_idle", 1))
                                  for r in reps), default=1.0)
@@ -140,6 +160,13 @@ def diagnose(prev: Optional[Dict[str, Any]], cur: Dict[str, Any],
             "bp_frac": d("Queue_blocked_put_usec") / dt_us,
             # this op's own time blocked on an EMPTY channel, per replica
             "starve_frac": d("Queue_blocked_get_usec") / (dt_us * par),
+            # this op's OWN thread blocked on the next op's full channel,
+            # could run and did not, and on the CPU; per replica (None
+            # where the record has no account of its worker)
+            "own_bp_frac": d("Worker_blocked_put_usec") / (dt_us * par)
+            if c["has_account"] else None,
+            "unacc_frac": d("Worker_unaccounted_usec") / (dt_us * par),
+            "busy_frac": d("Thread_cpu_usec") / (dt_us * par),
             "dispatch_frac": (d("Dispatch_host_prep_total_usec")
                               + d("Dispatch_commit_total_usec"))
             / (dt_us * par),
@@ -225,19 +252,32 @@ def diagnose(prev: Optional[Dict[str, Any]], cur: Dict[str, Any],
                           f"(svc {b['svc_us']:.0f} µs/tuple)")),
         })
         for r in rows[:bottleneck_idx]:
-            if r["is_source"] or r["bp_frac"] >= BP_MIN_FRAC \
+            own = r["own_bp_frac"]
+            if own is not None:
+                # the producer's own account: it stood blocked, or not
+                if own < BP_MIN_FRAC:
+                    continue
+                evidence = {"blocked_put_frac_own": round(own, 4)}
+                detail = (f"{r['name']}'s worker stood blocked {own:.0%} "
+                          f"of the tick on a full channel downstream "
+                          f"(drain bottleneck: {b['name']})")
+            elif r["is_source"] or r["bp_frac"] >= BP_MIN_FRAC \
                     or r["in_delta"] > 0:
-                findings.append({
-                    "operator": r["name"], "verdict": "backpressured-by",
-                    "by": b["name"],
-                    "score": round(min(1.0, b["bp_frac"]) * 0.5, 3),
-                    "evidence": {
-                        "bottleneck": b["name"],
-                        "blocked_put_frac_downstream": round(
-                            b["bp_frac"], 4)},
-                    "detail": (f"{r['name']} is throttled by downstream "
-                               f"{b['name']} (backpressure)"),
-                })
+                evidence = {}
+                detail = (f"{r['name']} is throttled by downstream "
+                          f"{b['name']} (backpressure)")
+            else:
+                continue
+            findings.append({
+                "operator": r["name"], "verdict": "backpressured-by",
+                "by": b["name"],
+                "score": round(min(1.0, b["bp_frac"] if own is None
+                                   else own) * 0.5, 3),
+                "evidence": {
+                    "bottleneck": b["name"], **evidence,
+                    "blocked_put_frac_downstream": round(b["bp_frac"], 4)},
+                "detail": detail,
+            })
 
     # -- event-time stall: traffic flows, watermark frozen ------------------
     stall_us = stall_sec * 1e6
@@ -286,6 +326,24 @@ def diagnose(prev: Optional[Dict[str, Any]], cur: Dict[str, Any],
                               f"recompiles (compile storm)"
                               if r["compile_delta"] >= COMPILE_STORM
                               else "")),
+            })
+
+    # -- interpreter-bound: a worker that could run and did not for longer
+    # than it ran (beside whatever else is said of its operator) -----------
+    for r in rows:
+        if r["unacc_frac"] >= INTERP_MIN_FRAC \
+                and r["unacc_frac"] > r["busy_frac"]:
+            findings.append({
+                "operator": r["name"], "verdict": "interpreter-bound",
+                "score": round(min(1.0, r["unacc_frac"]) * 0.6, 3),
+                "evidence": {
+                    "unaccounted_frac": round(r["unacc_frac"], 4),
+                    "busy_frac": round(r["busy_frac"], 4)},
+                "detail": (f"{r['name']}'s worker could run and did not "
+                           f"{r['unacc_frac']:.0%} of the tick, against "
+                           f"{r['busy_frac']:.0%} on the CPU: it waits "
+                           f"for the interpreter lock or for the host, "
+                           f"or its functor blocks"),
             })
 
     # -- ingest-bound: nobody backpressured, downstream starves -------------

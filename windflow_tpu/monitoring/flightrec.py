@@ -1,5 +1,8 @@
 """Flight recorder: per-worker ring buffers of span events, Chrome/
-Perfetto trace export, the stall watchdog and XLA compile attribution.
+Perfetto trace export, the watchdog (stalled workers under
+``WF_STALL_SEC``; always, a gauge of the whole process: how late a thread
+gets the interpreter, stalls with their cause, garbage-collection pauses)
+and XLA compile attribution.
 
 The stats plane (PR 2) answers "how fast is each operator on average";
 this module answers "where did THIS slow batch spend its time" and "why
@@ -306,34 +309,159 @@ def instrumented_jit(fn, stats=None, label: str = "",
 # ---------------------------------------------------------------------------
 # stall watchdog
 # ---------------------------------------------------------------------------
-class StallWatchdog(threading.Thread):
-    """Monitor-thread tick that flags live workers whose progress
-    counter (channel deliveries + idle ticks + tuples moved) has not
-    advanced for ``stall_sec``. Firing calls ``dump_fn(worker_name)``
-    once per stall episode (re-armed by any later progress) — the
-    PipeGraph wires that to a post-mortem trace dump with
-    ``sys._current_frames()`` stacks. Default off (``WF_STALL_SEC``
-    unset): a healthy-idle worker parked in a long ``channel.get`` would
-    otherwise look identical to a deadlocked one, which is why workers
-    run their idle tick whenever the watchdog is armed."""
+# the process gauge: the watchdog sleeps TICK_NS at a time; a wake-up later
+# than STALL_NS is a stall of the whole process
+TICK_NS = 10_000_000
+STALL_NS = 100_000_000
 
-    def __init__(self, graph, stall_sec: float, dump_fn=None) -> None:
+
+class StallWatchdog(threading.Thread):
+    """One monitor thread a graph, started with it, with two jobs.
+
+    **The workers** (``WF_STALL_SEC`` > 0, default off): flags live
+    workers whose progress counter (channel deliveries + idle ticks +
+    tuples moved) has not advanced for ``stall_sec``. Firing calls
+    ``dump_fn(worker_name)`` once per stall episode (re-armed by any
+    later progress) — the PipeGraph wires that to a post-mortem trace
+    dump with ``sys._current_frames()`` stacks. Off by default because a
+    healthy-idle worker parked in a long ``channel.get`` would otherwise
+    look identical to a deadlocked one, which is why workers run their
+    idle tick whenever it is armed.
+
+    **The process** (always): the thread sleeps 10 ms at a time and
+    measures how late each wake-up is. The lateness is the time a thread
+    that wants the interpreter waits for it (over the timer's own slack,
+    the reading of an idle graph); a wake-up later than 100 ms is a stall
+    of the whole process, counted with the process's CPU time across the
+    gap (near 0: the host took the process off the CPU; near the gap: a
+    thread kept the interpreter) and the garbage collector's pauses in
+    it, written to stderr and, as ``stall:process``, to the flight ring.
+    ``gc.callbacks`` times every collection while the thread runs (a
+    pause stops every Python thread). ``process_fields`` is what
+    ``get_stats()`` shows, on one record of the graph."""
+
+    def __init__(self, graph, stall_sec: float = 0.0, dump_fn=None) -> None:
         super().__init__(name=f"stallwatch:{graph.name}", daemon=True)
         self.graph = graph
         self.stall_sec = float(stall_sec)
         self.dump_fn = dump_fn
         self.fired: List[str] = []  # worker names, in firing order
-        self._stop_evt = threading.Event()
+        self._stopping = False
         self._seen: Dict[str, Any] = {}  # wname -> [progress, t, flagged]
+        # -- the process gauge (nanoseconds; only this thread writes the
+        # tick counters, only the collecting thread the gc pair) -----------
+        self.ticks = 0
+        self.tick_late_ns = 0
+        self.stalls = 0
+        self.stall_ns = 0
+        self.stall_cpu_ns = 0
+        self.gc_pause_ns = 0
+        self.gc_full = 0
+        self._gc_t0 = 0
+        # (clock, process CPU, gc pause) when the thread last went to sleep
+        self._asleep: Optional[tuple] = None
+        self._t_run = time.perf_counter_ns()
 
     def stop(self) -> None:
-        self._stop_evt.set()
+        """Ends the thread within a tick; joined where it was started."""
+        self._stopping = True
+        if self.is_alive() and threading.current_thread() is not self:
+            self.join(timeout=1.0)
 
     def run(self) -> None:
-        tick = min(1.0, max(0.05, self.stall_sec / 4.0))
-        while not self._stop_evt.wait(tick):
-            self._check(time.monotonic())
+        import gc
 
+        self._t_run = time.perf_counter_ns()
+        check_every = min(1.0, max(0.05, self.stall_sec / 4.0))
+        next_check = time.monotonic() + check_every
+        gc.callbacks.append(self._on_gc)
+        try:
+            while not self._stopping:
+                # armed anew before each sleep: what the loop does between
+                # two of them is not lateness
+                self._asleep = (time.perf_counter_ns(),
+                                time.process_time_ns(), self.gc_pause_ns)
+                time.sleep(TICK_NS / 1e9)
+                stall = self._tick(time.perf_counter_ns(),
+                                   time.process_time_ns(), self.gc_pause_ns)
+                if stall is not None:
+                    self._report(stall)
+                now = time.monotonic()
+                if now >= next_check:
+                    next_check = now + check_every
+                    if self.stall_sec > 0:
+                        self._check(now)
+                    if not self._graph_lives():
+                        return  # an abandoned graph: nothing left to watch
+        finally:
+            gc.callbacks.remove(self._on_gc)
+
+    # -- the process ---------------------------------------------------------
+    def _tick(self, now_ns: int, cpu_ns: int,
+              gc_ns: int) -> Optional[Dict[str, float]]:
+        """One wake-up, as a pure function of the three clocks now and
+        when the thread went to sleep (``_asleep``; the first reading
+        only arms it): counts a tick, its lateness over ``TICK_NS`` and,
+        past ``STALL_NS``, a stall, which it returns with its cause
+        (microseconds late, of process CPU and of gc pause in the gap)."""
+        before, self._asleep = self._asleep, (now_ns, cpu_ns, gc_ns)
+        if before is None:
+            return None
+        late = max(0, now_ns - before[0] - TICK_NS)
+        self.ticks += 1
+        self.tick_late_ns += late
+        if late < STALL_NS:
+            return None
+        self.stalls += 1
+        self.stall_ns += late
+        self.stall_cpu_ns += cpu_ns - before[1]
+        return {"late_us": late / 1e3, "cpu_us": (cpu_ns - before[1]) / 1e3,
+                "gc_us": (gc_ns - before[2]) / 1e3}
+
+    def _on_gc(self, phase: str, info: Dict[str, Any]) -> None:
+        if phase == "start":
+            self._gc_t0 = time.perf_counter_ns()
+        elif self._gc_t0:
+            self.gc_pause_ns += time.perf_counter_ns() - self._gc_t0
+            self._gc_t0 = 0
+            if info.get("generation") == 2:
+                self.gc_full += 1
+
+    def _report(self, stall: Dict[str, float]) -> None:
+        import sys
+
+        print(f"[windflow] {self.graph.name}: the process stood still "
+              f"{stall['late_us'] / 1e3:.0f} ms, "
+              f"{(time.perf_counter_ns() - self._t_run) / 1e9:.1f} s after "
+              f"the graph started (process CPU in the gap "
+              f"{stall['cpu_us'] / 1e3:.0f} ms, gc "
+              f"{stall['gc_us'] / 1e3:.0f} ms)", file=sys.stderr)
+        for rec in getattr(self.graph, "_recorders", ())[:1]:
+            rec_evt_safe(rec, "stall:process", stall["late_us"], stall)
+
+    def process_fields(self) -> Dict[str, Any]:
+        """The ``Process_*`` / ``Gc_*`` fields of ``get_stats()``."""
+        return {
+            "Process_ticks": self.ticks,
+            "Process_tick_late_total_usec": round(self.tick_late_ns / 1e3, 1),
+            "Process_stalls": self.stalls,
+            "Process_stall_usec": round(self.stall_ns / 1e3, 1),
+            "Process_stall_cpu_usec": round(self.stall_cpu_ns / 1e3, 1),
+            "Gc_pause_total_usec": round(self.gc_pause_ns / 1e3, 1),
+            "Gc_collections_full": self.gc_full,
+        }
+
+    def _graph_lives(self) -> bool:
+        """False for a graph whose workers have all ended and that nobody
+        will restart: one that never reached ``wait_end`` must not keep a
+        thread ticking (a supervised graph is always waited for)."""
+        g = self.graph
+        return (getattr(g, "_rescaling", False)
+                or getattr(g, "_supervising", False)
+                or getattr(g, "_supervisor", None) is not None
+                or any(w.is_alive() for w in g._workers))
+
+    # -- the workers ---------------------------------------------------------
     def _check(self, now: float) -> None:
         if getattr(self.graph, "_rescaling", False) \
                 or getattr(self.graph, "_supervising", False):

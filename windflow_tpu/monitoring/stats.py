@@ -73,7 +73,6 @@ class StatsRecord(StageCounters):
         "keys_admitted", "keys_reclaimed", "key_slots_live",
         "key_capacity_growths",
         "staging_pool_hits", "staging_pool_misses",
-        "dispatch_host_prep_us", "dispatch_commit_us",
         "dispatch_stalls", "dispatch_depth_max",
         # megabatch scan loop (runtime/dispatch.py + tpu/fused_ops.py):
         # grouped dispatches (loops), batches committed through them,
@@ -156,7 +155,7 @@ class StatsRecord(StageCounters):
         # EWMA seeding: value==0.0 is NOT a reliable "unseeded" sentinel
         # (a genuine ~0 first sample would re-seed forever, biasing early
         # readings); explicit flags instead
-        "_svc_seeded", "_prep_seeded", "_commit_seeded",
+        "_svc_seeded",
         # latency-tracing plane (None / 0 when sampling is off)
         "sample_every", "_svc_rec",
         "hist_service", "hist_prep", "hist_commit", "hist_e2e",
@@ -214,11 +213,9 @@ class StatsRecord(StageCounters):
         self.key_capacity_growths = 0
         self.staging_pool_hits = 0  # recycled staging buffers (ArrayPool)
         self.staging_pool_misses = 0
-        # device-ahead dispatch pipeline (runtime/dispatch.py): per-stage
-        # split of the device-operator batch path — host control plane
-        # (prep) vs program dispatch + emit readbacks (commit)
-        self.dispatch_host_prep_us = 0.0  # EWMA
-        self.dispatch_commit_us = 0.0  # EWMA
+        # device-ahead dispatch pipeline (runtime/dispatch.py); the split
+        # of a batch's path into host prep and commit is the stage
+        # table's (``prep`` / ``commit``: wall and thread-CPU totals)
         self.dispatch_stalls = 0  # forced ordering-point drains
         self.dispatch_depth_max = 0
         self.megabatch_loops = 0
@@ -273,8 +270,6 @@ class StatsRecord(StageCounters):
         self.is_terminated = False
         self._last_svc_start = 0.0
         self._svc_seeded = False
-        self._prep_seeded = False
-        self._commit_seeded = False
         # -- latency tracing (monitoring/histogram.py) ----------------------
         self.sample_every = max(0, int(sample_every))
         # service-histogram request flag: the replica's traced-message
@@ -351,30 +346,11 @@ class StatsRecord(StageCounters):
             if self.recorder is not None:
                 self.recorder.event("svc:" + self.op_name, dt_us, n_tuples)
 
-    # -- dispatch-pipeline latencies: the ``prep`` and ``commit`` stages feed
-    # each duration here (tracing.STAGES ``note``) for the dashboard's EWMAs
-    # and the Latency_prep/commit histograms; totals, counts and ring
-    # events are the stage helper's ------------------------------------------
-    def note_host_prep(self, us: float) -> None:
-        if not self._prep_seeded:
-            self._prep_seeded = True
-            self.dispatch_host_prep_us = us
-        else:
-            self.dispatch_host_prep_us += _EWMA_ALPHA * (
-                us - self.dispatch_host_prep_us)
-        if self.hist_prep is not None:
-            self.hist_prep.record(us)
-
-    def note_dispatch_commit(self, us: float) -> None:
-        if not self._commit_seeded:
-            self._commit_seeded = True
-            self.dispatch_commit_us = us
-        else:
-            self.dispatch_commit_us += _EWMA_ALPHA * (
-                us - self.dispatch_commit_us)
-        if self.hist_commit is not None:
-            self.hist_commit.record(us)
-
+    # -- dispatch-pipeline latencies: under latency sampling the ``prep``
+    # and ``commit`` stages record each duration into ``hist_prep`` /
+    # ``hist_commit`` (tracing.STAGES ``note``; no hook is bound where
+    # sampling is off); totals, counts and ring events are the stage
+    # helper's -----------------------------------------------------------------
     def note_megabatch(self, k: int, us: float) -> None:
         """One megabatch scan loop: K same-signature batches committed
         through ONE program dispatch (``FusedTPUReplica._run_megabatch``)."""
@@ -570,8 +546,6 @@ class StatsRecord(StageCounters):
             "Fused_ops": self.fused_ops,
             "Staging_pool_hits": self.staging_pool_hits,
             "Staging_pool_misses": self.staging_pool_misses,
-            "Dispatch_host_prep_usec": round(self.dispatch_host_prep_us, 3),
-            "Dispatch_commit_usec": round(self.dispatch_commit_us, 3),
             "Dispatch_readback_stalls": self.dispatch_stalls,
             "Dispatch_queue_depth_max": self.dispatch_depth_max,
             # megabatch scan loop (0s with WF_MEGABATCH off or on
@@ -683,12 +657,26 @@ class StatsRecord(StageCounters):
             else 0
         d["Queue_emit_fifo_depth_max"] = self.pipe_depth_max
         d["Worker_idle_ticks"] = self.worker_idle_ticks
-        # CPU and wall time of the worker thread that reports here (0 on
-        # the other records of its chain, so a sum counts a thread once)
+        # the account of the worker thread that reports here (0 on the
+        # other records of its chain, so a sum counts a thread once): its
+        # CPU and wall clocks, its own waits as the stage helper summed
+        # them on that thread (backpressured: blocked ``put``s; starved:
+        # blocked ``get``s, idle ticks too; on the device: the wall less
+        # the CPU of readback, d2h and launch), and what is left: a
+        # thread off the CPU in no wait the table names (waiting its turn
+        # on the interpreter lock, taken off the CPU by the host, or
+        # blocked inside a user functor: a sleep, I/O)
         w = self.worker
         cpu_ns, wall_ns = w.thread_clocks() if w is not None else (0, 0)
+        put_ns, get_ns, dev_ns = w.thread_waits() if w is not None \
+            else (0, 0, 0)
         d["Thread_cpu_usec"] = round(cpu_ns / 1e3, 1)
         d["Thread_wall_usec"] = round(wall_ns / 1e3, 1)
+        d["Worker_blocked_put_usec"] = round(put_ns / 1e3, 1)
+        d["Worker_blocked_get_usec"] = round(get_ns / 1e3, 1)
+        d["Worker_device_wait_usec"] = round(dev_ns / 1e3, 1)
+        d["Worker_unaccounted_usec"] = round(max(
+            0, wall_ns - cpu_ns - put_ns - get_ns - dev_ns) / 1e3, 1)
         # -- latency-tracing plane ------------------------------------------
         d["Latency_sample_every"] = self.sample_every
         for label, h in (("service", self.hist_service),

@@ -27,6 +27,24 @@ and counters only), ``blk`` is the envelope of one source block, work
 and waits together. Per batch, never per tuple: the CPU plane's
 per-tuple path has no stage.
 
+**CPU beside wall, and a worker's own waits.** Six stages (``prep``,
+``commit``, ``launch``, ``readback``, ``d2h``, ``ingest``:
+``StageDef.cpu``) also read the thread's CPU clock, ``thread_time_ns``,
+inside the two wall reads, on one span in ``CPU_EVERY`` and scaled up (the
+clock is a system call): a stage's wall less its CPU is time the thread
+was off the processor inside it, as an estimate over many spans (where
+that clock moves by the scheduler's tick, one span's CPU reads 0 or a
+whole tick anyway). The stages
+``StageDef.waits`` marks (``put`` and ``get`` by their wall; ``launch``,
+``readback`` and ``d2h`` by their wall less their CPU, since a blocking
+read may spin) add what they measured to the account of the thread that
+RAN them
+(``set_thread_account``, a worker's own: ``Worker_blocked_put_usec``,
+``Worker_blocked_get_usec``, ``Worker_device_wait_usec`` in
+``get_stats()``), so a producer's record says how long it stood
+backpressured while the consumer's ``Queue_blocked_put_usec`` says whose
+queue was full. Always on, like the counters; no other stage pays for it.
+
 **Batch ids.** ``next_batch_id()`` numbers batches process-wide at the
 staging edge; ``BatchTPU.bid`` travels with every batch derived from it
 and ``BatchTPU.cause`` names the input batch whose commit made a new one
@@ -57,7 +75,9 @@ from typing import Any, Dict, List, NamedTuple, Optional
 
 __all__ = ["parse_sample_rate", "env_sample_every", "resolve_sample_every",
            "STAGES", "StageDef", "StageCounters", "Stage", "next_batch_id",
-           "stamp_ns", "program_name"]
+           "stamp_ns", "program_name", "new_thread_account",
+           "set_thread_account", "account_waits",
+           "BACKPRESSURED", "STARVED", "DEVICE_WAIT"]
 
 
 def parse_sample_rate(value) -> int:
@@ -125,29 +145,43 @@ class StageDef(NamedTuple):
     layer: str            # PERF.md section 3 / BENCHMARK.json layer name
     total: Optional[str]  # get_stats() field of the cumulative usec
     count: Optional[str]  # get_stats() field of the span count
-    note: Optional[str] = None  # StatsRecord method fed each duration (us)
+    note: Optional[str] = None  # StatsRecord latency histogram fed each
+    # duration (us); bound only where sampling allocated it
     foreign: bool = False  # may run off the owner's worker thread: the
     # ring is then the CALLING thread's (single-writer rings), and a span
     # given no batch id takes the one of the enclosing ``scope`` stage
     scope: bool = False  # its batch id is the thread's while it is open
+    cpu: Optional[str] = None  # get_stats() field of the cumulative
+    # THREAD-CPU usec (``thread_time_ns`` beside the wall clock, for these
+    # stages only): wall less CPU is time off the processor inside it
+    waits: Optional[int] = None  # slot of the CALLING worker's account
+    # (``set_thread_account``) the span's wall, less its CPU where ``cpu``
+    # is set, is added to: a thread blocked, not a thread working
 
 
 _DISPATCH, _STAGING, _EXIT = "dispatch", "staging, H2D", "exit, D2H"
 _CHANNELS = "channels, workers"
+# a worker's own waits (``Worker_blocked_put/get_usec``,
+# ``Worker_device_wait_usec``): the slots of its thread's account
+BACKPRESSURED, STARVED, DEVICE_WAIT = 0, 1, 2
 
 STAGES: Dict[str, StageDef] = {
     # source thread
-    "ingest": StageDef("blk", _STAGING, None, "Ingest_blocks"),
+    "ingest": StageDef("blk", _STAGING, None, "Ingest_blocks",
+                       cpu="Ingest_cpu_total_usec"),
     "stage": StageDef("wf", _STAGING, "Stage_copy_total_usec", None),
     "h2d": StageDef("wf", _STAGING, "Stage_h2d_put_total_usec",
                     "Stage_batches"),
     # any producer / the consuming worker, blocked branches only
     "put": StageDef("wait", _CHANNELS, "Queue_blocked_put_usec",
-                    "Queue_puts_blocked", foreign=True),
-    "get": StageDef("wait", _CHANNELS, "Queue_blocked_get_usec", None),
+                    "Queue_puts_blocked", foreign=True, waits=BACKPRESSURED),
+    # idle ticks too: a ``silent`` span is counted
+    "get": StageDef("wait", _CHANNELS, "Queue_blocked_get_usec", None,
+                    waits=STARVED),
     # device operator's worker
     "prep": StageDef("wf", _DISPATCH, "Dispatch_host_prep_total_usec",
-                     "Dispatch_batches", note="note_host_prep", scope=True),
+                     "Dispatch_batches", note="hist_prep", scope=True,
+                     cpu="Dispatch_host_prep_cpu_total_usec"),
     # the window operator's fire planning, inside its prep (``count``
     # stays None: the plan runs once per batch, Dispatch_batches counts)
     "fireplan": StageDef("wf", _DISPATCH, "Fire_plan_total_usec", None),
@@ -157,23 +191,38 @@ STAGES: Dict[str, StageDef] = {
     "queue": StageDef("wait", _DISPATCH, "Dispatch_queue_wait_total_usec",
                       None),
     "commit": StageDef("wf", _DISPATCH, "Dispatch_commit_total_usec", None,
-                       note="note_dispatch_commit", scope=True),
+                       note="hist_commit", scope=True,
+                       cpu="Dispatch_commit_cpu_total_usec"),
+    # wall less CPU: the Python of a launch told from a wait on the
+    # device's queue
     "launch": StageDef("wf", _DISPATCH, "Device_launch_total_usec", None,
-                       foreign=True),
+                       foreign=True, cpu="Device_launch_cpu_total_usec",
+                       waits=DEVICE_WAIT),
+    # a blocking read may spin on the CPU while the device works: that is
+    # the thread busy, and only the wall less it a wait
     "readback": StageDef("wf", _DISPATCH,
-                         "Dispatch_readback_wait_total_usec", None),
+                         "Dispatch_readback_wait_total_usec", None,
+                         cpu="Dispatch_readback_cpu_total_usec",
+                         waits=DEVICE_WAIT),
     "emit": StageDef("wf", _DISPATCH, "Dispatch_emit_total_usec", None),
     # exit edge and sink
     "fifo": StageDef("wait", _EXIT, "Exit_fifo_wait_total_usec",
                      "Exit_fifo_batches"),
     "exit": StageDef("wf", _EXIT, "Exit_process_total_usec", None),
-    "d2h": StageDef("wf", _EXIT, "Sink_d2h_wait_total_usec", None),
+    "d2h": StageDef("wf", _EXIT, "Sink_d2h_wait_total_usec", None,
+                    cpu="Sink_d2h_cpu_total_usec", waits=DEVICE_WAIT),
     "sink": StageDef("wf", _EXIT, "Sink_functor_total_usec", None),
 }
 _INDEX = {name: i for i, name in enumerate(STAGES)}
 
 # enqueue stamps handed to ``Stage.since`` come from the helper's clock
 stamp_ns = _now_ns = time.perf_counter_ns
+_cpu_ns = time.thread_time_ns  # the calling thread's CPU clock
+# ... read on every CPU_EVERY-th span of a stage, the first one too, and
+# scaled up: that clock is a system call (5.8 us a read on the benchmark's
+# host, where perf_counter_ns is 0.09; on every span it cost the cell with
+# the shortest block 5.6%), so a stage's CPU total is an estimate
+CPU_EVERY = 16
 
 _batch_ids = itertools.count(1)
 
@@ -194,9 +243,38 @@ def program_name(kind: str, *ops: str) -> str:
 
 
 # -- per thread: its flight recorder ring (monitoring/flightrec.py owns the
-# ring itself; the slot lives here so this module imports nothing of it)
-# and the batch id of the open prep/commit stage ---------------------------
+# ring itself; the slot lives here so this module imports nothing of it),
+# the batch id of the open prep/commit stage, and a worker's account of
+# its own waits --------------------------------------------------------------
 _tls = threading.local()
+
+
+_OPEN_SLOT, _OPEN_SINCE = 3, 4
+
+
+def new_thread_account() -> List[int]:
+    """A worker's account of its own waits: nanoseconds by slot
+    (``BACKPRESSURED``, ``STARVED``, ``DEVICE_WAIT``), then the slot of
+    the wait it stands in now (-1: none) and when that began."""
+    return [0, 0, 0, -1, 0]
+
+
+def set_thread_account(acct: List[int]) -> None:
+    """``acct`` takes the waits of the stages this thread closes from now
+    on. ``Worker.run`` sets its own; only the thread itself writes it,
+    another reads it at poll time (``account_waits``)."""
+    _tls.acct = acct
+
+
+def account_waits(acct: List[int]) -> List[int]:
+    """The three waits of ``acct`` now, a wait still open counted up to
+    this instant (a worker parked on an empty channel is starved, not
+    unaccounted). One slice is one consistent reading."""
+    now = acct[:]
+    slot = now[_OPEN_SLOT]
+    if slot >= 0:
+        now[slot] += _now_ns() - now[_OPEN_SINCE]
+    return [max(0, ns) for ns in now[:3]]
 
 
 def set_thread_recorder(rec) -> None:
@@ -226,8 +304,8 @@ class _Span:
     ``silent`` inside the block keeps the span out of the ring (a timed
     ``Channel.get`` that ends in an idle tick would flood it)."""
 
-    __slots__ = ("_stage", "_b", "_cause", "_ann", "_t0", "_outer_b",
-                 "silent")
+    __slots__ = ("_stage", "_b", "_cause", "_ann", "_t0", "_c0",
+                 "_outer_b", "silent")
 
     def __init__(self, stage: "Stage", b: int, cause: int) -> None:
         self._stage = stage
@@ -252,16 +330,31 @@ class _Span:
             ann.__enter__()
             self._ann = ann
         self._t0 = _now_ns()
+        if stage._cpu:
+            # inside the wall reads: a span's CPU <= its wall; a span in
+            # CPU_EVERY (-1: not this one)
+            n = stage._cpu_n
+            stage._cpu_n = n + 1
+            self._c0 = -1 if n % CPU_EVERY else _cpu_ns()
+        elif stage._waits is not None:
+            # a pure wait (a blocked put or get may last for ever): the
+            # account says the thread stands in it, for a reader meanwhile
+            acct = getattr(_tls, "acct", None)
+            if acct is not None:
+                acct[_OPEN_SINCE] = self._t0
+                acct[_OPEN_SLOT] = stage._waits
         return self
 
     def __exit__(self, et, ev, tb) -> bool:
+        stage = self._stage
+        cpu = (_cpu_ns() - self._c0) * CPU_EVERY \
+            if stage._cpu and self._c0 >= 0 else 0
         dt = _now_ns() - self._t0
         if self._ann is not None:
             self._ann.__exit__(et, ev, tb)
-        stage = self._stage
         if stage._scope:
             _tls.b = self._outer_b
-        stage._done(dt, self._b, self._cause, self.silent)
+        stage._done(dt, self._b, self._cause, self.silent, cpu)
         return False
 
 
@@ -270,7 +363,8 @@ class Stage:
     once where a replica or emitter is built, called once per batch."""
 
     __slots__ = ("owner", "name", "_idx", "_prefix", "_fixed_op", "_op",
-                 "_label", "_note", "_foreign", "_scope")
+                 "_label", "_note", "_foreign", "_scope", "_cpu", "_cpu_n",
+                 "_waits")
 
     def __init__(self, owner: "StageCounters", name: str,
                  op: Optional[str] = None) -> None:
@@ -286,9 +380,13 @@ class Stage:
         self._fixed_op = op  # a program's label; else the owner's name
         self._op: Any = self  # sentinel: no label built yet
         self._label = ""
-        self._note = getattr(owner, sdef.note, None) if sdef.note else None
+        hist = getattr(owner, sdef.note, None) if sdef.note else None
+        self._note = hist.record if hist is not None else None
         self._foreign = sdef.foreign
         self._scope = sdef.scope
+        self._cpu = sdef.cpu is not None
+        self._cpu_n = 0  # spans entered (a racy count where ``foreign``)
+        self._waits = sdef.waits
 
     def label(self) -> str:
         """``<prefix>:<stage>:<op>``, rebuilt when the owner is renamed
@@ -316,11 +414,22 @@ class Stage:
         cannot be opened in the past."""
         self._done(_now_ns() - t0_ns, b, cause, False)
 
-    def _done(self, dt_ns: int, b: int, cause: int, silent: bool) -> None:
+    def _done(self, dt_ns: int, b: int, cause: int, silent: bool,
+              cpu_ns: int = 0) -> None:
         owner = self.owner
         i = self._idx
         owner.stage_ns[i] += dt_ns
         owner.stage_n[i] += 1
+        if cpu_ns:
+            owner.stage_cpu_ns[i] += cpu_ns
+        if self._waits is not None:
+            acct = getattr(_tls, "acct", None)
+            if acct is not None:
+                acct[_OPEN_SLOT] = -1
+                # not floored a span: where the CPU clock moves by the
+                # scheduler's tick (10 ms on some hosts) one span's CPU
+                # reads 0 or a whole tick, and only the sums are right
+                acct[self._waits] += dt_ns - cpu_ns
         if self._note is not None:
             self._note(dt_ns / 1e3)
         if silent:
@@ -337,13 +446,16 @@ class StageCounters:
     channel nobody wired to a replica."""
 
     __slots__ = ("op_name", "recorder", "stage_ns", "stage_n",
-                 "h2d_puts", "unpacked_columns")
+                 "stage_cpu_ns", "h2d_puts", "unpacked_columns")
 
     def __init__(self, op_name: str = "") -> None:
         self.op_name = op_name
         self.recorder = None  # the owning worker's FlightRecorder
         self.stage_ns: List[int] = [0] * len(STAGES)
         self.stage_n: List[int] = [0] * len(STAGES)
+        # thread-CPU nanoseconds of the stages whose StageDef names a
+        # ``cpu`` field (0 for the others, which never read that clock)
+        self.stage_cpu_ns: List[int] = [0] * len(STAGES)
         # the staging edge's transfers (tpu/batch.py): ``device_put``
         # calls issued inside ``wf:h2d`` (one per dtype group of a batch:
         # over Stage_batches, the groups of the schema), and columns of
@@ -362,6 +474,9 @@ class StageCounters:
     def stage_count(self, name: str) -> int:
         return self.stage_n[_INDEX[name]]
 
+    def stage_cpu_usec(self, name: str) -> float:
+        return self.stage_cpu_ns[_INDEX[name]] / 1e3
+
     def stage_fields(self) -> Dict[str, Any]:
         """The ``get_stats()`` fields ``STAGES`` names."""
         d: Dict[str, Any] = {}
@@ -370,6 +485,8 @@ class StageCounters:
                 d[sdef.total] = round(self.stage_ns[i] / 1e3, 1)
             if sdef.count is not None:
                 d[sdef.count] = self.stage_n[i]
+            if sdef.cpu is not None:
+                d[sdef.cpu] = round(self.stage_cpu_ns[i] / 1e3, 1)
         d["Stage_h2d_puts"] = self.h2d_puts
         d["Stage_unpacked_columns"] = self.unpacked_columns
         return d

@@ -31,6 +31,8 @@ from typing import Any, List, Optional, Tuple
 
 from ..basic import RescaleTeardown
 from ..message import EOS, Barrier
+from ..monitoring.tracing import (account_waits, new_thread_account,
+                                  set_thread_account)
 from .channel import Channel
 from .collectors import BarrierAligner
 
@@ -96,6 +98,13 @@ class Worker(threading.Thread):
         self._clock_lock = threading.Lock()
         self._t0_ns: Optional[int] = None
         self._end_clocks: Optional[Tuple[int, int]] = None
+        # this thread's own waits in nanoseconds, by the slots of
+        # monitoring/tracing.py (BACKPRESSURED, STARVED, DEVICE_WAIT): the
+        # stage helper adds a closed ``wait:put`` / ``wait:get`` span, and
+        # the wall less the CPU of a readback, d2h or launch span, on the
+        # thread that ran it (Worker_blocked_put/get_usec,
+        # Worker_device_wait_usec beside the two clocks)
+        self._account = new_thread_account()
         st = self._stats()
         if st is not None:
             st.worker = self
@@ -116,6 +125,7 @@ class Worker(threading.Thread):
                     bind(coordinator)
 
     def run(self) -> None:
+        set_thread_account(self._account)
         self._t0_ns = time.perf_counter_ns()
         try:
             self._run()
@@ -137,6 +147,11 @@ class Worker(threading.Thread):
             cpu_ns = (time.clock_gettime_ns(_THREAD_CPU_CLOCK(self.ident))
                       if _THREAD_CPU_CLOCK is not None else 0)
             return cpu_ns, time.perf_counter_ns() - self._t0_ns
+
+    def thread_waits(self) -> List[int]:
+        """``[backpressured, starved, on the device]`` nanoseconds of this
+        worker's thread so far, a wait it stands in now included."""
+        return account_waits(self._account)
 
     def _run(self) -> None:
         if self.flightrec is not None:

@@ -1169,14 +1169,13 @@ class PipeGraph:
         self._started = True
         self._t0 = time.monotonic()
         # flight-recorder registry (feeds MonitoringServer's /trace) +
-        # the stall watchdog (WF_STALL_SEC > 0, default off)
+        # the watchdog: its gauge of the process always, its check of the
+        # workers under WF_STALL_SEC > 0 (default off)
         from ..monitoring.flightrec import (StallWatchdog, env_stall_sec,
                                             register_graph)
         register_graph(self)
-        stall = env_stall_sec()
-        if stall > 0:
-            self._watchdog = StallWatchdog(self, stall,
-                                           dump_fn=self._stall_dump)
+        self._watchdog = StallWatchdog(self, env_stall_sec(),
+                                       dump_fn=self._stall_dump)
         if env_flag("WF_TRACING_ENABLED"):
             # reference: one MonitoringThread per PipeGraph when tracing
             # (wf/pipegraph.hpp:671-675)
@@ -1326,6 +1325,11 @@ class PipeGraph:
                 "parallelism": op.parallelism,
                 "replicas": [r.stats.to_dict() for r in op.replicas],
             })
+        # the process's own account (the watchdog's gauge: Process_*,
+        # Gc_*) on ONE record, the first source replica's, so a sum over
+        # the graph's records counts it once
+        if self._watchdog is not None and ops and ops[0]["replicas"]:
+            ops[0]["replicas"][0].update(self._watchdog.process_fields())
         # mark-final-then-drop: replicas a scale-down removed appear in
         # exactly ONE report with Final=true, then their series end
         finals, self._final_series = self._final_series, []
