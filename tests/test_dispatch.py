@@ -14,7 +14,8 @@ import pytest
 
 from windflow_tpu import (ExecutionMode, PipeGraph, Sink_Builder,
                           Source_Builder, TimePolicy, WindFlowError)
-from windflow_tpu.runtime.dispatch import DeviceDispatchQueue, dispatch_depth
+from windflow_tpu.runtime.dispatch import (DeviceDispatchQueue,
+                                           dispatch_depth, split_commit)
 
 from common import DictWinCollector, TupleT, expected_windows
 
@@ -116,6 +117,160 @@ def test_queue_stall_and_stage_counters():
     assert d["Dispatch_batches"] == 2
     # both batches sat in the queue from submit until the drain
     assert d["Dispatch_queue_wait_total_usec"] > 0.0
+
+
+# ---------------------------------------------------------------------------
+# the two-half contract: a commit may hand back its readback-and-emit as a
+# finish, which the queue runs one launch later
+# ---------------------------------------------------------------------------
+def _split(log, i, boom=False):
+    @split_commit
+    def launch():
+        log.append(("launch", i))
+
+        def finish():
+            if boom:
+                raise RuntimeError("synthetic finish failure")
+            log.append(("finish", i))
+
+        return finish
+    return launch
+
+
+def _whole(log, i):
+    return lambda: log.append(("whole", i))
+
+
+def _play(q, log, script):
+    """``s``: submit a split commit, ``w``: a whole one (numbered in
+    submission order), ``d``: drain(forced), ``i``: idle tick."""
+    n = 0
+    for step in script:
+        if step == "s":
+            q.submit(_split(log, n), n)
+            n += 1
+        elif step == "w":
+            q.submit(_whole(log, n), n)
+            n += 1
+        elif step == "d":
+            q.drain(forced=True)
+        else:
+            q.on_idle()
+
+
+L, F, W = "launch", "finish", "whole"
+TWO_HALF_CASES = {
+    # launch n+1 runs before finish n; two launches queued, one finish
+    # pending
+    "launch_ahead_depth2": (2, "ssss", [(L, 0), (L, 1), (F, 0)], 3),
+    "launch_ahead_depth1": (1, "sss", [(L, 0), (L, 1), (F, 0)], 2),
+    # the lag is one launch whatever the depth
+    "launch_ahead_depth8": (8, "s" * 11, [(L, 0), (L, 1), (F, 0),
+                                          (L, 2), (F, 1)], 9),
+    # finishes leave in submission order, a drain runs every launch and
+    # every finish
+    "drain_runs_both_halves": (2, "ssssd",
+                               [(L, 0), (L, 1), (F, 0), (L, 2), (F, 1),
+                                (L, 3), (F, 2), (F, 3)], 0),
+    "idle_tick_runs_both_halves": (4, "ssi",
+                                   [(L, 0), (L, 1), (F, 0), (F, 1)], 0),
+    # depth 0: both halves inside submit
+    "depth0_both_inside_submit": (0, "ss",
+                                  [(L, 0), (F, 0), (L, 1), (F, 1)], 0),
+    # a commit that returns None emits inside its call: the pending
+    # finish runs first
+    "whole_never_overtakes": (1, "sww", [(L, 0), (F, 0), (W, 1)], 1),
+    "mixed_kinds_keep_order": (2, "swsswd",
+                               [(L, 0), (F, 0), (W, 1), (L, 2), (L, 3),
+                                (F, 2), (F, 3), (W, 4)], 0),
+    "whole_commits_unchanged": (2, "wwwd", [(W, 0), (W, 1), (W, 2)], 0),
+    # one split commit, then quiet: nothing is parked past a drain
+    "lone_split_then_drain": (2, "sd", [(L, 0), (F, 0)], 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TWO_HALF_CASES))
+def test_two_half_order(case):
+    depth, script, expected, left = TWO_HALF_CASES[case]
+    q = DeviceDispatchQueue(depth=depth)
+    log = []
+    _play(q, log, script)
+    assert log == expected
+    assert len(q) == left
+
+
+READBACK_COUNT_CASES = {
+    # (depth, script) -> (Dispatch_readbacks, Dispatch_readbacks_deferred)
+    "steady_stream": (2, "s" * 12, (9, 9)),
+    "stream_then_drain": (2, "sssssd", (5, 4)),
+    "depth0_never_deferred": (0, "sssss", (5, 0)),
+    "idle_tick_not_deferred": (2, "si", (1, 0)),
+    "ahead_of_a_whole_commit_not_deferred": (1, "swsd", (2, 0)),
+    "no_split_commit_no_readback": (2, "wwwwd", (0, 0)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(READBACK_COUNT_CASES))
+def test_readback_counters(case):
+    from windflow_tpu.monitoring.stats import StatsRecord
+
+    depth, script, expected = READBACK_COUNT_CASES[case]
+    st = StatsRecord("op", 0)
+    q = DeviceDispatchQueue(stats=st, depth=depth)
+    _play(q, [], script)
+    d = st.to_dict()
+    assert (d["Dispatch_readbacks"],
+            d["Dispatch_readbacks_deferred"]) == expected
+
+
+@pytest.mark.parametrize("depth", [0, 2])
+def test_finish_that_raises_aborts_the_rest(depth):
+    q = DeviceDispatchQueue(depth=depth)
+    log = []
+    with pytest.raises(RuntimeError, match="synthetic finish failure"):
+        q.submit(_split(log, 0, boom=True), 0)
+        for i in (1, 2, 3):
+            q.submit(_split(log, i), i)
+        q.drain()
+    assert len(q) == 0  # queued launches and the pending finish: gone
+    q.drain()
+    # depth 2: launch 1 ran, then finish 0 raised; depth 0: inside submit
+    assert log == ([(L, 0)] if depth == 0 else [(L, 0), (L, 1)])
+
+
+def test_abort_drops_the_pending_finish():
+    q = DeviceDispatchQueue(depth=1)
+    log = []
+    _play(q, log, "ss")
+    assert log == [(L, 0)] and len(q) == 2
+    q.abort()
+    assert len(q) == 0
+    q.drain(forced=True)
+    assert log == [(L, 0)]
+
+
+def test_forced_drain_of_a_pending_finish_is_a_stall():
+    from windflow_tpu.monitoring.stats import StatsRecord
+
+    st = StatsRecord("op", 0)
+    q = DeviceDispatchQueue(stats=st, depth=1)
+    _play(q, [], "ss")
+    q.drain(forced=True)
+    assert st.dispatch_stalls == 1
+    q.drain(forced=True)  # nothing left: not a stall
+    assert st.dispatch_stalls == 1
+
+
+def test_split_finish_spans_carry_their_own_batch():
+    """A finish runs under ``wf:commit`` with ITS batch's id, not the
+    id of the launch it follows; commit time is both halves' sum."""
+    from windflow_tpu.monitoring.stats import StatsRecord
+
+    st = StatsRecord("op", 0)
+    q = DeviceDispatchQueue(stats=st, depth=1)
+    _play(q, [], "sssd")
+    # three launches and three finishes, a span each
+    assert st.stage_count("commit") == 6
 
 
 # ---------------------------------------------------------------------------
@@ -313,6 +468,208 @@ def test_depth0_equals_depth2_randomized(seed, monkeypatch):
     # depth >= 2 actually pipelined (otherwise this test proves nothing)
     assert st2.dispatch_depth_max >= 1
     assert st2.dispatch_batches == cfg[4]
+
+
+# ---------------------------------------------------------------------------
+# the commits that compact: launch and finish one launch apart, results
+# what they were
+# ---------------------------------------------------------------------------
+COMPACTING = ("filter", "stateful_filter", "map_filter_chain",
+              "chain_reduce", "chain_keyed_reduce")
+
+
+def _compacting_replica(kind, name):
+    import jax.numpy as jnp
+
+    from windflow_tpu.tpu.fused_ops import make_fused_replica
+    from windflow_tpu.tpu.ops_tpu import Filter_TPU, Map_TPU, Reduce_TPU
+
+    def keep(f):
+        return f["value"] % 3 != 0
+
+    def triple(f):
+        return {**f, "value": f["value"] * 3 + 1}
+
+    def add(a, b):
+        return {"key": b["key"], "value": a["value"] + b["value"]}
+
+    if kind == "filter":
+        op = Filter_TPU(keep, name=name)
+        op.build_replicas()
+        return op.replicas[0]
+    if kind == "stateful_filter":
+        def every_other(row, state):
+            n = state["n"] + 1
+            return n % 2 == 0, {"n": n}
+
+        op = Filter_TPU(every_other, name=name, key_extractor="key",
+                        state_init={"n": jnp.int32(0)})
+        op.build_replicas()
+        return op.replicas[0]
+    ops = [Map_TPU(triple, name=name + "_m"), Filter_TPU(keep,
+                                                         name=name + "_f")]
+    if kind == "chain_reduce":
+        ops.append(Reduce_TPU(add, name=name + "_r"))
+    elif kind == "chain_keyed_reduce":
+        ops.append(Reduce_TPU(add, key_extractor="key", name=name + "_r"))
+    return make_fused_replica(ops, 0)
+
+
+class _Recorder:
+    """Stands in for the emitter: every batch (columns, timestamps,
+    watermark, host keys) and every punctuation, in the order they
+    leave the replica."""
+
+    def __init__(self):
+        self.out = []
+
+    def emit_device_batch(self, b):
+        n = b.size
+        keys = b.host_keys
+        self.out.append((
+            "batch", n,
+            {f: np.asarray(v)[:n].tolist() for f, v in b.fields.items()},
+            np.asarray(b.ts_host)[:n].tolist(), int(b.wm),
+            None if keys is None else [int(k) for k in keys][:n]))
+
+    def propagate_punctuation(self, wm):
+        self.out.append(("punct", int(wm)))
+
+    def set_stats(self, s):
+        pass
+
+    def flush(self):
+        pass
+
+
+def _compacting_batches(seed, n_batches, size=32):
+    import jax
+
+    from windflow_tpu.tpu.batch import BatchTPU
+    from windflow_tpu.tpu.schema import TupleSchema
+
+    schema = TupleSchema({"key": np.int32, "value": np.int32})
+    rng = np.random.default_rng(seed)
+    ts0 = 0
+    for i in range(n_batches):
+        n = size if i % 3 else size - 5  # partial batches too
+        keys = rng.integers(0, 4, size).astype(np.int64)
+        vals = rng.integers(0, 60, size).astype(np.int32)
+        ts = ts0 + np.cumsum(rng.integers(0, 7, size)).astype(np.int64)
+        ts0 = int(ts[-1]) + 1
+        yield BatchTPU({"key": jax.device_put(keys.astype(np.int32)),
+                        "value": jax.device_put(vals)}, ts, n, schema,
+                       wm=int(ts[n - 1]), host_keys=keys[:n])
+
+
+def _drive_compacting(kind, depth, seed, monkeypatch):
+    from windflow_tpu.message import make_punctuation
+
+    monkeypatch.setenv("WF_DISPATCH_DEPTH", str(depth))
+    rep = _compacting_replica(kind, f"{kind}_d{depth}")
+    rec = rep.emitter = _Recorder()
+    n_batches = 9
+    for i, b in enumerate(_compacting_batches(seed, n_batches)):
+        rep.handle_msg(0, b)
+        if i == 4:
+            rep.handle_msg(0, make_punctuation(b.wm))
+    rep.terminate()
+    return rec.out, rep.stats
+
+
+@pytest.mark.parametrize("kind", COMPACTING)
+@pytest.mark.parametrize("seed", [11, 23, 47])
+def test_depth0_equals_depth2_compacting(kind, seed, monkeypatch):
+    """The differential for the commits that split: identical batches
+    (rows, order, timestamps, watermarks, keys) and punctuations, in the
+    same order, at WF_DISPATCH_DEPTH 0, 2 and 8 — and at depth >= 1 the
+    finishes did run one launch late."""
+    o0, st0 = _drive_compacting(kind, 0, seed, monkeypatch)
+    o2, st2 = _drive_compacting(kind, 2, seed, monkeypatch)
+    o8, st8 = _drive_compacting(kind, 8, seed, monkeypatch)
+    assert sum(1 for o in o0 if o[0] == "batch") >= 5, "vacuous"
+    assert [o for o in o0 if o[0] == "punct"], "no punctuation went out"
+    assert o0 == o2 == o8
+    assert st0.dispatch_readbacks == st2.dispatch_readbacks == 9
+    assert st0.dispatch_readbacks_deferred == 0
+    # nine batches, a punctuation after the fifth, EOS: every finish but
+    # the two the drains ran followed a later launch
+    assert st2.dispatch_readbacks_deferred == 7
+    assert st8.dispatch_readbacks_deferred == 7
+
+
+def _ordering_point(rep, name):
+    from windflow_tpu.message import make_punctuation
+
+    if name == "drain_forced":
+        rep.dispatch.drain(forced=True)
+    elif name == "on_idle":
+        assert rep.on_idle() is True
+    elif name == "punctuation":
+        rep.handle_msg(0, make_punctuation(rep.cur_wm))
+    elif name == "snapshot_state":
+        rep.snapshot_state()
+    else:
+        rep.terminate()
+
+
+@pytest.mark.parametrize("point", ["drain_forced", "on_idle", "punctuation",
+                                   "snapshot_state", "eos"])
+@pytest.mark.parametrize("kind", ["filter", "map_filter_chain"])
+def test_ordering_point_leaves_nothing_pending(kind, point, monkeypatch):
+    """Three batches at depth 2: one launched with its finish pending,
+    two queued. Every ordering point runs all three, launch and finish,
+    in order, before anything else leaves (a punctuation last)."""
+    monkeypatch.setenv("WF_DISPATCH_DEPTH", "2")
+    rep = _compacting_replica(kind, f"{kind}_{point}")
+    rec = rep.emitter = _Recorder()
+    sent = list(_compacting_batches(5, 3))
+    for b in sent:
+        rep.handle_msg(0, b)
+    assert len(rep.dispatch) == 3 and rec.out == []
+    _ordering_point(rep, point)
+    assert len(rep.dispatch) == 0
+    batches = [o for o in rec.out if o[0] == "batch"]
+    assert [o[4] for o in batches] == [int(b.wm) for b in sent]
+    if point == "punctuation":
+        assert rec.out[-1] == ("punct", int(sent[-1].wm))
+        assert rec.out[:-1] == batches
+
+
+@pytest.mark.parametrize("kind", ["filter", "map"])
+def test_readback_counters_in_get_stats(kind, monkeypatch):
+    """A graph with a filter reports how many finishes ran and how many
+    of them one launch late; a graph without one reports none."""
+    monkeypatch.setenv("WF_DISPATCH_DEPTH", "2")
+    from windflow_tpu.tpu import Filter_TPU_Builder, Map_TPU_Builder
+
+    graph = PipeGraph("dispatch_readbacks")
+    src = (Source_Builder(
+        lambda shipper, ctx: [shipper.push(TupleT(k % 3, k))
+                              for k in range(320)])
+        .with_output_batch_size(16).build())
+    if kind == "filter":
+        op = (Filter_TPU_Builder(lambda f: f["value"] % 2 == 0)
+              .with_name("dev").build())
+    else:
+        op = (Map_TPU_Builder(lambda f: {**f, "value": f["value"] + 1})
+              .with_name("dev").build())
+    seen = []
+    graph.add_source(src).add(op).add_sink(
+        Sink_Builder(lambda t: seen.append(t)).build())
+    graph.run()
+    (dev,) = [o for o in graph.get_stats()["Operators"]
+              if o["name"] == "dev"]
+    (rep,) = dev["replicas"]
+    assert rep["Dispatch_batches"] == 20
+    if kind == "filter":
+        assert rep["Dispatch_readbacks"] == 20
+        # idle ticks and punctuations may drain a few early
+        assert 0 < rep["Dispatch_readbacks_deferred"] <= 19
+        assert len([t for t in seen if t is not None]) == 160
+    else:
+        assert rep["Dispatch_readbacks"] == 0
+        assert rep["Dispatch_readbacks_deferred"] == 0
 
 
 def test_worker_idle_tick_commits_in_flight(monkeypatch):

@@ -221,6 +221,43 @@ def test_differential_reduce_terminator(monkeypatch):
     assert sums["1"][1] == sums["0"][1]
 
 
+@pytest.mark.parametrize("terminator", ["filter", "reduce", "keyed_reduce"])
+def test_differential_depth0_vs_depth2_ordered(terminator, monkeypatch):
+    """The chains whose emit reads their program's fresh outputs hand
+    that read back to the dispatch queue as a finish, run one launch
+    later: at parallelism 1 the sink sees the SAME rows in the SAME
+    order at WF_DISPATCH_DEPTH 0 (both halves inside submit) and 2."""
+    monkeypatch.setenv("WF_TPU_FUSION", "1")
+    rows, deferred = {}, {}
+    for depth in ("0", "2"):
+        monkeypatch.setenv("WF_DISPATCH_DEPTH", depth)
+        col = RowCollector()
+        g = PipeGraph("fusion_depth", ExecutionMode.DEFAULT,
+                      TimePolicy.INGRESS_TIME)
+        src = (Source_Builder(make_ingress_source(N_KEYS, STREAM_LEN))
+               .with_parallelism(1).with_output_batch_size(16).build())
+        m = (Map_TPU_Builder(lambda f: {**f, "value": f["value"] * 3})
+             .with_name("m").build())
+        flt = (Filter_TPU_Builder(lambda f: f["value"] % 2 == 0)
+               .with_name("f").build())
+        pipe = g.add_source(src).add(m).chain(flt)
+        if terminator != "filter":
+            red = Reduce_TPU_Builder(
+                lambda a, b: {"key": b["key"],
+                              "value": a["value"] + b["value"]})
+            if terminator == "keyed_reduce":
+                red = red.with_key_by("key")
+            pipe = pipe.chain(red.with_name("r").build())
+        pipe.add_sink(Sink_Builder(col.sink).build())
+        g.run()
+        rows[depth] = list(col.rows)
+        (rep,) = _fused_stage_stats(g)["replicas"]
+        assert rep["Dispatch_readbacks"] == rep["Dispatch_batches"] > 2
+        deferred[depth] = rep["Dispatch_readbacks_deferred"]
+    assert rows["0"] == rows["2"] and rows["0"]
+    assert deferred["0"] == 0 and deferred["2"] > 0
+
+
 # ---------------------------------------------------------------------------
 # legality + fallback diagnostics
 # ---------------------------------------------------------------------------

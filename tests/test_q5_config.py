@@ -322,6 +322,8 @@ def test_the_files_state_the_deployment():
     assert cell.module.windows_per_event(cfg) == 5
     names = {m["name"] for m, _ in cell.metrics("per_layer")}
     mine = {n for n in names if n.endswith(".q5")}
-    # nine `.sat` metrics with no `workloads` list, and PR 36's eleven
-    assert len(mine) == 13 and len(names - mine) == 20
+    # nine `.sat` metrics with no `workloads` list, PR 36's eleven, and
+    # PR 37's `readback_deferred_share.sat` (`bids` compacts)
+    assert len(mine) == 13 and len(names - mine) == 21
+    assert "readback_deferred_share.sat" in names
     assert all(n.endswith(".sat") for n in names - mine)

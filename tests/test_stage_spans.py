@@ -236,10 +236,18 @@ def test_one_block_from_stage_to_window_commit(served):
     b = log.named("wf:stage:src")[BLOCKS // 2]["b"]
     assert b > 0
     for name in ("wf:stage:src", "wf:h2d:src", f"wf:prep:{CHAIN}",
-                 f"wf:commit:{CHAIN}", f"wf:launch:{CHAIN}",
+                 f"wf:launch:{CHAIN}",
                  f"wf:readback:{CHAIN}", f"wf:emit:{CHAIN}",
                  "wf:prep:win", "wf:commit:win"):
         assert len(log.named(name, b=b)) == 1, (name, b)
+    # a chain that compacts commits in two halves under the batch's own
+    # id: the launch, and one launch later the readback and the emit
+    # (spans are logged as they close)
+    closed = [s["name"].split(":")[1] for s in log.spans
+              if s["b"] == b and s["name"].endswith(":" + CHAIN)
+              and s["name"].split(":")[1] in ("commit", "launch",
+                                              "readback", "emit")]
+    assert closed == ["launch", "commit", "readback", "emit", "commit"]
     # the source's work sits in the block's envelope, never in a wf: span
     for name in ("wf:stage:src", "wf:h2d:src"):
         assert log.named(name, b=b)[0]["parent"] == "blk:ingest:src"
@@ -820,7 +828,10 @@ def test_account_metric_reads_a_counter_the_program_has(name):
         spec = json.load(f)
     entry = [m for m in bench["per_layer"] if m["name"] == name]
     assert len(entry) == 1 and "workloads" not in entry[0]  # every cell
-    assert bench["per_layer"].index(entry[0]) >= len(bench["per_layer"]) - 11
+    # appended together in PR 36 (later PRs append after them)
+    names = [m["name"] for m in bench["per_layer"]]
+    first = names.index(ACCOUNT_METRICS[0])
+    assert tuple(names[first:first + 11]) == ACCOUNT_METRICS
     for k in ("unit", "layer", "source", "moves"):
         assert entry[0][k] == spec[k], k
     assert spec["source"] == "program_counter"

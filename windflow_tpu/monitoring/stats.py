@@ -74,6 +74,9 @@ class StatsRecord(StageCounters):
         "key_capacity_growths",
         "staging_pool_hits", "staging_pool_misses",
         "dispatch_stalls", "dispatch_depth_max",
+        # finish halves the dispatch queue ran (a compacting commit's
+        # readback-and-emit), and those that ran one launch later
+        "dispatch_readbacks", "dispatch_readbacks_deferred",
         # megabatch scan loop (runtime/dispatch.py + tpu/fused_ops.py):
         # grouped dispatches (loops), batches committed through them,
         # and the widest group observed — Programs_per_batch in to_dict
@@ -218,6 +221,8 @@ class StatsRecord(StageCounters):
         # table's (``prep`` / ``commit``: wall and thread-CPU totals)
         self.dispatch_stalls = 0  # forced ordering-point drains
         self.dispatch_depth_max = 0
+        self.dispatch_readbacks = 0  # finish halves run
+        self.dispatch_readbacks_deferred = 0  # ... one launch later
         self.megabatch_loops = 0
         self.megabatch_batches = 0
         self.megabatch_max = 0
@@ -367,6 +372,14 @@ class StatsRecord(StageCounters):
 
     def note_dispatch_stall(self) -> None:
         self.dispatch_stalls += 1
+
+    def note_dispatch_readback(self, deferred: bool) -> None:
+        """One finish half run by the dispatch queue; ``deferred`` where
+        a later launch of the same replica had been issued first (a
+        drain's or an idle tick's last finish has none)."""
+        self.dispatch_readbacks += 1
+        if deferred:
+            self.dispatch_readbacks_deferred += 1
 
     # -- checkpointing (windflow_tpu.checkpoint) -----------------------------
     def note_checkpoint(self, snapshot_us: float, nbytes: int,
@@ -548,6 +561,8 @@ class StatsRecord(StageCounters):
             "Staging_pool_misses": self.staging_pool_misses,
             "Dispatch_readback_stalls": self.dispatch_stalls,
             "Dispatch_queue_depth_max": self.dispatch_depth_max,
+            "Dispatch_readbacks": self.dispatch_readbacks,
+            "Dispatch_readbacks_deferred": self.dispatch_readbacks_deferred,
             # megabatch scan loop (0s with WF_MEGABATCH off or on
             # non-fused replicas; Programs_per_batch == 1.0 is the
             # un-amortized fused baseline, < 1.0 means the scan loop is

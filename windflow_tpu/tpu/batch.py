@@ -320,6 +320,16 @@ class ChunkedKeys:
                 for _ in range(n)]
 
 
+def async_host_copy(*arrays: Any) -> None:
+    """Start the async host copy of device arrays (nothing for a plain
+    numpy array or None): a later ``np.asarray`` / ``int()`` reads the
+    copy that has landed instead of waiting for the transfer too."""
+    for arr in arrays:
+        f = getattr(arr, "copy_to_host_async", None)
+        if f is not None:
+            f()
+
+
 class BatchTPU(StreamMsg):
     __slots__ = ("fields", "ts_host", "size", "capacity", "wm", "is_punct",
                  "stream_tag", "id", "schema", "_host_keys", "key_slots",
@@ -438,10 +448,7 @@ class BatchTPU(StreamMsg):
         and then for the copy; issuing the copies early lets them
         overlap each other and subsequent compute, after which
         ``np.asarray`` reads the cached host copy."""
-        for v in self.fields.values():
-            f = getattr(v, "copy_to_host_async", None)
-            if f is not None:
-                f()
+        async_host_copy(*self.fields.values())
 
     def to_rows(self) -> List[Tuple[Any, int]]:
         """TPU->CPU (the reference's ``transfer2CPU``,

@@ -35,8 +35,8 @@ from ..basic import ExecutionMode, WindFlowError
 from ..message import Batch
 from ..monitoring.tracing import StageCounters, next_batch_id, stamp_ns
 from ..runtime.emitters import BasicEmitter
-from .batch import (BatchTPU, StagingBuffers, bucket_capacity,
-                    gather_columns)
+from .batch import (BatchTPU, StagingBuffers, async_host_copy,
+                    bucket_capacity, gather_columns)
 from .schema import TupleSchema
 
 
@@ -482,14 +482,6 @@ class TPUStageEmitter(BasicEmitter):
         return cb
 
 
-def _async_copy(arr: Any) -> None:
-    """Start an async host copy of one device column (no-op for plain
-    numpy arrays on the CPU backend)."""
-    f = getattr(arr, "copy_to_host_async", None)
-    if f is not None:
-        f()
-
-
 def _maybe_prefetch_key(batch: BatchTPU, field: Optional[str]) -> None:
     """Start an async host copy of the key column when the downstream
     keyed device op will have to read it (no host key metadata on the
@@ -500,7 +492,7 @@ def _maybe_prefetch_key(batch: BatchTPU, field: Optional[str]) -> None:
     if field is None or batch.keys_for(field) is not None:
         return
     if field in batch.fields:
-        _async_copy(batch.fields[field])
+        async_host_copy(batch.fields[field])
 
 
 class TPUForwardEmitter(BasicEmitter):
@@ -971,7 +963,7 @@ class TPUKeyByEmitter(BasicEmitter, _D2HPipeline):
         if mine and batch.keys_for(mine) is None:
             for f in ((self.key_field,) if self.key_field is not None
                       else self.key_fields):
-                _async_copy(batch.fields.get(f))
+                async_host_copy(batch.fields.get(f))
             self._pipe_add(batch)
             return
         self._drain()  # keep stream order ahead of an immediate route
@@ -1098,7 +1090,7 @@ class TPUSplittingEmitter(BasicEmitter, _D2HPipeline):
     def emit_device_batch(self, batch: BatchTPU) -> None:
         logic = self.splitting_logic
         if isinstance(logic, str):
-            _async_copy(batch.fields[logic])
+            async_host_copy(batch.fields[logic])
         else:
             batch.prefetch_host()  # callable logic reads every column
         self._pipe_add(batch)

@@ -67,8 +67,8 @@ import numpy as np
 from ..basic import WindFlowError
 from ..monitoring.flightrec import instrumented_jit
 from ..monitoring.tracing import program_name
-from ..runtime.dispatch import megabatch_k
-from .batch import BatchTPU
+from ..runtime.dispatch import megabatch_k, split_commit
+from .batch import BatchTPU, async_host_copy
 from .ffat_tpu import Ffat_Windows_TPU, FfatTPUReplica
 from .ops_tpu import (Filter_TPU, Map_TPU, Reduce_TPU, TPUReplicaBase,
                       _compact_order, _grid_scan_core, _KeyedStateScan,
@@ -163,6 +163,12 @@ class FusedTPUReplica(TPUReplicaBase):
             raise WindFlowError(
                 f"{self.fused_name}: Reduce_TPU must terminate "
                 "the fused chain")
+        # the emit reads fresh outputs of the chain's program (the three
+        # branches of ``_commit_emit`` that take a readback): such a
+        # chain's commit is a launch half that returns its finish
+        self._emit_reads = (self._has_filter
+                            or self._reduce_combine is not None
+                            or self._kreduce_combine is not None)
         # compiled fused programs shared across this stage's replicas
         # (the graph build is single-threaded; worker threads only read)
         head = ops[0]
@@ -415,7 +421,7 @@ class FusedTPUReplica(TPUReplicaBase):
         hargs_t = tuple(hargs)
         engines = self._engines
 
-        def commit() -> None:
+        def commit() -> Optional[Callable[[], None]]:
             # tables (+ dirty bitmaps) read AT COMMIT TIME — earlier
             # queued commits reassign them (donation)
             tables = tuple((e.table, e.dirty) for e in engines)
@@ -423,7 +429,19 @@ class FusedTPUReplica(TPUReplicaBase):
             self.stats.device_programs_run += 1  # ONE program per batch
             for eng, td in zip(engines, res[-1]):
                 eng.table, eng.dirty = td
-            self._commit_emit(batch, res[:-1], kextra)
+            parts = res[:-1]
+            if not self._emit_reads:
+                self._commit_emit(batch, parts, kextra)
+                return None
+            # the launch half of a chain that ends in a filter or a
+            # reduce: what the emit reads (every part after the columns)
+            # starts its way to the host, and the readback-and-emit goes
+            # back to the queue as the finish, run one launch later
+            async_host_copy(*parts[1:])
+            return lambda: self._commit_emit(batch, parts, kextra)
+
+        if self._emit_reads:
+            split_commit(commit)
 
         # megabatch metadata: the dispatch queue groups consecutive
         # commits whose scan_sig matches (same chain, same grid shapes,
@@ -510,7 +528,8 @@ class FusedTPUReplica(TPUReplicaBase):
         elif self._has_filter:
             out, order, count = parts
             # emit_compacted's int(count)/np.asarray(order) readbacks
-            # run here, depth batches after dispatch
+            # run here: in the finish, one launch after this batch's own
+            # (inside the group's commit under a megabatch)
             self.emit_compacted(batch, out, order, count)
         else:
             (out,) = parts

@@ -37,9 +37,9 @@ from ..basic import ExecutionMode, OpType, RoutingMode, WindFlowError
 from ..monitoring.flightrec import instrumented_jit
 from ..monitoring.tracing import program_name
 from ..operators.base import BasicOperator, BasicReplica
-from ..runtime.dispatch import DeviceDispatchQueue
-from .batch import (BatchTPU, StagingBuffers, key_column_np,
-                    key_column_to_list, row_schema)
+from ..runtime.dispatch import DeviceDispatchQueue, split_commit
+from .batch import (BatchTPU, StagingBuffers, async_host_copy,
+                    key_column_np, key_column_to_list, row_schema)
 from .schema import TupleSchema
 
 
@@ -340,10 +340,14 @@ class TPUReplicaBase(BasicReplica):
     Batch processing is SPLIT into a host-prep stage and a device-commit
     stage pipelined through a per-replica ``DeviceDispatchQueue``
     (``WF_DISPATCH_DEPTH``, default 2): ``prep_device_batch`` runs the
-    host control plane for batch N+1 while batch N's program dispatch and
-    emit readbacks sit deferred in the queue. The queue drains at every
-    ordering point (punctuation, EOS/terminate, worker idle tick) and
-    whenever host code must touch the replica's device state."""
+    host control plane for batch N+1 while batch N's commit sits deferred
+    in the queue. A commit whose emit reads a fresh output of the program
+    it launches (a compaction) is itself two halves, one launch apart: it
+    launches and returns its readback-and-emit as a finish
+    (``finish_compacted``), which the queue runs after the replica's next
+    launch. The queue drains, launches and finishes, at every ordering
+    point (punctuation, EOS/terminate, worker idle tick) and whenever
+    host code must touch the replica's device state."""
 
     def __init__(self, op: BasicOperator, idx: int) -> None:
         super().__init__(op, idx)
@@ -454,12 +458,23 @@ class TPUReplicaBase(BasicReplica):
         with self._st_emit(batch.bid, batch.cause):
             self.emitter.emit_device_batch(batch)
 
+    def finish_compacted(self, batch: BatchTPU, out_fields, order, count
+                         ) -> Callable[[], None]:
+        """The end of a compacting commit's launch half (a
+        ``split_commit``): start the host copies of what the emit reads,
+        so the transfer too runs under the next batch's work, and hand
+        the readback-and-emit back as the finish the dispatch queue runs
+        one launch later."""
+        async_host_copy(count, order)
+        return lambda: self.emit_compacted(batch, out_fields, order, count)
+
     def emit_compacted(self, batch: BatchTPU, out_fields, order, count
                        ) -> None:
         """Emit a compaction result: device columns reordered keep-first,
         host ts/keys reordered to match (shared by the filter paths)."""
         # the compaction readbacks: int(count) + the order materialization
-        # block on the program result (this is why commits are deferred)
+        # block on the program result (this is why a compacting commit
+        # hands this call back as its finish)
         with self._st_readback(batch.bid):
             new_size = int(count)
             order_np = np.asarray(order)
@@ -1052,7 +1067,8 @@ class StatefulFilterTPUReplica(TPUReplicaBase):
         grid_idx, valid, touched, tmask, M, KB = self.engine.grid_meta(batch)
         prog = self.engine.program(M, KB)
 
-        def commit() -> None:
+        @split_commit
+        def commit() -> Callable[[], None]:
             out, order, count, table2, dirty2 = prog(
                 batch.fields, grid_idx, valid, touched, tmask,
                 self.engine.table, self.engine.dirty)
@@ -1060,8 +1076,8 @@ class StatefulFilterTPUReplica(TPUReplicaBase):
             self.engine.table = table2
             self.engine.dirty = dirty2
             # emit_compacted's int(count)/np.asarray(order) readbacks run
-            # here, depth batches after dispatch — no fresh-result stall
-            self.emit_compacted(batch, out, order, count)
+            # in the finish, one launch later — no fresh-result stall
+            return self.finish_compacted(batch, out, order, count)
 
         return commit
 
@@ -1146,10 +1162,14 @@ class FilterTPUReplica(TPUReplicaBase):
             run, self.stats, label=op.name,
             program=program_name(_PROG_FILTER, op.name))
 
-    def process_device_batch(self, batch: BatchTPU) -> None:
-        out, order, count = self._jitted(batch.fields, batch.size)
-        self.stats.device_programs_run += 1
-        self.emit_compacted(batch, out, order, count)
+    def prep_device_batch(self, batch: BatchTPU) -> Optional[Callable]:
+        @split_commit
+        def commit() -> Callable[[], None]:
+            out, order, count = self._jitted(batch.fields, batch.size)
+            self.stats.device_programs_run += 1
+            return self.finish_compacted(batch, out, order, count)
+
+        return commit
 
     def prewarm(self, caps) -> Optional[int]:
         """See ``MapTPUReplica.prewarm`` (``size`` traces as a weak
