@@ -246,10 +246,18 @@ def _abstract_signature(args) -> tuple:
         shape = getattr(leaf, "shape", None)
         dtype = getattr(leaf, "dtype", None)
         if shape is not None and dtype is not None:
-            parts.append((tuple(shape), str(dtype)))
+            # ``str(dtype)`` is numpy formatting the name anew, some 10 us
+            # a leaf, on every launch of every program
+            name = _DTYPE_NAMES.get(dtype)
+            if name is None:
+                name = _DTYPE_NAMES[dtype] = str(dtype)
+            parts.append((tuple(shape), name))
         else:
             parts.append(type(leaf).__name__)
     return tuple(parts)
+
+
+_DTYPE_NAMES: Dict[Any, str] = {}
 
 
 def instrumented_jit(fn, stats=None, label: str = "",

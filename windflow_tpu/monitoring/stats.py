@@ -72,6 +72,18 @@ class StatsRecord(StageCounters):
         # key capacity (each reallocates the forest and recompiles)
         "keys_admitted", "keys_reclaimed", "key_slots_live",
         "key_capacity_growths",
+        # the device interval join (tpu/join_tpu.py): rows that probed
+        # and rows archived, by side [A, B]; pairs delivered and the
+        # batches they left in; rows purged; live rows of both archives
+        # after the last step read back (a gauge) and the live rows of
+        # the archive a step probed, summed over the steps; doublings of
+        # an archive (each recompiles the step); rows that arrived behind
+        # their own side's purge line (they probe what is left and are
+        # not archived)
+        "join_probe_rows", "join_archived_rows", "join_pairs",
+        "join_output_batches", "join_purged_rows", "join_archive_rows",
+        "join_scanned_rows", "join_archive_growths", "join_late_probes",
+        "join_batches_held",
         "staging_pool_hits", "staging_pool_misses",
         "dispatch_stalls", "dispatch_depth_max",
         # finish halves the dispatch queue ran (a compacting commit's
@@ -214,6 +226,16 @@ class StatsRecord(StageCounters):
         self.keys_reclaimed = 0
         self.key_slots_live = 0
         self.key_capacity_growths = 0
+        self.join_probe_rows = [0, 0]
+        self.join_archived_rows = [0, 0]
+        self.join_pairs = 0
+        self.join_output_batches = 0
+        self.join_purged_rows = 0
+        self.join_archive_rows = 0
+        self.join_scanned_rows = 0
+        self.join_archive_growths = 0
+        self.join_late_probes = 0
+        self.join_batches_held = 0
         self.staging_pool_hits = 0  # recycled staging buffers (ArrayPool)
         self.staging_pool_misses = 0
         # device-ahead dispatch pipeline (runtime/dispatch.py); the split
@@ -556,6 +578,18 @@ class StatsRecord(StageCounters):
             "Keys_reclaimed": self.keys_reclaimed,
             "Key_slots_live": self.key_slots_live,
             "Key_capacity_growths": self.key_capacity_growths,
+            "Join_probe_rows_a": self.join_probe_rows[0],
+            "Join_probe_rows_b": self.join_probe_rows[1],
+            "Join_archived_rows_a": self.join_archived_rows[0],
+            "Join_archived_rows_b": self.join_archived_rows[1],
+            "Join_pairs": self.join_pairs,
+            "Join_output_batches": self.join_output_batches,
+            "Join_purged_rows": self.join_purged_rows,
+            "Join_archive_rows": self.join_archive_rows,
+            "Join_scanned_rows": self.join_scanned_rows,
+            "Join_archive_growths": self.join_archive_growths,
+            "Join_late_probes": self.join_late_probes,
+            "Join_batches_held": self.join_batches_held,
             "Fused_ops": self.fused_ops,
             "Staging_pool_hits": self.staging_pool_hits,
             "Staging_pool_misses": self.staging_pool_misses,

@@ -188,6 +188,9 @@ STAGES: Dict[str, StageDef] = {
     # the window operator's key turnover, inside its prep (or a dataless
     # fire): new keys given a slot, dead slots given back
     "keys": StageDef("wf", _DISPATCH, "Key_turnover_total_usec", None),
+    # the interval join's host half, inside its prep: the batch's event
+    # times as offsets, the purge lines, the bound on the archives
+    "join": StageDef("wf", _DISPATCH, "Join_host_total_usec", None),
     "queue": StageDef("wait", _DISPATCH, "Dispatch_queue_wait_total_usec",
                       None),
     "commit": StageDef("wf", _DISPATCH, "Dispatch_commit_total_usec", None,

@@ -70,6 +70,11 @@ def repartition_refusal(op) -> Optional[str]:
                 "chip; to change capacity, checkpoint and restore with a "
                 "different with_mesh(mesh_shape=...) (sharded restore "
                 "relayouts the key axis across the new factorization)")
+    if getattr(op, "is_device_join", False):
+        return ("Interval_Join_TPU keeps its archives as device arrays in "
+                "arrival order, rows of every key interleaved; there is no "
+                "per-key blob to re-bucket. Checkpoint and rebuild the "
+                "graph at the new parallelism (the sources replay)")
     if getattr(op, "exactly_once", False):
         return ("exactly-once sinks own per-replica transaction logs "
                 "(staged epoch segments / transactional producer ids); "

@@ -947,6 +947,9 @@ class PipeGraph:
                 "with_output_batch_size(n) on the producer")
         if c_tpu and not p_tpu:
             first.staged_input = True  # its batches arrive packed
+            sides = getattr(first, "staged_sides", None)
+            if sides is not None:   # a two-input operator: which input
+                sides[0 if producer in consumer.join_a_stages else 1] = True
         one_to_one = (routing is RoutingMode.FORWARD
                       and branch is None
                       and not (c_tpu and not p_tpu)

@@ -8,8 +8,8 @@ from __future__ import annotations
 
 from typing import Any, Callable, Optional
 
-from ..basic import WindFlowError
-from ..builders import _RoutableBuilder
+from ..basic import JoinMode, WindFlowError
+from ..builders import BasicBuilder, _RoutableBuilder
 from .ops_tpu import Filter_TPU, Map_TPU, Reduce_TPU
 from .schema import TupleSchema
 
@@ -294,3 +294,67 @@ class Ffat_Windows_TPU_Builder(_RoutableBuilder, _TPUBuilderMixin):
             self._slide_len, self._win_type, self._lateness, self._nwpb,
             self._name, self._parallelism, self._output_batch_size,
             self._schema, self._key_capacity))
+
+
+class Interval_Join_TPU_Builder(BasicBuilder):
+    """Sibling of ``Interval_Join_Builder`` (``wf/builders.hpp:1480-1538``)
+    for the device plane: the same options, the join function over
+    columns, the key a field name."""
+
+    _default_name = "interval_join_tpu"
+
+    def __init__(self, join_func: Callable) -> None:
+        super().__init__(join_func)
+        self._key_extractor = None
+        self._lower = self._upper = None
+        self._mode = JoinMode.KP
+        self._schemas = (None, None)
+
+    def with_key_by(self, key_field: str):
+        self._key_extractor = key_field
+        return self
+
+    def with_boundaries(self, lower_usec: int, upper_usec: int):
+        self._lower, self._upper = lower_usec, upper_usec
+        return self
+
+    def with_kp_mode(self):
+        self._mode = JoinMode.KP
+        return self
+
+    def with_dp_mode(self):
+        self._mode = JoinMode.DP
+        return self
+
+    def with_schemas(self, a, b):
+        """Declare both inputs' schemas (else each is taken from the
+        side's first batch): the archives are allocated and
+        ``PipeGraph.with_prewarm`` compiles both directions of the step
+        before the sources open."""
+        self._schemas = tuple(TupleSchema(s) if isinstance(s, dict) else s
+                              for s in (a, b))
+        return self
+
+    def build(self):
+        from .join_tpu import Interval_Join_TPU
+        if self._key_extractor is None:
+            raise WindFlowError("Interval_Join_TPU_Builder: withKeyBy "
+                                "mandatory")
+        if self._lower is None:
+            raise WindFlowError("Interval_Join_TPU_Builder: withBoundaries "
+                                "mandatory")
+        if self._mode is JoinMode.DP:
+            raise WindFlowError(
+                "Interval_Join_TPU_Builder: DP mode is not on the device "
+                "plane (every replica would archive a share of every key "
+                "and probe with every arrival, behind an ordering "
+                "collector); use with_kp_mode(), or Interval_Join_Builder "
+                "for DP")
+        if self._output_batch_size:
+            raise WindFlowError(
+                "Interval_Join_TPU_Builder: with_output_batch_size does "
+                "not apply (pairs leave in batches of the input's "
+                "capacity)")
+        return self._finish(Interval_Join_TPU(
+            self._func, self._key_extractor, self._lower, self._upper,
+            self._name, self._parallelism, self._schemas))

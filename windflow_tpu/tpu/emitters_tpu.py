@@ -27,7 +27,7 @@ import datetime as _dt
 import os
 import time
 from collections import deque
-from typing import Any, Callable, List, Optional, Tuple
+from typing import Any, Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -36,7 +36,7 @@ from ..message import Batch
 from ..monitoring.tracing import StageCounters, next_batch_id, stamp_ns
 from ..runtime.emitters import BasicEmitter
 from .batch import (BatchTPU, StagingBuffers, async_host_copy,
-                    bucket_capacity, gather_columns)
+                    bucket_capacity, gather_columns_each)
 from .schema import TupleSchema
 
 
@@ -889,28 +889,39 @@ def _int_keys_hashable_as_identity(kcol: np.ndarray, n: int) -> bool:
     return False
 
 
-def gather_sub_batch(batch: BatchTPU, idx: np.ndarray,
-                     host_keys=None) -> BatchTPU:
-    """Gather ``idx`` rows of a device batch into a new (smaller) device
-    batch without leaving HBM: one XLA gather per column (per dtype group
-    of a packed batch) from a host-computed index vector. Shared by the
-    keyed re-shard and the device-plane splitting emitter."""
-    import jax
-
-    cap = bucket_capacity(idx.size)
-    gather = np.zeros(cap, dtype=np.int32)
-    gather[:idx.size] = idx
-    gidx = jax.device_put(gather)
-    sub_fields = gather_columns(batch.fields, gidx)
-    ts2 = batch.ts_host[gather]
-    if host_keys is None and batch.host_keys is not None:
-        hk = batch.host_keys
-        host_keys = (hk[idx] if isinstance(hk, np.ndarray)
+def gather_sub_batches(batch: BatchTPU, idxs: Sequence[np.ndarray],
+                       host_keys: Optional[Sequence[Any]] = None
+                       ) -> List[BatchTPU]:
+    """Gather each of the row sets ``idxs`` of a device batch into a new
+    (smaller) device batch without leaving HBM, from host-computed index
+    vectors: ONE program for them all over a dict of columns, a gather a
+    dtype group over a packed batch (``gather_columns_each``).
+    ``host_keys[i]`` are the keys of ``idxs[i]``'s rows where the caller
+    has them already. Shared by the keyed re-shard (a call a destination)
+    and the device-plane splitting emitter (a call a batch: its program is
+    compiled for the branches' capacity buckets together, which a split
+    of steady proportions keeps to one or two)."""
+    if not idxs:
+        return []
+    gathers = []
+    for idx in idxs:
+        gather = np.zeros(bucket_capacity(idx.size), dtype=np.int32)
+        gather[:idx.size] = idx
+        gathers.append(gather)
+    subs = []
+    for i, sub_fields in enumerate(gather_columns_each(batch.fields,
+                                                       gathers)):
+        idx = idxs[i]
+        keys2 = None if host_keys is None else host_keys[i]
+        if keys2 is None and batch.host_keys is not None:
+            hk = batch.host_keys
+            keys2 = (hk[idx] if isinstance(hk, np.ndarray)
                      else [hk[j] for j in idx])
-    keys2 = host_keys
-    sub = BatchTPU(sub_fields, ts2, idx.size, batch.schema, batch.wm, keys2)
-    sub.stream_tag = batch.stream_tag
-    return sub.caused_by(batch)  # one of several made from ``batch``
+        sub = BatchTPU(sub_fields, batch.ts_host[gathers[i]], idx.size,
+                       batch.schema, batch.wm, keys2)
+        sub.stream_tag = batch.stream_tag
+        subs.append(sub.caused_by(batch))  # one of several from ``batch``
+    return subs
 
 
 class TPUKeyByEmitter(BasicEmitter, _D2HPipeline):
@@ -1001,10 +1012,13 @@ class TPUKeyByEmitter(BasicEmitter, _D2HPipeline):
             idx = np.nonzero(dests == d)[0]
             if idx.size == 0:
                 continue
-            sub = gather_sub_batch(
-                batch, idx,
-                host_keys[idx] if isinstance(host_keys, np.ndarray)
-                else [host_keys[j] for j in idx])
+            # a program a destination: how the keys fall varies by the
+            # batch, and one program for all would compile anew for every
+            # combination of the destinations' capacity buckets
+            sub, = gather_sub_batches(
+                batch, [idx],
+                [host_keys[idx] if isinstance(host_keys, np.ndarray)
+                 else [host_keys[j] for j in idx]])
             sub.key_origin = None    # keyed for the consumer, by its key
             sub.id = self._next_ids[d]
             self._next_ids[d] += 1
@@ -1053,10 +1067,10 @@ class TPUSplittingEmitter(BasicEmitter, _D2HPipeline):
             if self.stats is not None:
                 self.stats.device_bytes_d2h += int(col.nbytes)
             if col.size and (col.min() < 0 or col.max() >= n_branches):
-                from ..basic import WindFlowError
+                op = self.stats.op_name if self.stats is not None else "?"
                 raise WindFlowError(
-                    f"split field {logic!r} holds branch index "
-                    f"{int(col.min())}..{int(col.max())} outside "
+                    f"split after {op!r}: field {logic!r} holds branch "
+                    f"index {int(col.min())}..{int(col.max())} outside "
                     f"[0, {n_branches})")
             return [np.nonzero(col == b)[0] for b in range(n_branches)]
         sel: List[list] = [[] for _ in range(n_branches)]
@@ -1076,15 +1090,23 @@ class TPUSplittingEmitter(BasicEmitter, _D2HPipeline):
 
     def _pipe_process(self, batch: BatchTPU) -> None:
         per_branch = self._branch_rows(batch)
+        part = [b for b, idx in enumerate(per_branch)
+                if 0 < idx.size < batch.size]
+        gathered = dict(zip(part, gather_sub_batches(
+            batch, [per_branch[b] for b in part])))
         for b, idx in enumerate(per_branch):
             if idx.size == 0:
+                # nothing of this batch for the branch, but its watermark:
+                # a stage that aligns on this branch (a join after a
+                # merge) must not wait for the next generated punctuation
+                self.inner[b].propagate_punctuation(batch.wm)
                 continue
             if idx.size == batch.size:
                 # every row selected this branch: no gather needed (device
                 # arrays are immutable; copy only the metadata wrapper)
                 sub = batch.copy_for_dest()
             else:
-                sub = gather_sub_batch(batch, idx)
+                sub = gathered[b]
             self.inner[b].emit_device_batch(sub)
 
     def emit_device_batch(self, batch: BatchTPU) -> None:

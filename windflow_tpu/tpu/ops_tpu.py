@@ -51,16 +51,21 @@ _PROG_SMAP, _PROG_SFILTER = "smap", "sfilter"
 SCOPE_GRID_SCAN = "grid_scan"
 
 
-def prewarm_zero_fields(op: "TPUOperatorBase", cap: int):
+def prewarm_zero_fields(op: "TPUOperatorBase", cap: int,
+                        side: Optional[int] = None):
     """A zero-valued batch's ``fields`` of ``op``'s declared schema at one
     bucket capacity — the dummy input the compile-stability pre-warm
     feeds a program so its signature traces before any real batch
     arrives. In the form ``op``'s stream will present: fed from the host,
     the staging emitters' own transfer (``StagingBuffers.put``: one packed
     buffer per dtype group); fed by a device operator, a dict of
-    columns."""
-    fields = StagingBuffers(op.schema, cap).put(0)
-    return fields if op.staged_input else dict(fields)
+    columns. ``side`` names the input of a two-input operator, which
+    declares a schema an input (``schemas``) and is told which of them
+    arrive staged (``staged_sides``)."""
+    schema, staged = ((op.schema, op.staged_input) if side is None
+                      else (op.schemas[side], op.staged_sides[side]))
+    fields = StagingBuffers(schema, cap).put(0)
+    return fields if staged else dict(fields)
 
 
 def _compact_order(keep):
@@ -492,6 +497,12 @@ class TPUReplicaBase(BasicReplica):
         nb.copy_trace_from(batch)
         if new_size > 0:
             self._emit_batch(nb)
+        else:
+            # a batch that keeps nothing still carries its watermark on:
+            # a two-input stage downstream aligns on BOTH inputs, and a
+            # side silent for a batch would hold it back until the next
+            # generated punctuation (100 ms of wall time)
+            self.emitter.propagate_punctuation(batch.wm)
 
     def batch_keys_np(self, batch: BatchTPU):
         """``(keys, keys_arr)`` with at most ONE conversion — the
