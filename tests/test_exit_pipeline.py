@@ -193,11 +193,11 @@ def test_graft_entry_reexecutes():
     fn, args = g.entry()
     # the served step itself (FfatTPUReplica._make_step), handed what
     # _commit_step hands it: the batch's columns, the packed composite
-    # the program sorts, the forest, the fire plan, the key table
+    # the program sorts, the forest, the fire plan (its keys among it)
     import inspect
     assert list(inspect.signature(fn._wrapped_jit).parameters) == [
-        "fields", "comp", "trees", "tvalid", "fire_plan", "ktable"]
-    fields, comp, trees, tvalid, fire_plan, ktable = args
+        "fields", "comp", "trees", "tvalid", "fire_plan"]
+    fields, comp, trees, tvalid, fire_plan = args
     rep = g._ffat_replica()
     assert comp.shape == fields["key"].shape
     assert comp.dtype == rep._comp_dtype()[1]

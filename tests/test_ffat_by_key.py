@@ -1,12 +1,13 @@
 """Count-based windows by the KEY (``tpu/ffat_tpu.py``): the host hands
 a step the rows' slots and two words a slot (``_prep_by_key``), and a
-fire program chunk rows (``cb_pack_views``); the step numbers its own
+fire program chunk rows (``plan_views``); the step numbers its own
 rows (``cb_number_rows``) and the program expands its own lanes
-(``cb_plan_lanes``). Held here against what the parent commit built on
-the host, by row and by lane, kept below as the plain reference; a fired
-batch's keys leave by chunk (``ChunkedKeys``) and reach a keyed consumer
-as they did. The replica is driven directly on the CPU backend, as
-``test_ffat_sliding_fire.py`` drives it."""
+(``plan_lanes``; time-based plans too since PR 39, held in
+``test_ffat_time_chunks.py``). Held here against what the parent commit
+built on the host, by row and by lane, kept below as the plain
+reference; a fired batch's keys leave by chunk (``ChunkedKeys``) and
+reach a keyed consumer as they did. The replica is driven directly on
+the CPU backend, as ``test_ffat_sliding_fire.py`` drives it."""
 
 import inspect
 
@@ -18,9 +19,7 @@ from test_ffat_sliding_fire import (SCHEMA, Rows, batch, comb_sd, lift_sd,
 from windflow_tpu.basic import WinType
 from windflow_tpu.tpu.batch import BatchTPU, ChunkedKeys
 from windflow_tpu.tpu.ffat_tpu import (Ffat_Windows_TPU, cb_number_rows,
-                                       cb_pack_len, cb_pack_views,
-                                       cb_plan_lanes, fire_pack_len,
-                                       fire_pack_views)
+                                       plan_lanes, plan_len, plan_views)
 from windflow_tpu.tpu.keymap import group_positions
 
 
@@ -294,11 +293,12 @@ def test_rows_a_fused_prefix_filter_drops_take_no_leaf_and_no_count():
 # ---------------------------------------------------------------------------
 def expanded(rep, chunks, W):
     import jax
-    pack, n_groups = rep._pack_fire_arrays(chunks, W)
+    pack, n_groups = rep._pack_fire_arrays(
+        chunks, W, rep._chunk_keys(chunks[0]), rep._ranges_of(chunks))
     assert n_groups == 0 and pack.dtype == np.int32
-    assert pack.size == cb_pack_len(W, rep.K_cap) == rep._plan_len(W)
-    out = jax.jit(cb_plan_lanes, static_argnums=(1, 2, 3, 4, 5))(
-        pack, W, rep.K_cap, rep.F, rep.win_units, rep.slide_units)
+    assert pack.size == plan_len(W, rep.K_cap, False, 1) == rep._plan_len(W)
+    out = jax.jit(plan_lanes, static_argnums=range(1, 8))(
+        pack, W, rep.K_cap, rep.F, rep.win_units, rep.slide_units, False, 1)
     return [np.asarray(a) for a in out]
 
 
@@ -347,7 +347,7 @@ def test_a_plan_of_nothing_and_a_plan_of_one_full_program():
     # one slot, 16 windows: every lane live, chunk rows beyond it blank
     one = (np.array([2]), np.array([37]), np.array([16]), np.array([37]),
            np.array([37 + 23]))
-    slot, start, ln, wid, mask, rnd, _e = expanded(rep, one, 16)
+    slot, start, ln, wid, mask, rnd, _e, _key = expanded(rep, one, 16)
     assert mask.all() and (slot == 2).all() and (ln == 8).all()
     assert (rnd == np.arange(16)).all() and (wid == 37 + rnd).all()
     assert (start == (37 + rnd) % rep.F).all()
@@ -421,7 +421,11 @@ def test_emit_compacted_hands_on_the_kept_rows_keys(keys):
 # ---------------------------------------------------------------------------
 # who else runs the changed code: a time-based operator takes what it took
 # ---------------------------------------------------------------------------
-def test_a_time_based_operator_keeps_its_arguments_and_its_plan():
+def test_both_window_types_take_the_same_arguments_and_plan_by_chunk():
+    """Since PR 39 a time-based program takes the count-based layout:
+    the same arguments, a chunk row a firing slot, a program a width;
+    its head is the group table where a count-based one's is the
+    ``keyrows``."""
     ops = {}
     for wt in (WinType.TB, WinType.CB):
         op = Ffat_Windows_TPU(
@@ -434,30 +438,40 @@ def test_a_time_based_operator_keeps_its_arguments_and_its_plan():
     for rep in ops.values():
         step = rep._make_step(16, W=64)
         assert list(inspect.signature(step._wrapped_jit).parameters) == [
-            "fields", "comp", "trees", "tvalid", "fire_plan", "ktable"]
-    # time-based: the composite and the plan by lane, one jitted function
-    # whatever the width
-    assert tb._comp_dtype() == (tb.K_cap * tb.F, np.int16)
-    assert tb._plan_len(64) == fire_pack_len(64, tb.slide_units) \
-        == (6 + 3 * tb.slide_units) * 64 + 66
-    assert tb._wkey(("step", 16), 64) == ("step", 16)
+            "fields", "comp", "trees", "tvalid", "fire_plan"]
+        assert rep._key_words() == 1
     chunks = (np.arange(2), np.full(2, 8), np.full(2, 3), np.full(2, 4),
               np.full(2, 20))
-    pack, _n = tb._pack_fire_arrays(chunks, 64)
-    fire, _g, evict = fire_pack_views(pack, tb.slide_units)
-    assert pack.size == tb._plan_len(64) and fire[4].sum() == 6
-    assert evict[2].sum() == 2 * 3 * tb.slide_units
-    # count-based: the slots and a few words a key, a program a width
-    assert cb._comp_dtype() == (cb.K_cap, np.int16)
-    assert cb._plan_len(64) == cb_pack_len(64, cb.K_cap) \
-        == 1 + 2 * cb.K_cap + 5 * cb.K_cap
-    assert cb_pack_len(2, cb.K_cap) == 1 + 2 * cb.K_cap + 5 * 2
-    assert cb._wkey(("step", 16), 64) == ("step", 16, 64)
-    pack, _n = cb._pack_fire_arrays(chunks, 64)
-    keyrows, rows, total = cb_pack_views(pack, cb.K_cap)
-    assert total[0] == 6 and not keyrows.any()
+    for k in (7, 9):
+        assert tb._keymap.slot(k) == cb._keymap.slot(k)
+    # time-based: the composite, the group table and the chunk rows
+    assert tb._comp_dtype() == (tb.K_cap * tb.F, np.int16)
+    assert tb._plan_len(64) == plan_len(64, tb.K_cap, True, 1) \
+        == 1 + 66 + 6 * tb.K_cap
+    pack, n = tb._pack_fire_arrays(chunks, 64, tb._chunk_keys(chunks[0]),
+                                   tb._ranges_of(chunks))
+    groups, rows, total = plan_views(pack, tb.K_cap, True, 1)
+    assert pack.size == tb._plan_len(64) and total[0] == 6
+    assert n == 3 == groups[32, 0]          # three rounds, three ranges
     assert rows[:, :2].tolist() == [[0, 1], [8, 8], [3, 3], [4, 4],
-                                    [13, 13]]
+                                    [13, 13], [7, 9]]
+    # count-based: the slots and a few words a key, no ranges
+    assert cb._comp_dtype() == (cb.K_cap, np.int16)
+    assert cb._plan_len(64) == plan_len(64, cb.K_cap, False, 1) \
+        == 1 + 2 * cb.K_cap + 6 * cb.K_cap
+    assert plan_len(2, cb.K_cap, False, 1) == 1 + 2 * cb.K_cap + 6 * 2
+    pack, n = cb._pack_fire_arrays(chunks, 64, cb._chunk_keys(chunks[0]),
+                                   cb._ranges_of(chunks))
+    keyrows, rows, total = plan_views(pack, cb.K_cap, False, 1)
+    assert n == 0 and total[0] == 6 and not keyrows.any()
+    assert rows[:, :2].tolist() == [[0, 1], [8, 8], [3, 3], [4, 4],
+                                    [13, 13], [7, 9]]
+    # one jitted function a width, for both
+    for rep in ops.values():
+        rep._ensure_forest(batch([0], [1.0]).fields)
+        rep._full_step(rep._step_keys(16)[0], 16, 64)
+        rep._fire_step(64)
+        assert {k[-1] for k in rep._prog_cache} == {64}
 
 
 # ---------------------------------------------------------------------------
@@ -467,7 +481,7 @@ def test_the_counters_say_how_often_the_mechanism_engages():
     """``Fire_plan_rows / Fire_programs`` is at most the keys and
     ``Prep_by_key_batches`` every batch on a count-based operator; on a
     time-based one no batch is prepared by key and the plan's rows are
-    its lanes."""
+    its chunks too (its lanes before PR 39)."""
     import test_ffat_grouped_fire as tg
 
     rep = make_replica(win=8, slide=1, budget=64, keys=4)
@@ -485,4 +499,4 @@ def test_the_counters_say_how_often_the_mechanism_engages():
     timed.flush_on_termination()
     d = timed.stats.to_dict()
     assert d["Fire_programs"] > 0 and d["Prep_by_key_batches"] == 0
-    assert d["Fire_plan_rows"] == d["Windows_fired"]
+    assert d["Fire_plan_rows"] <= 3 * d["Fire_programs"] < d["Windows_fired"]

@@ -2,9 +2,9 @@
 walk per distinct ring range over every key slot at once, against the
 lane walk (one walk a fired window). The replica is driven directly, on
 the CPU backend; the lane walk is forced from the test's side by blanking
-the group table in the plan that ``_pack_plan`` hands the programs,
-which is exactly what the planner does for a program with more than
-``G_CAP`` distinct ranges where a budget was given. From ``(l)`` on: an
+the group table in the plan that ``_pack_fire_arrays`` hands the
+programs, which is exactly what the planner does for a program with more
+than ``G_CAP`` distinct ranges where a budget was given. From ``(l)`` on: an
 operator with no budget given (time-based windows) sizes the width of
 its fire programs by its plans (``_programs_by_plan``).
 
@@ -16,8 +16,7 @@ import pytest
 
 from windflow_tpu.basic import WinType
 from windflow_tpu.tpu.batch import BatchTPU
-from windflow_tpu.tpu.ffat_tpu import (G_CAP, Ffat_Windows_TPU,
-                                       fire_pack_views)
+from windflow_tpu.tpu.ffat_tpu import G_CAP, Ffat_Windows_TPU
 from windflow_tpu.tpu.schema import TupleSchema
 
 PANE = 1000
@@ -92,16 +91,9 @@ def make_replica(lane_only=False, win=4, slide=1, budget=8, keys=4,
     rep = op.replicas[0]
     rep.emitter = Rows()
     if lane_only:
-        pack = rep._pack_plan
-
-        def by_lane(chunks, W, lanes, ranges):
-            plan, _n_groups = pack(chunks, W, lanes, ranges)
-            fire, groups, _evict = fire_pack_views(plan, rep.slide_units)
-            fire[5] = 0
-            groups[:] = 0
-            return plan, 0
-
-        rep._pack_plan = by_lane
+        pack = rep._pack_fire_arrays
+        rep._pack_fire_arrays = lambda chunks, W, keys, pairs: pack(
+            chunks, W, keys, None)
     return rep
 
 

@@ -334,9 +334,8 @@ def test_a_program_holds_one_query(monkeypatch):
         rep = make_replica(win=8, slide=1, budget=64)
         rep._ensure_forest(batch([0], [1.0]).fields)
         fire = rep._query_fns()
-        pack = np.zeros(ft.fire_pack_len(64, 1), np.int32)
-        return jax.jit(fire).lower(rep.trees, rep.tvalid, pack,
-                                   np.zeros(rep.K_cap, np.int32)).as_text()
+        pack = np.zeros(rep._plan_len(64), np.int32)
+        return jax.jit(fire).lower(rep.trees, rep.tvalid, pack).as_text()
 
     scan, lane = text(1 << 40), text(0)
     assert "while" not in scan and "while" in lane

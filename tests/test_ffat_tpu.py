@@ -454,7 +454,7 @@ def test_ffat_tpu_programs_chosen_by_nothing_but_shapes():
     op.build_replicas()
     rep = op.replicas[0]
     assert rep._step_keys(64) == (
-        ("step", 64, rep.K_cap, rep.F, True, "int32", None),
+        ("step", 64, rep.K_cap, rep.F, "int32", None),
         ("ingest", 64, rep.K_cap, rep.F, None))
 
 

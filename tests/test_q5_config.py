@@ -323,7 +323,9 @@ def test_the_files_state_the_deployment():
     names = {m["name"] for m, _ in cell.metrics("per_layer")}
     mine = {n for n in names if n.endswith(".q5")}
     # nine `.sat` metrics with no `workloads` list, PR 36's eleven, and
-    # PR 37's `readback_deferred_share.sat` (`bids` compacts)
-    assert len(mine) == 13 and len(names - mine) == 21
+    # PR 37's `readback_deferred_share.sat` (`bids` compacts); PR 39's
+    # `fire_one_round_share.q5`
+    assert len(mine) == 14 and len(names - mine) == 21
+    assert "fire_one_round_share.q5" in mine
     assert "readback_deferred_share.sat" in names
     assert all(n.endswith(".sat") for n in names - mine)

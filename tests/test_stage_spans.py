@@ -394,43 +394,50 @@ def test_window_programs_keep_the_names_the_roofline_metric_reads(served):
 
 def test_time_based_programs_take_the_pack_and_the_arguments_they_took(
         served):
-    """PR 33 gave count-based windows a third query and the pack's row 5
-    a second reading (their lanes' rounds); a time-based operator's plan
-    is the words it was (``(6 + 3 slide) W + 2 (G_CAP + 1)``, row 5 the
-    lane's group), its programs take the same arguments, and the rule
-    that chooses the scan is never asked about it."""
+    """PR 39 gave a time-based plan the count-based layout: per firing
+    key slot, never per lane. A time-based operator's plan is ``1 + 2
+    (G_CAP + 1) + (5 + key words) min(K_cap, W)`` words, its head the
+    group table; its programs take the batch's columns, the composite,
+    the forest and the plan, and no key table; the rule that chooses
+    the scan is never asked about it."""
     import inspect
 
     from windflow_tpu.tpu import ffat_tpu
 
     replica = _replica(served["graph"], "win")
     assert replica.slide_units == 1 and ffat_tpu.G_CAP == 32
-    for W in (8, 64, 32768):
-        assert ffat_tpu.fire_pack_len(W, 1) == 9 * W + 66
-        assert ffat_tpu.fire_pack_len(W, 3) == 15 * W + 66
-        fire, groups, evict = ffat_tpu.fire_pack_views(
-            np.zeros(ffat_tpu.fire_pack_len(W, 3), np.int32), 3)
-        assert (fire.shape, groups.shape, evict.shape) == (
-            (6, W), (33, 2), (3, 3 * W))
+    for W, K_cap, kw in ((8, 16, 1), (64, 16, 2), (32768, 4096, 1)):
+        n = ffat_tpu.plan_len(W, K_cap, True, kw)
+        assert n == 1 + 66 + (5 + kw) * min(K_cap, W)
+        groups, chunks, total = ffat_tpu.plan_views(
+            np.zeros(n, np.int32), K_cap, True, kw)
+        assert (groups.shape, chunks.shape, total.shape) == (
+            (33, 2), (5 + kw, min(K_cap, W)), (1,))
     by_name = {p._wrapped_jit.__name__: p._wrapped_jit
                for p in replica._prog_cache.values()
                if hasattr(p, "_wrapped_jit")}
     args = {n: list(inspect.signature(f).parameters)
             for n, f in by_name.items()}
     assert args == {
-        "step": ["fields", "comp", "trees", "tvalid", "fire_plan", "ktable"],
-        "fire": ["trees", "tvalid", "fire_plan", "ktable"],
+        "step": ["fields", "comp", "trees", "tvalid", "fire_plan"],
+        "fire": ["trees", "tvalid", "fire_plan"],
         "rebuild": ["trees", "tvalid"]}
     # a plan of this operator: two keys' next windows, one ring range
-    for k in range(2):
-        replica._keymap.slot(1000 + k)
-    chunks = (np.arange(2), np.full(2, 40), np.ones(2, np.int64),
+    slots = np.array([replica._keymap.slot(1000 + k) for k in range(2)])
+    chunks = (slots, np.full(2, 40), np.ones(2, np.int64),
               np.full(2, 10), np.full(2, 43))
-    pack, n_groups = replica._pack_fire_arrays(chunks, 8)
-    fire, groups, _ = ffat_tpu.fire_pack_views(pack, 1)
-    assert pack.dtype == np.int32 and pack.size == 9 * 8 + 66
-    assert n_groups == 1 == groups[32, 0]
-    assert fire[5].tolist() == [0] * 8 and fire[4].tolist() == [1, 1] + [0] * 6
+    keys = replica._chunk_keys(chunks[0])
+    pack, n_groups = replica._pack_fire_arrays(
+        chunks, 8, keys, replica._ranges_of(chunks))
+    groups, rows, total = ffat_tpu.plan_views(pack, replica.K_cap, True, 1)
+    assert replica._key_words() == 1
+    assert pack.dtype == np.int32 and pack.size == replica._plan_len(8) \
+        == 1 + 66 + 6 * min(replica.K_cap, 8)
+    assert n_groups == 1 == groups[32, 0] and total[0] == 2
+    assert rows[:, :2].T.tolist() == [
+        [s, 40 % replica.F, 1, 10, 4, 1000 + k]
+        for k, s in enumerate(slots.tolist())]
+    assert not rows[:, 2:].any()
 
 
 def test_fused_chain_program_carries_its_operators_names(served):

@@ -61,11 +61,14 @@ class StatsRecord(StageCounters):
         "fire_grouped_programs", "fire_groups", "fire_range_cuts",
         "fire_sliding_programs",
         # the rows of the plans the HOST built for those programs (a
-        # count-based plan's chunks, which the program expands into its
-        # lanes; a time-based plan's lanes), and the batches whose prep
-        # built no batch-sized plane but the rows' slots (count-based
-        # windows: the step numbers its own rows)
-        "fire_plan_rows", "prep_by_key_batches",
+        # row a firing key slot, its chunk of consecutive windows, which
+        # the program expands into its lanes; both window types since
+        # PR 39), the programs whose plan took one window of every chunk
+        # (a time-based plan by the plan's width then reckons its ranges
+        # from the chunks themselves, no class detection), and the
+        # batches whose prep built no batch-sized plane but the rows'
+        # slots (count-based windows: the step numbers its own rows)
+        "fire_plan_rows", "fire_one_round_plans", "prep_by_key_batches",
         # key turnover of a time-based window operator: keys given a
         # slot, slots given back (a key none of whose windows holds an
         # event any more), slots in use now (a gauge), doublings of the
@@ -221,6 +224,7 @@ class StatsRecord(StageCounters):
         self.fire_range_cuts = 0
         self.fire_sliding_programs = 0
         self.fire_plan_rows = 0
+        self.fire_one_round_plans = 0
         self.prep_by_key_batches = 0
         self.keys_admitted = 0
         self.keys_reclaimed = 0
@@ -573,6 +577,7 @@ class StatsRecord(StageCounters):
             "Fire_range_cuts": self.fire_range_cuts,
             "Fire_sliding_programs": self.fire_sliding_programs,
             "Fire_plan_rows": self.fire_plan_rows,
+            "Fire_one_round_plans": self.fire_one_round_plans,
             "Prep_by_key_batches": self.prep_by_key_batches,
             "Keys_admitted": self.keys_admitted,
             "Keys_reclaimed": self.keys_reclaimed,

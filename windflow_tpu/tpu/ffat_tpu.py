@@ -11,17 +11,18 @@ device ring and firing watermark-complete windows through a per-key FlatFAT
 TPU-first redesign:
 - the control plane runs on HOST METADATA ONLY: keys and timestamps are
   already host-side on ``BatchTPU``, so per-key pane bookkeeping,
-  window-fire decisions and eviction lists are numpy — no D2H of data at
-  all (the reference pays a D2H of its unique arrays every batch,
+  window-fire decisions and eviction ranges are numpy — no D2H of data
+  at all (the reference pays a D2H of its unique arrays every batch,
   ``ffat_replica_gpu.hpp:945-988``). Segmentation (sort order + run
   detection) happens IN-PROGRAM, on one packed composite column the
-  host ships per batch, so it overlaps the host control plane. For
-  COUNT-BASED windows the host's half goes by the key: what it knows of
-  a batch is per key slot (a slot's rows are numbered from its count in
-  arrival order, its fired windows are consecutive), so it ships the
-  rows' slots and a few words a slot (``cb_pack_views``), and the
-  program numbers its own rows in that sort (``cb_number_rows``) and
-  expands its own fire lanes (``cb_plan_lanes``);
+  host ships per batch, so it overlaps the host control plane. A fire
+  plan goes by the key slot: a slot's fired windows are consecutive, so
+  the host ships a few words a firing slot, its key among them
+  (``plan_views``), and the program expands its own fire lanes
+  (``plan_lanes``). For COUNT-BASED windows the host's half of ingest
+  goes by the key too (a slot's rows are numbered from its count in
+  arrival order): it ships the rows' slots and two words a slot, and
+  the program numbers its own rows in that sort (``cb_number_rows``);
 - the data plane is ONE jitted XLA program per batch:
     lift(columns) -> sort of the packed (slot, leaf) composite ->
     gather(sort order) -> segmented associative scan with
@@ -145,77 +146,69 @@ def fire_slides(W: int, K_cap: int, F: int) -> bool:
     return W * SLIDE_X >= K_cap * F
 
 
-def fire_pack_len(W: int, slide_units: int) -> int:
+def plan_len(W: int, K_cap: int, timed: bool, key_words: int) -> int:
     """Words of the ONE int32 buffer that carries a program's fire plan
-    (see ``fire_pack_views``) for a budget of ``W`` windows."""
-    return (6 + 3 * slide_units) * W + 2 * (G_CAP + 1)
+    (see ``plan_views``) at a width of ``W`` lanes."""
+    head = 2 * (G_CAP + 1) if timed else 2 * K_cap
+    return 1 + head + (5 + key_words) * min(K_cap, W)
 
 
-def fire_pack_views(pack, slide_units: int):
-    """``(fire, groups, evict)`` views of a program's flat fire plan, on
-    the host (numpy, to fill it) and inside the program (static slices):
-    ``fire`` (6, W) rows slot, start, len, wid, mask, group (of a
-    count-based plan, which has no groups: the lane's round, its window's
-    place in its slot's chunk); ``groups`` (G_CAP + 1, 2) the distinct
-    ``(start_phys, length)`` pairs of the lanes and, in row ``G_CAP``,
-    their count; ``evict`` (3, W * slide_units) rows slot, leaf, mask.
-    One buffer, so one transfer a program: a launch pays for every host
-    argument it is handed."""
-    n_g = 2 * (G_CAP + 1)
-    W = (pack.shape[0] - n_g) // (6 + 3 * slide_units)
-    return (pack[:6 * W].reshape(6, W),
-            pack[6 * W:6 * W + n_g].reshape(G_CAP + 1, 2),
-            pack[6 * W + n_g:].reshape(3, W * slide_units))
+def plan_views(pack, K_cap: int, timed: bool, key_words: int):
+    """``(head, chunks, total)`` views of a program's flat fire plan, on
+    the host (numpy, to fill it) and inside the program (static
+    slices). Everything in it is per KEY slot; what is per lane of the
+    fire block the program derives (``plan_lanes``), for both window
+    types:
 
-
-def cb_pack_len(W: int, K_cap: int) -> int:
-    """Words of the ONE int32 buffer a COUNT-BASED program takes from the
-    host (see ``cb_pack_views``) at a width of ``W`` lanes."""
-    return 2 * K_cap + 5 * min(K_cap, W) + 1
-
-
-def cb_pack_views(pack, K_cap: int):
-    """``(keyrows, chunks, total)`` views of a count-based program's flat
-    buffer, on the host (numpy, to fill it) and inside the program
-    (static slices). Everything in it is per KEY; what is per row of the
-    batch or per lane of the fire block the program derives:
-
-    - ``keyrows`` (2, K_cap), read by the step's ingest: ``base``, a
-      slot's arrival count before the batch (mod ``F``: the ring place
-      of its next leaf), and ``skip``, how many of its first arrivals
-      in the batch lie behind its ``next_fire`` and are dropped (gap
-      windows, a re-registered key). A fire-only program leaves them 0;
-    - ``chunks`` (5, C) rows slot, start0 (mod ``F``), k, wid0, span: a
-      slot's ``k`` consecutive windows from ring place ``start0`` and
-      window id ``wid0``, over ``span`` leaves of data from ``start0``
-      on (``max_leaf + 1 - start0``). A program holds one chunk a slot
-      and a lane a window, so ``C = min(K_cap, W)``; rows past the
-      plan's chunks are 0 (``k`` 0: no lane);
+    - ``head``: a TIME-based plan's group table (G_CAP + 1, 2), the
+      distinct ring ranges ``(start_phys, length)`` of its lanes in
+      ascending order and, in row ``G_CAP``, their count (0: the
+      program walks by lane); a COUNT-based plan's ``keyrows`` (2,
+      K_cap), read by the step's ingest: ``base``, a slot's arrival
+      count before the batch (mod ``F``: the ring place of its next
+      leaf), and ``skip``, how many of its first arrivals in the batch
+      lie behind its ``next_fire`` and are dropped (gap windows, a
+      re-registered key). A fire-only program leaves them 0;
+    - ``chunks`` (5 + key_words, C) rows slot, start0 (mod ``F``), k,
+      wid0, span, then the chunk's key in ``key_words`` int32 words,
+      low word first (``join_key_words``): a slot's ``k`` consecutive
+      windows from ring place ``start0`` and window id ``wid0``, over
+      ``span`` leaves of data from ``start0`` on (``max_leaf + 1 -
+      start0``). A program holds one chunk a slot and a lane a window,
+      so ``C = min(K_cap, W)``; rows past the plan's chunks are 0 (``k``
+      0: no lane);
     - ``total`` (1,), the buffer's first word: the plan's windows, the
       sum of ``k``.
 
-    Lane ``i`` of chunk ``c``, round ``r = i - (windows of the chunks
-    before c)``: start ``start0 + r * slide``, length ``min(win, span -
-    r * slide)``, window id ``wid0 + r``; it evicts the ``slide``
-    leaves from its start that lie inside ``span``."""
-    n_k = 1 + 2 * K_cap
-    C = (pack.shape[0] - n_k) // 5
-    return (pack[1:n_k].reshape(2, K_cap),
-            pack[n_k:n_k + 5 * C].reshape(5, C), pack[:1])
+    One buffer, so one transfer a program: a launch pays for every host
+    argument it is handed."""
+    n_h = 2 * (G_CAP + 1) if timed else 2 * K_cap
+    rows = 5 + key_words
+    C = (pack.shape[0] - 1 - n_h) // rows
+    head = pack[1:1 + n_h].reshape((G_CAP + 1, 2) if timed else (2, K_cap))
+    return head, pack[1 + n_h:1 + n_h + rows * C].reshape(rows, C), pack[:1]
 
 
-def cb_plan_lanes(fire_plan, W: int, K_cap: int, F: int, win_units: int,
-                  slide_units: int):
-    """In a program: the lanes of a count-based plan, expanded from its
-    chunk rows (``cb_pack_views``) at the static width ``W``: ``(slots,
-    starts, lens, wids, mask, rounds, eflat)``, a lane each but ``eflat``,
-    the flat forest indices of the ``W * slide_units`` leaves evicted
-    (out of bounds where there is none). A lane finds its chunk through
-    a mark at each chunk's first lane and one cumulative sum. Masked
-    lanes read 0 in every row, as in a plan the host lays out by lane."""
+def plan_lanes(fire_plan, W: int, K_cap: int, F: int, win_units: int,
+               slide_units: int, timed: bool, key_words: int):
+    """In a program: the lanes of a plan, expanded from its chunk rows
+    (``plan_views``) at the static width ``W``: ``(slots, starts, lens,
+    wids, mask, group, eflat, keys)``, a lane each but ``eflat``, the
+    flat forest indices of the ``W * slide_units`` leaves evicted (out
+    of bounds where there is none), and ``keys``, the ``(key_words, W)``
+    words of each lane's key. ``group`` is a time-based lane's row in
+    the group table (a count of the rows whose range sorts below its
+    own: at most ``G_CAP`` compares a lane) and a count-based lane's
+    round, its window's place in its slot's chunk. Lane ``i`` of chunk
+    ``c``, round ``r = i - (windows of the chunks before c)``: start
+    ``start0 + r * slide``, length ``min(win, span - r * slide)``,
+    window id ``wid0 + r``; it evicts the ``slide`` leaves from its
+    start that lie inside ``span``. A lane finds its chunk through a
+    mark at each chunk's first lane and one cumulative sum. Masked lanes
+    read 0 in every row."""
     import jax.numpy as jnp
 
-    _keyrows, chunks, total = cb_pack_views(fire_plan, K_cap)
+    head, chunks, total = plan_views(fire_plan, K_cap, timed, key_words)
     c_k = chunks[2]
     before = jnp.cumsum(c_k) - c_k
     marks = jnp.zeros((W,), jnp.int32).at[
@@ -223,14 +216,25 @@ def cb_plan_lanes(fire_plan, W: int, K_cap: int, F: int, win_units: int,
     chunk = jnp.maximum(jnp.cumsum(marks) - 1, 0)
     lane = jnp.arange(W, dtype=jnp.int32)
     mask = lane < total[0]
-    # ONE gather of a lane's six words (what a gather from a small 1-D
-    # table costs in program text: cb_number_rows)
-    c_slot, c_start0, _k, c_wid0, c_span, c_before = jnp.where(
+    # ONE gather of a lane's words (what a gather from a small 1-D table
+    # costs in program text: cb_number_rows)
+    c_slot, c_start0, _k, c_wid0, c_span, *c_key, c_before = jnp.where(
         mask[None, :], jnp.concatenate([chunks, before[None]])[:, chunk], 0)
     rounds = jnp.where(mask, lane - c_before, 0)
     off = rounds * slide_units
     starts = (c_start0 + off) & (F - 1)
     lens = jnp.minimum(win_units, c_span - off)
+    if timed:
+        # the table is sorted by (start, length): a lane's row is the
+        # count of the live rows below its range (a masked lane's range
+        # (0, 0) has none below it)
+        g_s, g_l = head[:G_CAP, 0][None, :], head[:G_CAP, 1][None, :]
+        s, ln = starts[:, None], lens[:, None]
+        below = (jnp.arange(G_CAP) < head[G_CAP, 0])[None, :] & (
+            (g_s < s) | ((g_s == s) & (g_l < ln)))
+        group = below.sum(axis=1, dtype=jnp.int32)
+    else:
+        group = rounds
     # a lane evicts the ``slide`` leaves from its start, as far as the
     # chunk's data goes: over a chunk's rounds, the range [start0,
     # start0 + k * slide) clipped to the data
@@ -240,7 +244,30 @@ def cb_plan_lanes(fire_plan, W: int, K_cap: int, F: int, win_units: int,
         c_slot[:, None] * (2 * F) + (F + ((c_start0[:, None] + e_off)
                                           & (F - 1))),
         K_cap * 2 * F).reshape(-1)
-    return c_slot, starts, lens, c_wid0 + rounds, mask, rounds, eflat
+    return (c_slot, starts, lens, c_wid0 + rounds, mask, group, eflat,
+            jnp.stack(c_key) if c_key else None)
+
+
+def key_words_of(keys: np.ndarray, key_words: int) -> np.ndarray:
+    """``(key_words, n)`` int32 words of int keys, low word first: the
+    rows a plan carries them in (``plan_views``)."""
+    keys = np.asarray(keys, np.int64)
+    words = [keys.astype(np.int32), (keys >> 32).astype(np.int32)]
+    return np.stack(words[:key_words])
+
+
+def join_key_words(words, kd):
+    """In a program: the key column, in the key column's dtype ``kd``,
+    from the ``(key_words, W)`` words of ``key_words_of``: one word for a
+    key of up to 32 bits, two (reassembled) for a wider one."""
+    import jax
+    import jax.numpy as jnp
+
+    kd = jnp.dtype(kd)
+    lo = jax.lax.bitcast_convert_type(words[0], jnp.uint32)
+    if words.shape[0] == 2:
+        return (words[1].astype(kd) << 32) | lo.astype(kd)
+    return (lo if kd.kind == "u" else words[0]).astype(kd)
 
 
 def cb_number_rows(slots, keyrows, K_cap: int, F: int):
@@ -252,7 +279,7 @@ def cb_number_rows(slots, keyrows, K_cap: int, F: int):
     leaves a slot's rows in arrival order; a row's rank in its slot's
     run numbers it from ``base``, the slot's count before the batch, and
     the slot's first ``skip`` rows are late (``keyrows``:
-    ``cb_pack_views``)."""
+    ``plan_views``)."""
     import jax
     import jax.numpy as jnp
 
@@ -439,10 +466,6 @@ class FfatTPUReplica(TPUReplicaBase):
         # stale w.r.t. leaves (ingest-only batches ran since the last
         # rebuild); every fire path rebuilds first (see _make_step)
         self._rebuild_dirty = False
-        # device-resident per-slot key table (lazy; see _ktable_arg)
-        self._ktable_dev = None
-        self._ktable_kd = None
-        self._ktable_dirty = True
         self.ignored = 0
         # incremental checkpointing (WF_CKPT_DELTA): host-side dirty
         # slot set — ingest and fire mark the rows they touch, and a
@@ -523,16 +546,14 @@ class FfatTPUReplica(TPUReplicaBase):
     # the per-batch device program
     # ==================================================================
     def _query_fns(self, W: Optional[int] = None):
-        """``fire_block(trees, tvalid, fire_plan, ktable) -> (tvalid,
-        values, valid, wid column, key column)``: what the full step and
-        the fire-only step do with a program's fire plan: answer its
-        fired windows, evict the leaves they consumed, build the ``wid``
-        and key columns. A time-based plan is the host's lanes
-        (``fire_pack_views``) and its shape is the program's width; a
-        count-based plan is chunk rows (``cb_pack_views``) that the
-        program expands into the same lane arrays (``cb_plan_lanes``) at
-        ``W``, its static width, which the plan's shape does not tell
-        (None: the operator's budget). Three queries
+        """``fire_block(trees, tvalid, fire_plan) -> (tvalid, values,
+        valid, wid column, key column)``: what the full step and the
+        fire-only step do with a program's fire plan: answer its fired
+        windows, evict the leaves they consumed, build the ``wid`` and
+        key columns. A plan is chunk rows (``plan_views``) that the
+        program expands into its lanes (``plan_lanes``) at ``W``, its
+        static width, which the plan's shape does not tell (None: the
+        operator's budget); a lane's key is its chunk's. Three queries
         answer the windows. A program holds the ones its operator can
         take: time-based windows the first two, chosen inside the
         program by the count in the plan's group table (see
@@ -585,7 +606,7 @@ class FfatTPUReplica(TPUReplicaBase):
         F = self.F
         K_cap = self.K_cap
         slide_units = self.slide_units
-        use_ktable = self._use_ktable()
+        kd, key_words = self._key_dtype, self._key_words()
         NNODES = 2 * F
         LOGQ = NNODES.bit_length()  # enough iterations for the tree walk
         tmap = jax.tree_util.tree_map
@@ -753,17 +774,14 @@ class FfatTPUReplica(TPUReplicaBase):
             return tv.reshape(-1)[pick], tmap(lambda t: t.reshape(-1)[pick],
                                               tr)
 
-        def fire_block(trees, tvalid, fire_plan, ktable):
-            if grouped:
-                fire, g_table, evict = fire_pack_views(fire_plan, slide_units)
-                slots, starts, lens, wids, mask_i, group = fire
-                mask = mask_i != 0
-            else:
-                slots, starts, lens, wids, mask, group, eflat = \
-                    cb_plan_lanes(fire_plan, W, K_cap, F, win_units,
-                                  slide_units)
+        def fire_block(trees, tvalid, fire_plan):
+            slots, starts, lens, wids, mask, group, eflat, keys = \
+                plan_lanes(fire_plan, W, K_cap, F, win_units, slide_units,
+                           grouped, key_words)
             with jax.named_scope(SCOPE_FIRE):
                 if grouped:
+                    g_table = plan_views(fire_plan, K_cap, True,
+                                         key_words)[0]
                     qv, qr = jax.lax.cond(
                         g_table[G_CAP, 0] > 0,
                         lambda: by_group(trees, tvalid, slots, group,
@@ -776,32 +794,22 @@ class FfatTPUReplica(TPUReplicaBase):
                 else:
                     qv, qr = by_lane(trees, tvalid, slots, starts, lens)
                 qv = qv & mask
-            # evict leaves consumed by the fired windows
+            # evict leaves consumed by the fired windows (masked lanes:
+            # out of bounds)
             with jax.named_scope(SCOPE_EVICT):
-                if grouped:
-                    evict_slots, evict_leaves, evict_mask_i = evict
-                    eflat = jnp.where(
-                        evict_mask_i != 0,
-                        evict_slots * NNODES + (F + evict_leaves),
-                        K_cap * NNODES)  # masked lanes: out of bounds
                 tvalid = tvalid.reshape(-1).at[eflat].set(
                     False, mode="drop").reshape(tvalid.shape)
-            # output wid/key columns built ON DEVICE: they ride the
-            # program's batched argument transfer instead of costing one
-            # device_put round trip each at emit time
-            if use_ktable:
-                key_out = jnp.where(mask, ktable[slots],
-                                    jnp.zeros((), ktable.dtype))
-            else:
-                key_out = jnp.zeros((1,), jnp.int32)
-            return tvalid, qr, qv, jnp.asarray(wids), key_out
+            # output wid/key columns built ON DEVICE from the plan's
+            # chunk rows: no device_put of their own at emit time
+            key_out = (jnp.zeros((1,), jnp.int32) if keys is None
+                       else join_key_words(keys, kd))
+            return tvalid, qr, qv, wids, key_out
 
         return fire_block
 
     def _make_step(self, cap: int, donate: bool = True,
                    ingest_only: bool = False, W: Optional[int] = None):
-        """``W``: the width of a count-based program's fire block
-        (_query_fns).
+        """``W``: the width of the program's fire block (_query_fns).
 
         ``ingest_only=True`` builds the DEFERRED-REBUILD variant: lift
         + segmented scan + leaf scatter only — no level rebuild, no
@@ -828,8 +836,9 @@ class FfatTPUReplica(TPUReplicaBase):
         fire_block = self._query_fns(W)
         rebuild_levels = xla_rebuild_levels(combine, F)
         counted = self.op.win_type is WinType.CB
+        key_words = self._key_words()
 
-        def step(fields, comp, trees, tvalid, fire_plan, ktable):
+        def step(fields, comp, trees, tvalid, fire_plan):
             # 1. lift + sort + segmented scan. The host ships ONE
             # batch-sized column in the narrowest int dtype (_comp_dtype);
             # the sort order and the run boundaries are computed here, so
@@ -844,7 +853,8 @@ class FfatTPUReplica(TPUReplicaBase):
                 big = jnp.int32(K_cap * F)  # sentinel: late + padding
                 if counted:
                     order, sc = cb_number_rows(
-                        comp, cb_pack_views(fire_plan, K_cap)[0], K_cap, F)
+                        comp, plan_views(fire_plan, K_cap, False,
+                                         key_words)[0], K_cap, F)
                 else:
                     order = jnp.argsort(comp, stable=True)
                     sc = comp[order].astype(jnp.int32)
@@ -903,7 +913,7 @@ class FfatTPUReplica(TPUReplicaBase):
 
             # 4.-6. fired-window queries, eviction of the leaves they
             # consumed, output wid/key columns (_query_fns)
-            return (trees,) + fire_block(trees, tvalid, fire_plan, ktable)
+            return (trees,) + fire_block(trees, tvalid, fire_plan)
 
         # trees/tvalid are DONATED: the leaf scatter and level rebuild
         # update the forest in place in HBM instead of copying the whole
@@ -931,9 +941,10 @@ class FfatTPUReplica(TPUReplicaBase):
         pane satisfies p <= max_leaf < next_fire_at_rebuild + F (the
         _grow_ring span guard enforces this at arrival), so an evicted
         pane's ring slot can only be re-queried at pane p_evicted + F >
-        max_leaf — excluded because _lanes clips every query
-        to the data extent. The clip is also what keeps the invariant
-        robust if F sizing ever changes (regression-tested).
+        max_leaf — excluded because every lane's length is clipped to
+        its chunk's data, ``span`` (plan_lanes). The clip is also what
+        keeps the invariant robust if F sizing ever changes
+        (regression-tested).
 
         The argument is about a slot's RANGES, not about which program
         or which walk answers them, so it holds for the plan by rounds
@@ -1020,7 +1031,6 @@ class FfatTPUReplica(TPUReplicaBase):
                 self._obj_keys = self._out_keys_by_slot
                 self._keys_all_int = False
             self._place_obj_key(s, key)
-        self._ktable_dirty = True
         self._note_admitted(1)
 
     def _place_obj_key(self, s: int, key) -> None:
@@ -1034,10 +1044,7 @@ class FfatTPUReplica(TPUReplicaBase):
         """KeySlotMap callback for a batch's new int keys, all at once
         (no Python call a key): grows the key table until it holds the
         highest slot, refusing BEFORE anything mutates, then writes the
-        keys into the slot table. The device key table is re-staged only
-        where a slot's key changed (a key that takes back the slot it
-        had, as the one key of a keyed-by-constant stage does after every
-        fire, changes nothing)."""
+        keys into the slot table."""
         cap = self.K_cap
         while cap <= int(slots.max()):
             cap *= 2
@@ -1049,9 +1056,7 @@ class FfatTPUReplica(TPUReplicaBase):
         if self._obj_keys is not None:
             for s, k in zip(slots.tolist(), keys.tolist()):
                 self._place_obj_key(s, k)
-        if (self._keys_np[slots] != keys).any():
-            self._keys_np[slots] = keys
-            self._ktable_dirty = True
+        self._keys_np[slots] = keys
         self._note_admitted(len(keys))
 
     def _note_admitted(self, n: int) -> None:
@@ -1130,7 +1135,6 @@ class FfatTPUReplica(TPUReplicaBase):
             setattr(self, name, g)
         if new_trees is not None:
             self.trees, self.tvalid = new_trees, new_tvalid
-        self._ktable_dirty = True
         self._dirty_all = True  # geometry changed under the delta base
         self.stats.key_capacity_growths += 1
 
@@ -1367,8 +1371,9 @@ class FfatTPUReplica(TPUReplicaBase):
         order and the first of them that lie behind its ``next_fire``
         (gap windows, a re-registered key) are dropped, so two words a
         slot say everything of the batch's rows that the step needs
-        (``cb_pack_views``: ``base``, ``skip``), and the step numbers
-        the rows itself, in the sort it does anyway. The one pass by
+        (the plan's ``keyrows``, ``plan_views``: ``base``, ``skip``), and
+        the step numbers the rows itself, in the sort it does anyway.
+        The one pass by
         row here is the count of each slot's rows; ``slots`` (the
         surviving rows', where ``rowsel`` names the rows a fused prefix
         filter kept) is the one batch-sized plane built, with the
@@ -1502,100 +1507,64 @@ class FfatTPUReplica(TPUReplicaBase):
         take[more] += 1
         return take
 
-    @staticmethod
-    def _segmented_arange(k: np.ndarray) -> np.ndarray:
-        """[0..k0), [0..k1), ... concatenated (standard cumsum trick)."""
-        tot = int(k.sum())
-        before = np.cumsum(k) - k
-        return np.arange(tot, dtype=np.int64) - np.repeat(before, k)
+    def _range_words(self, start0, k, end):
+        """``(words, rounds)`` of the lanes of chunks that start at pane
+        ``start0``, hold ``k`` windows and end their data before pane
+        ``end``: a lane's ring range ``(start_phys, length)`` as ONE word,
+        ``start_phys * F + length`` (lengths <= win_units < F: the words
+        sort as the pairs do), and its round (0 for a chunk's first
+        window). A length is clipped to the slot's data, as the program
+        clips it (plan_lanes). Where every chunk takes one window the
+        lanes are the chunks, their rounds 0, and nothing is repeated."""
+        su, F = self.slide_units, self.F
+        if int(k.max()) == 1:
+            if not k.all():
+                start0, end = start0[k > 0], end[k > 0]
+            rnd, starts = 0, start0
+        else:
+            rnd = np.arange(int(k.sum())) - np.repeat(np.cumsum(k) - k, k)
+            starts = np.repeat(start0, k) + rnd * su
+            end = np.repeat(end, k)
+        return ((starts & (F - 1)) * F
+                + np.minimum(self.win_units, end - starts)), rnd
 
-    def _lanes(self, start0, k, ml):
-        """Per-lane ``(round, start, length)`` of chunks that start at
-        pane ``start0``, hold ``k`` windows and end their data at pane
-        ``ml``: a lane a window, a chunk's lanes in ``wid`` order
-        (``round``: 0 for a chunk's first window). The length is ALWAYS
-        clipped to the slot's data extent (max_leaf): panes beyond it
-        hold no current data, and their ring slots may alias panes
-        evicted after the last level rebuild — clipping is what makes
-        the rebuild-free fire-only program sound (every slot inside the
-        clipped range was valid at the last rebuild and is untouched by
-        this drain sequence's evictions; aliases land at pane+F >
-        max_leaf, which is excluded here, and _grow_ring guarantees live
-        spans stay below F)."""
-        rnd = self._segmented_arange(k)
-        starts = np.repeat(start0, k) + rnd * self.slide_units
-        lens = np.minimum(self.win_units, np.repeat(ml, k) + 1 - starts)
-        return rnd, starts, lens
-
-    def _ranges(self, starts, lens):
-        """``(pairs, group)``: the distinct ring ranges ``(start_phys,
-        length)`` of a program's lanes, each packed into one word
-        (lens <= win_units < F), and every lane's index into them."""
-        return np.unique((starts % self.F) * self.F + lens,
-                         return_inverse=True)
-
-    def _pack_plan(self, chunks, W: int, lanes, ranges):
-        """A program's flat int32 fire plan (``fire_pack_views``: fire
-        rows, group table, evict rows) for width ``W``, from its chunks,
-        their lanes (_lanes) and the ranges to answer them by (_ranges;
-        None: the group table stays blank, its count 0, and the program
-        walks by lane). Pure numpy (repeat + segmented arange): zero
-        per-window or per-chunk Python. ONE buffer, so one program
-        argument from the host and one transfer a launch. Returns the
-        buffer and the count of ranges in its table."""
-        c_slots, c_start0, c_k, c_wid0, c_ml = chunks
-        rnd, starts, lens = lanes
-        n_out = rnd.size
-        pack = np.zeros(fire_pack_len(W, self.slide_units), dtype=np.int32)
-        f_pack, g_table, e_pack = fire_pack_views(pack, self.slide_units)
-        f_pack[0, :n_out] = np.repeat(c_slots, c_k)
-        f_pack[1, :n_out] = starts % self.F
-        f_pack[2, :n_out] = lens
-        f_pack[3, :n_out] = np.repeat(c_wid0, c_k) + rnd
-        f_pack[4, :n_out] = 1  # mask row: rides the SAME transfer as the
-        # spec rows (one H2D enqueue per pack instead of pack+mask pairs)
-        n_groups = 0
-        if ranges is not None:
-            pairs, group = ranges
-            n_groups = pairs.size
-            g_table[:n_groups, 0] = pairs // self.F
-            g_table[:n_groups, 1] = pairs % self.F
-            g_table[G_CAP, 0] = n_groups
-            f_pack[5, :n_out] = group
-        # evicted panes: one contiguous range per chunk
-        ne = np.maximum(
-            0, np.minimum(c_start0 + c_k * self.slide_units, c_ml + 1)
-            - c_start0)
-        tot_e = int(ne.sum())
-        if tot_e:
-            ep = np.repeat(c_start0, ne) + self._segmented_arange(ne)
-            e_pack[0, :tot_e] = np.repeat(c_slots, ne)
-            e_pack[1, :tot_e] = ep % self.F
-            e_pack[2, :tot_e] = 1
-        return pack, n_groups
-
-    def _pack_fire_arrays(self, chunks, W: int):
-        """Chunk arrays -> the packed fire plan of a program of width
-        ``W`` and the count of ranges it is answered by. Time-based
-        windows: the plan by lane (_pack_plan; jit re-traces per shape);
-        the group table holds the distinct ``(start_phys, length)``
-        pairs of the lanes where there are at most ``G_CAP``, else the
-        count is 0 and the program walks by lane. Count-based windows:
-        the chunk rows themselves (``cb_pack_views``), which the program
-        expands; no ranges."""
-        c_slots, c_start0, c_k, c_wid0, c_ml = chunks
+    def _ranges_of(self, chunks):
+        """The ring ranges (sorted words of _range_words) of a
+        time-based program's lanes where there are at most ``G_CAP``,
+        else None: the program walks by lane. None for count-based
+        windows, whose ranges are per key."""
         if self.op.win_type is WinType.CB:
-            pack = np.zeros(cb_pack_len(W, self.K_cap), dtype=np.int32)
-            _keyrows, rows, total = cb_pack_views(pack, self.K_cap)
-            rows[:, :c_k.size] = (c_slots, c_start0 & (self.F - 1), c_k,
-                                  c_wid0, c_ml + 1 - c_start0)
-            total[0] = c_k.sum()
+            return None
+        _slots, c_start0, c_k, _wid0, c_ml = chunks
+        pairs = np.unique(self._range_words(c_start0, c_k, c_ml + 1)[0])
+        return pairs if pairs.size <= G_CAP else None
+
+    def _pack_fire_arrays(self, chunks, W: int, keys, pairs):
+        """The flat int32 fire plan (``plan_views``) of a program of
+        width ``W``: its chunk rows, with their original ``keys``
+        (_chunk_keys) where the program builds the key column
+        (_plan_keys), and for a time-based plan the ring ranges to answer
+        it by, ``pairs`` (sorted words of _range_words; None: a blank
+        table, its count 0, and the program walks by lane). Pure numpy,
+        a row a firing slot; ONE buffer, so one program argument from
+        the host and one transfer a launch. Returns the buffer and the
+        count of ranges in its table."""
+        c_slots, c_start0, c_k, c_wid0, c_ml = chunks
+        F, n, kw = self.F, c_k.size, self._key_words()
+        pack = np.zeros(self._plan_len(W), dtype=np.int32)
+        head, rows, total = plan_views(
+            pack, self.K_cap, self.op.win_type is WinType.TB, kw)
+        total[0] = c_k.sum()
+        rows[:5, :n] = (c_slots, c_start0 & (F - 1), c_k, c_wid0,
+                        c_ml + 1 - c_start0)
+        if kw and isinstance(keys, np.ndarray):
+            rows[5:, :n] = key_words_of(keys, kw)
+        if pairs is None:
             return pack, 0
-        lanes = self._lanes(c_start0, c_k, c_ml)
-        ranges = self._ranges(lanes[1], lanes[2])
-        if ranges[0].size > G_CAP:
-            ranges = None
-        return self._pack_plan(chunks, W, lanes, ranges)
+        head[:pairs.size, 0] = pairs // F
+        head[:pairs.size, 1] = pairs % F
+        head[G_CAP, 0] = pairs.size
+        return pack, pairs.size
 
     def _plan_program(self, slots, k):
         """ONE program of an operator that sizes its width by the plan
@@ -1615,6 +1584,10 @@ class FfatTPUReplica(TPUReplicaBase):
         different, about as many ranges as lanes) does the program walk
         by lane, and then at the width it has today, ``W_cap``: a lane
         walk costs by the lane, masked or live, so it is never widened.
+        The ranges are reckoned from the chunks, never by the lane:
+        where every chunk takes one window (a slide that closes once a
+        batch) the chunks are their own lanes, else from the CLASSES of
+        chunks whose lanes hold the same ranges round for round.
 
         The costs behind the rule (my chip runs, PR 30, PERF.md section
         6; a 302 MB forest): a program by range 1.4 ms whatever it
@@ -1629,71 +1602,56 @@ class FfatTPUReplica(TPUReplicaBase):
         start0, end = self.next_fire[slots], self.max_leaf[slots] + 1
         for W in dict.fromkeys((self.W_wide, self.W_cap)):
             take = self._clip(k, W)
-            # CLASSES of chunks whose lanes hold the same ranges round
-            # for round: same start, same data end (as far as it clips
-            # a lane), same count. Ranges are counted over the classes'
-            # lanes, a few hundred where the plugs fire in step, not
-            # over the program's tens of thousands
-            reach = (int(take.max()) - 1) * su + self.win_units
-            _, i_s = np.unique(start0, return_inverse=True)
-            _, i_e = np.unique(np.minimum(end - start0, reach),
-                               return_inverse=True)
-            _, rep, cls = np.unique(
-                (i_s * (int(i_e.max()) + 1) + i_e) * (W + 1) + take,
-                return_index=True, return_inverse=True)
-            q_take = take[rep]
-            q_rnd, q_starts, q_lens = self._lanes(
-                start0[rep], q_take, end[rep] - 1)
-            pairs, q_group = self._ranges(q_starts, q_lens)
+            if int(take.max()) == 1:
+                words, rnd = self._range_words(start0, take, end)
+            else:
+                # CLASSES of chunks whose lanes hold the same ranges
+                # round for round: same start, same data end (as far as
+                # it clips a lane), same count. Ranges are counted over
+                # the classes' lanes, a few hundred where the plugs fire
+                # in step, not over the program's tens of thousands
+                reach = (int(take.max()) - 1) * su + self.win_units
+                _, i_s = np.unique(start0, return_inverse=True)
+                _, i_e = np.unique(np.minimum(end - start0, reach),
+                                   return_inverse=True)
+                _, rep = np.unique(
+                    (i_s * (int(i_e.max()) + 1) + i_e) * (W + 1) + take,
+                    return_index=True)
+                words, rnd = self._range_words(start0[rep], take[rep],
+                                               end[rep])
+            pairs = np.unique(words)
             if pairs.size <= G_CAP:
                 break
             # the round in which each range first appears: rounds below
             # the (G_CAP + 1)-th smallest hold at most G_CAP ranges
             first = np.full(pairs.size, W, dtype=np.int64)
-            np.minimum.at(first, q_group, q_rnd)
+            np.minimum.at(first, np.searchsorted(pairs, words), rnd)
             r = int(np.partition(first, G_CAP)[G_CAP])
             if r:
-                kept = first < r
-                pairs = pairs[kept]
-                q_group = (np.cumsum(kept) - 1)[q_group]
+                pairs = pairs[first < r]
                 take = np.minimum(take, r)
                 self.stats.fire_range_cuts += 1
                 break
         else:
             pairs = None  # ragged: by lane, at the narrow width
-        # a lane is its chunk's class's lane of the same round
-        rnd = self._segmented_arange(take)
-        lane = np.repeat((np.cumsum(q_take) - q_take)[cls], take) + rnd
         chunks = self._take(slots, take)
-        pack, n_groups = self._pack_plan(
-            chunks, W, (rnd, q_starts[lane], q_lens[lane]),
-            None if pairs is None else (pairs, q_group[lane]))
-        return (chunks, rnd.size, pack, n_groups, W,
-                self._chunk_keys(chunks[0])), take
+        keys = self._chunk_keys(chunks[0])
+        return (chunks, int(take.sum())) + self._pack_fire_arrays(
+            chunks, W, keys, pairs) + (W, keys), take
 
-    def _use_ktable(self) -> bool:
-        """Whether programs gather the output key column from a
-        device-resident per-slot key table (int keys with a named key
-        field; non-int keys fall back to host construction)."""
+    def _key_words(self) -> int:
+        """int32 words a key takes in a plan's chunk rows (``plan_views``):
+        none where the operator has no named key field, else by the key
+        column's dtype (two for a key wider than 32 bits)."""
+        if self.op.key_field is None:
+            return 0
+        return 2 if np.dtype(self._key_dtype).itemsize > 4 else 1
+
+    def _plan_keys(self) -> bool:
+        """Whether a fired batch's key column is the one its program
+        builds from the keys its plan carries (int keys with a named key
+        field); keys that are no ints are built on the host."""
         return self._keys_all_int and self.op.key_field is not None
-
-    def _ktable_arg(self):
-        """Device key table for the programs' key-column gather; re-staged
-        only when a new key registered or the capacity/dtype changed —
-        zero steady-state transfer."""
-        if not self._use_ktable():
-            return np.zeros(1, dtype=np.int32)
-        import jax
-        kd = self._key_dtype
-        if (self._ktable_dev is None or self._ktable_dirty
-                or self._ktable_kd != kd):
-            # a NEW array a staging: programs queued with the table as
-            # it stood keep the keys their plans were made with
-            with self._st_keys(self._bid):
-                self._ktable_dev = jax.device_put(self._keys_np.astype(kd))
-            self._ktable_kd = kd
-            self._ktable_dirty = False
-        return self._ktable_dev
 
     def _first_budget(self) -> int:
         """Fire budget for the first (full) program of a batch of an
@@ -1726,9 +1684,8 @@ class FfatTPUReplica(TPUReplicaBase):
 
     def _plan_len(self, W: int) -> int:
         """Words of the plan buffer of a program ``W`` lanes wide."""
-        if self.op.win_type is WinType.CB:
-            return cb_pack_len(W, self.K_cap)
-        return fire_pack_len(W, self.slide_units)
+        return plan_len(W, self.K_cap, self.op.win_type is WinType.TB,
+                        self._key_words())
 
     def _zero_fire(self, W: int):
         """Device-resident all-zero fire plan (cached per length: zero
@@ -1743,28 +1700,21 @@ class FfatTPUReplica(TPUReplicaBase):
                 np.zeros(n, dtype=np.int32))
         return z
 
-    def _wkey(self, key, W: int):
-        """Cache key of the program ``key`` at fire width ``W``: a
-        time-based program's width is its plan's shape (one jitted
-        function, traced per shape), a count-based program's a constant
-        it is built with (the chunk rows of its plan do not tell it)."""
-        return key + (W,) if self.op.win_type is WinType.CB else key
-
     def _fire_key(self):
-        return ("fire", self.K_cap, self.F, self._use_ktable(),
-                str(self._key_dtype))
+        return ("fire", self.K_cap, self.F, str(self._key_dtype))
 
+    # a program's fire width is a constant it is built with (the chunk
+    # rows of its plan do not tell it): its cache key ends with it
     def _fire_step(self, W: int):
         from .ops_tpu import cached_compile
         return cached_compile(self._prog_cache, self.op._prog_lock,
-                              self._wkey(self._fire_key(), W),
+                              self._fire_key() + (W,),
                               lambda: self._make_fire_step(W))
 
     def _full_step(self, ckey, cap: int, W: int):
         from .ops_tpu import cached_compile
         return cached_compile(self._prog_cache, self.op._prog_lock,
-                              self._wkey(ckey, W),
-                              lambda: self._make_step(cap, W=W))
+                              ckey + (W,), lambda: self._make_step(cap, W=W))
 
     def _warm_fire_step(self) -> None:
         """Compile the fire-only program EAGERLY (masked no-op runs) at
@@ -1780,11 +1730,10 @@ class FfatTPUReplica(TPUReplicaBase):
             # all-masked no-op run (both queries compile with the
             # program, whichever runs); tvalid is DONATED: reassign it
             self.tvalid, *_ = self._fire_step(W)(
-                self.trees, self.tvalid, self._zero_fire(W),
-                self._ktable_arg())
+                self.trees, self.tvalid, self._zero_fire(W))
             self._warm_shapes.add((fkey, W))
 
-    def _warm_programs(self, cap, ckey, ikey, fields, ktable) -> None:
+    def _warm_programs(self, cap, ckey, ikey, fields) -> None:
         """Compile every program variant of a capacity bucket with no-op
         sentinel runs (every lane the composite's sentinel, zero fire
         args): the full step at each fire width (W_step, W_cap, W_wide:
@@ -1811,12 +1760,12 @@ class FfatTPUReplica(TPUReplicaBase):
                 continue
             (self.trees, self.tvalid, *_) = self._full_step(ckey, cap, W)(
                 fields, comp_s, self.trees, self.tvalid,
-                self._zero_fire(W), ktable)
+                self._zero_fire(W))
             self._warm_shapes.add((ckey, W))
         if (ikey, self.W_step) not in self._warm_shapes:
             (self.trees, self.tvalid, *_) = istep(
                 fields, comp_s, self.trees, self.tvalid,
-                self._zero_fire(self.W_step), ktable)
+                self._zero_fire(self.W_step))
             self._warm_shapes.add((ikey, self.W_step))
         if rb is not None:
             self.trees, self.tvalid = rb(self.trees, self.tvalid)
@@ -1842,16 +1791,19 @@ class FfatTPUReplica(TPUReplicaBase):
         from .ops_tpu import prewarm_zero_fields
         kf = self.op.key_field
         if kf is not None and kf in sch.fields:
-            self._key_dtype = np.dtype(sch.fields[kf])
+            # the dtype the column has on the device (field_dtype's):
+            # the programs build their key column in it
+            from jax.dtypes import canonicalize_dtype
+            self._key_dtype = np.dtype(canonicalize_dtype(sch.fields[kf]))
         warmed = 0
         for cap in caps:
             fields = prewarm_zero_fields(entry, cap)
             self._ensure_forest(fields)
             ckey, ikey = self._step_keys(cap)
-            if (self._wkey(ckey, self.W_cap) in self._prog_cache
+            if (ckey + (self.W_cap,) in self._prog_cache
                     and ikey in self._prog_cache):
                 continue
-            self._warm_programs(cap, ckey, ikey, fields, self._ktable_arg())
+            self._warm_programs(cap, ckey, ikey, fields)
             warmed += 1
         return warmed
 
@@ -1862,16 +1814,15 @@ class FfatTPUReplica(TPUReplicaBase):
         nobody reuses and defeat the compile-flat guarantee). The chain
         tag pins fused-prefix variants to their own cache rows."""
         tag = self._chain_tag()
-        ckey = ("step", cap, self.K_cap, self.F,
-                self._use_ktable(), str(self._key_dtype), tag)
+        ckey = ("step", cap, self.K_cap, self.F, str(self._key_dtype), tag)
         ikey = ("ingest", cap, self.K_cap, self.F, tag)
         return ckey, ikey
 
     def _prep_step(self, fields, wm, cap, comp_p, frontier, bid: int = 0,
                    keyrows=None):
         """Host half of the per-batch step: program warm-up, the ENTIRE
-        fire plan — every program's chunk arrays and packed fire/evict
-        args, computed up front because the planner reads host metadata
+        fire plan — every program's chunk arrays and packed plan,
+        computed up front because the planner reads host metadata
         only (no control decision ever waits on a device result) — and,
         for the operators that keep the tiers, the fire-rate EWMA.
         ``keyrows``: a count-based batch's per-slot words
@@ -1879,7 +1830,6 @@ class FfatTPUReplica(TPUReplicaBase):
         where the step fires nothing (so the cached all-zero plan never
         serves a count-based step). Returns the device-commit thunk for
         the dispatch pipeline."""
-        ktable = self._ktable_arg()
         ckey, ikey = self._step_keys(cap)
         self._cap_seen = max(self._cap_seen, cap)
 
@@ -1887,7 +1837,7 @@ class FfatTPUReplica(TPUReplicaBase):
             # the warm-up's no-op runs consume the live forest
             # (donation), so in-flight commits land first
             self.dispatch.drain(forced=True)
-            self._warm_programs(cap, ckey, ikey, fields, ktable)
+            self._warm_programs(cap, ckey, ikey, fields)
 
         if (ikey not in self._prog_cache
                 or (ckey, self.W_wide) not in self._warm_shapes):
@@ -1919,10 +1869,10 @@ class FfatTPUReplica(TPUReplicaBase):
                     else np.zeros(self._plan_len(self.W_step), np.int32)]
         if keyrows is not None:
             first = plan[0]
-            cb_pack_views(first[3] if isinstance(first, tuple) else first,
-                          self.K_cap)[0][:] = keyrows
-        return lambda: self._commit_step(fields, wm, comp_p, ktable,
-                                         ckey, ikey, plan, bid)
+            plan_views(first[3] if isinstance(first, tuple) else first,
+                       self.K_cap, False, self._key_words())[0][:] = keyrows
+        return lambda: self._commit_step(fields, wm, comp_p, ckey, ikey,
+                                         plan, bid)
 
     def _programs(self, frontier, partial: bool, first_budget: int, warm):
         """The programs that fire what is eligible now, one ``(chunks,
@@ -1954,8 +1904,10 @@ class FfatTPUReplica(TPUReplicaBase):
                 left, _k = self._eligible(frontier, partial)
                 if left.size:
                     owed = int(self.fired[left].min())
+            keys = self._chunk_keys(chunks[0])
             yield (chunks, n_out) + self._pack_fire_arrays(
-                chunks, budget) + (budget, self._chunk_keys(chunks[0]), owed)
+                chunks, budget, keys, self._ranges_of(chunks)) + (
+                    budget, keys, owed)
             if n_out < budget or (timed and owed is None):
                 return
             budget = self.W_cap
@@ -1990,8 +1942,8 @@ class FfatTPUReplica(TPUReplicaBase):
             yield prog + (int(self.fired[slots].min()) if slots.size
                           else None,)
 
-    def _commit_step(self, fields, wm, comp_p, ktable, ckey, ikey,
-                     plan, bid: int) -> None:
+    def _commit_step(self, fields, wm, comp_p, ckey, ikey, plan,
+                     bid: int) -> None:
         """Device half: runs the planned program sequence in order and
         emits each iteration's windows. Reads ``self.trees``/
         ``self.tvalid`` at COMMIT time — earlier queued commits reassign
@@ -2007,7 +1959,7 @@ class FfatTPUReplica(TPUReplicaBase):
                 # batch-size-independent — the dominant per-batch term of
                 # the low-cardinality small-batch regime)
                 (self.trees, self.tvalid, *_) = self._prog_cache[ikey](
-                    fields, comp_p, self.trees, self.tvalid, entry, ktable)
+                    fields, comp_p, self.trees, self.tvalid, entry)
                 self._rebuild_dirty = True
                 self.stats.device_programs_run += 1
                 continue
@@ -2015,20 +1967,20 @@ class FfatTPUReplica(TPUReplicaBase):
             if is_first:
                 # full program: lift + scan + scatter + rebuild + fire
                 (self.trees, self.tvalid, qr, qv, wid_dev,
-                 key_dev) = self._prog_cache[self._wkey(ckey, budget)](
-                    fields, comp_p, self.trees, self.tvalid, pack, ktable)
+                 key_dev) = self._prog_cache[ckey + (budget,)](
+                    fields, comp_p, self.trees, self.tvalid, pack)
                 self._rebuild_dirty = False  # in-program rebuild covers
                 # every deferred ingest-only batch (full-forest rebuild)
                 self._dirty_all = True  # ... and rewrote internal rows
             else:
                 # drain iterations: fire-only program (no rebuild)
                 self.tvalid, qr, qv, wid_dev, key_dev = self._fire_step(
-                    budget)(self.trees, self.tvalid, pack, ktable)
+                    budget)(self.trees, self.tvalid, pack)
             self.stats.device_programs_run += 1
-            self._emit_windows(wm, chunks, keys, owed, pack, n_out, qr, qv,
+            self._emit_windows(wm, chunks, keys, owed, n_out, qr, qv,
                                wid_dev, key_dev, budget, n_groups, bid)
 
-    def _emit_windows(self, wm, chunks, c_keys, owed, pack, n_out, qr, qv,
+    def _emit_windows(self, wm, chunks, c_keys, owed, n_out, qr, qv,
                       wid_dev, key_dev, W: int, n_groups: int,
                       cause: int = 0) -> None:
         """``c_keys``, ``owed``: see _programs. ``W``: the width of the
@@ -2057,13 +2009,12 @@ class FfatTPUReplica(TPUReplicaBase):
         self.stats.fire_lanes += W
         self.stats.windows_fired += n_out
         _slots, _st, c_k, c_w0, _ml = chunks
-        if op.win_type is WinType.CB:
-            # the host's plan is the chunk rows; the program expands them
-            self.stats.fire_plan_rows += c_k.size
-            if fire_slides(W, self.K_cap, self.F):
-                self.stats.fire_sliding_programs += 1
-        else:
-            self.stats.fire_plan_rows += n_out  # laid out by lane
+        # the host's plan is the chunk rows; the program expands them
+        self.stats.fire_plan_rows += c_k.size
+        if int(c_k.max()) == 1:
+            self.stats.fire_one_round_plans += 1
+        if op.win_type is WinType.CB and fire_slides(W, self.K_cap, self.F):
+            self.stats.fire_sliding_programs += 1
         if n_groups:
             self.stats.fire_grouped_programs += 1
             self.stats.fire_groups += n_groups
@@ -2075,8 +2026,8 @@ class FfatTPUReplica(TPUReplicaBase):
         # (most read their own column, or none)
         out_keys = ChunkedKeys(c_keys, c_k)
         if op.key_field is not None:
-            if self._use_ktable():
-                fields[op.key_field] = key_dev  # gathered in-program
+            if self._plan_keys():
+                fields[op.key_field] = key_dev  # from the plan's keys
             else:
                 # build directly in the key column's dtype (float keys
                 # must not round-trip through int64)
@@ -2092,9 +2043,11 @@ class FfatTPUReplica(TPUReplicaBase):
             wm = min(wm, first * op.slide_len + op.win_len - 1)
         ts = np.full(W, wm, dtype=np.int64)
         if op.win_type is WinType.TB:
-            wids = fire_pack_views(pack, self.slide_units)[0][3, :n_out]
-            ts[:n_out] = (wids.astype(np.int64) * op.slide_len
-                          + (op.win_len - 1))
+            # lane i of chunk c is its window wid0 + (i - the windows of
+            # the chunks before c)
+            wids = np.arange(n_out) + np.repeat(c_w0 - (np.cumsum(c_k) - c_k),
+                                                c_k)
+            ts[:n_out] = wids * op.slide_len + (op.win_len - 1)
         out = BatchTPU(fields, ts, n_out, out_schema, wm, out_keys)
         # the keys are this operator's: a consumer keyed by another
         # field reads its own column (BatchTPU.keys_for)
@@ -2116,15 +2069,14 @@ class FfatTPUReplica(TPUReplicaBase):
         # direct drivers — bench, profile scripts — reach here too)
         self.dispatch.drain(forced=True)
         self._bid = 0
-        ktable = self._ktable_arg()     # as the plans' keys stand now
         for chunks, n_out, pack, n_groups, W, keys, owed in self._programs(
                 frontier, partial, self.W_cap, self._warm_fire_step):
             self._ensure_rebuilt()
             self.tvalid, qr, qv, wid_dev, key_dev = self._fire_step(W)(
-                self.trees, self.tvalid, pack, ktable)
+                self.trees, self.tvalid, pack)
             self.stats.device_programs_run += 1
-            self._emit_windows(self.cur_wm, chunks, keys, owed, pack, n_out,
-                               qr, qv, wid_dev, key_dev, W, n_groups)
+            self._emit_windows(self.cur_wm, chunks, keys, owed, n_out, qr,
+                               qv, wid_dev, key_dev, W, n_groups)
 
     def on_punctuation(self, wm: int) -> None:
         if self.op.win_type is WinType.TB:
@@ -2141,7 +2093,7 @@ class FfatTPUReplica(TPUReplicaBase):
     # processing state is the key map, the per-slot host bookkeeping
     # arrays, and the device forest — one device_get per tree field
     # (array-shaped state keeps the snapshot a transfer, not a
-    # serializer). Device-side caches (ktable, zero-fire constants) and
+    # serializer). Device-side caches (zero-fire constants) and
     # compiled programs rebuild lazily after restore.
     def snapshot_state(self) -> dict:
         import jax
@@ -2287,7 +2239,4 @@ class FfatTPUReplica(TPUReplicaBase):
         self.tvalid = (None if d["tvalid"] is None
                        else jnp.asarray(d["tvalid"]))
         # device-side caches are stale for the restored geometry
-        self._ktable_dev = None
-        self._ktable_kd = None
-        self._ktable_dirty = True
         self._zero_fire_cache = {}

@@ -510,7 +510,7 @@ class TPUReplicaBase(BasicReplica):
         ``np.asarray`` boxes every key twice per batch). Int key columns
         return the raw array for both forms: every ``KeySlotMap`` path
         that registers keys from an int array goes through ``int()``, so
-        slot identity and the ktable fast path's ``isinstance(key, int)``
+        slot identity and the int fast path's ``isinstance(key, int)``
         checks still see Python ints. Other dtypes keep the list form
         (their consumers iterate Python keys)."""
         return op_batch_keys_np(self.op, batch)
