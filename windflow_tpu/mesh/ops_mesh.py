@@ -45,13 +45,24 @@ import numpy as np
 from ..basic import KeyCapacityError, OpType, RoutingMode, WindFlowError
 from ..tpu.batch import (BatchTPU, bucket_capacity, gather_columns,
                          host_columns)
-from ..tpu.ops_tpu import TPUOperatorBase, TPUReplicaBase, cached_compile
+from ..tpu.ops_tpu import (TPUOperatorBase, TPUReplicaBase, cached_compile,
+                           has_array_leaves)
 from ..tpu.schema import TupleSchema
 
 
 # ---------------------------------------------------------------------------
 # operators
 # ---------------------------------------------------------------------------
+def refuse_array_leaves(name: str, state_init) -> None:
+    """The mesh plane's keyed state (``sharded_grid_scan``) holds one
+    scalar a leaf and key; an array leaf runs on one chip."""
+    if has_array_leaves(state_init):
+        raise WindFlowError(
+            f"{name}: the mesh plane's keyed state (sharded_grid_scan) "
+            "holds scalar leaves only; a state leaf that is an array runs "
+            "on one chip (drop with_mesh)")
+
+
 class _MeshKeyedOperator(TPUOperatorBase):
     """Shared metadata of the mesh-sharded keyed operators."""
 
@@ -95,6 +106,7 @@ class Map_Mesh(_MeshKeyedOperator):
                 f"{name}: with_mesh applies to the KEYED-STATE plane; a "
                 "stateless Map_TPU is data-parallel already (every chip "
                 "can run it) — add with_state(...) or drop with_mesh")
+        refuse_array_leaves(name, state_init)
         super().__init__(name, key_extractor, schema, key_capacity,
                          n_devices, mesh_shape, local_batch)
         self.func = func
@@ -121,6 +133,7 @@ class Filter_Mesh(_MeshKeyedOperator):
                 f"{name}: with_mesh applies to the KEYED-STATE plane; a "
                 "stateless Filter_TPU is data-parallel already — add "
                 "with_state(...) or drop with_mesh")
+        refuse_array_leaves(name, state_init)
         super().__init__(name, key_extractor, schema, key_capacity,
                          n_devices, mesh_shape, local_batch)
         self.pred = pred

@@ -75,6 +75,12 @@ class StatsRecord(StageCounters):
         # key capacity (each reallocates the forest and recompiles)
         "keys_admitted", "keys_reclaimed", "key_slots_live",
         "key_capacity_growths",
+        # the keyed state plane's grid scans (tpu/ops_tpu.py
+        # _KeyedStateScan, standalone or fused): scans run, rows given a
+        # grid cell, the grids' cells (keys bucket x depth bucket), their
+        # depths and the keys they touched, each summed over the scans
+        "scan_programs", "scan_rows", "scan_cells", "scan_depth",
+        "scan_keys",
         # the device interval join (tpu/join_tpu.py): rows that probed
         # and rows archived, by side [A, B]; pairs delivered and the
         # batches they left in; rows purged; live rows of both archives
@@ -230,6 +236,11 @@ class StatsRecord(StageCounters):
         self.keys_reclaimed = 0
         self.key_slots_live = 0
         self.key_capacity_growths = 0
+        self.scan_programs = 0
+        self.scan_rows = 0
+        self.scan_cells = 0
+        self.scan_depth = 0
+        self.scan_keys = 0
         self.join_probe_rows = [0, 0]
         self.join_archived_rows = [0, 0]
         self.join_pairs = 0
@@ -583,6 +594,11 @@ class StatsRecord(StageCounters):
             "Keys_reclaimed": self.keys_reclaimed,
             "Key_slots_live": self.key_slots_live,
             "Key_capacity_growths": self.key_capacity_growths,
+            "Scan_programs": self.scan_programs,
+            "Scan_rows": self.scan_rows,
+            "Scan_cells": self.scan_cells,
+            "Scan_depth": self.scan_depth,
+            "Scan_keys": self.scan_keys,
             "Join_probe_rows_a": self.join_probe_rows[0],
             "Join_probe_rows_b": self.join_probe_rows[1],
             "Join_archived_rows_a": self.join_archived_rows[0],

@@ -191,6 +191,10 @@ STAGES: Dict[str, StageDef] = {
     # the interval join's host half, inside its prep: the batch's event
     # times as offsets, the purge lines, the bound on the archives
     "join": StageDef("wf", _DISPATCH, "Join_host_total_usec", None),
+    # a keyed state operator's grid assembly, inside its prep (a fused
+    # chain's: one span a stateful member, labelled by the member): the
+    # key lookup and admission, table growth, the grid's cells
+    "grid": StageDef("wf", _DISPATCH, "Scan_host_total_usec", None),
     "queue": StageDef("wait", _DISPATCH, "Dispatch_queue_wait_total_usec",
                       None),
     "commit": StageDef("wf", _DISPATCH, "Dispatch_commit_total_usec", None,

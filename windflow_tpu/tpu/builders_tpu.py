@@ -50,6 +50,21 @@ class _TieredStateMixin:
                                 "(tiers hold the keyed device state)")
 
 
+class _KeyCapacityMixin:
+    """``with_key_capacity`` for the keyed-state operators."""
+
+    _key_capacity: Optional[int] = None
+
+    def with_key_capacity(self, n: int):
+        """Slots of the keyed state table (``with_state``): allocated once
+        at that size where it starts at 64 and doubles, and for int keys
+        the key directory's direct table laid over as many ids. More
+        keys still grow it (``Key_capacity_growths``). A stateless
+        operator refuses it at ``build()``."""
+        self._key_capacity = int(n)
+        return self
+
+
 class _MeshBuilderMixin:
     """``with_mesh`` for the keyed device operators: shard the operator's
     keyed-state plane over a ``('key','data')`` device mesh
@@ -90,10 +105,14 @@ class _MeshBuilderMixin:
         if self._key_extractor is None:
             raise WindFlowError(f"{what}: with_mesh requires with_key_by "
                                 "(the mesh shards the KEYED plane)")
+        if getattr(self, "_key_capacity", None) is not None:
+            raise WindFlowError(
+                f"{what}: with_key_capacity sizes the single-chip table; "
+                "the mesh's is with_mesh(key_capacity=...)")
 
 
 class Map_TPU_Builder(_RoutableBuilder, _TPUBuilderMixin, _MeshBuilderMixin,
-                      _TieredStateMixin):
+                      _TieredStateMixin, _KeyCapacityMixin):
     _default_name = "map_tpu"
 
     def __init__(self, func: Callable) -> None:
@@ -123,11 +142,13 @@ class Map_TPU_Builder(_RoutableBuilder, _TPUBuilderMixin, _MeshBuilderMixin,
         return self._finish(Map_TPU(self._func, self._name, self._parallelism,
                                     self._routing, self._key_extractor,
                                     self._output_batch_size, self._schema,
-                                    self._state_init, self._tiering))
+                                    self._state_init, self._tiering,
+                                    self._key_capacity))
 
 
 class Filter_TPU_Builder(_RoutableBuilder, _TPUBuilderMixin,
-                         _MeshBuilderMixin, _TieredStateMixin):
+                         _MeshBuilderMixin, _TieredStateMixin,
+                         _KeyCapacityMixin):
     _default_name = "filter_tpu"
 
     def __init__(self, pred: Callable) -> None:
@@ -158,7 +179,8 @@ class Filter_TPU_Builder(_RoutableBuilder, _TPUBuilderMixin,
                                        self._parallelism, self._routing,
                                        self._key_extractor,
                                        self._output_batch_size, self._schema,
-                                       self._state_init, self._tiering))
+                                       self._state_init, self._tiering,
+                                       self._key_capacity))
 
 
 class Reduce_TPU_Builder(_RoutableBuilder, _TPUBuilderMixin,

@@ -64,14 +64,19 @@ class KeySlotMap:
     got. ``on_new_many(keys, slots)`` admits a batch's new int keys in
     one call (else ``on_new`` a key); both may refuse (capacity) and then
     nothing is registered. ``admit_span()`` is opened around a batch's
-    admission (the owner's stage span)."""
+    admission (the owner's stage span). ``span``, where given, is the
+    ids the owner expects live at once (its key capacity): the direct
+    table is never smaller than that and may pass ``LUT_MAX`` up to
+    twice it, so a directory that never releases a key is not refitted
+    from every live key as its ids climb."""
 
     LUT_MAX = 1 << 22  # 16 MiB int32 ceiling for the direct table
     DENSE_MAX = 1 << 16  # ids below this are looked up from 0 (base 0)
 
     def __init__(self, on_new: Optional[Callable[[Any, int], None]] = None,
                  on_new_many: Optional[Callable] = None,
-                 admit_span: Optional[Callable] = None) -> None:
+                 admit_span: Optional[Callable] = None,
+                 span: int = 0) -> None:
         self.slot_of_key: Dict[Any, int] = {}
         self._on_new = on_new  # called as on_new(key, slot) for each new key
         self._on_new_many = on_new_many  # (int keys array, slots array)
@@ -81,6 +86,9 @@ class KeySlotMap:
         self._lut = None
         self._base = 0
         self._sorted = None         # (keys, slots) sorted by key, or None
+        # the direct table's least size and its ceiling
+        self._lut_min = 1 << (span - 1).bit_length() if span > 1 else 0
+        self._lut_max = max(self.LUT_MAX, 2 * self._lut_min)
 
     def __len__(self) -> int:
         return len(self.slot_of_key)
@@ -191,26 +199,29 @@ class KeySlotMap:
 
     def _fit_lut(self, kmin: int, kmax: int) -> bool:
         """Lay the direct table over the live keys and ``[kmin, kmax]``;
-        False where they span more than ``LUT_MAX``."""
+        False where they span more than its ceiling (``LUT_MAX``, or
+        twice the owner's ``span``)."""
+        top = self._lut_max
         if self._sorted is not None and len(self._sorted[0]):
             # looked up by search so far: the live range without a walk
             kmin = min(kmin, int(self._sorted[0][0]))
             kmax = max(kmax, int(self._sorted[0][-1]))
-        if kmax - kmin >= self.LUT_MAX:
+        if kmax - kmin >= top:
             self._lut = None
             return False
         keys, slots = self._live_int_keys()
         if len(keys):
             kmin, kmax = min(kmin, int(keys.min())), max(kmax,
                                                          int(keys.max()))
-        if kmax - kmin >= self.LUT_MAX:
+        if kmax - kmin >= top:
             self._lut = None
             return False
-        if 0 <= kmin and kmax < self.DENSE_MAX:
+        if 0 <= kmin and kmax < max(self.DENSE_MAX, self._lut_min):
             base, span = 0, kmax + 1     # small ids: the table from 0
         else:
             base, span = kmin, kmax - kmin + 1
-        size = min(self.LUT_MAX, 1 << max(10, (2 * span - 1).bit_length()))
+        size = min(top, max(self._lut_min,
+                            1 << max(10, (2 * span - 1).bit_length())))
         lut = np.full(size, -1, dtype=np.int32)
         lut[keys - base] = slots
         self._lut, self._base = lut, base

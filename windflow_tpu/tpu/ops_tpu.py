@@ -299,6 +299,51 @@ def _grid_scan_core(func, filter_mode: bool, M: int, KB: int):
     return core
 
 
+def state_table(state_init, capacity: int):
+    """The keyed state table of ``capacity`` slots: every leaf of
+    ``state_init`` (a scalar or an array of any shape) repeated along a
+    leading slot axis, ``(capacity,) + leaf.shape``."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.tree_util.tree_map(
+        lambda v: jnp.full((capacity,) + np.shape(v), v,
+                           dtype=jnp.asarray(v).dtype), state_init)
+
+
+def has_array_leaves(state_init) -> bool:
+    """True where a leaf of ``state_init`` is an array, not a scalar."""
+    import jax
+
+    return any(np.ndim(v) for v in jax.tree_util.tree_leaves(state_init))
+
+
+def check_keyed_state(name: str, state_init, tiering,
+                      key_capacity: Optional[int]) -> None:
+    """Refuse, by name, what the keyed state plane of a single chip does
+    not take: a key capacity without state, a capacity beside tiering
+    (whose hot tier sizes the table), array leaves under tiering (the
+    cold store holds one scalar column a leaf)."""
+    if key_capacity is not None:
+        if state_init is None:
+            raise WindFlowError(
+                f"{name}: with_key_capacity sizes the keyed state table of "
+                "with_state; a stateless operator keeps none")
+        if int(key_capacity) < 1:
+            raise WindFlowError(f"{name}: with_key_capacity needs at least "
+                                f"one slot, got {key_capacity}")
+        if tiering is not None:
+            raise WindFlowError(
+                f"{name}: with_key_capacity and with_tiering both size the "
+                "device table; give the tiers' hot_capacity alone")
+    if tiering is not None and state_init is not None \
+            and has_array_leaves(state_init):
+        raise WindFlowError(
+            f"{name}: with_tiering keeps scalar state leaves only (its cold "
+            "store holds one column a leaf); an array leaf needs the "
+            "dense table (drop with_tiering, size it by with_key_capacity)")
+
+
 def masked_tree_reduce(combine, fields, valid):
     """Whole-batch fold to one tuple via a masked pairwise tree
     reduction (log2(cap) fused halving passes — associativity is the
@@ -576,14 +621,17 @@ class TPUOperatorBase(BasicOperator):
 class Map_TPU(TPUOperatorBase):
     """Stateless: ``func(fields) -> fields`` (elementwise over columns).
     Stateful (``state_init`` given): ``func(row, state) -> (row, state)``
-    over scalars, scanned in arrival order with per-key state."""
+    over one row's scalars and its key's state (leaves of any shape),
+    scanned in arrival order; ``key_capacity`` sizes the state table."""
 
     def __init__(self, func: Callable, name: str = "map_tpu",
                  parallelism: int = 1,
                  input_routing: RoutingMode = RoutingMode.FORWARD,
                  key_extractor=None, output_batch_size: int = 0,
                  schema: Optional[TupleSchema] = None,
-                 state_init: Any = None, tiering=None) -> None:
+                 state_init: Any = None, tiering=None,
+                 key_capacity: Optional[int] = None) -> None:
+        check_keyed_state(name, state_init, tiering, key_capacity)
         if state_init is not None and key_extractor is None:
             raise WindFlowError(f"{name}: stateful Map_TPU requires a key "
                                 "extractor (KEYBY)")
@@ -597,6 +645,7 @@ class Map_TPU(TPUOperatorBase):
         self.func = func
         self.state_init = state_init
         self.tiering = tiering
+        self.key_capacity = key_capacity
 
     @property
     def fusion_role(self) -> Optional[str]:
@@ -667,26 +716,40 @@ class _KeyedStateScan:
     the scan walks the per-key POSITION axis (M = max tuples of one key in
     the batch) while ``vmap`` processes all keys in parallel each step.
     Sequential work is the per-key chain depth, not the batch size; state
-    lives in a device-resident (K_cap,) table pytree between batches.
+    lives in a device-resident table pytree between batches, each leaf
+    ``(K_cap,) + its shape in the initial state``.
+
+    The table starts at the operator's ``key_capacity`` (64 where none
+    was given) and doubles when the keys outgrow it. Counted on the
+    replica's record, per grid scan: ``Scan_programs``, ``Scan_rows``
+    (rows given a grid cell), ``Scan_cells`` (KB x M), ``Scan_depth``
+    (M), ``Scan_keys`` (touched keys); the host's grid assembly is the
+    stage ``wf:grid:<op>``; ``Keys_admitted``, ``Key_slots_live``,
+    ``Key_capacity_growths`` as on the window operator.
     """
 
     def __init__(self, replica, func, state_init, filter_mode: bool,
                  op=None) -> None:
         from .keymap import KeySlotMap
-        self.replica = replica
-        self.func = func
-        self.state_init = state_init
-        self.filter_mode = filter_mode
-        self._keymap = KeySlotMap()
-        self.slot_of_key = self._keymap.slot_of_key  # shared dict
-        self.table_capacity = 64
-        # compiled grid-scan programs shared across replicas of the op
-        # (keyed by grid shape; the table capacity is read from the table
-        # ARGUMENT at trace time, so growth re-traces automatically).
         # ``op`` overrides the owner: a fused chain replica hosts one
         # engine per stateful SUB-operator, each resolving keys and
         # caching against its own op.
         self.op = replica.op if op is None else op
+        self.replica = replica
+        self.func = func
+        self.state_init = state_init
+        self.filter_mode = filter_mode
+        capacity = getattr(self.op, "key_capacity", None)
+        self.table_capacity = capacity or 64
+        # int keys are looked up through a direct table laid over the
+        # given capacity's span from the first batch on
+        self._keymap = KeySlotMap(span=capacity or 0)
+        self.slot_of_key = self._keymap.slot_of_key  # shared dict
+        self.stats = replica.stats
+        self._st_grid = replica.stats.stage("grid", self.op.name)
+        # compiled grid-scan programs shared across replicas of the op
+        # (keyed by grid shape; the table capacity is read from the table
+        # ARGUMENT at trace time, so growth re-traces automatically).
         self._cache = self.op._scan_prog_cache
         self._cache_lock = self.op._scan_prog_lock
         self.table = None  # pytree of (table_capacity, ...) arrays
@@ -753,13 +816,9 @@ class _KeyedStateScan:
     # -- host side ---------------------------------------------------------
     def _ensure_table(self, n_keys_needed: int) -> None:
         import jax
-        import jax.numpy as jnp
 
         if self.table is None:
-            init = self.state_init
-            self.table = jax.tree_util.tree_map(
-                lambda v: jnp.full((self.table_capacity,), v,
-                                   dtype=jnp.asarray(v).dtype), init)
+            self.table = state_table(self.state_init, self.table_capacity)
         self._sync_dirty()
         if self.tier is not None:
             # tiered mode: the device table IS the hot tier, fixed at
@@ -778,12 +837,10 @@ class _KeyedStateScan:
         while n_keys_needed > self.table_capacity:
             self.table_capacity *= 2
             old = self.table
-            fresh = jax.tree_util.tree_map(
-                lambda v: jnp.full((self.table_capacity,), v,
-                                   dtype=jnp.asarray(v).dtype),
-                self.state_init)
+            fresh = state_table(self.state_init, self.table_capacity)
             self.table = jax.tree_util.tree_map(
                 lambda f, o: f.at[:o.shape[0]].set(o), fresh, old)
+            self.stats.key_capacity_growths += 1
         self._sync_dirty()
 
     def _sync_dirty(self) -> None:
@@ -803,6 +860,20 @@ class _KeyedStateScan:
                           .at[:old.shape[0]].set(old))
 
     def grid_meta(self, batch: BatchTPU):
+        """``_grid_meta`` timed as ``wf:grid:<op>`` and counted as one
+        grid scan."""
+        with self._st_grid(batch.bid):
+            meta = self._grid_meta(batch)
+        st = self.stats
+        M, KB = meta[4], meta[5]
+        st.scan_programs += 1
+        st.scan_rows += batch.size
+        st.scan_cells += KB * M
+        st.scan_depth += M
+        st.scan_keys += int(meta[3].sum())
+        return meta
+
+    def _grid_meta(self, batch: BatchTPU):
         """(grid_idx, valid, touched, touched_mask, M, KB): batch-local
         grid positions, the touched global table rows, and the grid
         bucket sizes. No comparison sort on the hot path: global slots
@@ -822,7 +893,10 @@ class _KeyedStateScan:
             if plan is not None:
                 self._submit_tier_plan(plan)
             self.tier.publish_gauges(len(self.slot_of_key))
+        n_keys = len(self.slot_of_key)
         gslots = self._keymap.slots_of(keys, keys_arr, n)
+        self.stats.keys_admitted += len(self.slot_of_key) - n_keys
+        self.stats.key_slots_live = len(self.slot_of_key)
         self._ensure_table(len(self.slot_of_key))
         if self.table_capacity <= 4 * max(1, n):
             # touched rows + dense local ids, O(n + table) via bincount
@@ -1109,14 +1183,17 @@ class StatefulFilterTPUReplica(TPUReplicaBase):
 class Filter_TPU(TPUOperatorBase):
     """Stateless: ``pred(fields) -> bool column``; the batch compacts.
     Stateful (``state_init`` given): ``pred(row, state) -> (keep, state)``
-    over scalars with per-key device state (grid scan)."""
+    over one row's scalars and its key's state (leaves of any shape;
+    grid scan); ``key_capacity`` sizes the state table."""
 
     def __init__(self, pred: Callable, name: str = "filter_tpu",
                  parallelism: int = 1,
                  input_routing: RoutingMode = RoutingMode.FORWARD,
                  key_extractor=None, output_batch_size: int = 0,
                  schema: Optional[TupleSchema] = None,
-                 state_init: Any = None, tiering=None) -> None:
+                 state_init: Any = None, tiering=None,
+                 key_capacity: Optional[int] = None) -> None:
+        check_keyed_state(name, state_init, tiering, key_capacity)
         if state_init is not None and key_extractor is None:
             raise WindFlowError(f"{name}: stateful Filter_TPU requires a "
                                 "key extractor (KEYBY)")
@@ -1130,6 +1207,7 @@ class Filter_TPU(TPUOperatorBase):
         self.pred = pred
         self.state_init = state_init
         self.tiering = tiering
+        self.key_capacity = key_capacity
 
     @property
     def fusion_role(self) -> Optional[str]:
