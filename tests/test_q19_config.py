@@ -291,7 +291,8 @@ def test_the_files_state_the_deployment():
         "grid_fill_share.q19", "grid_depth_per_program.q19",
         "grid_host_us_per_batch.q19", "key_capacity_growths.q19",
         "key_slots_live.q19", "ranked_share.q19",
-        "grid_scan_device_share.q19", "grid_scan_roofline.q19"}
+        "grid_scan_device_share.q19", "grid_scan_roofline.q19",
+        "batch_admit_share.q19"}
     assert all(n.endswith(".sat") for n in names - mine)
     assert [m["name"] for m, _ in cell.metrics("end_to_end")] == [
         "events_per_s", "setup_s"]

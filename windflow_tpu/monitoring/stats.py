@@ -78,9 +78,10 @@ class StatsRecord(StageCounters):
         # the keyed state plane's grid scans (tpu/ops_tpu.py
         # _KeyedStateScan, standalone or fused): scans run, rows given a
         # grid cell, the grids' cells (keys bucket x depth bucket), their
-        # depths and the keys they touched, each summed over the scans
+        # depths and the keys they touched, each summed over the scans;
+        # the scans whose new keys were admitted in one operation
         "scan_programs", "scan_rows", "scan_cells", "scan_depth",
-        "scan_keys",
+        "scan_keys", "scan_batch_admits",
         # the device interval join (tpu/join_tpu.py): rows that probed
         # and rows archived, by side [A, B]; pairs delivered and the
         # batches they left in; rows purged; live rows of both archives
@@ -241,6 +242,7 @@ class StatsRecord(StageCounters):
         self.scan_cells = 0
         self.scan_depth = 0
         self.scan_keys = 0
+        self.scan_batch_admits = 0
         self.join_probe_rows = [0, 0]
         self.join_archived_rows = [0, 0]
         self.join_pairs = 0
@@ -599,6 +601,7 @@ class StatsRecord(StageCounters):
             "Scan_cells": self.scan_cells,
             "Scan_depth": self.scan_depth,
             "Scan_keys": self.scan_keys,
+            "Scan_batch_admits": self.scan_batch_admits,
             "Join_probe_rows_a": self.join_probe_rows[0],
             "Join_probe_rows_b": self.join_probe_rows[1],
             "Join_archived_rows_a": self.join_archived_rows[0],

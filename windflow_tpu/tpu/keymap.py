@@ -61,14 +61,16 @@ class KeySlotMap:
     Slots given back (``release``) join ``free`` and are handed out again
     before a new one: every slot below ``n_slots`` is live or free. A
     caller that never releases gets the insertion-order slots it always
-    got. ``on_new_many(keys, slots)`` admits a batch's new int keys in
-    one call (else ``on_new`` a key); both may refuse (capacity) and then
-    nothing is registered. ``admit_span()`` is opened around a batch's
-    admission (the owner's stage span). ``span``, where given, is the
-    ids the owner expects live at once (its key capacity): the direct
-    table is never smaller than that and may pass ``LUT_MAX`` up to
-    twice it, so a directory that never releases a key is not refitted
-    from every live key as its ids climb."""
+    got. A batch's new int keys are admitted in one operation (counted
+    in ``batch_admits``), with one ``on_new_many(keys, slots)`` call
+    where the owner gave it; an owner with only ``on_new`` is called a
+    key. Either may refuse (capacity) and then nothing is registered.
+    ``admit_span()`` is opened around a batch's admission (the owner's
+    stage span). ``span``, where given, is the ids the owner expects live
+    at once (its key capacity): the direct table is never smaller than
+    that and may pass ``LUT_MAX`` up to twice it, so a directory that
+    never releases a key is not refitted from every live key as its ids
+    climb."""
 
     LUT_MAX = 1 << 22  # 16 MiB int32 ceiling for the direct table
     DENSE_MAX = 1 << 16  # ids below this are looked up from 0 (base 0)
@@ -83,6 +85,7 @@ class KeySlotMap:
         # a context around a batch's admission (the owner's stage span)
         self._admit_span = admit_span or nullcontext
         self.free: List[int] = []   # slots given back, reused last-in first
+        self.batch_admits = 0       # admissions made in one operation
         self._lut = None
         self._base = 0
         self._sorted = None         # (keys, slots) sorted by key, or None
@@ -122,11 +125,13 @@ class KeySlotMap:
 
     def _admit(self, new: np.ndarray) -> np.ndarray:
         """Slots for the distinct unseen int keys ``new``: free ones
-        first, then past the high-water mark. One callback for all where
-        the owner takes a batch."""
+        first (last in, first out), then past the high-water mark, in one
+        operation with one callback, or none where the owner gave none;
+        key for key the slots ``slot`` would give. An owner with only a
+        per-key ``on_new`` is called once a key."""
         m = len(new)
         with self._admit_span():
-            if self._on_new_many is None:
+            if self._on_new is not None and self._on_new_many is None:
                 return np.fromiter((self.slot(int(k)) for k in new),
                                    dtype=np.int64, count=m)
             n_free = min(m, len(self.free))
@@ -135,11 +140,14 @@ class KeySlotMap:
             slots = np.concatenate([
                 np.asarray(reused[::-1], dtype=np.int64),
                 np.arange(top, top + m - n_free, dtype=np.int64)])
-            self._on_new_many(new, slots)  # may refuse: nothing mutated yet
+            if self._on_new_many is not None:
+                # may refuse: nothing mutated yet
+                self._on_new_many(new, slots)
             if n_free:
                 del self.free[len(self.free) - n_free:]
             self.slot_of_key.update(zip(new.tolist(), slots.tolist()))
             self._sorted = None
+            self.batch_admits += 1
             return slots
 
     def release(self, keys) -> None:

@@ -753,6 +753,9 @@ class _KeyedStateScan:
         self._cache = self.op._scan_prog_cache
         self._cache_lock = self.op._scan_prog_lock
         self.table = None  # pytree of (table_capacity, ...) arrays
+        # (table_capacity,) int32, -1 between batches: _grid_meta's
+        # numbering of the touched rows, grown with the table
+        self._mark = None
         # tiered keyed state (windflow_tpu.state): with_tiering caps the
         # device table at hot_capacity and spills the cold tail to a
         # host sqlite store; None = the dense path, byte-identical to
@@ -829,19 +832,21 @@ class _KeyedStateScan:
                 raise KeyCapacityError(
                     self.op.name, self.table_capacity,
                     n_keys_needed - self.table_capacity)
-            return
-        if n_keys_needed > self.table_capacity:
-            # growth reads the CURRENT table: in-flight commits reassign
-            # it (donation), so they must land first
-            self.replica.dispatch.drain(forced=True)
-        while n_keys_needed > self.table_capacity:
-            self.table_capacity *= 2
-            old = self.table
-            fresh = state_table(self.state_init, self.table_capacity)
-            self.table = jax.tree_util.tree_map(
-                lambda f, o: f.at[:o.shape[0]].set(o), fresh, old)
-            self.stats.key_capacity_growths += 1
-        self._sync_dirty()
+        else:
+            if n_keys_needed > self.table_capacity:
+                # growth reads the CURRENT table: in-flight commits
+                # reassign it (donation), so they must land first
+                self.replica.dispatch.drain(forced=True)
+            while n_keys_needed > self.table_capacity:
+                self.table_capacity *= 2
+                old = self.table
+                fresh = state_table(self.state_init, self.table_capacity)
+                self.table = jax.tree_util.tree_map(
+                    lambda f, o: f.at[:o.shape[0]].set(o), fresh, old)
+                self.stats.key_capacity_growths += 1
+            self._sync_dirty()
+        if self._mark is None or len(self._mark) != self.table_capacity:
+            self._mark = np.full(self.table_capacity, -1, dtype=np.int32)
 
     def _sync_dirty(self) -> None:
         """Keep the dirty bitmap allocated and shape-matched to the
@@ -876,11 +881,15 @@ class _KeyedStateScan:
     def _grid_meta(self, batch: BatchTPU):
         """(grid_idx, valid, touched, touched_mask, M, KB): batch-local
         grid positions, the touched global table rows, and the grid
-        bucket sizes. No comparison sort on the hot path: global slots
-        come from the KeySlotMap LUT; touched rows + dense local ids come
-        from a bincount when the table is batch-sized (falling back to
-        np.unique when total keys dwarf the batch — bincount would pay
-        O(table) per batch) and the grouping from a radix argsort."""
+        bucket sizes. No per-key Python and no comparison sort: global
+        slots come from the KeySlotMap's direct table, a batch's new keys
+        admitted in one operation; touched rows and their dense local ids
+        from one O(n) pass through ``_mark`` at any table size; the
+        grouping from a radix argsort. ``touched`` is in the order the
+        keys' surviving rows come, not in slot order: the program gathers
+        and scatters the touched rows by distinct index and each key's
+        walk is its own, so the outputs and the table after the step do
+        not depend on it."""
         from .keymap import group_positions
 
         n = batch.size
@@ -893,20 +902,25 @@ class _KeyedStateScan:
             if plan is not None:
                 self._submit_tier_plan(plan)
             self.tier.publish_gauges(len(self.slot_of_key))
-        n_keys = len(self.slot_of_key)
-        gslots = self._keymap.slots_of(keys, keys_arr, n)
-        self.stats.keys_admitted += len(self.slot_of_key) - n_keys
-        self.stats.key_slots_live = len(self.slot_of_key)
+        km, st = self._keymap, self.stats
+        n_keys, admits = len(self.slot_of_key), km.batch_admits
+        # as intp once: numpy converts any other index array on each of
+        # the numbering's five passes
+        gslots = km.slots_of(keys, keys_arr, n).astype(np.intp, copy=False)
+        st.keys_admitted += len(self.slot_of_key) - n_keys
+        st.scan_batch_admits += km.batch_admits - admits
+        st.key_slots_live = len(self.slot_of_key)
         self._ensure_table(len(self.slot_of_key))
-        if self.table_capacity <= 4 * max(1, n):
-            # touched rows + dense local ids, O(n + table) via bincount
-            cnt = np.bincount(gslots, minlength=self.table_capacity)
-            touched_list = np.nonzero(cnt)[0]
-            lmap = np.zeros(self.table_capacity, dtype=np.int64)
-            lmap[touched_list] = np.arange(len(touched_list))
-            lslots = lmap[gslots]
-        else:  # high cardinality: O(n log n) beats O(table_capacity)
-            touched_list, lslots = np.unique(gslots, return_inverse=True)
+        # touched rows + dense local ids in one pass: every row writes its
+        # index at its slot, the row whose index survived stands for its
+        # key, and the keys are numbered in the order of those rows
+        mark = self._mark
+        rows = np.arange(n, dtype=np.int32)
+        mark[gslots] = rows
+        touched_list = gslots[mark[gslots] == rows]
+        mark[touched_list] = rows[:len(touched_list)]
+        lslots = mark[gslots]
+        mark[touched_list] = -1
         _, within = group_positions(lslots, len(touched_list))
         max_depth = int(within.max()) + 1 if n else 1
         M = 1
