@@ -201,7 +201,8 @@ def test_graft_entry_reexecutes():
     rep = g._ffat_replica()
     assert comp.shape == fields["key"].shape
     assert comp.dtype == rep._comp_dtype()[1]
-    assert tvalid.shape == trees["value"].shape == (rep.K_cap, 2 * rep.F)
+    # the forest is node-major: a row a node of every key slot's tree
+    assert tvalid.shape == trees["value"].shape == (2 * rep.F, rep.K_cap)
     jax.block_until_ready(fn(*args))
     jax.block_until_ready(fn(*args))  # donated args would fail here
 

@@ -40,9 +40,10 @@ def parent_numbering(count, next_fire, max_leaf, slots, K_cap, F):
     return comp, count, max_leaf, int((~live).sum())
 
 
-def parent_lanes(chunks, W, F, win_units, slide_units):
+def parent_lanes(chunks, W, F, win_units, slide_units, K_cap):
     """The parent's ``_lanes`` + ``_pack_plan`` of a count-based plan:
-    the six fire rows at width ``W`` and the set of evicted leaves."""
+    the six fire rows at width ``W`` and the set of evicted leaves, as
+    flat indices of the node-major (2F, K_cap) forest."""
     c_slots, c_start0, c_k, c_wid0, c_ml = chunks
     tot = int(c_k.sum())
     rnd = np.arange(tot) - np.repeat(np.cumsum(c_k) - c_k, c_k)
@@ -59,7 +60,7 @@ def parent_lanes(chunks, W, F, win_units, slide_units):
                     - c_start0)
     ep = (np.repeat(c_start0, ne) + np.arange(int(ne.sum()))
           - np.repeat(np.cumsum(ne) - ne, ne))
-    evicted = set((np.repeat(c_slots, ne) * 2 * F + F + ep % F).tolist())
+    evicted = set(((F + ep % F) * K_cap + np.repeat(c_slots, ne)).tolist())
     return fire, evicted
 
 
@@ -326,7 +327,7 @@ def test_lanes_expanded_from_chunk_rows_equal_the_parents_lanes(
     chunks = rep._take(slots, rep._clip(k, W))
     assert 0 < int(chunks[2].sum()) <= W
     got = expanded(rep, chunks, W)
-    fire, evicted = parent_lanes(chunks, W, F, win, slide)
+    fire, evicted = parent_lanes(chunks, W, F, win, slide, rep.K_cap)
     for name, g, want in zip(
             ("slot", "start", "len", "wid", "mask", "round"), got, fire):
         assert (g == want).all(), name
@@ -447,7 +448,7 @@ def test_both_window_types_take_the_same_arguments_and_plan_by_chunk():
     # time-based: the composite, the group table and the chunk rows
     assert tb._comp_dtype() == (tb.K_cap * tb.F, np.int16)
     assert tb._plan_len(64) == plan_len(64, tb.K_cap, True, 1) \
-        == 1 + 66 + 6 * tb.K_cap
+        == 1 + 70 + 6 * tb.K_cap
     pack, n = tb._pack_fire_arrays(chunks, 64, tb._chunk_keys(chunks[0]),
                                    tb._ranges_of(chunks))
     groups, rows, total = plan_views(pack, tb.K_cap, True, 1)

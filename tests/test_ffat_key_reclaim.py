@@ -53,7 +53,7 @@ def test_a_dead_slot_is_given_back_and_its_row_holds_no_valid_leaf():
     rep = make_replica(budget=None, keys=4)
     feed(rep, [(10, 0, 1.0), (11, 0, 2.0), (10, 1, 3.0)], 0)
     s10, s11 = rep.slot_of_key[10], rep.slot_of_key[11]
-    assert np.asarray(rep.tvalid)[[s10, s11], rep.F:].any(axis=1).all()
+    assert np.asarray(rep.tvalid)[rep.F:, [s10, s11]].any(axis=0).all()
     # the watermark passes every window that holds an event of either
     # key; key 12 arrives with it
     feed(rep, [(12, 9, 5.0)], 9)
@@ -64,7 +64,7 @@ def test_a_dead_slot_is_given_back_and_its_row_holds_no_valid_leaf():
             st["Key_slots_live"], st["Key_capacity_growths"]) == (3, 2, 1, 0)
     # every leaf of the rows given back was evicted by the fires that
     # consumed it: nothing on the device needed clearing
-    assert not np.asarray(rep.tvalid)[[s10, s11], rep.F:].any()
+    assert not np.asarray(rep.tvalid)[rep.F:, [s10, s11]].any()
     # a new key takes a free slot before the table grows, and its windows
     # hold its own events alone
     feed(rep, [(13, 10, 7.0), (14, 10, 8.0), (12, 10, 1.0)], 10)

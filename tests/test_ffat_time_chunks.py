@@ -39,7 +39,7 @@ def parent_ranges(rep, starts, lens):
 def parent_plan(rep, chunks, W, lanes, ranges):
     """The parent's ``_pack_plan``, decoded: the six fire rows (slot,
     start, len, wid, mask, group) at width ``W``, the group table and
-    the set of evicted flat leaf indices."""
+    the set of evicted flat leaf indices (of the node-major forest)."""
     c_slots, c_start0, c_k, c_wid0, c_ml = chunks
     rnd, starts, lens = lanes
     n = rnd.size
@@ -60,8 +60,8 @@ def parent_plan(rep, chunks, W, lanes, ranges):
                     - c_start0)
     ep = (np.repeat(c_start0, ne) + np.arange(int(ne.sum()))
           - np.repeat(np.cumsum(ne) - ne, ne))
-    F2 = 2 * rep.F
-    evicted = set((np.repeat(c_slots, ne) * F2 + rep.F + ep % rep.F).tolist())
+    evicted = set(((rep.F + ep % rep.F) * rep.K_cap
+                   + np.repeat(c_slots, ne)).tolist())
     return (fire, groups, evicted), int(groups[G_CAP, 0])
 
 
@@ -216,7 +216,7 @@ def test_a_time_based_plan_by_the_chunk_expands_to_the_parents_lanes(case):
         assert (g_n, g_groups, g_W, g_owed) == (w_n, w_groups, w_W, w_owed)
         # one row a firing slot, never one a lane
         assert pack.size == new._plan_len(g_W) \
-            == 1 + 66 + 6 * min(new.K_cap, g_W)
+            == 1 + 70 + 6 * min(new.K_cap, g_W)
         rows, gone, keys = expanded(new, pack, g_W)
         assert (rows == fire).all()
         assert gone == evicted
@@ -437,7 +437,7 @@ def test_keys_that_are_no_ints_are_built_on_the_host():
     keys = [k for b in seen for k in b.host_keys]
     assert set(keys) == {("k", 1), ("k", 2)} and len(keys) == sum(
         b.size for b in seen)
-    assert rep._plan_len(16) == 1 + 66 + 5 * 4
+    assert rep._plan_len(16) == 1 + 70 + 5 * 4
 
 
 def test_a_named_key_field_of_non_int_keys_builds_the_column_on_the_host():

@@ -625,13 +625,13 @@ def test_growth_build_then_commit(monkeypatch):
     assert rep2.K_cap == cap_before
     assert rep2._out_keys_by_slot == keys_before
     assert len(rep2._keymap) == cap_before
-    assert rep2.trees["v"].shape[0] == cap_before
+    assert rep2.trees["v"].shape[1] == cap_before
     monkeypatch.undo()
     s = rep2._keymap.slot(999)            # retry succeeds from scratch
     assert s == cap_before
     assert rep2.K_cap == 2 * cap_before
     assert rep2._out_keys_by_slot[-1] == 999
-    assert rep2.trees["v"].shape[0] == 2 * cap_before
+    assert rep2.trees["v"].shape[1] == 2 * cap_before
 
 
 def test_ffat_tpu_composite_key_columnar_pipeline():

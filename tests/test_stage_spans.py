@@ -396,8 +396,9 @@ def test_time_based_programs_take_the_pack_and_the_arguments_they_took(
         served):
     """PR 39 gave a time-based plan the count-based layout: per firing
     key slot, never per lane. A time-based operator's plan is ``1 + 2
-    (G_CAP + 1) + (5 + key words) min(K_cap, W)`` words, its head the
-    group table; its programs take the batch's columns, the composite,
+    (G_CAP + 3) + (5 + key words) min(K_cap, W)`` words, its head the
+    group table and the two dirty ring ranges a step's level rebuild
+    goes by; its programs take the batch's columns, the composite,
     the forest and the plan, and no key table; the rule that chooses
     the scan is never asked about it."""
     import inspect
@@ -408,11 +409,11 @@ def test_time_based_programs_take_the_pack_and_the_arguments_they_took(
     assert replica.slide_units == 1 and ffat_tpu.G_CAP == 32
     for W, K_cap, kw in ((8, 16, 1), (64, 16, 2), (32768, 4096, 1)):
         n = ffat_tpu.plan_len(W, K_cap, True, kw)
-        assert n == 1 + 66 + (5 + kw) * min(K_cap, W)
+        assert n == 1 + 70 + (5 + kw) * min(K_cap, W)
         groups, chunks, total = ffat_tpu.plan_views(
             np.zeros(n, np.int32), K_cap, True, kw)
         assert (groups.shape, chunks.shape, total.shape) == (
-            (33, 2), (5 + kw, min(K_cap, W)), (1,))
+            (35, 2), (5 + kw, min(K_cap, W)), (1,))
     by_name = {p._wrapped_jit.__name__: p._wrapped_jit
                for p in replica._prog_cache.values()
                if hasattr(p, "_wrapped_jit")}
@@ -432,7 +433,7 @@ def test_time_based_programs_take_the_pack_and_the_arguments_they_took(
     groups, rows, total = ffat_tpu.plan_views(pack, replica.K_cap, True, 1)
     assert replica._key_words() == 1
     assert pack.dtype == np.int32 and pack.size == replica._plan_len(8) \
-        == 1 + 66 + 6 * min(replica.K_cap, 8)
+        == 1 + 70 + 6 * min(replica.K_cap, 8)
     assert n_groups == 1 == groups[32, 0] and total[0] == 2
     assert rows[:, :2].T.tolist() == [
         [s, 40 % replica.F, 1, 10, 4, 1000 + k]

@@ -69,6 +69,11 @@ class StatsRecord(StageCounters):
         # batches whose prep built no batch-sized plane but the rows'
         # slots (count-based windows: the step numbers its own rows)
         "fire_plan_rows", "fire_one_round_plans", "prep_by_key_batches",
+        # the level rebuilds of a window operator's forest (a step's
+        # in-program rebuild or the standalone one), and of those the
+        # steps that rebuilt only the ancestors of the panes written and
+        # evicted since the last rebuild (time-based windows)
+        "rebuild_programs", "rebuild_partial_programs",
         # key turnover of a time-based window operator: keys given a
         # slot, slots given back (a key none of whose windows holds an
         # event any more), slots in use now (a gauge), doublings of the
@@ -233,6 +238,8 @@ class StatsRecord(StageCounters):
         self.fire_plan_rows = 0
         self.fire_one_round_plans = 0
         self.prep_by_key_batches = 0
+        self.rebuild_programs = 0
+        self.rebuild_partial_programs = 0
         self.keys_admitted = 0
         self.keys_reclaimed = 0
         self.key_slots_live = 0
@@ -592,6 +599,8 @@ class StatsRecord(StageCounters):
             "Fire_plan_rows": self.fire_plan_rows,
             "Fire_one_round_plans": self.fire_one_round_plans,
             "Prep_by_key_batches": self.prep_by_key_batches,
+            "Rebuild_programs": self.rebuild_programs,
+            "Rebuild_partial_programs": self.rebuild_partial_programs,
             "Keys_admitted": self.keys_admitted,
             "Keys_reclaimed": self.keys_reclaimed,
             "Key_slots_live": self.key_slots_live,

@@ -27,9 +27,13 @@ TPU-first redesign:
     lift(columns) -> sort of the packed (slot, leaf) composite ->
     gather(sort order) -> segmented associative scan with
     the user combine -> gather segment tails -> scatter-combine into the
-    leaves of a FlatFAT FOREST (K_cap keys x 2F nodes, one segment tree
-    per key slot, circular leaf addressing ``pane mod F``) -> vectorized
-    level rebuild (log F fused passes over the whole forest) -> iterative
+    leaves of a FlatFAT FOREST (one segment tree per key slot, circular
+    leaf addressing ``pane mod F``, stored NODE-MAJOR: a (2F, K_cap)
+    array a field, node ``i`` of every slot's tree one row) -> level
+    rebuild, bottom up, of the internal nodes over the panes written
+    and evicted since the last rebuild (a window of each level around
+    their ancestors), or of every node where those ranges are wide ->
+    iterative
     range queries for the program's fired windows (each walks <= 2 log F
     nodes with ordered left/right accumulators, safe for non-commutative
     combines): ONE walk per distinct ring range over every key slot at
@@ -120,7 +124,7 @@ SCOPE_REBUILD, SCOPE_FIRE, SCOPE_EVICT = "level_rebuild", "fire", "evict"
 # flush needs half the programs, and every step takes 0.35 ms longer
 G_CAP = 32
 
-# count-based windows: a program of W lanes over a (K_cap, 2F) forest
+# count-based windows: a program of W lanes over a forest of K_cap rings
 # answers BY SLIDING SCAN (two block scans over every leaf, see
 # _query_fns) where ``W * SLIDE_X >= K_cap * F``, else by lane. The walk
 # costs by the lane, the scan by the forest's leaves; SLIDE_X is how many
@@ -137,6 +141,26 @@ G_CAP = 32
 # forest would walk for 23 ms where the scan takes 13
 SLIDE_X = 2048
 
+# time-based windows: the static width, in nodes of one tree level, of
+# the window in which a step's partial level rebuild recomputes a dirty
+# ring range's ancestors (rebuild_levels_by_ranges): rows of the
+# node-major forest, every key slot at once, so a window costs by its
+# rows, four sublane tiles of the device's (8, 128) registers here. It
+# holds the parents of a range of up to 2 * REBUILD_W - 1 leaves; a
+# wider range, or one that wraps the ring, takes the full rebuild. What
+# a range spans is the event time of the batches since the last
+# rebuild, which no static shape tells (a batch's capacity bounds its
+# rows, not its panes): 32 holds a few firing batches of an in-order
+# stream with room to spare (sg2: a 32,768-row batch writes ~9 of its
+# 4,096 leaves and its fire evicts ~8), and the partial rebuild pays
+# only over a ring with levels wider than two windows
+# (rebuilds_by_ranges), whose full rebuild passes every node
+REBUILD_W = 32
+# the rows of a time-based plan's head (plan_views) that carry the
+# step's two dirty ring ranges, ``(start_phys, length)``: the panes
+# written and the panes evicted since the last rebuild
+RANGE_ROWS = slice(G_CAP + 1, G_CAP + 3)
+
 
 def fire_slides(W: int, K_cap: int, F: int) -> bool:
     """Whether a count-based program of ``W`` lanes over ``K_cap`` rings
@@ -149,7 +173,7 @@ def fire_slides(W: int, K_cap: int, F: int) -> bool:
 def plan_len(W: int, K_cap: int, timed: bool, key_words: int) -> int:
     """Words of the ONE int32 buffer that carries a program's fire plan
     (see ``plan_views``) at a width of ``W`` lanes."""
-    head = 2 * (G_CAP + 1) if timed else 2 * K_cap
+    head = 2 * (G_CAP + 3) if timed else 2 * K_cap
     return 1 + head + (5 + key_words) * min(K_cap, W)
 
 
@@ -160,10 +184,14 @@ def plan_views(pack, K_cap: int, timed: bool, key_words: int):
     fire block the program derives (``plan_lanes``), for both window
     types:
 
-    - ``head``: a TIME-based plan's group table (G_CAP + 1, 2), the
-      distinct ring ranges ``(start_phys, length)`` of its lanes in
-      ascending order and, in row ``G_CAP``, their count (0: the
-      program walks by lane); a COUNT-based plan's ``keyrows`` (2,
+    - ``head``: a TIME-based plan's (G_CAP + 3, 2) rows: its group
+      table, the distinct ring ranges ``(start_phys, length)`` of its
+      lanes in ascending order and, in row ``G_CAP``, their count (0:
+      the program walks by lane); then, in ``RANGE_ROWS``, the two
+      dirty ring ranges a step's level rebuild goes by (the panes
+      written and the panes evicted since the last rebuild, a length of
+      ``F`` where the whole forest is; read by a step only, 0 in any
+      other plan); a COUNT-based plan's ``keyrows`` (2,
       K_cap), read by the step's ingest: ``base``, a slot's arrival
       count before the batch (mod ``F``: the ring place of its next
       leaf), and ``skip``, how many of its first arrivals in the batch
@@ -182,10 +210,10 @@ def plan_views(pack, K_cap: int, timed: bool, key_words: int):
 
     One buffer, so one transfer a program: a launch pays for every host
     argument it is handed."""
-    n_h = 2 * (G_CAP + 1) if timed else 2 * K_cap
+    n_h = 2 * (G_CAP + 3) if timed else 2 * K_cap
     rows = 5 + key_words
     C = (pack.shape[0] - 1 - n_h) // rows
-    head = pack[1:1 + n_h].reshape((G_CAP + 1, 2) if timed else (2, K_cap))
+    head = pack[1:1 + n_h].reshape((G_CAP + 3, 2) if timed else (2, K_cap))
     return head, pack[1 + n_h:1 + n_h + rows * C].reshape(rows, C), pack[:1]
 
 
@@ -241,9 +269,8 @@ def plan_lanes(fire_plan, W: int, K_cap: int, F: int, win_units: int,
     e_off = off[:, None] + jnp.arange(slide_units, dtype=jnp.int32)[None, :]
     eflat = jnp.where(
         mask[:, None] & (e_off < c_span[:, None]),
-        c_slot[:, None] * (2 * F) + (F + ((c_start0[:, None] + e_off)
-                                          & (F - 1))),
-        K_cap * 2 * F).reshape(-1)
+        (F + ((c_start0[:, None] + e_off) & (F - 1))) * K_cap
+        + c_slot[:, None], K_cap * 2 * F).reshape(-1)
     return (c_slot, starts, lens, c_wid0 + rounds, mask, group, eflat,
             jnp.stack(c_key) if c_key else None)
 
@@ -296,35 +323,120 @@ def cb_number_rows(slots, keyrows, K_cap: int, F: int):
         ss * F + ((base + rank) & (F - 1)), K_cap * F)
 
 
-def xla_rebuild_levels(combine: Callable, F: int):
-    """``rebuild(trees, tvalid) -> (trees, tvalid)``: recompute internal
-    nodes ``[1, F)`` of every (K_cap, 2F) tree row from its leaves, one
-    fused XLA pass per level (an invalid child passes the other
-    through). The ONE definition traced by the step's in-program
-    rebuild and by the standalone settle program (divergence would make
-    deferred batches aggregate differently from direct ones)."""
+def _parents(combine: Callable, lc, rc, vlc, vrc):
+    """In a program: the nodes over the left children ``lc`` and the
+    right children ``rc`` (fields alike) and their validity: an invalid
+    child passes the other through. The one combine of every rebuild,
+    so a node comes out the same whichever rebuild computed it."""
     import jax
     import jax.numpy as jnp
+
+    node = jax.tree_util.tree_map(
+        lambda m, a, b: jnp.where(vlc & vrc, m, jnp.where(vlc, a, b)),
+        combine(lc, rc), lc, rc)
+    return node, vlc | vrc
+
+
+def xla_rebuild_levels(combine: Callable, F: int):
+    """``rebuild(trees, tvalid) -> (trees, tvalid)``: recompute internal
+    nodes ``[1, F)`` of every tree of a (2F, K_cap) node-major forest
+    from its leaves, one fused XLA pass per level (an invalid child
+    passes the other through). The ONE full rebuild, traced by the
+    step's in-program rebuild and by the standalone settle program
+    (divergence would make deferred batches aggregate differently from
+    direct ones)."""
+    import jax
 
     tmap = jax.tree_util.tree_map
 
     def rebuild_levels(trees, tvalid):
         lvl = F >> 1
         while lvl >= 1:
-            lc = tmap(lambda t: t[:, 2 * lvl:4 * lvl:2], trees)
-            rc = tmap(lambda t: t[:, 2 * lvl + 1:4 * lvl:2], trees)
-            vlc = tvalid[:, 2 * lvl:4 * lvl:2]
-            vrc = tvalid[:, 2 * lvl + 1:4 * lvl:2]
-            merged = combine(lc, rc)
-            node = tmap(lambda m, a, b: jnp.where(
-                vlc & vrc, m, jnp.where(vlc, a, b)), merged, lc, rc)
-            trees = tmap(lambda t, nd: t.at[:, lvl:2 * lvl].set(nd),
+            node, nv = _parents(
+                combine, tmap(lambda t: t[2 * lvl:4 * lvl:2], trees),
+                tmap(lambda t: t[2 * lvl + 1:4 * lvl:2], trees),
+                tvalid[2 * lvl:4 * lvl:2], tvalid[2 * lvl + 1:4 * lvl:2])
+            trees = tmap(lambda t, nd: t.at[lvl:2 * lvl].set(nd),
                          trees, node)
-            tvalid = tvalid.at[:, lvl:2 * lvl].set(vlc | vrc)
+            tvalid = tvalid.at[lvl:2 * lvl].set(nv)
             lvl >>= 1
         return trees, tvalid
 
     return rebuild_levels
+
+
+def rebuilds_by_ranges(F: int) -> bool:
+    """Whether a time-based step over rings of ``F`` leaves holds the
+    partial rebuild (``rebuild_levels_by_ranges``): where some level is
+    wider than ``REBUILD_W``, and so wider than a window of it."""
+    return F > 2 * REBUILD_W
+
+
+def rebuild_fits(ranges, F: int):
+    """Whether a step rebuilds by its plan's dirty ring ranges (``ranges``,
+    rows ``(start_phys, length)``, numpy on the host or traced in the
+    program): where no range wraps the ring's end and the parents of
+    each, ``(start + length - 1) // 2 - start // 2 + 1`` nodes, fit a
+    window of ``REBUILD_W``. A range the host marks full has length
+    ``F``, which fits no window of a ring that rebuilds by ranges."""
+    s, n = ranges[:, 0], ranges[:, 1]
+    return ((s + n <= F) & ((s + n - 1) // 2 - s // 2 < REBUILD_W)).all()
+
+
+def rebuild_levels_by_ranges(combine: Callable, F: int):
+    """``rebuild(trees, tvalid, ranges) -> (trees, tvalid)``: recompute
+    the internal nodes over the leaves of the ring ranges ``ranges``
+    (rows ``(start_phys, length)`` that ``rebuild_fits``) of every tree
+    of a node-major forest, bottom up, level by level, each range's
+    ancestors a window of ``REBUILD_W`` rows (``dynamic_slice`` of its
+    children, written back in place), and every node of a level no
+    wider than the window.
+
+    Sound where every node with no leaf of a range below it agrees with
+    its leaves (the replica keeps the ranges so: ``_dirty_in``,
+    ``_dirty_ev``): a node recomputed here is an ancestor of a range's
+    leaves, whose children a lower level of this sweep has recomputed,
+    or a node whose children agree already and which comes out as it
+    was. Two ranges that share ancestors near the root are one sweep:
+    a level's windows read only the level below, which is current for
+    both. Every node is computed by ``_parents``, as the full rebuild
+    computes it, so the forest comes out node for node the same."""
+    import jax
+    import jax.numpy as jnp
+
+    tmap = jax.tree_util.tree_map
+    W = REBUILD_W
+
+    def window(trees, tvalid, j0, n):
+        # nodes ``[j0, j0 + n)`` of every tree from their ``2 n``
+        # children, a child pair two rows
+        def pairs(x):   # (2 n, K_cap) children -> (n, 2, K_cap)
+            return jax.lax.dynamic_slice_in_dim(x, 2 * j0, 2 * n).reshape(
+                n, 2, -1)
+        ch, cv = tmap(pairs, trees), pairs(tvalid)
+        node, nv = _parents(combine, tmap(lambda c: c[:, 0], ch),
+                            tmap(lambda c: c[:, 1], ch), cv[:, 0], cv[:, 1])
+        return (tmap(lambda t, nd: jax.lax.dynamic_update_slice_in_dim(
+            t, nd, j0, axis=0), trees, node),
+            jax.lax.dynamic_update_slice_in_dim(tvalid, nv, j0, axis=0))
+
+    def rebuild(trees, tvalid, ranges):
+        lvl, h = F >> 1, 1
+        while lvl >= 1:
+            if lvl <= W:
+                trees, tvalid = window(trees, tvalid, lvl, lvl)
+            else:
+                for r in range(ranges.shape[0]):
+                    # the window of the level's nodes that holds the
+                    # range's ancestors, ``lvl + (leaf >> h)``
+                    j0 = jnp.clip(lvl + (ranges[r, 0] >> h), lvl,
+                                  2 * lvl - W)
+                    trees, tvalid = window(trees, tvalid, j0, W)
+            lvl >>= 1
+            h += 1
+        return trees, tvalid
+
+    return rebuild
 
 
 class Ffat_Windows_TPU(TPUOperatorBase):
@@ -466,6 +578,18 @@ class FfatTPUReplica(TPUReplicaBase):
         # stale w.r.t. leaves (ingest-only batches ran since the last
         # rebuild); every fire path rebuilds first (see _make_step)
         self._rebuild_dirty = False
+        # what a time-based step's rebuild goes by (_plan_rebuild): the
+        # panes written (_dirty_in) and evicted (_dirty_ev) since the
+        # last rebuild, each ``(lo, hi)`` in absolute panes or None, and
+        # _dirty_full where the forest was rewritten wholesale (growth,
+        # first allocation, restore). Kept in PREP order, which is the
+        # device order of the programs (a batch's prep plans every
+        # program of the batch and the commits run them in that order),
+        # not in commit order as _rebuild_dirty is: a later batch's prep
+        # runs before an earlier commit lands. Invariant: a node with no
+        # leaf of these below it agrees with its leaves
+        self._dirty_in = self._dirty_ev = None
+        self._dirty_full = True
         self.ignored = 0
         # incremental checkpointing (WF_CKPT_DELTA): host-side dirty
         # slot set — ingest and fire mark the rows they touch, and a
@@ -482,8 +606,12 @@ class FfatTPUReplica(TPUReplicaBase):
         self._base_dirver = None  # ... as it stood at the last full one
         self._base_geom = None  # (K_cap, F, trees-allocated) at base
         # device forest (lazily shaped once the lift output is known)
-        self.trees = None  # dict field -> (K_cap, 2F)
-        self.tvalid = None  # (K_cap, 2F) bool
+        # node-major: row ``i`` is node ``i`` of every slot's tree (a
+        # level is a slice of rows; node ``i``'s children are rows 2i,
+        # 2i + 1, its leaves rows F..2F-1 by ``pane mod F``). A
+        # snapshot holds it slot-major, (K_cap, 2F)
+        self.trees = None  # dict field -> (2F, K_cap)
+        self.tvalid = None  # (2F, K_cap) bool
         self._prog_cache = op._prog_cache  # shared across replicas
         self._warm_shapes = op._warm_shapes
         # wf:fireplan: the host's fire planning inside wf:prep
@@ -651,11 +779,12 @@ class FfatTPUReplica(TPUReplicaBase):
             st = jax.lax.fori_loop(0, LOGQ, body, init)
             return comb_valid(st[2], st[3], st[4], st[5])
 
-        def window_query(tree_row, vrow, start_phys, length):
-            """One lane: logical ring range of one tree row -> <=2
-            physical ranges, combined in order."""
+        def window_query(trees, tvalid, slot, start_phys, length):
+            """One lane: logical ring range of its slot's tree -> <=2
+            physical ranges, combined in order (a node read is one
+            element of the forest)."""
             def node(i):
-                return vrow[i], tmap(lambda a: a[i], tree_row)
+                return tvalid[i, slot], tmap(lambda a: a[i, slot], trees)
 
             len1 = jnp.minimum(length, F - start_phys)
             v1, r1 = range_query(node, start_phys, len1)
@@ -664,15 +793,16 @@ class FfatTPUReplica(TPUReplicaBase):
             return comb_valid(v1, r1, v2, r2)
 
         def group_query(trees, tvalid, start_phys, length):
-            """One ring range (scalars) of EVERY tree row: the lane's
-            walk with column slices for node reads. The second walk of a
-            range that does not wrap the ring is skipped, not walked
-            masked (it could only add to an invalid answer)."""
-            def column(t, i):
-                return jax.lax.dynamic_slice_in_dim(t, i, 1, axis=1)[:, 0]
+            """One ring range (scalars) of EVERY tree: the lane's walk
+            with rows of the node-major forest for node reads. The
+            second walk of a range that does not wrap the ring is
+            skipped, not walked masked (it could only add to an invalid
+            answer)."""
+            def row(t, i):
+                return jax.lax.dynamic_slice_in_dim(t, i, 1)[0]
 
             def node(i):
-                return column(tvalid, i), tmap(lambda t: column(t, i), trees)
+                return row(tvalid, i), tmap(lambda t: row(t, i), trees)
 
             len1 = jnp.minimum(length, F - start_phys)
             v1, r1 = range_query(node, start_phys, len1)
@@ -683,8 +813,8 @@ class FfatTPUReplica(TPUReplicaBase):
                 lambda: (v1, r1))
 
         def by_lane(trees, tvalid, slots, starts, lens):
-            ftrees = tmap(lambda t: t[slots], trees)
-            return jax.vmap(window_query)(ftrees, tvalid[slots], starts, lens)
+            return jax.vmap(window_query, in_axes=(None, None, 0, 0, 0))(
+                trees, tvalid, slots, starts, lens)
 
         def by_group(trees, tvalid, slots, group, g_table):
             def one(g, tabs):
@@ -701,15 +831,16 @@ class FfatTPUReplica(TPUReplicaBase):
 
         def by_scan(trees, tvalid, slots, rounds, starts, mask):
             # 1. every ring in the order of its chunk: column ``o`` of
-            # row ``slot`` is leaf ``start0 + o``. ``base`` is the
+            # row ``slot`` is leaf ``start0 + o`` (the leaves turned
+            # slot-major: the scan runs along a ring). ``base`` is the
             # chunk's first window's place in the ring, from its lane of
             # round 0 (0 for a slot that fires nothing here); the
             # rotation is log2 F conditional rolls, dense passes
             first = mask & (rounds == 0)
             base = jnp.zeros((K_cap,), jnp.int32).at[
                 jnp.where(first, slots, K_cap)].set(starts, mode="drop")
-            rows = tmap(lambda t: t[:, F:], trees)
-            rvalid = tvalid[:, F:]
+            rows = tmap(lambda t: t[F:].T, trees)
+            rvalid = tvalid[F:].T
             bit = 1
             while bit < F:
                 turn = ((base & bit) != 0)[:, None]
@@ -811,17 +942,26 @@ class FfatTPUReplica(TPUReplicaBase):
                    ingest_only: bool = False, W: Optional[int] = None):
         """``W``: the width of the program's fire block (_query_fns).
 
+        The full program rebuilds the internal levels before its fire
+        block. A time-based step over a ring that rebuilds by ranges
+        (``rebuilds_by_ranges``) chooses, by the dirty ring ranges its
+        plan carries (``RANGE_ROWS``: the panes written and evicted since
+        the last rebuild, kept by the host in device order), between the
+        rebuild of their ancestors alone (``rebuild_levels_by_ranges``)
+        and the full rebuild, where a range is wider than the window,
+        wraps the ring or is marked full (``rebuild_fits``); every other
+        step rebuilds the whole forest.
+
         ``ingest_only=True`` builds the DEFERRED-REBUILD variant: lift
         + segmented scan + leaf scatter only — no level rebuild, no
         window queries, no eviction. Used for batches the host control
         plane already knows fire NOTHING (chunks empty): leaves stay
-        current and the next firing program's full-forest rebuild covers
-        every deferred batch at once, so the per-batch rebuild cost —
-        independent of batch size, hence the dominant term of the
-        low-cardinality small-batch regime — is paid per FIRING batch
-        only. Soundness: internal nodes are only ever read by fire
-        queries, and every fire path rebuilds first (the full program
-        in-program; the dataless path via _ensure_rebuilt)."""
+        current and the next firing program's rebuild covers every
+        deferred batch at once (the panes they wrote widen the dirty
+        range it goes by), so the per-batch rebuild cost is paid per
+        FIRING batch only. Soundness: internal nodes are only ever read
+        by fire queries, and every fire path rebuilds first (the full
+        program in-program; the dataless path via _ensure_rebuilt)."""
         import jax
         import jax.numpy as jnp
 
@@ -837,6 +977,8 @@ class FfatTPUReplica(TPUReplicaBase):
         rebuild_levels = xla_rebuild_levels(combine, F)
         counted = self.op.win_type is WinType.CB
         key_words = self._key_words()
+        by_ranges = not counted and rebuilds_by_ranges(F)
+        rebuild_part = rebuild_levels_by_ranges(combine, F)
 
         def step(fields, comp, trees, tvalid, fire_plan):
             # 1. lift + sort + segmented scan. The host ships ONE
@@ -865,7 +1007,7 @@ class FfatTPUReplica(TPUReplicaBase):
                      jnp.ones((1,), bool)]) & (sc < big)
                 # decode slot/leaf from the sorted composite (F is a
                 # power of two, so these lower to shift/mask)
-                flat_idx = (sc // F) * NNODES + (F + sc % F)
+                flat_idx = (F + sc % F) * K_cap + sc // F
             svals = tmap(lambda a: a[order], vals)
 
             def seg_op(a, b):
@@ -907,9 +1049,18 @@ class FfatTPUReplica(TPUReplicaBase):
                         jnp.zeros((1,), jnp.int32),
                         jnp.zeros((1,), jnp.int32))
 
-            # 3. rebuild internal levels across the whole forest
+            # 3. rebuild the internal levels: over the dirty ranges'
+            # ancestors where they fit the window, else the whole forest
             with jax.named_scope(SCOPE_REBUILD):
-                trees, tvalid = rebuild_levels(trees, tvalid)
+                if by_ranges:
+                    ranges = plan_views(fire_plan, K_cap, True,
+                                        key_words)[0][RANGE_ROWS]
+                    trees, tvalid = jax.lax.cond(
+                        rebuild_fits(ranges, F), rebuild_part,
+                        lambda t, v, _r: rebuild_levels(t, v),
+                        trees, tvalid, ranges)
+                else:
+                    trees, tvalid = rebuild_levels(trees, tvalid)
 
             # 4.-6. fired-window queries, eviction of the leaves they
             # consumed, output wid/key columns (_query_fns)
@@ -936,8 +1087,14 @@ class FfatTPUReplica(TPUReplicaBase):
         first per-batch step and for data-less firing (punctuation/EOS).
 
         Soundness of skipping the level rebuild: internal nodes are stale
-        only where leaves were evicted after the last rebuild, and those
-        panes satisfy p_evicted >= next_fire_at_rebuild. Every queried
+        only where leaves were evicted after the last rebuild (a program
+        runs after a step, or after _ensure_rebuilt, so no pane written
+        since is unrebuilt: the replica's dirty written range is empty
+        here, its dirty EVICTED range, ``_dirty_ev``, is what is stale),
+        and those panes satisfy p_evicted >= next_fire_at_rebuild. The
+        last rebuild may have been partial: it left every node agreeing
+        with its leaves all the same (rebuild_levels_by_ranges), since
+        a node over no dirty pane agrees already. Every queried
         pane satisfies p <= max_leaf < next_fire_at_rebuild + F (the
         _grow_ring span guard enforces this at arrival), so an evicted
         pane's ring slot can only be re-queried at pane p_evicted + F >
@@ -995,8 +1152,53 @@ class FfatTPUReplica(TPUReplicaBase):
                               self._make_rebuild_step)
         self.trees, self.tvalid = prog(self.trees, self.tvalid)
         self.stats.device_programs_run += 1
+        self.stats.rebuild_programs += 1
         self._rebuild_dirty = False
+        self._rebuilt()
         self._dirty_all = True  # rebuild rewrote internal rows forest-wide
+
+    def _rebuilt(self) -> None:
+        """A rebuild was planned (a step's, in prep order) or has run
+        (the standalone one, after a drain): no node is stale."""
+        self._dirty_in = self._dirty_ev = None
+        self._dirty_full = False
+
+    @staticmethod
+    def _widen(rng, lo: int, hi: int):
+        return (lo, hi) if rng is None else (min(rng[0], lo), max(rng[1], hi))
+
+    def _note_evicted(self, chunks) -> None:
+        """Widen the evicted range by a program's chunks: a chunk evicts
+        from its first window's start on, ``k`` slides at most (as far as
+        its data goes; a wider range is only more to rebuild)."""
+        if self.op.win_type is WinType.TB:
+            _slots, start0, k, _wid0, _ml = chunks
+            self._dirty_ev = self._widen(
+                self._dirty_ev, int(start0.min()),
+                int((start0 + k * self.slide_units).max()) - 1)
+
+    def _plan_rebuild(self, pack) -> None:
+        """The rebuild of a batch's step, planned in prep: a time-based
+        plan carries the dirty ranges (``RANGE_ROWS``: the physical
+        ``(start, length)`` of each, length ``F`` where the whole forest
+        is dirty, ``(0, 0)`` where nothing is), and the ranges start
+        anew, since the step rebuilds before it fires. Counts the
+        rebuild, and whether the step goes by the ranges."""
+        st = self.stats
+        st.rebuild_programs += 1
+        if self.op.win_type is WinType.TB:
+            F = self.F
+            ranges = plan_views(pack, self.K_cap, True,
+                                self._key_words())[0][RANGE_ROWS]
+            for row, rng in zip(ranges, (self._dirty_in, self._dirty_ev)):
+                if self._dirty_full or (rng is not None
+                                        and rng[1] - rng[0] >= F):
+                    row[:] = (0, F)
+                elif rng is not None:
+                    row[:] = (rng[0] & (F - 1), rng[1] - rng[0] + 1)
+            if rebuilds_by_ranges(F) and rebuild_fits(ranges, F):
+                st.rebuild_partial_programs += 1
+        self._rebuilt()
 
     # ==================================================================
     # host control plane
@@ -1126,16 +1328,17 @@ class FfatTPUReplica(TPUReplicaBase):
         new_trees = new_tvalid = None
         if self.trees is not None:
             new_trees = jax.tree_util.tree_map(
-                lambda t: jnp.zeros((new_cap,) + t.shape[1:], t.dtype)
-                .at[:old].set(t), self.trees)
-            new_tvalid = jnp.zeros((new_cap, 2 * self.F), bool
-                                   ).at[:old].set(self.tvalid)
+                lambda t: jnp.zeros((2 * self.F, new_cap), t.dtype)
+                .at[:, :old].set(t), self.trees)
+            new_tvalid = jnp.zeros((2 * self.F, new_cap), bool
+                                   ).at[:, :old].set(self.tvalid)
         self.K_cap = new_cap
         for name, g in grown.items():
             setattr(self, name, g)
         if new_trees is not None:
             self.trees, self.tvalid = new_trees, new_tvalid
         self._dirty_all = True  # geometry changed under the delta base
+        self._dirty_full = True
         self.stats.key_capacity_growths += 1
 
     def _grow_ring(self, needed_span: int) -> None:
@@ -1159,8 +1362,8 @@ class FfatTPUReplica(TPUReplicaBase):
             return
         old_trees, old_valid = self.trees, self.tvalid
         new_trees = jax.tree_util.tree_map(
-            lambda t: jnp.zeros((self.K_cap, 2 * new_F), t.dtype), old_trees)
-        new_tvalid = jnp.zeros((self.K_cap, 2 * new_F), bool)
+            lambda t: jnp.zeros((2 * new_F, self.K_cap), t.dtype), old_trees)
+        new_tvalid = jnp.zeros((2 * new_F, self.K_cap), bool)
         src_rows, src_cols, dst_cols = [], [], []
         for _, s in self.slot_of_key.items():
             for p in range(int(self.next_fire[s]), int(self.max_leaf[s]) + 1):
@@ -1171,14 +1374,15 @@ class FfatTPUReplica(TPUReplicaBase):
             sr, sc, dc = (np.asarray(src_rows), np.asarray(src_cols),
                           np.asarray(dst_cols))
             new_trees = jax.tree_util.tree_map(
-                lambda new, old: new.at[sr, dc].set(old[sr, sc]),
+                lambda new, old: new.at[dc, sr].set(old[sc, sr]),
                 new_trees, old_trees)
-            new_tvalid = new_tvalid.at[sr, dc].set(old_valid[sr, sc])
+            new_tvalid = new_tvalid.at[dc, sr].set(old_valid[sc, sr])
         self.F = new_F
         self.trees, self.tvalid = new_trees, new_tvalid
         # only leaves were carried over: internal levels need a rebuild
         # before any fire-only program may query them
         self._rebuild_dirty = True
+        self._dirty_full = True
         self._dirty_all = True  # geometry changed under the delta base
 
     def _ensure_forest(self, sample_fields) -> None:
@@ -1190,9 +1394,10 @@ class FfatTPUReplica(TPUReplicaBase):
         if not isinstance(shapes, dict):
             raise WindFlowError(f"{self.op.name}: lift must return a dict "
                                 "of columns")
-        self.trees = {name: jnp.zeros((self.K_cap, 2 * self.F), sh.dtype)
+        self.trees = {name: jnp.zeros((2 * self.F, self.K_cap), sh.dtype)
                       for name, sh in shapes.items()}
-        self.tvalid = jnp.zeros((self.K_cap, 2 * self.F), bool)
+        self.tvalid = jnp.zeros((2 * self.F, self.K_cap), bool)
+        self._dirty_full = True
 
     # ------------------------------------------------------------------
     def prep_device_batch(self, batch: BatchTPU):
@@ -1321,7 +1526,8 @@ class FfatTPUReplica(TPUReplicaBase):
                 if span >= self.F:
                     self._grow_ring(span)
                 self.max_leaf[slots] = leaves
-                self._leaf_frontier = int(leaves[-1])
+                lo = int(leaves[0])
+                hi = self._leaf_frontier = int(leaves[-1])
             else:
                 # masked forms avoid boolean fancy-index allocations; the
                 # -1 sentinel is a no-op under maximum (max_leaf starts
@@ -1331,8 +1537,12 @@ class FfatTPUReplica(TPUReplicaBase):
                 if span >= self.F:
                     self._grow_ring(span)
                 np.maximum.at(self.max_leaf, slots, masked_leaves)
-                self._leaf_frontier = max(self._leaf_frontier,
-                                          int(masked_leaves.max()))
+                hi = int(masked_leaves.max())
+                self._leaf_frontier = max(self._leaf_frontier, hi)
+                lo = int(np.where(live, leaves, hi).min())
+            # the panes this batch writes: its step's rebuild, or the
+            # next one's where it fires nothing, goes by them
+            self._dirty_in = self._widen(self._dirty_in, lo, hi)
         if born is not None and n_late:
             # a key none of whose rows is live (all behind the floor of
             # a new key's first window, or in a gap between windows)
@@ -1848,8 +2058,13 @@ class FfatTPUReplica(TPUReplicaBase):
             # mid-stream compile
             warm()
         with self._st_fireplan(bid):
-            plan = [(i == 0,) + prog for i, prog in enumerate(
-                self._programs(frontier, False, self._first_budget(), warm))]
+            plan = []
+            for prog in self._programs(frontier, False,
+                                       self._first_budget(), warm):
+                if not plan:     # the step: it rebuilds, then it fires
+                    self._plan_rebuild(prog[2])
+                self._note_evicted(prog[0])
+                plan.append((not plan,) + prog)
         if not self._by_plan:
             # fast-rise / slow-decay: a burst switches to the wide tier
             # on the very next batch (both tier shapes are already
@@ -1970,7 +2185,8 @@ class FfatTPUReplica(TPUReplicaBase):
                  key_dev) = self._prog_cache[ckey + (budget,)](
                     fields, comp_p, self.trees, self.tvalid, pack)
                 self._rebuild_dirty = False  # in-program rebuild covers
-                # every deferred ingest-only batch (full-forest rebuild)
+                # every deferred ingest-only batch (the panes they wrote
+                # are in the dirty range its plan carries: _plan_rebuild)
                 self._dirty_all = True  # ... and rewrote internal rows
             else:
                 # drain iterations: fire-only program (no rebuild)
@@ -2072,6 +2288,7 @@ class FfatTPUReplica(TPUReplicaBase):
         for chunks, n_out, pack, n_groups, W, keys, owed in self._programs(
                 frontier, partial, self.W_cap, self._warm_fire_step):
             self._ensure_rebuilt()
+            self._note_evicted(chunks)
             self.tvalid, qr, qv, wid_dev, key_dev = self._fire_step(W)(
                 self.trees, self.tvalid, pack)
             self.stats.device_programs_run += 1
@@ -2126,10 +2343,14 @@ class FfatTPUReplica(TPUReplicaBase):
             "fire_ewma": self._fire_ewma,
             "rebuild_dirty": self._rebuild_dirty,
             "ignored": self.ignored,
-            "trees": (None if self.trees is None
-                      else jax.device_get(self.trees)),
+            # slot-major, as a snapshot holds the forest
+            "trees": (None if self.trees is None else
+                      jax.tree_util.tree_map(
+                          lambda v: np.ascontiguousarray(np.asarray(v).T),
+                          jax.device_get(self.trees))),
             "tvalid": (None if self.tvalid is None
-                       else np.asarray(jax.device_get(self.tvalid))),
+                       else np.ascontiguousarray(
+                           np.asarray(jax.device_get(self.tvalid)).T)),
         }
         if ctx is not None and ckpt_delta.env_ckpt_delta():
             # this full capture is the new delta baseline (capture runs
@@ -2161,9 +2382,9 @@ class FfatTPUReplica(TPUReplicaBase):
         jsl = jnp.asarray(sl)
         leaves, _ = jax.tree_util.tree_flatten(self.trees)
         rows["trees"] = {"slots": sl, "leaves": [
-            np.asarray(jax.device_get(lf[jsl])) for lf in leaves]}
+            np.asarray(jax.device_get(lf[:, jsl].T)) for lf in leaves]}
         rows["tvalid"] = {"slots": sl, "leaves": [
-            np.asarray(jax.device_get(self.tvalid[jsl]))]}
+            np.asarray(jax.device_get(self.tvalid[:, jsl].T))]}
         repl = {"K_cap": self.K_cap, "F": self.F,
                 "keys_all_int": self._keys_all_int,
                 "key_dtype": self._key_dtype,
@@ -2233,10 +2454,16 @@ class FfatTPUReplica(TPUReplicaBase):
         self._leaf_frontier = d["leaf_frontier"]
         self._fire_ewma = d["fire_ewma"]
         self._rebuild_dirty = d["rebuild_dirty"]
+        # the dirty ranges are no part of a snapshot: the next step
+        # rebuilds the whole forest
+        self._dirty_in = self._dirty_ev = None
+        self._dirty_full = True
         self.ignored = d["ignored"]
+        # a snapshot holds the forest slot-major: node-major again
         self.trees = (None if d["trees"] is None else
-                      jax.tree_util.tree_map(jnp.asarray, d["trees"]))
+                      jax.tree_util.tree_map(lambda v: jnp.asarray(v.T),
+                                             d["trees"]))
         self.tvalid = (None if d["tvalid"] is None
-                       else jnp.asarray(d["tvalid"]))
+                       else jnp.asarray(np.asarray(d["tvalid"]).T))
         # device-side caches are stale for the restored geometry
         self._zero_fire_cache = {}
