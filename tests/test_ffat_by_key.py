@@ -295,7 +295,7 @@ def test_rows_a_fused_prefix_filter_drops_take_no_leaf_and_no_count():
 def expanded(rep, chunks, W):
     import jax
     pack, n_groups = rep._pack_fire_arrays(
-        chunks, W, rep._chunk_keys(chunks[0]), rep._ranges_of(chunks))
+        chunks, W, rep._chunk_keys(chunks[0]), None)
     assert n_groups == 0 and pack.dtype == np.int32
     assert pack.size == plan_len(W, rep.K_cap, False, 1) == rep._plan_len(W)
     out = jax.jit(plan_lanes, static_argnums=range(1, 8))(
@@ -304,7 +304,7 @@ def expanded(rep, chunks, W):
 
 
 @pytest.mark.parametrize("win,slide", [(8, 1), (10, 3), (8, 8), (3, 5)])
-@pytest.mark.parametrize("W", [64, 16])     # the two tiers of a budget
+@pytest.mark.parametrize("W", [64, 16])     # two widths of a program
 @pytest.mark.parametrize("partial", [False, True],
                          ids=["complete", "flush_partial"])
 def test_lanes_expanded_from_chunk_rows_equal_the_parents_lanes(
@@ -449,8 +449,10 @@ def test_both_window_types_take_the_same_arguments_and_plan_by_chunk():
     assert tb._comp_dtype() == (tb.K_cap * tb.F, np.int16)
     assert tb._plan_len(64) == plan_len(64, tb.K_cap, True, 1) \
         == 1 + 70 + 6 * tb.K_cap
+    pairs = np.unique(tb._range_words(chunks[1], chunks[2],
+                                      chunks[4] + 1)[0])
     pack, n = tb._pack_fire_arrays(chunks, 64, tb._chunk_keys(chunks[0]),
-                                   tb._ranges_of(chunks))
+                                   pairs)
     groups, rows, total = plan_views(pack, tb.K_cap, True, 1)
     assert pack.size == tb._plan_len(64) and total[0] == 6
     assert n == 3 == groups[32, 0]          # three rounds, three ranges
@@ -462,7 +464,7 @@ def test_both_window_types_take_the_same_arguments_and_plan_by_chunk():
         == 1 + 2 * cb.K_cap + 6 * cb.K_cap
     assert plan_len(2, cb.K_cap, False, 1) == 1 + 2 * cb.K_cap + 6 * 2
     pack, n = cb._pack_fire_arrays(chunks, 64, cb._chunk_keys(chunks[0]),
-                                   cb._ranges_of(chunks))
+                                   None)
     keyrows, rows, total = plan_views(pack, cb.K_cap, False, 1)
     assert n == 0 and total[0] == 6 and not keyrows.any()
     assert rows[:, :2].tolist() == [[0, 1], [8, 8], [3, 3], [4, 4],

@@ -3,13 +3,16 @@ walk per distinct ring range over every key slot at once, against the
 lane walk (one walk a fired window). The replica is driven directly, on
 the CPU backend; the lane walk is forced from the test's side by blanking
 the group table in the plan that ``_pack_fire_arrays`` hands the
-programs, which is exactly what the planner does for a program with more
-than ``G_CAP`` distinct ranges where a budget was given. From ``(l)`` on: an
-operator with no budget given (time-based windows) sizes the width of
-its fire programs by its plans (``_programs_by_plan``).
+programs, which is exactly what the planner does for a program whose
+first round alone holds more than ``G_CAP`` distinct ranges. One planner
+serves every operator (``_programs``): a budget given caps the width of
+its programs, and from ``(l)`` on an operator with no budget given
+(time-based windows) sizes that width by its plans (``_fit_width``).
 
 Counters pinned "at the parent" were read on commit 20733da (PR 29),
 the last before the width by the plan."""
+
+import itertools
 
 import numpy as np
 import pytest
@@ -212,25 +215,31 @@ def test_grouped_equals_lane_walk(case):
 
 
 def test_more_ranges_than_the_table_holds_take_the_lane_walk():
-    """(g) a budget GIVEN, two keys and ``4 * G_CAP`` lanes: the flush's
-    rounds give a program ``2 * G_CAP`` slides of each key, more distinct
-    ranges than ``G_CAP``: the planner of a given budget does not cut,
-    it blanks the table, and the program walks by lane at its width."""
-    n = 5 * G_CAP
+    """(g) a budget GIVEN, more keys than ``G_CAP``, each silent from a
+    pane of its own on, so that no two share a (clipped) range in any
+    round: the first round alone holds more ranges than the table, no
+    whole round can be cut, and every program walks by lane at the
+    budget's width. (Where fewer keys share the ranges of a round, a plan
+    over the table is cut at a whole round and stays by range: (n).)"""
+    # two rounds of every key a program: all of its keys in each
+    n_keys, win = G_CAP + 8, 100                        # F = 128
+    budget = 2 * n_keys
 
     def stream():
-        bs = aligned_stream(2, n, n, np.random.default_rng(3))
-        bs[0].wm = 0
-        return bs
+        rng = np.random.default_rng(3)
+        last = 40 + np.arange(n_keys)       # key k is silent after this
+        ks = np.repeat(np.arange(n_keys), last + 1)
+        ps = np.concatenate([np.arange(n + 1) for n in last])
+        # the watermark closes windows 0..30 of every key: 31 rounds
+        return [batch(ks, ps, rng.random(len(ks)) * 100, win + 30)]
 
-    grouped, lane, got, want = both(stream, budget=4 * G_CAP, keys=2, win=8)
-    assert got == want and len(got) == 2 * n
+    grouped, lane, got, want = both(stream, flush=False, budget=budget,
+                                    keys=n_keys, win=win)
+    assert got == want and len(got) == 31 * n_keys
     st = grouped.stats
-    # twice 4 * G_CAP lanes by lane, then G_CAP slides of each key: G_CAP
-    # ranges, by range
     assert (st.fire_programs, st.fire_grouped_programs, st.fire_groups,
-            st.fire_range_cuts) == (3, 1, G_CAP, 0)
-    assert set(grouped.emitter.widths) == {4 * G_CAP}
+            st.fire_range_cuts) == (16, 0, 0, 0)
+    assert set(grouped.emitter.widths) == {budget}
 
 
 def test_count_based_windows_never_take_the_grouped_query(monkeypatch):
@@ -275,15 +284,9 @@ def test_snapshot_and_restore_between_two_programs_of_a_plan():
 
     cut = make_replica(budget=4)
     run(cut, stream(), flush=False)
-    fireable, calls = cut._fireable, []
-
-    def once(frontier, partial, budget):
-        calls.append(budget)
-        if len(calls) > 1:
-            return (np.zeros(0, np.int64),) * 5
-        return fireable(frontier, partial, budget)
-
-    cut._fireable = once
+    programs = cut._programs
+    # the first program only: the rest of the plan is never taken
+    cut._programs = lambda *a: itertools.islice(programs(*a), 1)
     punctuate(cut, 7)
     assert len(cut.emitter.rows) == 4    # one round of the plan is out
     state = cut.snapshot_state()
@@ -303,13 +306,15 @@ def test_snapshot_and_restore_between_two_programs_of_a_plan():
 
 @pytest.mark.parametrize("budget", [1, 3, 5, 8, 13, 100])
 def test_fireable_by_rounds(budget):
-    """(j) the plan by rounds: a slot's windows leave in ``wid`` order,
+    """(j) the plan by rounds (``_programs`` of a replica with a budget
+    given): a slot's windows leave in ``wid`` order,
     no program over its budget and none short while windows remain, a
     program holds at most two rounds, and once all rounds are out
     ``next_fire`` and ``fired`` stand where the slot-order plan leaves
     them."""
     eligible = np.array([3, 0, 7, 1, 4, 4, 0, 2])
     rep = make_replica(budget=budget, keys=8, win=2, slide=1)
+    assert rep.W_wide == rep.W_cap == budget
     for k in range(8):
         rep._keymap.slot(k)
     fired0 = np.arange(8) * 10
@@ -320,8 +325,9 @@ def test_fireable_by_rounds(budget):
     rep.max_leaf[:8] = np.where(eligible > 0, fired0 + eligible - 1, -1)
     seen = {k: [] for k in range(8)}
     left = eligible.copy()
-    while left.sum():
-        slots, start0, k, wid0, _ml = rep._fireable(None, True, budget)
+    for prog in rep._programs(None, True, lambda: None):
+        slots, start0, k, wid0, _ml = prog[0]
+        assert prog[4] == budget
         assert k.sum() == min(budget, left.sum()) and (k > 0).all()
         assert (np.diff(slots) > 0).all()
         rounds = k.max()
@@ -334,44 +340,79 @@ def test_fireable_by_rounds(budget):
             assert st == w0
             seen[int(s)] += list(range(int(w0), int(w0 + n)))
         left[slots] -= k
-    assert rep._fireable(None, True, budget)[0].size == 0
+    assert left.sum() == 0
+    assert not list(rep._programs(None, True, lambda: None))
     for s in range(8):
         assert seen[s] == list(range(fired0[s], fired0[s] + eligible[s]))
     assert (rep.fired[:8] == fired0 + eligible).all()
     assert (rep.next_fire[:8] == fired0 + eligible).all()
 
 
+def padded(keys, panes, wm_pane, rng, cap=2048):
+    """``batch`` at a capacity of ``cap`` rows, so that batches of any
+    size are one compiled shape."""
+    import jax
+    n = len(keys)
+    k = np.zeros(cap, np.int64)
+    k[:n] = keys
+    ts = np.zeros(cap, np.int64)
+    ts[:n] = np.asarray(panes, np.int64) * PANE + 5
+    v = np.zeros(cap, np.float32)
+    v[:n] = rng.random(n) * 100
+    b = BatchTPU({"key": jax.device_put(k.astype(np.int32)),
+                  "v": jax.device_put(v)}, ts, n, SCHEMA, wm=0,
+                 host_keys=k[:n])
+    b.wm = wm_pane * PANE
+    return b
+
+
 def test_counters_in_get_stats_and_no_compile_when_the_query_switches():
     """(k) ``Fire_grouped_programs`` and ``Fire_groups`` beside
     ``Fire_programs``; a stream (budget given) whose programs go by
     range, then by lane, then by range again compiles nothing after its
-    first batch."""
-    parked = G_CAP // 4          # batches of four panes the watermark sits out
-    rep = make_replica(budget=2 * G_CAP, keys=2, win=20)    # F = 64
-    bs = aligned_stream(1, 4 * (parked + 16), 4, np.random.default_rng(2))
+    first batch. By range: eight keys in step. By lane: 32 more keys,
+    each admitted at a window of its own, so that a round holds 33
+    ranges. By range: all 40 in step once those have fired."""
+    rng = np.random.default_rng(2)
+    rep = make_replica(budget=2 * G_CAP, keys=G_CAP + 8, win=20)  # F = 64
+    old = np.arange(8)
+
+    def panes_of(keys, lo, hi):
+        ks = np.repeat(keys, hi - lo)
+        return ks, np.tile(np.arange(lo, hi), len(keys))
+
+    bs = [padded(*panes_of(old, p, p + 4), p + 4, rng)
+          for p in range(0, 24, 4)]
+    # key k (8..39) from pane k + 17 on: its first window k - 2
+    new = np.arange(8, G_CAP + 8)
+    ks, ps = panes_of(old, 24, 64)
+    ks = np.concatenate([ks] + [np.full(47 - k, k) for k in new])
+    ps = np.concatenate([ps] + [np.arange(k + 17, 64) for k in new])
+    bs.append(padded(ks, ps, 64, rng))
+    every = np.arange(G_CAP + 8)
+    bs += [padded(*panes_of(every, p, p + 4), p + 4, rng)
+           for p in range(64, 80, 4)]
     rep.handle_msg(0, bs[0])
     compiled = rep.stats.compile_count
     assert compiled > 0
-    for b in bs[1:10]:                    # four windows a batch
+    for b in bs[1:6]:
         rep.handle_msg(0, b)
     rep.dispatch.drain(forced=True)       # commits are deferred
     by_range = rep.stats.fire_grouped_programs
     assert by_range == rep.stats.fire_programs > 0
-    for b in bs[10:10 + parked]:          # parked: nothing fires
-        b.wm = bs[9].wm
-        rep.handle_msg(0, b)
-    rep.handle_msg(0, bs[10 + parked])    # G_CAP + 4 slides at once
+    rep.handle_msg(0, bs[6])              # 33 ranges in the first round
     rep.dispatch.drain(forced=True)
-    by_lane = rep.stats.fire_programs - by_range
-    assert by_lane == 1 and rep.stats.fire_grouped_programs == by_range
-    for b in bs[11 + parked:]:
+    by_lane = rep.stats.fire_programs - rep.stats.fire_grouped_programs
+    assert by_lane > 0
+    for b in bs[7:]:
         rep.handle_msg(0, b)
     rep.flush_on_termination()
     st = rep.stats.to_dict()
     assert st["Fire_programs"] - by_lane == st["Fire_grouped_programs"] \
         > by_range
     assert st["Fire_groups"] > st["Fire_grouped_programs"]
-    assert st["Fire_range_cuts"] == 0     # a budget given is never cut
+    assert st["Windows_fired"] == 8 * 80 + sum(80 - (k - 2) for k in new)
+    assert set(rep.emitter.widths) == {2 * G_CAP}
     assert st["Compile_count"] == compiled
 
 
@@ -448,7 +489,7 @@ def test_a_batchs_whole_plan_leaves_in_one_program(case):
     reps = [make_replica(lane_only=lane, budget=None, **spec["kw"])
             for lane in (False, True)]
     rep, lane = reps
-    assert rep._by_plan and rep.W_wide == rep.W_cap == max(16, n_keys)
+    assert rep.W_wide == rep.W_cap == max(16, n_keys)
     per_batch_programs = fed(rep, stream())
     rep.flush_on_termination()
     got = rep.emitter.rows
@@ -570,7 +611,7 @@ def test_the_width_grows_with_the_plan_and_compiles_nothing_after():
     rep.flush_on_termination()
     assert rep.stats.compile_count == compiled[2]
     assert rep.W_wide == 320 == bs[0].capacity
-    # step at two widths today (the narrow one and W_wide), like the tiers
+    # the step at W_cap and at every W_wide it grew to
     assert sorted(W for key, W in rep._warm_shapes
                   if key[0] == "step") == [40, 256, 320]
 
@@ -623,10 +664,11 @@ def test_snapshot_and_restore_between_two_programs_of_a_wide_plan():
         == (2 * n - 2 * G_CAP) // 16
 
 
-# (r) who keeps the parent's path: (Fire_programs, Fire_grouped_programs,
-# Fire_groups, Windows_fired, Device_programs_run, Compile_count) and the
-# widths of the programs as commit 20733da (PR 29) reads them on these
-# streams
+# (r) a given budget and count-based windows on the one planner:
+# (Fire_programs, Fire_grouped_programs, Fire_groups, Windows_fired,
+# Device_programs_run, Compile_count) and the widths of the programs as
+# commit 20733da reads them on these streams, where its budget given had
+# one width
 AS_AT_THE_PARENT = {
     "time_based_budget_given": dict(
         kw=dict(budget=8, keys=4, win=6), widths={8},
@@ -637,11 +679,12 @@ AS_AT_THE_PARENT = {
     "count_based_no_budget": dict(
         kw=dict(budget=None, keys=4, win=6, slide=2, win_type=WinType.CB),
         widths={16}, counters=(6, 0, 0, 48, 7, 4)),
-    # 96 windows a batch: the EWMA moves the step from the small tier to
-    # the budget's
+    # 96 windows a batch, a budget of 96: every program 96 lanes wide
+    # (commit 20733da ran its first firing step 64 lanes wide and read
+    # (8, 8, 27, 576, 14, 5): the same 576 rows)
     "time_based_two_tiers": dict(
-        kw=dict(budget=96, keys=24, win=6), widths={64, 96},
-        counters=(8, 8, 27, 576, 14, 5)),
+        kw=dict(budget=96, keys=24, win=6), widths={96},
+        counters=(7, 7, 24, 576, 13, 4)),
 }
 
 
@@ -655,8 +698,7 @@ def test_a_given_budget_and_count_based_windows_plan_as_at_the_parent(case):
             b.wm = bs[i - i % 3].wm       # the watermark moves in steps
     run(rep, bs)
     st = rep.stats
-    assert not rep._by_plan
-    assert rep.W_wide == rep.W_cap and rep.W_step == min(rep.W_cap, 64)
+    assert rep.W_wide == rep.W_cap
     assert set(rep.emitter.widths) == spec["widths"]
     assert (st.fire_programs, st.fire_grouped_programs, st.fire_groups,
             st.windows_fired, st.device_programs_run,

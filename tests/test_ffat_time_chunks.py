@@ -6,7 +6,7 @@ program expands its own lanes, group indices and evicted leaves
 the parent commit built on the host, by lane (``_lanes`` + ``_pack_plan``,
 kept below as the plain reference), for every way a time-based operator
 plans: by range in one round and in many, a ``G_CAP`` cut, a ragged plan
-on the lane walk, the tiers of a budget given, gap windows and the
+on the lane walk, a budget given, gap windows and the
 end-of-stream flush. The replica is driven directly, on the CPU backend,
 as ``test_ffat_grouped_fire.py`` drives it."""
 
@@ -172,7 +172,8 @@ PLANS = {
     "ragged_on_the_lane_walk": dict(
         win=30, slide=1, w0=200 + np.arange(N), span=np.ones(N, np.int64),
         frontier=200 + N + 30),
-    # ysb: a budget given, two tiers, the rounds split over programs
+    # ysb: a budget given, a cap on the width: the rounds split over
+    # programs
     "tiers_of_a_budget": dict(
         win=4, slide=4, w0=np.full(N, 30), span=np.full(N, 3),
         frontier=40, budget=16),
@@ -197,8 +198,7 @@ def planned(case, parent):
     rep.next_fire[slots], rep.fired[slots] = nf, c["w0"]
     rep.max_leaf[slots] = nf + np.asarray(c["span"]) - 1
     partial = c["frontier"] is None
-    progs = list(rep._programs(c["frontier"], partial, rep._first_budget(),
-                               lambda: None))
+    progs = list(rep._programs(c["frontier"], partial, lambda: None))
     return rep, progs
 
 

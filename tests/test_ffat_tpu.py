@@ -399,24 +399,15 @@ def test_ffat_tpu_gap_windows_late_first_key_reanchor():
 
 @pytest.mark.parametrize("win,slide,count_based", [
     (WIN_US, SLIDE_US, False), (WIN_CB, SLIDE_CB, True)])
-def test_ffat_tpu_adaptive_fire_tiers(win, slide, count_based, monkeypatch):
-    """Exercise the adaptive two-tier fire budget (W_cap > W_step) that
-    a GIVEN ``num_win_per_batch`` keeps, for time-based windows too
-    (without one they size their width by the plan,
-    tests/test_ffat_grouped_fire.py): a stream firing more than W_step
-    windows per batch must switch to the wide tier, warm both program
-    shapes eagerly (no compile after the first batch's), and keep exact
-    window results on both tiers, for the walk by range (time-based) and
-    the lane walk (count-based)."""
-    from windflow_tpu.tpu.ffat_tpu import FfatTPUReplica
-    budgets = []
-    orig = FfatTPUReplica._first_budget
-
-    def spy(self):
-        budgets.append(orig(self))
-        return budgets[-1]
-
-    monkeypatch.setattr(FfatTPUReplica, "_first_budget", spy)
+def test_ffat_tpu_adaptive_fire_tiers(win, slide, count_based):
+    """A GIVEN ``num_win_per_batch`` caps the width of every fire
+    program, for time-based windows (which without one size their width
+    by the plan, tests/test_ffat_grouped_fire.py) and count-based ones
+    alike: a stream firing more windows a batch than the budget runs
+    every program at exactly the budget's width, warms its one width
+    eagerly (no compile after the first batch's), and keeps exact window
+    results, for the walk by range (time-based) and the lane walk
+    (count-based)."""
     n_keys, stream_len = 96, 60
     expected = expected_windows(model_seqs(n_keys, stream_len), win,
                                 slide, count_based, sum_or_none)
@@ -424,11 +415,13 @@ def test_ffat_tpu_adaptive_fire_tiers(win, slide, count_based, monkeypatch):
                         n_keys=n_keys, stream_len=stream_len,
                         nwpb=256, obs=512)
     rep = coll.op.replicas[0]
-    assert (rep.W_step, rep.W_cap, rep.W_wide) == (64, 256, 256)
-    assert not rep._by_plan and rep.stats.fire_range_cuts == 0
-    assert set(budgets) == {64, 256} and budgets[0] == 64
-    # step at both tiers, ingest-only, fire-only, rebuild: all at warm-up
-    assert rep.stats.compile_count == 5
+    st = rep.stats
+    assert (rep.W_cap, rep.W_wide) == (256, 256)
+    assert st.fire_programs > 1 and st.windows_fired > 256
+    assert st.fire_lanes == 256 * st.fire_programs   # every width: 256
+    # the step at its one width, ingest-only, fire-only, rebuild: all at
+    # warm-up
+    assert st.compile_count == 4
     assert coll.dups == 0
     assert coll.results == expected
 

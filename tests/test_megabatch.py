@@ -176,7 +176,7 @@ def _run_ffat_chain(monkeypatch, fusion, with_filter, stream_len=90,
     monkeypatch.setenv("WF_TPU_FUSION", fusion)
     monkeypatch.setenv("WF_MEGABATCH", megabatch)
     # the callers compare program counts of two runs: a batch's programs
-    # follow the windows it fires (the two fire tiers), so no partial
+    # follow the windows it fires, so no partial
     # batch may ship by wall-clock age and move the batch boundaries
     monkeypatch.setenv("WF_MAX_STAGING_MS", "0")
     coll = DictWinCollector()

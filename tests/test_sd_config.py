@@ -174,19 +174,16 @@ def test_narrow_is_astype_float32(case):
 
 def test_more_windows_than_one_fire_block_gives_the_same_rows(sd):
     """A 512-row block fires 512 windows. With the budget 512 they leave
-    in one wide program (after the first firing batch, which starts on
-    the small tier); with a budget of 64 a block needs eight programs.
-    The rows are the same, and ``Fire_lanes`` says how wide the programs
-    were."""
+    in one wide program; with a budget of 64 a block needs eight
+    programs. The rows are the same, and ``Fire_lanes`` says how wide
+    the programs were: a budget given is the width of every one."""
     narrow = run_sd(num_win_per_batch=64)
     win, nwin = sd["stats"]["win"], narrow["stats"]["win"]
     assert nwin["Windows_fired"] == win["Windows_fired"]
     assert nwin["Fire_programs"] > 4 * win["Fire_programs"]
     assert nwin["Fire_lanes"] == 64 * nwin["Fire_programs"]
-    # two tiers of the budget given: 64 lanes or 512, nothing else
-    small, rest = divmod(512 * win["Fire_programs"] - win["Fire_lanes"],
-                         512 - 64)
-    assert rest == 0 and 0 <= small <= 4 < win["Fire_programs"]
+    # the budget given is every program's width: 512, nothing else
+    assert win["Fire_lanes"] == 512 * win["Fire_programs"] > 4 * 512
 
     def as_set(run):
         r = valid_rows(run)
@@ -229,15 +226,16 @@ def test_the_host_plans_by_the_key_and_its_counters_say_so(sd):
 def test_sliding_programs_are_the_ones_the_rule_names(sd):
     """``Fire_sliding_programs`` is ``Fire_programs`` minus the programs
     the rule left on the lane walk. At the rehearsal sizes (8 slots, a
-    ring grown from 128 leaves to 256) both tiers of the budget slide;
-    at the cell's sizes they do too (16,384 and 64 lanes over 64 x 2,048
-    leaves), and a 4,096-slot operator's 64-lane program does not."""
+    ring grown from 128 leaves to 256) the budget's width slides, and a
+    64-lane program would too; at the cell's sizes so do 16,384 lanes
+    over 64 x 2,048 leaves, and a 4,096-slot operator's 64-lane program
+    does not."""
     from windflow_tpu.tpu.ffat_tpu import fire_slides
 
     win = sd["stats"]["win"]
     rep = [n for w in sd["graph"]._workers for n in w.chain
            if getattr(getattr(n, "op", None), "name", None) == "win"][0]
-    assert (rep.K_cap, rep.F, rep.W_step, rep.W_cap) == (8, 256, 64, 512)
+    assert (rep.K_cap, rep.F, rep.W_cap, rep.W_wide) == (8, 256, 512, 512)
     assert all(fire_slides(W, 8, F) for W in (64, 512) for F in (128, 256))
     assert win["Fire_sliding_programs"] == win["Fire_programs"] > 4
     assert all(fire_slides(W, 64, F) for W in (64, 16384)

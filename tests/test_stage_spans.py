@@ -428,8 +428,9 @@ def test_time_based_programs_take_the_pack_and_the_arguments_they_took(
     chunks = (slots, np.full(2, 40), np.ones(2, np.int64),
               np.full(2, 10), np.full(2, 43))
     keys = replica._chunk_keys(chunks[0])
-    pack, n_groups = replica._pack_fire_arrays(
-        chunks, 8, keys, replica._ranges_of(chunks))
+    pairs = np.unique(replica._range_words(chunks[1], chunks[2],
+                                           chunks[4] + 1)[0])
+    pack, n_groups = replica._pack_fire_arrays(chunks, 8, keys, pairs)
     groups, rows, total = ffat_tpu.plan_views(pack, replica.K_cap, True, 1)
     assert replica._key_words() == 1
     assert pack.dtype == np.int32 and pack.size == replica._plan_len(8) \

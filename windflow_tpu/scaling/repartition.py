@@ -262,7 +262,6 @@ def _split_ffat_tpu(ffats: List[dict], new_n: int,
             "key_dtype": proto["key_dtype"],
             "saw_new_key": True,  # force key-table refresh on first batch
             "leaf_frontier": max(d["leaf_frontier"] for d in ffats),
-            "fire_ewma": max(d["fire_ewma"] for d in ffats),
             "rebuild_dirty": True,  # level caches are stale by definition
             "ignored": sum(d["ignored"] for d in ffats) if j == 0 else 0,
             # the live keys are packed from slot 0: no free slot below

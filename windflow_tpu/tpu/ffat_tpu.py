@@ -45,16 +45,16 @@ TPU-first redesign:
     once (a sliding aggregate; ``fire_slides`` is the rule, by the
     program's lanes against the forest's leaves)
     -> leaf eviction;
-- the width of a program's fire block (its lanes) is the user's
-  ``num_win_per_batch`` where one was given, honoured as given, in two
-  tiers (_first_budget); count-based windows default it to the key
-  capacity. Time-based windows with no budget given answer by range and
-  cost by the range, not by the lane, so they size the width by what
-  their plans hold (_programs_by_plan): a batch's whole plan leaves in
-  ONE program, the step itself, at a width that grows to the capacity
-  bucket that holds the plan, up to the capacity of the input batch; a
-  plan over ``G_CAP`` ranges is cut at a whole round and stays by range,
-  and only a ragged plan walks by lane, at the narrow width;
+- ONE planner fires every operator's windows (_programs, _plan_program),
+  at one width rule: a program is ``W_cap`` lanes wide, the user's
+  ``num_win_per_batch`` where one was given (a cap: no program is
+  wider), else the key capacity's default. Only time-based windows with
+  no budget given grow it (``W_wide``, _fit_width): they answer by range
+  and cost by the range, not by the lane, so a batch's whole plan leaves
+  in ONE program, the step itself, at the capacity bucket that holds
+  it, up to the capacity of the input batch. A time-based plan over
+  ``G_CAP`` ranges is cut at a whole round and stays by range; only a
+  ragged plan walks by lane, at ``W_cap``;
 - all shapes are static per (cap, K_cap, F) bucket and fire width;
   key capacity and ring length grow by doubling with a device-side rebuild
   (the reference resizes its pending-pane ring on demand,
@@ -463,10 +463,10 @@ class Ffat_Windows_TPU(TPUOperatorBase):
         self.win_type = win_type
         self.lateness = lateness
         self.key_capacity = max(1, key_capacity)
-        # a budget the user gave is honoured as given; without one,
-        # time-based windows size their fire programs by what their
-        # plans hold (FfatTPUReplica._programs_by_plan) and start from
-        # the default below
+        # a budget the user gave caps the width of every fire program;
+        # without one, time-based windows size their fire programs by
+        # what their plans hold (FfatTPUReplica._fit_width) and start
+        # from the default below
         self.budget_given = num_win_per_batch is not None
         if num_win_per_batch is None:
             # fired windows per step scale with key count (each key slides
@@ -518,31 +518,13 @@ class FfatTPUReplica(TPUReplicaBase):
         # (wf/builders_gpu.hpp has no analog; growth still works past it)
         self.K_cap = 1 << max(2, math.ceil(math.log2(op.key_capacity)))
         # The widths (lanes) of the fire programs. W_cap is the budget:
-        # the user's, or the key capacity's default. What else there is
-        # depends on who sized it:
-        # - time-based windows with no budget given size the width BY
-        #   THE PLAN (_programs_by_plan): their programs answer by range
-        #   and cost by the range, not by the lane, so a batch's whole
-        #   plan leaves in one program of W_wide lanes, which grows to
-        #   the capacity bucket that holds the plan, up to the capacity
-        #   of the input batch (_fit_width); W_cap stays the width of
-        #   the programs that walk by lane. No small tier: W_step is
-        #   W_cap;
-        # - count-based windows, and any operator with a budget given,
-        #   keep two tiers of the budget: W_step keeps the step's query
-        #   block small (the lane walk costs by the lane, masked or
-        #   live), W_cap is used when the recent fire rate overflows it
-        #   (_first_budget: an EWMA of windows fired a batch, 0 at the
-        #   start so low-fire streams begin on the small tier), by drain
-        #   iterations and by data-less firing. W_wide is W_cap.
-        self.W_cap = op.num_win_per_batch
-        self._by_plan = (not op.budget_given
-                         and op.win_type is WinType.TB)
-        self.W_step = (self.W_cap if self._by_plan
-                       else min(self.W_cap, 64))
-        self.W_wide = self.W_cap
+        # the user's (a cap), or the key capacity's default. W_wide, the
+        # width a program is planned at first, is W_cap, and only
+        # time-based windows with no budget given grow it to the
+        # capacity bucket that holds their plan (_fit_width); a program
+        # that walks by lane is W_cap wide (_plan_program)
+        self.W_cap = self.W_wide = op.num_win_per_batch
         self._cap_seen = 0  # widest input batch so far: W_wide's bound
-        self._fire_ewma = 0.0
         from .keymap import KeySlotMap
         # wf:keys: key turnover (admission, slots given back)
         self._st_keys = self.stats.stage("keys")
@@ -699,7 +681,7 @@ class FfatTPUReplica(TPUReplicaBase):
           ``(K_cap,)`` vectors, a node read one column slice of the
           forest. Lane ``i`` then picks ``table[group[i], slot[i]]``.
           Time-based windows number from absolute time 0, so the lanes
-          of a program planned by rounds (_fireable) share one to three
+          of a program planned by rounds (_clip) share one to three
           ranges;
         - the SLIDING SCAN (``by_scan``), count-based windows only. No
           two keys share a ring range, but a program holds ONE chunk a
@@ -1664,34 +1646,22 @@ class FfatTPUReplica(TPUReplicaBase):
             self._reclaim(slots)
         return chunks
 
-    def _fireable(self, frontier, partial: bool, budget: int):
-        """Fire-eligible windows as per-slot chunk ARRAYS
-        (slots, start0, k, wid0, max_leaf), each chunk covering the
-        slot's next ``k`` consecutive eligible windows, ``budget`` windows
-        in all. Where more are eligible than the budget holds:
+    def _clip(self, k: np.ndarray, budget: int) -> np.ndarray:
+        """Windows taken of each slot's ``k`` eligible ones under a
+        budget, a slot's windows in ``wid`` order either way:
 
-        - time-based windows leave by ROUNDS: every firing slot gives its
+        - time-based windows by ROUNDS: every firing slot gives its
           first ``min(k, r)`` windows for the largest ``r`` that fits,
           and what is left of the budget goes to round ``r + 1`` in slot
           order (so a program is full while windows remain, and where
           even one round overflows, this is the slot-order clip within
           it). Window ``w`` of every key is the same ring range, so a
-          program holds a few distinct ranges, in the steady state
-          and in the end-of-stream flush alike, and the fire query walks
+          program holds a few distinct ranges, in the steady state and
+          in the end-of-stream flush alike, and the fire query walks
           each once (_query_fns);
-        - count-based windows in slot order, clipped where the cumulative
-          sum crosses the budget (their ranges are per key anyway).
-
-        A slot's windows leave in ``wid`` order either way. Advances
-        next_fire/fired for the windows taken."""
-        slots, k = self._eligible(frontier, partial)
-        if slots.size == 0:
-            return (np.zeros(0, np.int64),) * 5
-        return self._take(slots, self._clip(k, budget))
-
-    def _clip(self, k: np.ndarray, budget: int) -> np.ndarray:
-        """Windows taken of each slot's ``k`` eligible ones under a
-        budget: by rounds (time-based) or in slot order (count-based)."""
+        - count-based windows in slot order, clipped where the
+          cumulative sum crosses the budget (their ranges are per key
+          anyway)."""
         if int(k.sum()) <= budget:
             return k
         if self.op.win_type is WinType.TB:
@@ -1738,17 +1708,6 @@ class FfatTPUReplica(TPUReplicaBase):
         return ((starts & (F - 1)) * F
                 + np.minimum(self.win_units, end - starts)), rnd
 
-    def _ranges_of(self, chunks):
-        """The ring ranges (sorted words of _range_words) of a
-        time-based program's lanes where there are at most ``G_CAP``,
-        else None: the program walks by lane. None for count-based
-        windows, whose ranges are per key."""
-        if self.op.win_type is WinType.CB:
-            return None
-        _slots, c_start0, c_k, _wid0, c_ml = chunks
-        pairs = np.unique(self._range_words(c_start0, c_k, c_ml + 1)[0])
-        return pairs if pairs.size <= G_CAP else None
-
     def _pack_fire_arrays(self, chunks, W: int, keys, pairs):
         """The flat int32 fire plan (``plan_views``) of a program of
         width ``W``: its chunk rows, with their original ``keys``
@@ -1777,23 +1736,24 @@ class FfatTPUReplica(TPUReplicaBase):
         return pack, pairs.size
 
     def _plan_program(self, slots, k):
-        """ONE program of an operator that sizes its width by the plan
-        (time-based windows, no budget given), from the eligible
-        windows ``k`` of ``slots``: ``(chunks, n_out, pack, n_groups,
-        W, keys)`` (_programs), the windows taken advanced past, and how
-        many of each slot's it took.
+        """ONE program, from the eligible windows ``k`` of ``slots``:
+        ``(chunks, n_out, pack, n_groups, W, keys)`` (_programs), the
+        windows taken advanced past, and how many of each slot's it
+        took.
 
         The program is ``W_wide`` lanes and takes all that is eligible,
-        by rounds where even that width overflows. It stays a program
-        BY RANGE: where its lanes hold more than ``G_CAP`` distinct
-        ranges it is closed at the last WHOLE round that keeps it
-        within ``G_CAP`` (a round: window ``j`` of every firing slot;
-        the rounds left make the next program), and
-        ``Fire_range_cuts`` counts it. Only where the first round alone
-        holds more (a ragged plan: sparse keys, every ``max_leaf``
-        different, about as many ranges as lanes) does the program walk
-        by lane, and then at the width it has today, ``W_cap``: a lane
-        walk costs by the lane, masked or live, so it is never widened.
+        clipped to that width where it overflows (_clip). A count-based
+        program carries no ranges: it walks by lane or answers by
+        sliding scan, by its static shapes (``fire_slides``). A
+        time-based one stays a program BY RANGE: where its lanes hold
+        more than ``G_CAP`` distinct ranges it is closed at the last
+        WHOLE round that keeps it within ``G_CAP`` (a round: window
+        ``j`` of every firing slot; the rounds left make the next
+        program), and ``Fire_range_cuts`` counts it. Only where the
+        first round alone holds more (a ragged plan: sparse keys, every
+        ``max_leaf`` different, about as many ranges as lanes) does the
+        program walk by lane, and then at ``W_cap``: a lane walk costs
+        by the lane, masked or live, so it is never widened.
         The ranges are reckoned from the chunks, never by the lane:
         where every chunk takes one window (a slide that closes once a
         batch) the chunks are their own lanes, else from the CLASSES of
@@ -1812,6 +1772,9 @@ class FfatTPUReplica(TPUReplicaBase):
         start0, end = self.next_fire[slots], self.max_leaf[slots] + 1
         for W in dict.fromkeys((self.W_wide, self.W_cap)):
             take = self._clip(k, W)
+            if self.op.win_type is WinType.CB:
+                pairs = None    # ranges per key: no range table
+                break
             if int(take.max()) == 1:
                 words, rnd = self._range_words(start0, take, end)
             else:
@@ -1843,7 +1806,7 @@ class FfatTPUReplica(TPUReplicaBase):
                 self.stats.fire_range_cuts += 1
                 break
         else:
-            pairs = None  # ragged: by lane, at the narrow width
+            pairs = None  # ragged: by lane, at W_cap
         chunks = self._take(slots, take)
         keys = self._chunk_keys(chunks[0])
         return (chunks, int(take.sum())) + self._pack_fire_arrays(
@@ -1863,29 +1826,16 @@ class FfatTPUReplica(TPUReplicaBase):
         field); keys that are no ints are built on the host."""
         return self._keys_all_int and self.op.key_field is not None
 
-    def _first_budget(self) -> int:
-        """Fire budget for the first (full) program of a batch of an
-        operator that keeps the two tiers (count-based windows, or a
-        budget given: see __init__) — one of exactly TWO (both compiled
-        eagerly, so no mid-stream retrace ever): the small W_step block,
-        or W_cap when the recent fire rate (the EWMA of windows fired a
-        batch) overflows it, so a stream that fires many windows a batch
-        answers them in the step itself, not in fire-only programs
-        behind it. The small tier exists because a block costs by the
-        lane, live or masked: that holds for the lane walk only, so
-        operators that size their width by the plan have no tiers
-        (_programs_by_plan)."""
-        if self._fire_ewma * 1.25 <= self.W_step:
-            return self.W_step
-        return self.W_cap
-
     def _fit_width(self, total: int) -> bool:
         """Grow ``W_wide`` for a plan of ``total`` windows that has
-        outgrown it: to the capacity bucket that holds the plan, never
-        past the capacity of the widest input batch (a result batch no
-        wider than the batch that made it). Like ``K_cap`` and ``F`` it
-        only grows, and the caller warms the new shapes (a width is a
-        compiled shape). True where it grew."""
+        outgrown it, where the operator is time-based with no budget
+        given (a budget is a cap): to the capacity bucket that holds the
+        plan, never past the capacity of the widest input batch (a
+        result batch no wider than the batch that made it). Like
+        ``K_cap`` and ``F`` it only grows, and the caller warms the new
+        shapes (a width is a compiled shape). True where it grew."""
+        if self.op.budget_given or self.op.win_type is WinType.CB:
+            return False
         W = min(bucket_capacity(total), max(self.W_cap, self._cap_seen))
         if W <= self.W_wide:
             return False
@@ -1946,8 +1896,8 @@ class FfatTPUReplica(TPUReplicaBase):
     def _warm_programs(self, cap, ckey, ikey, fields) -> None:
         """Compile every program variant of a capacity bucket with no-op
         sentinel runs (every lane the composite's sentinel, zero fire
-        args): the full step at each fire width (W_step, W_cap, W_wide:
-        two distinct ones), the ingest-only deferred-rebuild step, the
+        args): the full step at each fire width (W_cap, W_wide), the
+        ingest-only deferred-rebuild step, the
         fire-only drain step, and the standalone rebuild; called again
         when W_wide has grown, it runs the new shapes alone. All runs
         are semantic no-ops on the forest (sentinel rows drop, rebuild
@@ -1965,18 +1915,18 @@ class FfatTPUReplica(TPUReplicaBase):
         # bucket must not pay a redundant full-forest rebuild execution
         M, cdt = self._comp_dtype()
         comp_s = np.full(cap, M, dtype=cdt)  # all-sentinel lanes
-        for W in sorted({self.W_step, self.W_cap, self.W_wide}):
+        for W in sorted({self.W_cap, self.W_wide}):
             if (ckey, W) in self._warm_shapes:
                 continue
             (self.trees, self.tvalid, *_) = self._full_step(ckey, cap, W)(
                 fields, comp_s, self.trees, self.tvalid,
                 self._zero_fire(W))
             self._warm_shapes.add((ckey, W))
-        if (ikey, self.W_step) not in self._warm_shapes:
+        if (ikey, self.W_cap) not in self._warm_shapes:
             (self.trees, self.tvalid, *_) = istep(
                 fields, comp_s, self.trees, self.tvalid,
-                self._zero_fire(self.W_step))
-            self._warm_shapes.add((ikey, self.W_step))
+                self._zero_fire(self.W_cap))
+            self._warm_shapes.add((ikey, self.W_cap))
         if rb is not None:
             self.trees, self.tvalid = rb(self.trees, self.tvalid)
 
@@ -1988,7 +1938,7 @@ class FfatTPUReplica(TPUReplicaBase):
 
     def prewarm(self, caps) -> Optional[int]:
         """``PipeGraph.with_prewarm`` hook: compile every program
-        variant (full step at both fire tiers, ingest-only, fire-only,
+        variant (full step at its fire width, ingest-only, fire-only,
         standalone rebuild) per bucket capacity BEFORE the stream
         starts, so ragged streams hopping between capacity buckets never
         pay a mid-stream compile. Needs a declared schema — the forest
@@ -2033,8 +1983,7 @@ class FfatTPUReplica(TPUReplicaBase):
         """Host half of the per-batch step: program warm-up, the ENTIRE
         fire plan — every program's chunk arrays and packed plan,
         computed up front because the planner reads host metadata
-        only (no control decision ever waits on a device result) — and,
-        for the operators that keep the tiers, the fire-rate EWMA.
+        only (no control decision ever waits on a device result).
         ``keyrows``: a count-based batch's per-slot words
         (_prep_by_key), which ride the step's plan buffer, an empty plan
         where the step fires nothing (so the cached all-zero plan never
@@ -2059,29 +2008,19 @@ class FfatTPUReplica(TPUReplicaBase):
             warm()
         with self._st_fireplan(bid):
             plan = []
-            for prog in self._programs(frontier, False,
-                                       self._first_budget(), warm):
+            for prog in self._programs(frontier, False, warm):
                 if not plan:     # the step: it rebuilds, then it fires
                     self._plan_rebuild(prog[2])
                 self._note_evicted(prog[0])
                 plan.append((not plan,) + prog)
-        if not self._by_plan:
-            # fast-rise / slow-decay: a burst switches to the wide tier
-            # on the very next batch (both tier shapes are already
-            # compiled), while decay back to the small tier is smoothed
-            total_fired = sum(entry[2] for entry in plan)
-            if total_fired > self._fire_ewma:
-                self._fire_ewma = float(total_fired)
-            else:
-                self._fire_ewma += 0.25 * (total_fired - self._fire_ewma)
         if not plan:
             # nothing fireable: the ingest-only program (its entry in
             # the plan is the buffer it takes, no tuple), its rebuild
             # DEFERRED to the next firing/rebuild program. Fire args are
-            # unused in that variant but still traced: pin the W_step
-            # shape so tier switches never retrace it
-            plan = [self._zero_fire(self.W_step) if keyrows is None
-                    else np.zeros(self._plan_len(self.W_step), np.int32)]
+            # unused in that variant but still traced: pin the W_cap
+            # shape so a grown W_wide never retraces it
+            plan = [self._zero_fire(self.W_cap) if keyrows is None
+                    else np.zeros(self._plan_len(self.W_cap), np.int32)]
         if keyrows is not None:
             first = plan[0]
             plan_views(first[3] if isinstance(first, tuple) else first,
@@ -2089,7 +2028,7 @@ class FfatTPUReplica(TPUReplicaBase):
         return lambda: self._commit_step(fields, wm, comp_p, ckey, ikey,
                                          plan, bid)
 
-    def _programs(self, frontier, partial: bool, first_budget: int, warm):
+    def _programs(self, frontier, partial: bool, warm):
         """The programs that fire what is eligible now, one ``(chunks,
         n_out, pack, n_groups, W, keys, owed)`` at a time, each advanced
         past as it is yielded (so a caller may run one, and a snapshot
@@ -2101,37 +2040,11 @@ class FfatTPUReplica(TPUReplicaBase):
         windows, whose rows carry no window time). ``warm()`` compiles
         the shapes of a width that has just grown.
 
-        With the tiers: ``first_budget`` windows in the first program,
-        ``W_cap`` in those behind it, until one is not full. By the
-        plan: see _programs_by_plan."""
-        if self._by_plan:
-            yield from self._programs_by_plan(frontier, partial, warm)
-            return
-        timed = self.op.win_type is WinType.TB
-        budget = first_budget
-        while True:
-            chunks = self._fireable(frontier, partial, budget)
-            n_out = int(chunks[2].sum())
-            if not n_out:
-                return
-            owed = None
-            if timed and n_out == budget:   # full: more may be eligible
-                left, _k = self._eligible(frontier, partial)
-                if left.size:
-                    owed = int(self.fired[left].min())
-            keys = self._chunk_keys(chunks[0])
-            yield (chunks, n_out) + self._pack_fire_arrays(
-                chunks, budget, keys, self._ranges_of(chunks)) + (
-                    budget, keys, owed)
-            if n_out < budget or (timed and owed is None):
-                return
-            budget = self.W_cap
-
-    def _programs_by_plan(self, frontier, partial: bool, warm):
-        """Time-based windows with no budget given: everything eligible
-        leaves in ONE program where it fits the width and ``G_CAP``
+        The eligible windows are found once; everything leaves in ONE
+        program where it fits the width and, time-based, ``G_CAP``
         ranges (_plan_program), in the step itself where there is one,
-        and the width follows the plans (_fit_width).
+        and a time-based width with no budget follows the plans
+        (_fit_width).
 
         Soundness of many rounds in one program (eight consecutive
         windows of every slot, each evicting what the next would have
@@ -2150,12 +2063,13 @@ class FfatTPUReplica(TPUReplicaBase):
         # operator too), and the compiles would be the flush's whole cost
         if not partial and total > self.W_wide and self._fit_width(total):
             warm()
+        timed = self.op.win_type is WinType.TB
         while slots.size:
             prog, take = self._plan_program(slots, k)
             k = k - take
             slots, k = slots[k > 0], k[k > 0]
-            yield prog + (int(self.fired[slots].min()) if slots.size
-                          else None,)
+            yield prog + (int(self.fired[slots].min())
+                          if timed and slots.size else None,)
 
     def _commit_step(self, fields, wm, comp_p, ckey, ikey, plan,
                      bid: int) -> None:
@@ -2286,7 +2200,7 @@ class FfatTPUReplica(TPUReplicaBase):
         self.dispatch.drain(forced=True)
         self._bid = 0
         for chunks, n_out, pack, n_groups, W, keys, owed in self._programs(
-                frontier, partial, self.W_cap, self._warm_fire_step):
+                frontier, partial, self._warm_fire_step):
             self._ensure_rebuilt()
             self._note_evicted(chunks)
             self.tvalid, qr, qv, wid_dev, key_dev = self._fire_step(W)(
@@ -2340,7 +2254,6 @@ class FfatTPUReplica(TPUReplicaBase):
             "key_dtype": self._key_dtype,
             "saw_new_key": self._saw_new_key,
             "leaf_frontier": self._leaf_frontier,
-            "fire_ewma": self._fire_ewma,
             "rebuild_dirty": self._rebuild_dirty,
             "ignored": self.ignored,
             # slot-major, as a snapshot holds the forest
@@ -2390,7 +2303,6 @@ class FfatTPUReplica(TPUReplicaBase):
                 "key_dtype": self._key_dtype,
                 "saw_new_key": self._saw_new_key,
                 "leaf_frontier": self._leaf_frontier,
-                "fire_ewma": self._fire_ewma,
                 "rebuild_dirty": self._rebuild_dirty,
                 "ignored": self.ignored,
                 "reclaimed_wid": self._reclaimed_wid}
@@ -2452,7 +2364,6 @@ class FfatTPUReplica(TPUReplicaBase):
         self._key_dtype = d["key_dtype"]
         self._saw_new_key = d["saw_new_key"]
         self._leaf_frontier = d["leaf_frontier"]
-        self._fire_ewma = d["fire_ewma"]
         self._rebuild_dirty = d["rebuild_dirty"]
         # the dirty ranges are no part of a snapshot: the next step
         # rebuilds the whole forest
