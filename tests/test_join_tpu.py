@@ -90,10 +90,10 @@ def join_builder(lower, upper):
 
 
 def run_device(a, b, lower, upper, *, blk=BLK, order=None, par=1,
-               src_par=1):
+               src_par=1, capacity=None):
     """Two block sources -> merge -> the device join -> columnar sink.
     ``order``: ``"a_first"`` / ``"b_first"`` holds one source back until
-    the other has ended."""
+    the other has ended; ``capacity``: the archives' rows, A's and B's."""
     gate = threading.Event()
     first = {"a_first": "a", "b_first": "b"}.get(order)
     out = Pairs()
@@ -114,8 +114,10 @@ def run_device(a, b, lower, upper, *, blk=BLK, order=None, par=1,
         pipes[side] = g.add_source(
             Source_Builder(make(parts, side)).with_name("src_" + side)
             .with_parallelism(src_par).with_output_batch_size(blk).build())
-    pipes["a"].merge(pipes["b"]).add(
-        join_builder(lower, upper).with_parallelism(par).build()).add_sink(
+    join = join_builder(lower, upper).with_parallelism(par)
+    if capacity is not None:
+        join = join.with_archive_capacity(*capacity)
+    pipes["a"].merge(pipes["b"]).add(join.build()).add_sink(
         Sink_Builder(out).with_name("snk").with_columns().build())
     g.run()
     stats = {o["name"]: o["replicas"] for o in g.get_stats()["Operators"]}
@@ -469,6 +471,156 @@ def test_late_rows_probe_what_is_left_as_the_per_tuple_join():
     # nothing of A that old is left: the late rows find nothing, and the
     # rest is what it was
     assert out2.sorted() == out.sorted()
+
+
+# ---------------------------------------------------------------------------
+# an archive sized for the deployment, donated to its step
+# ---------------------------------------------------------------------------
+def test_a_given_capacity_gives_the_growing_rings_pairs_without_growth():
+    """B's archive holds 71 batches: from 64 slots it doubles; allocated
+    for 128 batches at its first batch, it never does."""
+    sa, sb, lower, upper, _ = CASES["archive_growth"]
+    a, b = stream(*sa), stream(*sb)
+    grown, st_grown = run_device(a, b, lower, upper)
+    sized, st_sized = run_device(a, b, lower, upper,
+                                 capacity=(None, 128 * BLK))
+    assert sized.sorted() == grown.sorted() == brute(a, b, lower, upper)
+    assert total(st_grown, "Join_archive_growths") > 0
+    assert total(st_sized, "Join_archive_growths") == 0
+    # B's 128 slots of a batch, A's 64 (no capacity given)
+    assert total(st_sized, "Join_archive_capacity_rows") == 192 * BLK
+    assert total(st_grown, "Join_archive_capacity_rows") == (128 + 64) * BLK
+
+
+def test_a_batch_past_the_capacity_still_grows_and_is_counted():
+    sa, sb, lower, upper, _ = CASES["archive_growth"]
+    a, b = stream(*sa), stream(*sb)
+    out, st = run_device(a, b, lower, upper, capacity=(2 * BLK, 8 * BLK))
+    assert out.sorted() == brute(a, b, lower, upper)
+    # 8 slots doubled to 128 for B's 71 batches; A's two to 8 for its 5
+    assert total(st, "Join_archive_growths") == 4 + 2
+    assert total(st, "Join_archive_capacity_rows") == (128 + 8) * BLK
+
+
+def test_a_capacity_is_a_number_of_rows():
+    with pytest.raises(WindFlowError, match="capacity"):
+        join_builder(1, 1).with_archive_capacity(None, 0).build()
+
+
+def test_further_batches_are_gathered_before_the_next_step_takes_the_ring(
+        monkeypatch):
+    """Both inputs through one split: the join's launches alternate
+    sides, so a step whose pairs outnumber its first output batch is
+    followed by a step donated the archive its further batches gather
+    from. Those are gathered before that launch (``_resolve``), its
+    finish emits them after it, and every pair leaves once with its
+    stamp."""
+    from windflow_tpu.tpu.join_tpu import IntervalJoinTPUReplica
+
+    gathered = []
+    resolve = IntervalJoinTPUReplica._resolve
+
+    def spy(self, hold, bid):
+        resolve(self, hold, bid)
+        gathered.append(len(hold["more"]))
+
+    monkeypatch.setattr(IntervalJoinTPUReplica, "_resolve", spy)
+    a, b = stream(43, 600, 3, 6_000), stream(44, 1_800, 3, 6_000)
+    out, stats = run_one_source(a, b, 1_000, 1_000, blk=64)
+    want = brute(a, b, 1_000, 1_000)
+    assert out.sorted() == want and len(want) > 50_000
+    assert (np.asarray(out.ts) == np.maximum(a["ts"][out.va],
+                                             b["ts"][out.vb])).all()
+    assert sum(gathered) > 10
+    assert total(stats, "Join_output_batches") > len(want) // 64
+
+
+def test_a_row_whose_valid_is_false_neither_probes_nor_is_archived():
+    """A ``valid`` column of booleans marks the rows that are there (a
+    window operator fires an empty window's row with it False): a row
+    with False meets nothing, whichever input comes later."""
+    a, b = stream(45, 400, 6, 40_000), stream(46, 900, 6, 40_000)
+    valid = a["v"] % 3 != 0
+
+    def src_a(shipper, ctx=None):
+        for i in range(0, len(a["ts"]), BLK):
+            sl = slice(i, i + BLK)
+            shipper.set_next_watermark(max(0, int(a["ts"][sl][0]) - 1))
+            shipper.push_columns({"k": a["k"][sl], "va": a["v"][sl],
+                                  "valid": valid[sl]}, ts=a["ts"][sl])
+
+    out = Pairs()
+    g = PipeGraph("valid", ExecutionMode.DEFAULT, TimePolicy.EVENT_TIME,
+                  channel_capacity=2)
+    pa = g.add_source(Source_Builder(src_a).with_output_batch_size(BLK)
+                      .build())
+    pb = g.add_source(Source_Builder(block_source(b, "vb"))
+                      .with_output_batch_size(BLK).build())
+    pa.merge(pb).add(join_builder(700, 300).build()).add_sink(
+        Sink_Builder(out).with_name("snk").with_columns().build())
+    g.run()
+    kept = {k: v[valid] for k, v in a.items()}
+    want = sorted((int(kept["v"][i]), j) for i, j in brute(kept, b, 700, 300))
+    assert out.sorted() == want and len(want) > 100
+    assert len(brute(a, b, 700, 300)) > len(want)
+
+
+def test_a_window_feeding_input_a_delivers_the_per_tuple_joins_pairs():
+    """``Ffat_Windows_TPU``'s fired rows as input A, as they stand: a
+    keyed tumbling max over one stream joined with another on the key,
+    each window's row against B over ``[T - win, T]`` (its row is
+    stamped ``T - 1``), exactly the pairs of the per-tuple
+    ``Interval_Join`` over the window's rows as a plain model fires them:
+    every window of a key that holds a row."""
+    import jax.numpy as jnp
+
+    from windflow_tpu.tpu import Ffat_Windows_TPU_Builder
+
+    win = 4_000
+    x, b = stream(47, 1_200, 4, 60_000), stream(48, 1_500, 4, 60_000)
+    # the model's rows: (key, window, its max), stamped T - 1
+    w = x["ts"] // win
+    rows = sorted({(int(k), int(i)) for k, i in zip(x["k"], w)})
+    top = {r: int(x["v"][(x["k"] == r[0]) & (w == r[1])].max())
+           for r in rows}
+    a = {"k": np.array([k for k, _ in rows], np.int32),
+         "ts": np.array([(i + 1) * win - 1 for _, i in rows], np.int64)}
+    order = np.argsort(a["ts"], kind="stable")
+    a = {k: v[order] for k, v in a.items()}
+    a["v"] = np.arange(len(rows), dtype=np.int32)     # its place, in time
+    want = run_per_tuple(a, b, win - 1, 1)
+    assert want == brute(a, b, win - 1, 1) and len(want) > 300
+
+    got = []
+
+    def sink(cols, ts):
+        if cols is not None:
+            got.extend(zip(cols["k"].tolist(), cols["wid"].tolist(),
+                           cols["m"].tolist(), cols["vb"].tolist()))
+
+    g = PipeGraph("win_join", ExecutionMode.DEFAULT, TimePolicy.EVENT_TIME,
+                  channel_capacity=2)
+    px = g.add_source(Source_Builder(block_source(x, "vx"))
+                      .with_output_batch_size(BLK).build())
+    px.add(Ffat_Windows_TPU_Builder(
+               lambda f: {"m": f["vx"]},
+               lambda p, q: {"m": jnp.maximum(p["m"], q["m"])})
+           .with_key_by("k").with_tb_windows(win, win).with_key_capacity(8)
+           .with_name("max").build())
+    pb = g.add_source(Source_Builder(block_source(b, "vb"))
+                      .with_output_batch_size(BLK).build())
+    px.merge(pb).add(
+        Interval_Join_TPU_Builder(
+            lambda p, q: {"k": p["k"], "wid": p["wid"], "m": p["m"],
+                          "vb": q["vb"]})
+        .with_key_by("k").with_boundaries(win - 1, 1).with_kp_mode()
+        .with_name("join").build()).add_sink(
+        Sink_Builder(sink).with_columns().build())
+    g.run()
+    at = {(int(k), int(i)): j for j, (k, i) in enumerate(
+        zip(a["k"].tolist(), (a["ts"] // win).tolist()))}
+    assert sorted((at[(k, i)], vb) for k, i, _, vb in got) == want
+    assert all(m == top[(k, i)] for k, i, m, _ in got)
 
 
 # ---------------------------------------------------------------------------

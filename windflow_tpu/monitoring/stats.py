@@ -94,11 +94,17 @@ class StatsRecord(StageCounters):
         # the archive a step probed, summed over the steps; doublings of
         # an archive (each recompiles the step); rows that arrived behind
         # their own side's purge line (they probe what is left and are
-        # not archived)
+        # not archived); the rows both rings have room for (a gauge) and
+        # the rows of the other ring a step's probe compared with, live
+        # or dead, summed over the steps
         "join_probe_rows", "join_archived_rows", "join_pairs",
         "join_output_batches", "join_purged_rows", "join_archive_rows",
         "join_scanned_rows", "join_archive_growths", "join_late_probes",
-        "join_batches_held",
+        "join_batches_held", "join_archive_capacity_rows",
+        "join_probed_rows",
+        # a device split's deliveries to its branches: the batch whole (every
+        # row selected the branch) or a gathered sub-batch
+        "split_whole_batches", "split_gathered_batches",
         "staging_pool_hits", "staging_pool_misses",
         "dispatch_stalls", "dispatch_depth_max",
         # finish halves the dispatch queue ran (a compacting commit's
@@ -260,6 +266,10 @@ class StatsRecord(StageCounters):
         self.join_archive_growths = 0
         self.join_late_probes = 0
         self.join_batches_held = 0
+        self.join_archive_capacity_rows = 0
+        self.join_probed_rows = 0
+        self.split_whole_batches = 0
+        self.split_gathered_batches = 0
         self.staging_pool_hits = 0  # recycled staging buffers (ArrayPool)
         self.staging_pool_misses = 0
         # device-ahead dispatch pipeline (runtime/dispatch.py); the split
@@ -623,6 +633,10 @@ class StatsRecord(StageCounters):
             "Join_archive_growths": self.join_archive_growths,
             "Join_late_probes": self.join_late_probes,
             "Join_batches_held": self.join_batches_held,
+            "Join_archive_capacity_rows": self.join_archive_capacity_rows,
+            "Join_probed_rows": self.join_probed_rows,
+            "Split_whole_batches": self.split_whole_batches,
+            "Split_gathered_batches": self.split_gathered_batches,
             "Fused_ops": self.fused_ops,
             "Staging_pool_hits": self.staging_pool_hits,
             "Staging_pool_misses": self.staging_pool_misses,

@@ -297,6 +297,27 @@ class BroadcastEmitter(BasicEmitter):
             self._batch = None
 
 
+class SplitMask:
+    """The multicast form of a split's logic (``MultiPipe.split(field, n,
+    mask=True)``): ``field`` holds a bitmask of branches a row, bit ``b``
+    set sending the row to branch ``b`` and 0 dropping it, the columnar
+    sibling of a splitting function that returns a vector of indices."""
+
+    __slots__ = ("field",)
+
+    def __init__(self, field: str) -> None:
+        self.field = field
+
+    def branches(self, t) -> list:
+        """The branch indices of one tuple (the host plane's form)."""
+        m = int(t[self.field] if isinstance(t, dict)
+                else getattr(t, self.field))
+        if m < 0:
+            from ..basic import WindFlowError
+            raise WindFlowError(f"split mask {self.field!r} holds {m} < 0")
+        return [b for b in range(m.bit_length()) if m >> b & 1]
+
+
 def check_branch_index(s: int, n_branches: int) -> int:
     """Shared split-branch validation (CPU and device planes)."""
     if not 0 <= s < n_branches:

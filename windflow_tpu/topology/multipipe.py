@@ -136,16 +136,31 @@ class MultiPipe:
         return self.chain(op)
 
     # ------------------------------------------------------------------
-    def split(self, splitting_logic, n_branches: int) -> "MultiPipe":
+    def split(self, splitting_logic, n_branches: int,
+              mask: bool = False) -> "MultiPipe":
         """Split the pipe into ``n_branches`` children; ``splitting_logic``
         maps a tuple to a branch index (or an iterable of indices, or None to
         drop). ``wf/multipipe.hpp:1178-1256``. A string names a tuple field
         holding the branch index — after a TPU operator this routes from one
         column D2H with no per-tuple Python (``split_gpu``,
-        ``wf/multipipe.hpp:698-708``)."""
+        ``wf/multipipe.hpp:698-708``). With ``mask=True`` the field holds
+        a bitmask of branches instead (bit ``b`` sends the row to branch
+        ``b``, 0 drops it): a row may go to several branches, from the same
+        one column, and a branch every row of a device batch selects gets
+        the batch whole."""
         self._check_open("split")
         if n_branches < 2:
             raise WindFlowError("split requires at least 2 branches")
+        if mask:
+            if not isinstance(splitting_logic, str):
+                raise WindFlowError(
+                    "split(mask=True) takes the name of the field that "
+                    "holds each row's bitmask of branches")
+            if n_branches > 31:
+                raise WindFlowError("split(mask=True): at most 31 branches "
+                                    "(a bit each of an int32 mask)")
+            from ..runtime.emitters import SplitMask
+            splitting_logic = SplitMask(splitting_logic)
         tails = self._tails
         if len(tails) != 1:
             raise WindFlowError("split right after a merge is not supported; "

@@ -26,7 +26,8 @@ from ..runtime.collectors import (AtomicCounter, DPJoinCollector,
                                   IDSequencerCollector, KSlackCollector,
                                   OrderingCollector, WatermarkCollector)
 from ..runtime.emitters import (BasicEmitter, BroadcastEmitter, ForwardEmitter,
-                                KeyByEmitter, NullEmitter, SplittingEmitter)
+                                KeyByEmitter, NullEmitter, SplitMask,
+                                SplittingEmitter)
 from ..runtime.worker import Worker
 from .multipipe import MultiPipe
 from .stage import Stage
@@ -915,7 +916,9 @@ class PipeGraph:
                         se: BasicEmitter = TPUSplittingEmitter(
                             logic, ems, self.execution_mode)
                     else:
-                        if isinstance(logic, str):
+                        if isinstance(logic, SplitMask):
+                            logic = logic.branches
+                        elif isinstance(logic, str):
                             field = logic
                             logic = (lambda t, _f=field:
                                      t[_f] if isinstance(t, dict)

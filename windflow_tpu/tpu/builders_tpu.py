@@ -331,9 +331,21 @@ class Interval_Join_TPU_Builder(BasicBuilder):
         self._lower = self._upper = None
         self._mode = JoinMode.KP
         self._schemas = (None, None)
+        self._capacity = (None, None)
 
     def with_key_by(self, key_field: str):
         self._key_extractor = key_field
+        return self
+
+    def with_archive_capacity(self, a_rows=None, b_rows=None):
+        """The rows a replica's archive of input A and of input B is
+        allocated for, once, at the input's first batch (rounded up to
+        whole slots of its batches' width): the stream a deployment holds
+        between its purge lines, so the ring never doubles (and
+        recompiles its step) inside a run. Past it the ring still
+        doubles, counted in ``Join_archive_growths``. None (the default)
+        starts the ring at 64 slots."""
+        self._capacity = (a_rows, b_rows)
         return self
 
     def with_boundaries(self, lower_usec: int, upper_usec: int):
@@ -379,4 +391,4 @@ class Interval_Join_TPU_Builder(BasicBuilder):
                 "capacity)")
         return self._finish(Interval_Join_TPU(
             self._func, self._key_extractor, self._lower, self._upper,
-            self._name, self._parallelism, self._schemas))
+            self._name, self._parallelism, self._schemas, self._capacity))

@@ -34,7 +34,7 @@ import numpy as np
 from ..basic import ExecutionMode, WindFlowError
 from ..message import Batch
 from ..monitoring.tracing import StageCounters, next_batch_id, stamp_ns
-from ..runtime.emitters import BasicEmitter
+from ..runtime.emitters import BasicEmitter, SplitMask
 from .batch import (BatchTPU, StagingBuffers, async_host_copy,
                     bucket_capacity, gather_columns_each)
 from .schema import TupleSchema
@@ -1038,8 +1038,17 @@ class TPUSplittingEmitter(BasicEmitter, _D2HPipeline):
     ``splitting_logic`` forms:
     - a string field name: the int32/int64 column holds the branch index
       per row (vectorized: one column D2H, no per-tuple Python);
+    - a ``SplitMask`` (``split(field, n, mask=True)``): the column holds
+      a bitmask of branches per row, bit ``b`` for branch ``b``, so a row
+      may go to several (the same one column D2H, a few whole-column
+      operations, no per-row Python);
     - a callable payload -> int | iterable[int] | None (reference
       contract): rows are materialized once per batch to evaluate it.
+
+    A branch that every row of a batch selects gets the batch whole
+    (``Split_whole_batches``), one that some rows select a device gather
+    of them (``Split_gathered_batches``), one that none selects the
+    batch's watermark alone.
     """
 
     def __init__(self, splitting_logic, inner_emitters: List[BasicEmitter],
@@ -1048,6 +1057,11 @@ class TPUSplittingEmitter(BasicEmitter, _D2HPipeline):
         super().__init__(sum(e.num_dests for e in inner_emitters), 0,
                          execution_mode)
         self.splitting_logic = splitting_logic
+        # the one column the routing reads, where the logic names one
+        self._field = splitting_logic.field \
+            if isinstance(splitting_logic, SplitMask) else (
+                splitting_logic if isinstance(splitting_logic, str)
+                else None)
         self.inner = inner_emitters
         # the routing decision needs a D2H read; pipeline it (_D2HPipeline)
         self._pipe_init("WF_SPLIT_PIPELINE_DEPTH", 2, depth)
@@ -1058,61 +1072,92 @@ class TPUSplittingEmitter(BasicEmitter, _D2HPipeline):
         for e in self.inner:
             e.set_stats(stats)
 
-    def _branch_rows(self, batch: BatchTPU) -> List[np.ndarray]:
-        """Row indices per branch (host-side routing decision)."""
-        n_branches = len(self.inner)
-        logic = self.splitting_logic
-        if isinstance(logic, str):
-            col = np.asarray(batch.fields[logic])[:batch.size]
-            if self.stats is not None:
-                self.stats.device_bytes_d2h += int(col.nbytes)
-            if col.size and (col.min() < 0 or col.max() >= n_branches):
-                op = self.stats.op_name if self.stats is not None else "?"
-                raise WindFlowError(
-                    f"split after {op!r}: field {logic!r} holds branch "
-                    f"index {int(col.min())}..{int(col.max())} outside "
-                    f"[0, {n_branches})")
-            return [np.nonzero(col == b)[0] for b in range(n_branches)]
-        sel: List[list] = [[] for _ in range(n_branches)]
+    def _routing_column(self, batch: BatchTPU) -> np.ndarray:
+        col = np.asarray(batch.fields[self._field])[:batch.size]
         if self.stats is not None:
-            self.stats.device_bytes_d2h += batch.nbytes()
-        from ..runtime.emitters import check_branch_index
-        for i, (payload, _ts) in enumerate(batch.to_rows()):
-            s = logic(payload)
-            if s is None:
-                continue
-            if isinstance(s, int):
-                sel[check_branch_index(s, n_branches)].append(i)
-            else:
-                for b in s:
-                    sel[check_branch_index(b, n_branches)].append(i)
-        return [np.asarray(ix, dtype=np.int64) for ix in sel]
+            self.stats.device_bytes_d2h += int(col.nbytes)
+        return col
+
+    def _out_of_range(self, what: str, lo: int, hi: int, bound: int):
+        op = self.stats.op_name if self.stats is not None else "?"
+        return WindFlowError(
+            f"split after {op!r}: field {self._field!r} holds {what} "
+            f"{lo}..{hi} outside [0, {bound})")
+
+    def _branch_rows(self, batch: BatchTPU) -> List[Optional[np.ndarray]]:
+        """Row indices per branch (host-side routing decision); None for
+        a branch that takes every row."""
+        n_branches, n = len(self.inner), batch.size
+        logic = self.splitting_logic
+        if isinstance(logic, SplitMask):
+            col = self._routing_column(batch)
+            if col.dtype.kind not in "iu":
+                raise WindFlowError(
+                    f"split mask {self._field!r} is of dtype {col.dtype}, "
+                    "not an integer bitmask")
+            if not col.size:
+                return [np.zeros(0, np.int64)] * n_branches
+            lo, hi = int(col.min()), int(col.max())
+            if lo < 0 or hi >= 1 << n_branches:
+                raise self._out_of_range("branch mask", lo, hi,
+                                         1 << n_branches)
+            some = int(np.bitwise_or.reduce(col))
+            every = int(np.bitwise_and.reduce(col))
+            return [None if every >> b & 1 else
+                    np.nonzero(col & (1 << b))[0] if some >> b & 1 else
+                    np.zeros(0, np.int64) for b in range(n_branches)]
+        if isinstance(logic, str):
+            col = self._routing_column(batch)
+            if col.size and (col.min() < 0 or col.max() >= n_branches):
+                raise self._out_of_range("branch index", int(col.min()),
+                                         int(col.max()), n_branches)
+            sel = [np.nonzero(col == b)[0] for b in range(n_branches)]
+        else:
+            rows: List[list] = [[] for _ in range(n_branches)]
+            if self.stats is not None:
+                self.stats.device_bytes_d2h += batch.nbytes()
+            from ..runtime.emitters import check_branch_index
+            for i, (payload, _ts) in enumerate(batch.to_rows()):
+                s = logic(payload)
+                if s is None:
+                    continue
+                if isinstance(s, int):
+                    rows[check_branch_index(s, n_branches)].append(i)
+                else:
+                    for b in s:
+                        rows[check_branch_index(b, n_branches)].append(i)
+            sel = [np.asarray(ix, dtype=np.int64) for ix in rows]
+        return [None if n and idx.size == n else idx for idx in sel]
 
     def _pipe_process(self, batch: BatchTPU) -> None:
         per_branch = self._branch_rows(batch)
         part = [b for b, idx in enumerate(per_branch)
-                if 0 < idx.size < batch.size]
+                if idx is not None and idx.size]
         gathered = dict(zip(part, gather_sub_batches(
             batch, [per_branch[b] for b in part])))
+        st = self.stats
         for b, idx in enumerate(per_branch):
-            if idx.size == 0:
+            if idx is None:
+                # every row selected this branch: no gather needed (device
+                # arrays are immutable; copy only the metadata wrapper)
+                sub = batch.copy_for_dest()
+                if st is not None:
+                    st.split_whole_batches += 1
+            elif idx.size == 0:
                 # nothing of this batch for the branch, but its watermark:
                 # a stage that aligns on this branch (a join after a
                 # merge) must not wait for the next generated punctuation
                 self.inner[b].propagate_punctuation(batch.wm)
                 continue
-            if idx.size == batch.size:
-                # every row selected this branch: no gather needed (device
-                # arrays are immutable; copy only the metadata wrapper)
-                sub = batch.copy_for_dest()
             else:
                 sub = gathered[b]
+                if st is not None:
+                    st.split_gathered_batches += 1
             self.inner[b].emit_device_batch(sub)
 
     def emit_device_batch(self, batch: BatchTPU) -> None:
-        logic = self.splitting_logic
-        if isinstance(logic, str):
-            async_host_copy(batch.fields[logic])
+        if self._field is not None:
+            async_host_copy(batch.fields[self._field])
         else:
             batch.prefetch_host()  # callable logic reads every column
         self._pipe_add(batch)

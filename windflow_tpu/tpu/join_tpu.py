@@ -23,7 +23,13 @@ purge line, and doubles the ring BEFORE a batch would meet a slot still
 in use (``Join_archive_growths``): it never reads the device to decide.
 What this avoids was measured on the chip: compacting a 131,072-row
 archive of twelve columns is 13.6 ms of gathers a step, a column at a
-time; one packed gather of 16,384 lanes is 0.2 ms.
+time; one packed gather of 16,384 lanes is 0.2 ms. A ring starts with
+``RING_SLOTS`` slots, or, where its input was given a capacity
+(``with_archive_capacity``), with the slots that hold that many rows,
+allocated once at the input's first batch: a ring sized for the
+deployment does not double inside a run, and one that must still does
+and is counted. ``Join_archive_capacity_rows`` is the rows both rings
+have room for.
 
 **One step a batch** of either input, one program (``jit_join_<op>``):
 
@@ -43,11 +49,23 @@ time; one packed gather of 16,384 lanes is 0.2 ms.
   two packed gathers, ``join_func``, the stamps;
 - *insert* (``SCOPE_JOIN_INSERT``): the batch into its own ring.
 
+The step is DONATED its own archive and returns it: the insert writes in
+place, and so does the shift of the archive's times the few times the
+base has moved. The other archive is read and never returned: each
+archive keeps its times against a base of its own (``abase``), moved by
+its own side's steps, and a step shifts the other's times to the current
+base as it reads them. So no step copies a ring.
+
 A batch's pairs may outnumber that first batch (any fan-out: the full
 product of a key's rows inside the interval): the total is one scalar
 read back in the commit's FINISH half (``runtime/dispatch.py``: one
 launch later, a copy that has landed), and what lies past it is gathered
-by ``jit_join_more_<op>``, a batch a call, from the same ranks.
+by ``jit_join_more_<op>``, a batch a call, from the same ranks and the
+other archive. The replica's next launch is donated that archive where
+it is of the other side: before it, the pending step's count is read
+(where the device keeps up, a copy that has landed) and, only where its
+pairs pass one output batch, the further batches are gathered then
+(``_resolve``); its finish emits them.
 
 What is the host's, per BATCH and never per row, key or pair (the
 ``join`` stage inside ``wf:prep``): the batch's event times as int32
@@ -74,9 +92,15 @@ each pair is still found once, by the later of its two steps.
 
 Event time on the device is int32 offsets from ``base``. After a step
 every live row lies at or above ``wm - max(lower, upper)``, so the base
-moves there whenever the newest offset passes ``2**28`` and the step
-shifts what it holds. A stream whose watermark stays more than ``2**29``
-µs behind its newest event cannot be held and is refused by name.
+moves there whenever the newest offset passes ``2**28``, and each archive
+follows at its own side's next step. A stream whose watermark stays more
+than ``2**29`` µs behind its newest event cannot be held and is refused
+by name.
+
+**A window's rows.** A row whose ``valid`` column is a False boolean (the
+row of an empty window, as ``Ffat_Windows_TPU`` fires one) is not there:
+it neither probes nor is archived. So a window operator feeds either
+input as it stands.
 """
 
 from __future__ import annotations
@@ -108,23 +132,28 @@ SCOPE_JOIN_PURGE, SCOPE_JOIN_PROBE, SCOPE_JOIN_INSERT = (
 # written behind its purge line, an empty slot) has time T_DEAD
 T_LIM, T_MOVE, T_BOUND, T_DEAD = 1 << 29, 1 << 28, 1 << 27, -(1 << 30)
 _I32_MAX = (1 << 31) - 1
-# the step's scalars ride in front of the batch's time offsets: rows,
-# base shift, whether the archives outlive the shift, purge lines of A
-# and of B, the column of its own archive the batch is written at
+# the step's scalars ride in front of the batch's time offsets: rows, the
+# shift of its own archive's times to the base and whether they outlive
+# it, purge lines of A and of B, the column of its own archive the batch
+# is written at, and the other archive's shift and whether its times
+# outlive that
 N_PARAMS = 8
-_P_ROWS, _P_SHIFT, _P_KEEP, _P_CUT_A, _P_CUT_B, _P_AT = range(6)
+(_P_ROWS, _P_SHIFT, _P_KEEP, _P_CUT_A, _P_CUT_B, _P_AT, _P_SHIFT_O,
+ _P_KEEP_O) = range(N_PARAMS)
 # what a step reports, in front of its pairs' stamps: pairs, live rows of
 # A and of B after it
 _M_PAIRS, _M_LIVE_A, _M_LIVE_B, N_META = range(4)
-# a ring starts with this many slots and doubles when full. What the
-# aligned watermark has not passed is a few batches by nature, but how many
-# is the other input's lag: under backpressure, what the slower input's
-# path can hold more than the faster one's (a stage more between a split
-# and the join, at 16 a channel: 20 batches where every channel is full
-# and both inputs share the join's own evenly, up to ~55 where the faster
-# input's path has run empty; one run in thirty passed 32, measured). A
-# doubling recompiles the step, so the start is generous; the price is a
-# probe that compares with more dead rows
+# a ring given no capacity starts with this many slots and doubles when
+# full. What the aligned watermark has not passed is a few batches by
+# nature, but how many is the other input's lag: under backpressure, what
+# the slower input's path can hold more than the faster one's (a stage
+# more between a split and the join, at 16 a channel: 20 batches where
+# every channel is full and both inputs share the join's own evenly, up
+# to ~55 where the faster input's path has run empty; one run in thirty
+# passed 32, measured). A doubling recompiles the step, so the start is
+# generous; the price is a probe that compares with more dead rows. A
+# capacity given for an input (``with_archive_capacity``: the span of
+# stream a deployment holds) takes the start's place
 RING_SLOTS = 64
 # A batches that may wait for input B to pass them (module doc)
 HOLD_MAX = 2
@@ -144,7 +173,8 @@ class Interval_Join_TPU(TPUOperatorBase):
                  lower_bound: int, upper_bound: int,
                  name: str = "interval_join_tpu", parallelism: int = 1,
                  schemas: Tuple[Optional[TupleSchema],
-                                Optional[TupleSchema]] = (None, None)
+                                Optional[TupleSchema]] = (None, None),
+                 capacity: Tuple[Optional[int], Optional[int]] = (None, None)
                  ) -> None:
         if not isinstance(key_field, str):
             raise WindFlowError(
@@ -159,6 +189,11 @@ class Interval_Join_TPU(TPUOperatorBase):
                 f"{name}: boundaries must lie in [0, 2**27) microseconds "
                 f"(got {lower_bound}, {upper_bound}): event time on the "
                 "device is an int32 offset")
+        capacity = tuple(None if c is None else int(c) for c in capacity)
+        if any(c is not None and c <= 0 for c in capacity):
+            raise WindFlowError(
+                f"{name}: an archive's capacity is a number of rows > 0 "
+                f"(got {capacity})")
         # the two inputs' schemas are the operator's own: ``schema`` (the
         # one a one-input stage declares) stays None, so each staging
         # edge infers its side's from its first payload
@@ -167,6 +202,9 @@ class Interval_Join_TPU(TPUOperatorBase):
         self.join_func = join_func
         self.lower_bound, self.upper_bound = lower_bound, upper_bound
         self.schemas = tuple(schemas)
+        # rows a replica's archive of each input is allocated for at its
+        # first batch (None: RING_SLOTS slots)
+        self.capacity = capacity
         # which inputs arrive staged from the host (packed), by side: the
         # graph's wiring says (``PipeGraph._wire_edge``)
         self.staged_sides = [False, False]
@@ -207,10 +245,19 @@ def _from_i32(row, dtype):
     return jax.lax.bitcast_convert_type(row, dt)
 
 
-def _live(mat, cutoff):
-    """The rows of an archive (or of a batch with its times) that are
+def _live(t, cutoff):
+    """The rows of an archive (or of a batch) with times ``t`` that are
     there: at or above the purge line, and not dead."""
-    return (mat[-1] >= cutoff) & (mat[-1] > T_DEAD)
+    return (t >= cutoff) & (t > T_DEAD)
+
+
+def _shifted(t, shift, keep):
+    """An archive's times ``t`` against a base ``shift`` later, where
+    they outlive it (``keep``; else every row is dead): a row the shift
+    takes past ``T_DEAD`` is dead too."""
+    import jax.numpy as jnp
+
+    return jnp.where(keep > 0, jnp.maximum(t - shift, T_DEAD), T_DEAD)
 
 
 def _match(key_l, lo, hi, valid_l, key_s, ts_s, valid_s):
@@ -241,12 +288,12 @@ def _match(key_l, lo, hi, valid_l, key_s, ts_s, valid_s):
     return start, jnp.cumsum(counts, dtype=i32), by_rank
 
 
-def _pairs(larger, smaller, start, cum, by_rank, first_lane, n_lanes: int):
+def _pairs(start, cum, by_rank, first_lane, n_lanes: int):
     """Output lanes ``[first_lane, first_lane + n_lanes)`` of the step's
-    pairs, as the packed rows of both sides: lane ``j`` is match ``j -
-    (cum[r] - count[r])`` of the larger side's row ``r`` whose running
-    count first passes ``j``. Lanes past the total hold a copy of some
-    pair; the batch's size says how many count."""
+    pairs, as ``(row of the larger side, row of the smaller)``: lane ``j``
+    is match ``j - (cum[r] - count[r])`` of the larger side's row ``r``
+    whose running count first passes ``j``. Lanes past the total hold
+    some pair; the batch's size says how many count."""
     import jax.numpy as jnp
 
     i32 = jnp.int32
@@ -271,8 +318,7 @@ def _pairs(larger, smaller, start, cum, by_rank, first_lane, n_lanes: int):
         stretch * side + jnp.sum(grid[stretch] <= lanes[:, None], axis=1,
                                  dtype=i32), n - 1)
     rank = start[row] + lanes - before[row]
-    other = by_rank[jnp.clip(rank, 0, by_rank.shape[0] - 1)]
-    return larger[:, row], smaller[:, other]
+    return row, by_rank[jnp.clip(rank, 0, by_rank.shape[0] - 1)]
 
 
 class IntervalJoinTPUReplica(TPUReplicaBase):
@@ -294,7 +340,13 @@ class IntervalJoinTPUReplica(TPUReplicaBase):
         self.head = [0, 0]
         self.held = (deque(), deque())
         self.live = [0, 0]      # rows there, by the last step read back
-        self.base: Optional[int] = None     # event time of offset 0
+        # event time of offset 0 for the batch being prepared, and of each
+        # archive's times (its own side's last step moved them there)
+        self.base: Optional[int] = None
+        self.abase: List[Optional[int]] = [None, None]
+        # the last step launched whose further output batches may still
+        # have to be gathered from the archive it probed (``_resolve``)
+        self._pend: Optional[dict] = None
         # pairs leave in batches of the widest input batch seen (either
         # side's: the later arrival delivers, and a small batch of one
         # side may meet every row of a large one of the other)
@@ -311,7 +363,8 @@ class IntervalJoinTPUReplica(TPUReplicaBase):
     # -- archives ----------------------------------------------------------
     def _fit(self, side: int, rows: int) -> None:
         """``side``'s ring with a free slot of at least ``rows`` rows:
-        made on the input's first batch, doubled when every slot is in
+        made on the input's first batch (``RING_SLOTS`` slots, or those
+        that hold the input's capacity), doubled when every slot is in
         use, laid out anew for a wider batch. The commits in flight
         reassign the archive, so they land first."""
         import jax.numpy as jnp
@@ -326,11 +379,13 @@ class IntervalJoinTPUReplica(TPUReplicaBase):
             raise WindFlowError(
                 f"{self.op.name}: input {'AB'[side]} has no integer column "
                 f"{key!r} to join by (columns: {sorted(cols)})")
-        new_slots = max(RING_SLOTS, slots)
+        new_width = max(rows, width)
+        cap = self.op.capacity[side]
+        new_slots = max(RING_SLOTS if cap is None
+                        else max(2, -(-cap // new_width)), slots)
         while used >= new_slots:
             new_slots *= 2
             self.stats.join_archive_growths += 1
-        new_width = max(rows, width)
         mat = jnp.full((len(cols) + 1, new_slots, new_width), T_DEAD,
                        jnp.int32)
         if used:
@@ -339,40 +394,50 @@ class IntervalJoinTPUReplica(TPUReplicaBase):
             tail = (self.head[side] - used) % slots
             mat = mat.at[:, :used, :width].set(
                 jnp.roll(old, -tail, axis=1)[:, :used])
+        if self.arch[side] is None:
+            self.abase[side] = self.base     # nothing in it is alive
         self.arch[side] = mat.reshape(len(cols) + 1, -1)
         self.ring[side], self.head[side] = (new_slots, new_width), used
+        self.stats.join_archive_capacity_rows = sum(
+            n * w for n, w in self.ring)
 
     # -- programs ----------------------------------------------------------
     def _unpack(self, side: int, mat) -> Dict[str, Any]:
         return {k: _from_i32(mat[i], dt)
                 for i, (k, dt) in enumerate(self.cols[side].items())}
 
-    def _emit_pairs(self, side: int, x, other, ranks, first_lane, lanes):
+    def _emit_pairs(self, side: int, x, other, other_t, ranks, first_lane,
+                    lanes):
         """``(out_fields, out_ts)`` of ``lanes`` output lanes from
         ``first_lane`` on: the larger of batch ``x`` and archive ``other``
-        leads (``_match``), each side's packed rows are gathered once."""
+        (its times ``other_t`` against the base) leads (``_match``), each
+        side's packed rows are gathered once."""
         import jax.numpy as jnp
 
         batch_leads = x.shape[1] >= other.shape[1]
-        led, follows = _pairs(*((x, other) if batch_leads else (other, x)),
-                              *ranks, first_lane, lanes)
-        mine, theirs = (led, follows) if batch_leads else (follows, led)
+        led, follows = _pairs(*ranks, first_lane, lanes)
+        at_x, at_o = (led, follows) if batch_leads else (follows, led)
+        # the whole archive, its times too (replaced below): a gather of
+        # its columns alone lays that slice out anew first, a row's words
+        # along 128 lanes (8.6 GB for 16.8M rows of seven words)
+        mine, theirs = x[:, at_x], other[:, at_o]
         fields = (self._unpack(side, mine), self._unpack(1 - side, theirs))
         out = self.op.join_func(*(fields if side == 0 else fields[::-1]))
         if not isinstance(out, dict):
             raise WindFlowError(f"{self.op.name}: the join function must "
                                 "return a dict of columns")
-        return out, jnp.maximum(mine[-1], theirs[-1])
+        return out, jnp.maximum(mine[-1], other_t[at_o])
 
     def _step(self, side: int, probe: bool) -> Callable:
         """The step of a batch of ``side``: ``(fields, tsp, own, other)
-        -> (own, other, x, out_fields, back, ranks)``, ``back`` its three
-        counts and then the first output batch's stamps, ``x`` the
-        batch packed as an archive's rows, as wide as its ring's slots
-        (a batch a filter left mostly empty is probed and archived at its
-        size's bucket, not its capacity's). Without ``probe`` (the other
-        input's columns are not known yet: it has sent nothing and
-        declared nothing) it archives only."""
+        -> (own, x, out_fields, back, ranks)``, ``own`` DONATED and
+        returned with the batch written in, ``other`` read only,
+        ``back`` the three counts and then the first output batch's
+        stamps, ``x`` the batch packed as an archive's rows, as wide as
+        its ring's slots (a batch a filter left mostly empty is probed and
+        archived at its size's bucket, not its capacity's). Without
+        ``probe`` (the other input's columns are not known yet: it has
+        sent nothing and declared nothing) it archives only."""
         lanes, width = self.out_rows, self.ring[side][1]
         sig = (side, probe, lanes, width)
         prog = self._steps.get(sig)
@@ -386,52 +451,61 @@ class IntervalJoinTPUReplica(TPUReplicaBase):
         k_own = names.index(op.key_field)
         k_other = list(self.cols[1 - side]).index(op.key_field) \
             if probe else 0
+        # a window's rows: a False ``valid`` is an empty window's row
+        k_valid = names.index("valid") \
+            if self.cols[side].get("valid") == np.dtype(bool) else None
         bounds = (op.lower_bound, op.upper_bound)
         cut_own, cut_other = ((_P_CUT_A, _P_CUT_B) if side == 0
                               else (_P_CUT_B, _P_CUT_A))
 
         def rebase(mat, tsp):
-            t = jnp.where(tsp[_P_KEEP] > 0,
-                          jnp.maximum(mat[-1] - tsp[_P_SHIFT], T_DEAD),
-                          T_DEAD)
-            return mat.at[-1].set(t)
+            return mat.at[-1].set(_shifted(mat[-1], tsp[_P_SHIFT],
+                                           tsp[_P_KEEP]))
 
         def step(fields, tsp, own, other):
             ts_in = tsp[N_PARAMS:][:width]
             there = jnp.arange(ts_in.shape[0], dtype=jnp.int32) < tsp[_P_ROWS]
             x = jnp.stack([_to_i32(fields[k])[:width] for k in names]
                           + [ts_in])
+            if k_valid is not None:
+                there = there & (x[k_valid] != 0)
             with jax.named_scope(SCOPE_JOIN_PURGE):
-                own = rebase(own, tsp)
-                other = rebase(other, tsp) if probe else None
+                # the archive's times follow the base only where it moved
+                # since this side's last step: a pass over its time row
+                # the few times it has
+                own = jax.lax.cond(
+                    (tsp[_P_SHIFT] != 0) | (tsp[_P_KEEP] == 0),
+                    rebase, lambda mat, _: mat, own, tsp)
+                other_t = _shifted(other[-1], tsp[_P_SHIFT_O],
+                                   tsp[_P_KEEP_O]) if probe else None
             out = out_ts = ranks = None
             pairs = jnp.zeros((), jnp.int32)
             if probe:
                 with jax.named_scope(SCOPE_JOIN_PROBE):
-                    live = _live(other, tsp[cut_other])
+                    live = _live(other_t, tsp[cut_other])
                     if x.shape[1] >= other.shape[1]:
                         # an A row takes B from [ts - lower, ts + upper],
                         # a B row takes A from [ts - upper, ts + lower]
                         before, after = bounds[::1 if side == 0 else -1]
                         ranks = _match(x[k_own], ts_in - before,
                                        ts_in + after, there, other[k_other],
-                                       other[-1], live)
+                                       other_t, live)
                     else:
                         # the archive leads: its rows are of the OTHER side
                         before, after = bounds[::-1 if side == 0 else 1]
-                        ranks = _match(other[k_other], other[-1] - before,
-                                       other[-1] + after, live, x[k_own],
+                        ranks = _match(other[k_other], other_t - before,
+                                       other_t + after, live, x[k_own],
                                        ts_in, there)
                     pairs = ranks[1][-1]
-                    out, out_ts = self._emit_pairs(side, x, other, ranks, 0,
-                                                   lanes)
+                    out, out_ts = self._emit_pairs(side, x, other, other_t,
+                                                   ranks, 0, lanes)
             with jax.named_scope(SCOPE_JOIN_INSERT):
                 # a row behind its own purge line is written dead
                 block = x.at[-1].set(jnp.where(
                     there & (ts_in >= tsp[cut_own]), ts_in, T_DEAD))
                 own = jax.lax.dynamic_update_slice(own, block,
                                                    (0, tsp[_P_AT]))
-            n_own = jnp.sum(_live(own, tsp[cut_own]), dtype=jnp.int32)
+            n_own = jnp.sum(_live(own[-1], tsp[cut_own]), dtype=jnp.int32)
             n_other = jnp.sum(live, dtype=jnp.int32) if probe else 0
             meta = jnp.stack([pairs, *((n_own, n_other) if side == 0
                                        else (n_other, n_own))])
@@ -440,20 +514,23 @@ class IntervalJoinTPUReplica(TPUReplicaBase):
             back = meta.astype(jnp.int32)
             if probe:
                 back = jnp.concatenate([back, out_ts])
-            return own, other, x, out, back, ranks
+            return own, x, out, back, ranks
 
         prog = self._steps[sig] = instrumented_jit(
             step, self.stats, label=op.name,
-            program=program_name(_PROG_JOIN, op.name))
+            program=program_name(_PROG_JOIN, op.name), donate_argnums=(2,))
         return prog
 
     def _more_pairs(self, side: int, lanes: int) -> Callable:
         """Output batch ``chunk`` (from 1) of a step whose pairs
-        outnumber its first."""
+        outnumber its first: ``(x, other, shift, ranks, chunk) ->
+        (out_fields, out_ts)``, ``shift`` the step's shift of the other
+        archive's times and whether they outlive it."""
         prog = self._more.get((side, lanes))
         if prog is None:
-            def more(x, other, ranks, chunk):
-                return self._emit_pairs(side, x, other, ranks,
+            def more(x, other, shift, ranks, chunk):
+                other_t = _shifted(other[-1], shift[0], shift[1])
+                return self._emit_pairs(side, x, other, other_t, ranks,
                                         chunk * lanes, lanes)
 
             prog = self._more[(side, lanes)] = instrumented_jit(
@@ -475,9 +552,7 @@ class IntervalJoinTPUReplica(TPUReplicaBase):
         horizon = wm - span      # after this step no live row lies below
         if self.base is None:
             self.base = max(horizon, lo_ts - span)
-        shift = 0
         if hi_ts - self.base > T_MOVE and horizon > self.base:
-            shift = horizon - self.base
             self.base = horizon
         if hi_ts - self.base > T_LIM:
             raise WindFlowError(
@@ -510,6 +585,10 @@ class IntervalJoinTPUReplica(TPUReplicaBase):
                 held.popleft()
         self._fit(side, bucket_capacity(n))
         slots, width = self.ring[side]
+        # how far each archive's times lie behind the base: its own this
+        # step shifts, the other's it reads shifted
+        lag = [0 if a is None else self.base - a for a in self.abase]
+        self.abase[side] = self.base
         tsp = np.empty(N_PARAMS + batch.capacity, np.int32)
         if lo_ts - self.base >= -T_LIM:
             # every row's offset is an int32 as it stands (what lies past
@@ -523,8 +602,12 @@ class IntervalJoinTPUReplica(TPUReplicaBase):
                     out=tsp[N_PARAMS:], casting="unsafe")
         tsp[:N_PARAMS] = 0
         tsp[_P_ROWS] = n
-        tsp[_P_SHIFT] = min(shift, T_LIM)
-        tsp[_P_KEEP] = shift <= T_LIM   # else all of both lies behind
+        # an archive whose times lie further behind than any live offset
+        # reaches holds nothing alive
+        tsp[_P_SHIFT], tsp[_P_SHIFT_O] = (min(lag[s], T_LIM)
+                                          for s in (side, 1 - side))
+        tsp[_P_KEEP], tsp[_P_KEEP_O] = (lag[s] <= T_LIM
+                                        for s in (side, 1 - side))
         tsp[_P_CUT_A], tsp[_P_CUT_B] = (
             max(-_I32_MAX, min(_I32_MAX, c - self.base)) for c in cut)
         tsp[_P_AT] = self.head[side] * width
@@ -596,30 +679,61 @@ class IntervalJoinTPUReplica(TPUReplicaBase):
         if probe and self.arch[1 - side] is None:
             # declared, and silent so far: an empty ring
             self._fit(1 - side, bucket_capacity(batch.size))
+        if probe:
+            # the probe compares with every row of the other ring
+            slots, width = self.ring[1 - side]
+            self.stats.join_probed_rows += slots * width
         self.out_rows = max(self.out_rows, batch.capacity)
         prog = self._step(side, probe)
         base, cap = self.base, self.out_rows
 
         @split_commit
         def commit() -> Callable[[], None]:
-            own, other, x, out, back, ranks = prog(
-                batch.fields, tsp, self.arch[side],
-                self.arch[1 - side] if probe else None)
+            pend = self._pend
+            if pend is not None and pend["other"] is self.arch[side]:
+                # this launch is donated what the pending step's further
+                # output batches would gather from
+                self._resolve(pend, batch.bid)
+            other = self.arch[1 - side] if probe else None
+            own, x, out, back, ranks = prog(batch.fields, tsp,
+                                            self.arch[side], other)
             self.stats.device_programs_run += 1
             self.arch[side] = own
-            if probe:
-                self.arch[1 - side] = other
+            hold = self._pend = None if not probe else {
+                "side": side, "cap": cap, "other": other, "x": x,
+                "shift": tsp[_P_SHIFT_O:_P_KEEP_O + 1], "ranks": ranks,
+                "back": back, "more": None}
             # the finish reads the step's counts and the pairs' times
             async_host_copy(back)
             return lambda: self._finish(batch, side, archived, base, cap,
-                                        x, other, out, back, ranks)
+                                        out, back, hold)
 
         return commit
 
+    def _resolve(self, hold: dict, bid: int) -> None:
+        """Read the pending step's pair count now, and gather its further
+        output batches where it has any, before its other archive goes to
+        the next step."""
+        with self._st_readback(bid):
+            pairs = int(np.asarray(hold["back"])[_M_PAIRS])
+        hold["more"] = [self._more_of(hold, chunk)
+                        for chunk in range(1, -(-pairs // hold["cap"]))]
+        hold["other"] = None
+
+    def _more_of(self, hold: dict, chunk: int):
+        """``(out_fields, out_ts)`` of output batch ``chunk`` of the step
+        ``hold`` keeps, launched."""
+        self.stats.device_programs_run += 1
+        return self._more_pairs(hold["side"], hold["cap"])(
+            hold["x"], hold["other"], hold["shift"], hold["ranks"], chunk)
+
     def _finish(self, batch: BatchTPU, side: int, archived: int, base: int,
-                cap: int, x, other, out, back, ranks) -> None:
+                cap: int, out, back, hold: Optional[dict]) -> None:
         """The step's readback: its counts, then one output batch for
-        every ``cap`` pairs (the first is the step's own)."""
+        every ``cap`` pairs (the first is the step's own, the others
+        gathered now or, where ``_resolve`` ran, then)."""
+        if self._pend is hold:
+            self._pend = None
         st = self.stats
         with self._st_readback(batch.bid):
             meta = np.asarray(back)
@@ -633,9 +747,9 @@ class IntervalJoinTPUReplica(TPUReplicaBase):
         self.live = live
         for chunk in range(-(-pairs // cap)):
             if chunk:
-                out, out_ts = self._more_pairs(side, cap)(x, other, ranks,
-                                                          chunk)
-                st.device_programs_run += 1
+                out, out_ts = hold["more"][chunk - 1] \
+                    if hold["more"] is not None \
+                    else self._more_of(hold, chunk)
                 with self._st_readback(batch.bid):
                     ts0 = np.asarray(out_ts)
             if self._out_schema is None:
@@ -678,14 +792,15 @@ class IntervalJoinTPUReplica(TPUReplicaBase):
             self._fit(side, max(caps))
         for cap in caps:
             tsp = np.zeros(N_PARAMS + cap, np.int32)
-            tsp[_P_KEEP] = 1
+            tsp[_P_KEEP] = tsp[_P_KEEP_O] = 1
             self.out_rows = max(self.out_rows, cap)
             for side in (0, 1):
-                _, other, x, _, _, ranks = self._step(side, True)(
+                self.arch[side], x, _, _, ranks = self._step(side, True)(
                     prewarm_zero_fields(self.op, cap, side), tsp,
                     self.arch[side], self.arch[1 - side])
                 jax.block_until_ready(self._more_pairs(side, self.out_rows)(
-                    x, other, ranks, 1))
+                    x, self.arch[1 - side], tsp[_P_SHIFT_O:_P_KEEP_O + 1],
+                    ranks, 1))
         return 4 * len(caps)
 
     # -- checkpointing -----------------------------------------------------
@@ -698,6 +813,7 @@ class IntervalJoinTPUReplica(TPUReplicaBase):
                       "ring": list(self.ring), "head": list(self.head),
                       "held": [list(h) for h in self.held],
                       "live": list(self.live), "base": self.base,
+                      "abase": list(self.abase),
                       "b_seen": self._b_seen}
         return st
 
@@ -713,4 +829,7 @@ class IntervalJoinTPUReplica(TPUReplicaBase):
         self.cols, self.ring, self.head = j["cols"], j["ring"], j["head"]
         self.held = tuple(deque(h) for h in j["held"])
         self.live, self.base = j["live"], j["base"]
+        self.abase = list(j["abase"])
+        self.stats.join_archive_capacity_rows = sum(
+            n * w for n, w in self.ring)
         self._b_seen = j.get("b_seen")
