@@ -43,7 +43,7 @@ def parent_numbering(count, next_fire, max_leaf, slots, K_cap, F):
 def parent_lanes(chunks, W, F, win_units, slide_units, K_cap):
     """The parent's ``_lanes`` + ``_pack_plan`` of a count-based plan:
     the six fire rows at width ``W`` and the set of evicted leaves, as
-    flat indices of the node-major (2F, K_cap) forest."""
+    (node, slot) pairs of the node-major (2F, K_cap) forest."""
     c_slots, c_start0, c_k, c_wid0, c_ml = chunks
     tot = int(c_k.sum())
     rnd = np.arange(tot) - np.repeat(np.cumsum(c_k) - c_k, c_k)
@@ -60,7 +60,7 @@ def parent_lanes(chunks, W, F, win_units, slide_units, K_cap):
                     - c_start0)
     ep = (np.repeat(c_start0, ne) + np.arange(int(ne.sum()))
           - np.repeat(np.cumsum(ne) - ne, ne))
-    evicted = set(((F + ep % F) * K_cap + np.repeat(c_slots, ne)).tolist())
+    evicted = set(zip((F + ep % F).tolist(), np.repeat(c_slots, ne).tolist()))
     return fire, evicted
 
 
@@ -331,11 +331,13 @@ def test_lanes_expanded_from_chunk_rows_equal_the_parents_lanes(
     for name, g, want in zip(
             ("slot", "start", "len", "wid", "mask", "round"), got, fire):
         assert (g == want).all(), name
-    eflat = got[6]
-    assert eflat.size == W * slide
-    live = eflat[eflat < rep.K_cap * 2 * F]
-    assert len(set(live.tolist())) == live.size
-    assert set(live.tolist()) == evicted
+    evict = got[6]
+    assert evict.shape == (2, W * slide)
+    live = evict[:, evict[0] < 2 * F]
+    assert (live[0] >= F).all() and (live[1] < rep.K_cap).all()
+    pairs = set(zip(*live.tolist()))
+    assert len(pairs) == live.shape[1]
+    assert pairs == evicted
     if partial and slide <= win:
         assert (fire[2][fire[4] == 1] < win).any()    # clipped by the data
 

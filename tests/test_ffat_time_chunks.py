@@ -39,7 +39,8 @@ def parent_ranges(rep, starts, lens):
 def parent_plan(rep, chunks, W, lanes, ranges):
     """The parent's ``_pack_plan``, decoded: the six fire rows (slot,
     start, len, wid, mask, group) at width ``W``, the group table and
-    the set of evicted flat leaf indices (of the node-major forest)."""
+    the set of evicted leaves, (node, slot) pairs of the node-major
+    forest."""
     c_slots, c_start0, c_k, c_wid0, c_ml = chunks
     rnd, starts, lens = lanes
     n = rnd.size
@@ -60,8 +61,8 @@ def parent_plan(rep, chunks, W, lanes, ranges):
                     - c_start0)
     ep = (np.repeat(c_start0, ne) + np.arange(int(ne.sum()))
           - np.repeat(np.cumsum(ne) - ne, ne))
-    evicted = set(((rep.F + ep % rep.F) * rep.K_cap
-                   + np.repeat(c_slots, ne)).tolist())
+    evicted = set(zip((rep.F + ep % rep.F).tolist(),
+                      np.repeat(c_slots, ne).tolist()))
     return (fire, groups, evicted), int(groups[G_CAP, 0])
 
 
@@ -131,14 +132,15 @@ def expanded(rep, pack, W):
     out = jax.jit(plan_lanes, static_argnums=range(1, 8))(
         pack, W, rep.K_cap, rep.F, rep.win_units, rep.slide_units, True,
         rep._key_words())
-    slots, starts, lens, wids, mask, group, eflat, words = (
+    slots, starts, lens, wids, mask, group, evict, words = (
         np.asarray(a) for a in out)
     keys = np.asarray(jax.jit(join_key_words, static_argnums=1)(
         words, rep._key_dtype))
-    live = eflat[eflat < rep.K_cap * 2 * rep.F]
-    assert len(set(live.tolist())) == live.size
-    return np.stack([slots, starts, lens, wids, mask, group]), \
-        set(live.tolist()), keys
+    live = evict[:, evict[0] < 2 * rep.F]
+    assert (live[0] >= rep.F).all() and (live[1] < rep.K_cap).all()
+    pairs = set(zip(*live.tolist()))
+    assert len(pairs) == live.shape[1]
+    return np.stack([slots, starts, lens, wids, mask, group]), pairs, keys
 
 
 # ---------------------------------------------------------------------------

@@ -160,6 +160,14 @@ REBUILD_W = 32
 # step's two dirty ring ranges, ``(start_phys, length)``: the panes
 # written and the panes evicted since the last rebuild
 RANGE_ROWS = slice(G_CAP + 1, G_CAP + 3)
+# leaf rows of the window through which a program writes the forest's
+# leaves where every leaf it writes lies in one (set_leaves): the TPU's
+# compiler lowers an element scatter into a tiled 2-D array as one into
+# its rank-1 row-major view, a copy of the whole array there and back,
+# so a scatter costs by the array it writes. 32 holds what an in-order
+# stream's batch writes, and what its fire evicts, with room to spare
+# (sg2: ~9 leaves of 4,096 each)
+LEAF_W = 32
 
 
 def fire_slides(W: int, K_cap: int, F: int) -> bool:
@@ -221,13 +229,14 @@ def plan_lanes(fire_plan, W: int, K_cap: int, F: int, win_units: int,
                slide_units: int, timed: bool, key_words: int):
     """In a program: the lanes of a plan, expanded from its chunk rows
     (``plan_views``) at the static width ``W``: ``(slots, starts, lens,
-    wids, mask, group, eflat, keys)``, a lane each but ``eflat``, the
-    flat forest indices of the ``W * slide_units`` leaves evicted (out
-    of bounds where there is none), and ``keys``, the ``(key_words, W)``
-    words of each lane's key. ``group`` is a time-based lane's row in
-    the group table (a count of the rows whose range sorts below its
-    own: at most ``G_CAP`` compares a lane) and a count-based lane's
-    round, its window's place in its slot's chunk. Lane ``i`` of chunk
+    wids, mask, group, evict, keys)``, a lane each but ``evict``, the
+    ``(2, W * slide_units)`` (node, slot) pairs of the node-major forest
+    of the leaves evicted (node ``2 F``, out of bounds, where there is
+    none), and ``keys``, the ``(key_words, W)`` words of each lane's
+    key. ``group`` is a time-based lane's row in the group table (a
+    count of the rows whose range sorts below its own: at most
+    ``G_CAP`` compares a lane) and a count-based lane's round, its
+    window's place in its slot's chunk. Lane ``i`` of chunk
     ``c``, round ``r = i - (windows of the chunks before c)``: start
     ``start0 + r * slide``, length ``min(win, span - r * slide)``,
     window id ``wid0 + r``; it evicts the ``slide`` leaves from its
@@ -267,11 +276,11 @@ def plan_lanes(fire_plan, W: int, K_cap: int, F: int, win_units: int,
     # chunk's data goes: over a chunk's rounds, the range [start0,
     # start0 + k * slide) clipped to the data
     e_off = off[:, None] + jnp.arange(slide_units, dtype=jnp.int32)[None, :]
-    eflat = jnp.where(
-        mask[:, None] & (e_off < c_span[:, None]),
-        (F + ((c_start0[:, None] + e_off) & (F - 1))) * K_cap
-        + c_slot[:, None], K_cap * 2 * F).reshape(-1)
-    return (c_slot, starts, lens, c_wid0 + rounds, mask, group, eflat,
+    e_node = jnp.where(mask[:, None] & (e_off < c_span[:, None]),
+                       F + ((c_start0[:, None] + e_off) & (F - 1)), 2 * F)
+    evict = jnp.stack([e_node.reshape(-1), jnp.broadcast_to(
+        c_slot[:, None], e_node.shape).reshape(-1)])
+    return (c_slot, starts, lens, c_wid0 + rounds, mask, group, evict,
             jnp.stack(c_key) if c_key else None)
 
 
@@ -321,6 +330,41 @@ def cb_number_rows(slots, keyrows, K_cap: int, F: int):
     return order, jnp.where(
         (ss < K_cap) & (rank >= skip),
         ss * F + ((base + rank) & (F - 1)), K_cap * F)
+
+
+def set_leaves(planes, values, node, slot, F: int):
+    """In a program: ``planes`` (node-major ``(2F, K_cap)`` arrays, a
+    pytree) with ``values`` (alike: one value an index, or a scalar)
+    written at ``(node, slot)``; node ``2F``, out of bounds, writes
+    nothing. Where every written node lies within ``LEAF_W`` leaf rows,
+    those rows are sliced out, written and put back in place; else (a
+    batch across the ring's end, count-based rings in different places)
+    the forest is written as a whole. The forest is indexed as it lies
+    either way: two indices, no rank-1 view of it in the program."""
+    import jax
+    import jax.numpy as jnp
+
+    R = min(LEAF_W, F)
+    live = node < 2 * F
+    lo = jnp.min(jnp.where(live, node, 2 * F))
+    hi = jnp.max(jnp.where(live, node, F))
+    j0 = jnp.clip(lo, F, 2 * F - R)
+
+    def window(planes):
+        r = node - j0   # a dropped node's row: 2F - j0 >= R, dropped
+
+        def one(x, v):
+            w = jax.lax.dynamic_slice_in_dim(x, j0, R)
+            return jax.lax.dynamic_update_slice_in_dim(
+                x, w.at[r, slot].set(v, mode="drop"), j0, 0)
+        return jax.tree_util.tree_map(one, planes, values)
+
+    def whole(planes):
+        return jax.tree_util.tree_map(
+            lambda x, v: x.at[node, slot].set(v, mode="drop"),
+            planes, values)
+
+    return jax.lax.cond(hi - lo < R, window, whole, planes)
 
 
 def _parents(combine: Callable, lc, rc, vlc, vrc):
@@ -610,9 +654,10 @@ class FfatTPUReplica(TPUReplicaBase):
         return M, (np.int16 if M < 2**15 - 1 else np.int32)
 
     def _check_index_plane(self, k_cap: int = 0, f: int = 0) -> None:
-        """Every forest index (packed composite, device scatter/evict
-        flat ids) lives in int32; enforced at init and BEFORE any growth
-        commits. ``k_cap``/``f`` check a PROSPECTIVE capacity/ring
+        """Every forest index (the packed composite; the element of a
+        field, which the compiler's rank-1 lowering of a scatter into the
+        forest counts) lives in int32; enforced at init and BEFORE any
+        growth commits. ``k_cap``/``f`` check a PROSPECTIVE capacity/ring
         before mutating toward it (growth must
         raise-before-mutate: a caught refusal mid-growth would leave a
         wrapped index plane that no later per-batch guard re-checks)."""
@@ -883,12 +928,10 @@ class FfatTPUReplica(TPUReplicaBase):
                 at_e(pv) & jnp.asarray(edge | split)[None, :],
                 tmap(at_e, pre))
             # 4. lane i picks (slot_i, round_i)
-            pick = slots * o.size + rounds
-            return tv.reshape(-1)[pick], tmap(lambda t: t.reshape(-1)[pick],
-                                              tr)
+            return tv[slots, rounds], tmap(lambda t: t[slots, rounds], tr)
 
         def fire_block(trees, tvalid, fire_plan):
-            slots, starts, lens, wids, mask, group, eflat, keys = \
+            slots, starts, lens, wids, mask, group, evict, keys = \
                 plan_lanes(fire_plan, W, K_cap, F, win_units, slide_units,
                            grouped, key_words)
             with jax.named_scope(SCOPE_FIRE):
@@ -908,10 +951,14 @@ class FfatTPUReplica(TPUReplicaBase):
                     qv, qr = by_lane(trees, tvalid, slots, starts, lens)
                 qv = qv & mask
             # evict leaves consumed by the fired windows (masked lanes:
-            # out of bounds)
+            # node 2F, out of bounds), in place: the barrier orders every
+            # query's read of the validity before the write, which
+            # nothing else does (the compiler copies the forest's
+            # validity where an unordered read might follow the write)
             with jax.named_scope(SCOPE_EVICT):
-                tvalid = tvalid.reshape(-1).at[eflat].set(
-                    False, mode="drop").reshape(tvalid.shape)
+                tvalid, qv, qr = jax.lax.optimization_barrier(
+                    (tvalid, qv, qr))
+                tvalid = set_leaves(tvalid, False, evict[0], evict[1], F)
             # output wid/key columns built ON DEVICE from the plan's
             # chunk rows: no device_put of their own at emit time
             key_out = (jnp.zeros((1,), jnp.int32) if keys is None
@@ -951,8 +998,6 @@ class FfatTPUReplica(TPUReplicaBase):
         combine = self.op.combine
         F = self.F
         K_cap = self.K_cap
-        NNODES = 2 * F
-        OOB = K_cap * NNODES  # scatter target for masked lanes (mode=drop)
 
         tmap = jax.tree_util.tree_map
         fire_block = self._query_fns(W)
@@ -987,9 +1032,12 @@ class FfatTPUReplica(TPUReplicaBase):
                 is_end = jnp.concatenate(
                     [sc[1:] != sc[:-1],
                      jnp.ones((1,), bool)]) & (sc < big)
-                # decode slot/leaf from the sorted composite (F is a
-                # power of two, so these lower to shift/mask)
-                flat_idx = (F + sc % F) * K_cap + sc // F
+                # a segment's end writes its leaf's node and slot,
+                # decoded from the sorted composite (F is a power of
+                # two: a mask and a shift); every other row node 2F, out
+                # of bounds, which the scatter drops
+                node = jnp.where(is_end, F + (sc & (F - 1)), 2 * F)
+                slot = sc >> (F.bit_length() - 1)
             svals = tmap(lambda a: a[order], vals)
 
             def seg_op(a, b):
@@ -1003,23 +1051,19 @@ class FfatTPUReplica(TPUReplicaBase):
                 scanned, _ = jax.lax.associative_scan(
                     seg_op, (svals, same_prev))
 
-            # 2. scatter-combine segment tails into forest leaves
+            # 2. scatter-combine segment tails into forest leaves, read
+            # and written by (node, slot) (the gathers clip a dropped
+            # row's node: it reads a leaf it never writes)
             with jax.named_scope(SCOPE_SCATTER):
-                safe_idx = jnp.where(is_end, flat_idx, OOB)
-                gather_idx = jnp.where(is_end, flat_idx, 0)
-                leaf_valid = tvalid.reshape(-1)[gather_idx] & is_end
-                cur_leaves = tmap(lambda t: t.reshape(-1)[gather_idx],
-                                  trees)
+                leaf_valid = tvalid.at[node, slot].get(mode="clip") & is_end
+                cur_leaves = tmap(
+                    lambda t: t.at[node, slot].get(mode="clip"), trees)
                 merged_all = combine(cur_leaves, scanned)
                 new_leaves = tmap(
                     lambda m, sv: jnp.where(leaf_valid, m, sv),
                     merged_all, scanned)
-                trees = tmap(
-                    lambda t, nl: t.reshape(-1).at[safe_idx].set(
-                        nl, mode="drop").reshape(t.shape),
-                    trees, new_leaves)
-                tvalid = tvalid.reshape(-1).at[safe_idx].set(
-                    True, mode="drop").reshape(tvalid.shape)
+                trees, tvalid = set_leaves((trees, tvalid),
+                                           (new_leaves, True), node, slot, F)
 
             if ingest_only:
                 # deferred rebuild: leaves are current, internal nodes
